@@ -19,8 +19,9 @@ import modal_tpu
 
 app = modal_tpu.App("example-serving")
 
-# real deployments: model="llama3-8b", tpu="v5e-8", checkpoint=<volume path>,
-# and SLO targets the scheduler scales replicas on
+# real deployments: model="llama3-8b" (or {"name": ..., **overrides}),
+# tpu="v5e-1" (the engine runs on one chip; replicas scale out),
+# checkpoint=<volume path>, and SLO targets the scheduler scales replicas on
 Service = modal_tpu.serving.llm_service(
     app,
     model="tiny",

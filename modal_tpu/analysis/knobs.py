@@ -31,10 +31,10 @@ from .core import (
 KNOB_RE = re.compile(r"MODAL_TPU_[A-Z0-9_]+")
 CATALOG_RELPATH = "analysis/knob_catalog.py"
 
-# knob families owned by out-of-package tooling (bench.py orchestration,
-# tools/relay_watcher.py): they never appear in modal_tpu/ and are not part
-# of the product configuration surface this catalog governs
-_EXTERNAL_PREFIXES = ("MODAL_TPU_BENCH_", "MODAL_TPU_WATCH_")
+# knob family owned by out-of-package tooling (bench.py orchestration): it
+# never appears in modal_tpu/ and is not part of the product configuration
+# surface this catalog governs
+_EXTERNAL_PREFIXES = ("MODAL_TPU_BENCH_",)
 
 
 def collect_knob_literals(modules: list[SourceModule]) -> dict[str, list[tuple[str, int]]]:

@@ -252,6 +252,8 @@ class _Client:
             sup: Any = ShardedSupervisor(num_shards=num_shards, num_workers=1, port=int(port_s))
         else:
             sup = LocalSupervisor(num_workers=1, port=int(port_s))
+        from .server.worker import TpuProbeError
+
         try:
             await sup.start()
         except Exception as exc:  # noqa: BLE001 — e.g. lost a port race
@@ -260,6 +262,11 @@ class _Client:
                 await sup.stop()  # release anything that did bind (port!)
             except Exception:  # noqa: BLE001
                 pass
+            if isinstance(exc, TpuProbeError):
+                # not a race to paper over: this host's chips cannot be
+                # inventoried, and a worker reporting zero would leave every
+                # tpu= function queued for ever
+                raise
             return server_url
         cls._local_supervisor = sup
         loop = asyncio.get_running_loop()

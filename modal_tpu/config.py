@@ -123,8 +123,15 @@ _SETTINGS: dict[str, _Setting] = {
     "worker_zone": _Setting(""),
     "worker_spot": _Setting(False, _to_boolean),
     "worker_instance_type": _Setting(""),
-    # jax persistent compilation cache for cold-start elimination.
-    "compilation_cache_dir": _Setting(os.path.expanduser("~/.modal_tpu_state/jit_cache")),
+    # jax persistent compilation cache for cold-start elimination, used where
+    # JAX_COMPILATION_CACHE_DIR is not set (compile_cache_dir below). Inside
+    # the checkout, at a path that never moves: the directory is what a later
+    # run must find again, and a copy of the tree carries it along.
+    "compilation_cache_dir": _Setting(
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".modal_tpu_state", "jit_cache"
+        )
+    ),
     # Default TPU runtime visible-device pinning behavior.
     "tpu_chip_pinning": _Setting(True, _to_boolean),
     # Local supervisor: number of simulated hosts for multi-host dev.
@@ -183,6 +190,16 @@ class Config:
 
 
 config = Config()
+
+
+def compile_cache_dir() -> str:
+    """Where every process this program starts keeps its persistent XLA
+    compile cache: the `JAX_COMPILATION_CACHE_DIR` the program itself was
+    started with, else the `compilation_cache_dir` setting. The worker pins
+    the result into each container, sandbox and image-build environment
+    AFTER the image's and the function's own env, so nothing a process
+    inherits can point it at a second cache."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or config["compilation_cache_dir"]
 
 # Configure only our own named logger — never the root logger, which belongs
 # to the host application (the reference makes the same choice in

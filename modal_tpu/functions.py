@@ -581,15 +581,23 @@ class _Function(_Object, type_prefix="fu"):
     @live_method
     async def get_web_url(self, timeout: float = 60.0) -> str:
         """URL of this function's web endpoint, long-polling while the
-        serving container boots (reference web_url on function handles)."""
-        resp = await retry_transient_errors(
-            self.client.stub.FunctionGetWebUrl,
-            api_pb2.FunctionGetWebUrlRequest(function_id=self.object_id, timeout=timeout),
-            attempt_timeout=timeout + 5.0,
-        )
-        if not resp.web_url:
-            raise ExecutionError("web endpoint did not come up (is webhook_type set?)")
-        return resp.web_url
+        serving container boots (reference web_url on function handles). The
+        server answers each poll within 60 s, so a longer `timeout` — a
+        container that loads real weights first — is a series of polls."""
+        deadline = time.monotonic() + timeout
+        while True:
+            window = min(60.0, max(0.0, deadline - time.monotonic()))
+            resp = await retry_transient_errors(
+                self.client.stub.FunctionGetWebUrl,
+                api_pb2.FunctionGetWebUrlRequest(function_id=self.object_id, timeout=window),
+                attempt_timeout=window + 5.0,
+            )
+            if resp.web_url:
+                return resp.web_url
+            if time.monotonic() >= deadline:
+                raise ExecutionError(
+                    f"web endpoint did not come up in {timeout:.0f}s (is webhook_type set?)"
+                )
 
     @live_method
     async def get_current_stats(self) -> api_pb2.FunctionStats:

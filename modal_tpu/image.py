@@ -212,14 +212,14 @@ class _Image(_Object, type_prefix="im"):
     @staticmethod
     def tpu_base(python_version: Optional[str] = None, jax_version: str = "", force_build: bool = False) -> "_Image":
         """The flagship TPU image: debian slim + libtpu + jax[tpu] + the TPU
-        runtime env (persistent XLA compilation cache, premapped-buffer
-        transfers). This replaces the reference's CUDA base images as the
-        'batteries included' accelerator image."""
+        runtime env (premapped-buffer transfers). Where the persistent XLA
+        compilation cache lives is the worker's decision, not the image's
+        (config.compile_cache_dir). This replaces the reference's CUDA base
+        images as the 'batteries included' accelerator image."""
         pin = f"=={jax_version}" if jax_version else ""
         return _Image.debian_slim(python_version, force_build)._extend(
             [
                 f"RUN uv pip install --system 'jax[tpu]{pin}' -f https://storage.googleapis.com/jax-releases/libtpu_releases.html",
-                "ENV JAX_COMPILATION_CACHE_DIR=/cache/jax",
                 "ENV JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=1",
                 "ENV TPU_PREMAPPED_BUFFER_SIZE=17179869184",
             ],

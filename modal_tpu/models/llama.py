@@ -108,8 +108,19 @@ CONFIGS: dict[str, LlamaConfig] = {
 }
 
 
-def get_config(name: str, **overrides: Any) -> LlamaConfig:
-    cfg = CONFIGS[name]
+def get_config(name: Any, **overrides: Any) -> LlamaConfig:
+    """`name` is a preset name, a LlamaConfig, or a mapping
+    `{"name": preset, **field overrides}` — the picklable form the entry
+    points (`llm_service`, `train_demo`) take from a parent process that must
+    not import jax, e.g. `{"name": "llama3-8b", "n_layers": 16}`."""
+    if isinstance(name, LlamaConfig):
+        cfg = name
+    elif isinstance(name, str):
+        cfg = CONFIGS[name]
+    else:
+        spec = dict(name)
+        cfg = CONFIGS[spec.pop("name")]
+        overrides = {**spec, **overrides}
     return dataclasses.replace(cfg, **overrides) if overrides else cfg
 
 

@@ -31,21 +31,19 @@ from typing import Optional
 _install_lock = threading.Lock()
 _installed = False
 
-# jax.monitoring event names (jax 0.4.x) -> our compile-event label. Matched
-# by substring so minor renames across jax versions degrade to "other"
-# instead of dropping samples.
-_EVENT_MAP = (
-    ("compilation_cache/cache_hits", "cache_hit"),
-    ("compilation_cache/cache_misses", "cache_miss"),
-    ("compilation_cache/task_disabled_cache", "cache_disabled"),
-    ("compilation_cache_miss", "cache_miss"),
-    ("compilation_cache_hit", "cache_hit"),
-)
-_DURATION_MAP = (
-    ("compilation_cache/cache_retrieval", "cache_retrieval"),
-    ("backend_compile", "backend_compile"),
-    ("write_cache", "cache_write"),
-)
+# jax.monitoring event names (jax 0.9.0: _src/compiler.py,
+# _src/compilation_cache.py, _src/dispatch.py) -> our compile-event label.
+# cache_misses is recorded where jax WRITES an entry, so a compile below
+# JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS counts as neither hit nor miss.
+_EVENT_MAP = {
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+    "/jax/compilation_cache/task_disabled_cache": "cache_disabled",
+}
+_DURATION_MAP = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
 
 
 def _compile_source() -> str:
@@ -69,40 +67,30 @@ def install_compile_hooks() -> bool:
             # start must not pay the jax import bill for telemetry hooks —
             # callers retry once user code has pulled jax in (heartbeat path)
             return False
-        try:
-            from jax import monitoring
-        except Exception:  # noqa: BLE001 — partial/broken jax install
-            return False
+        from jax import monitoring
 
         from .catalog import COMPILE_EVENTS, COMPILE_SECONDS
 
         def _on_event(event: str, **kw) -> None:
             try:
-                for needle, label in _EVENT_MAP:
-                    if needle in event:
-                        COMPILE_EVENTS.inc(event=label, source=_compile_source())
-                        return
-                if "compil" in event:
-                    COMPILE_EVENTS.inc(event="other", source=_compile_source())
+                label = _EVENT_MAP.get(event) or ("other" if "compil" in event else None)
+                if label is not None:
+                    COMPILE_EVENTS.inc(event=label, source=_compile_source())
             except Exception:  # noqa: BLE001 — a metrics bug must not break jit
                 pass
 
         def _on_duration(event: str, duration: float, **kw) -> None:
             try:
-                for needle, label in _DURATION_MAP:
-                    if needle in event:
-                        COMPILE_SECONDS.observe(float(duration), phase=label)
-                        if label == "backend_compile":
-                            COMPILE_EVENTS.inc(event="compile", source=_compile_source())
-                        return
+                label = _DURATION_MAP.get(event)
+                if label is not None:
+                    COMPILE_SECONDS.observe(float(duration), phase=label)
+                    if label == "backend_compile":
+                        COMPILE_EVENTS.inc(event="compile", source=_compile_source())
             except Exception:  # noqa: BLE001
                 pass
 
-        try:
-            monitoring.register_event_listener(_on_event)
-            monitoring.register_event_duration_secs_listener(_on_duration)
-        except Exception:  # noqa: BLE001 — listener API drift
-            return False
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
         _installed = True
         return True
 
@@ -112,13 +100,16 @@ def maybe_install_fleet_cache() -> bool:
     (ISSUE 20, runtime/compile_client.py). Same lazy contract as
     install_compile_hooks: a no-op until user code has imported jax, a no-op
     when the MODAL_TPU_COMPILE_CACHE gate is off or no fleet coordinates are
-    set, and silent on every failure — telemetry/caching must never be the
-    reason a container errors."""
+    set. A failure is logged and the container carries on with the local
+    cache — caching must never be the reason a container errors."""
     try:
         from ..runtime.compile_client import install_fleet_cache
 
         return install_fleet_cache()
-    except Exception:  # noqa: BLE001 — degrade to local-only compile
+    except Exception as exc:  # noqa: BLE001 — degrade to local-only compile, loudly
+        from ..config import logger
+
+        logger.warning(f"fleet compile cache not installed: {type(exc).__name__}: {exc}")
         return False
 
 
@@ -139,13 +130,10 @@ def sample_device_memory(min_interval_s: float = 0.0) -> int:
     jax = sys.modules.get("jax")
     if jax is None:
         return 0
-    try:
-        from jax._src import xla_bridge
+    from jax._src import xla_bridge
 
-        if not xla_bridge.backends_are_initialized():
-            return 0
-    except Exception:  # noqa: BLE001 — private-API drift: fall through and try
-        pass
+    if not xla_bridge.backends_are_initialized():
+        return 0
     from .catalog import DEVICE_MEMORY_BYTES
 
     _last_sample_t = now
@@ -365,7 +353,8 @@ def merge_container_report(telemetry_json: str, prev_json: str = "", task_id: st
 
 
 def telemetry_summary() -> dict:
-    """Compact roll-up for bench.py: compile counts + step p50s, when any."""
+    """Compact roll-up (bench.py, /v1/stats): compile counts and seconds +
+    step p50s, when any."""
     from .catalog import (
         COMPILE_CACHE_HITS,
         COMPILE_CACHE_MISSES,
@@ -387,6 +376,9 @@ def telemetry_summary() -> dict:
         out["compile_cache"] = fleet
     if COMPILE_SECONDS.count_total():
         out["compile_p50_s"] = COMPILE_SECONDS.quantile(0.5)
+        # {phase: {count, sum}}: backend_compile sums what this process spent
+        # compiling, cache_retrieval what it spent reading the persistent cache
+        out["compile_seconds"] = COMPILE_SECONDS.snapshot()
     if STEP_SECONDS.count_total():
         out["step_p50_s"] = STEP_SECONDS.quantile(0.5)
     return out

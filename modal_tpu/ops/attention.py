@@ -11,7 +11,9 @@ softmax (running max/sum), accumulating in fp32. Causal masking by global
 position. Block sizes default to the MXU/VPU-friendly 128 lane width
 (see /opt/skills/guides/pallas_guide.md).
 
-`flash_attention` falls back to the plain einsum path on non-TPU backends
+`flash_attention` chooses by platform and shape (`flash_kernel_refusal`
+says which and why): the kernels on a TPU for block-aligned causal calls
+that fit the VMEM staging budget, the plain einsum path everywhere else
 (pallas interpret mode is used in tests).
 """
 
@@ -25,17 +27,25 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.sharding import Mesh, PartitionSpec as P
+
+from ..config import logger
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
 
 # The kernels stage the full K/V (forward, dQ) or Q/dO (dK/dV) for one
-# (batch, head) into VMEM per grid step. Budget those full-sequence operands
-# to a fraction of VMEM (~128 MiB on v5e, 16 MiB on v4-gen cores — use a
-# conservative floor) so very long sequences fall back to the einsum path
-# instead of failing to compile. Overridable for chips with more VMEM.
-VMEM_STAGED_BUDGET_BYTES = 24 * 1024 * 1024
+# (batch, head) into VMEM per grid step, and no `vmem_limit_bytes` is passed
+# to the pallas_calls, so Mosaic's default scoped limit applies: 16 MiB on a
+# v5e (my chip run, PR 21, head dim 128 bf16: S=8192 compiled forward and
+# backward; S=32768 compiled forward but the dK/dV kernel was refused,
+# "scoped allocation 16.25M exceeded limit 16.00M" — its two staged operands
+# alone are 16 MiB; S=48384 was refused forward). The budget stays under
+# that limit with room for the blocks and accumulators, so a longer sequence
+# takes the einsum path instead of failing to compile. Raising it means
+# raising the scoped limit on the calls, or tiling K/V through the grid.
+VMEM_STAGED_BUDGET_BYTES = 12 * 1024 * 1024
 
 
 def _fits_vmem_budget(q: jax.Array, k: jax.Array) -> bool:
@@ -375,30 +385,77 @@ def _flash_vjp_bwd(block_q, block_k, interpret, res, do):
 flash_attention_causal.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def flash_kernel_refusal(q: jax.Array, k: jax.Array, mask: Optional[jax.Array]) -> str:
+    """Why this call cannot take the Pallas kernels ('' = it can). The one
+    place the kernel-vs-einsum choice is made, from what the code can observe
+    (platform, mask, shape), so callers and logs can say which path ran.
+    Only the sequence length and head dim matter for the shape test: batch
+    and heads are grid dimensions, so the answer is the same per shard."""
+    try:
+        platform = next(iter(q.devices())).platform
+    except Exception:  # tracers raise ConcretizationTypeError under jit
+        platform = jax.default_backend()
+    if platform != "tpu":
+        return f"platform is {platform}, not tpu"
+    if mask is not None:
+        return "explicit mask (cached/chunked call): the kernel assumes 0-aligned causal positions"
+    s = q.shape[1]
+    if s < DEFAULT_BLOCK_Q or s % DEFAULT_BLOCK_Q:
+        return f"sequence length {s} is not a multiple of the {DEFAULT_BLOCK_Q}-row block"
+    if not _fits_vmem_budget(q, k):
+        return f"full-sequence K/V staging for S={s} exceeds VMEM_STAGED_BUDGET_BYTES"
+    return ""
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, mask: Optional[jax.Array] = None) -> jax.Array:
     """Drop-in for models.llama.attention (same attn_impl contract:
     `mask=None` = pure causal, q/k aligned at position 0, requires Sq == Sk).
     Pallas kernel on TPU for block-aligned causal calls; einsum elsewhere.
     KV-cache/chunked-prefill calls must pass an explicit mask and take the
-    einsum path — the kernel assumes 0-aligned positions."""
+    einsum path — the kernel assumes 0-aligned positions. Runs at trace time,
+    so the path taken is logged once per compiled shape, not per step."""
     if mask is None and q.shape[1] != k.shape[1]:
         raise ValueError(
             f"mask=None implies aligned causal attention but Sq={q.shape[1]} != Sk={k.shape[1]}; "
             "pass the cache visibility mask for cached/chunked calls"
         )
-    try:
-        platform = next(iter(q.devices())).platform
-    except Exception:  # tracers raise ConcretizationTypeError under jit
-        platform = jax.default_backend()
-    if (
-        platform == "tpu"
-        and mask is None
-        and q.shape[1] >= DEFAULT_BLOCK_Q
-        and q.shape[1] % DEFAULT_BLOCK_Q == 0
-        and _fits_vmem_budget(q, k)
-    ):
+    refusal = flash_kernel_refusal(q, k, mask)
+    if not refusal:
+        logger.debug(f"flash_attention{q.shape}: pallas kernels")
         # custom_vjp: differentiable, so the training path can use it too
         return flash_attention_causal(q, k, v)
+    logger.debug(f"flash_attention{q.shape}: einsum path ({refusal})")
     from ..models.llama import attention as einsum_attention
 
     return einsum_attention(q, k, v, mask)
+
+
+def make_sharded_flash_attention(
+    mesh: Mesh, batch_axes: tuple = ("data", "fsdp"), head_axis: Optional[str] = "model"
+):
+    """attn_impl for a jit whose activations are sharded over `mesh`. The
+    SPMD partitioner cannot split a Mosaic custom call: left alone, lowering
+    fails ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map" — what the train step did on a v5e 2x2, PR 21).
+    Attention needs no communication across batch rows or heads, so the
+    kernel runs
+    under `shard_map` with the batch over `batch_axes` and the heads over
+    `head_axis`, each device on its own block. Calls the kernels refuse
+    (off-TPU, masked, unaligned) go to `flash_attention` unwrapped — the
+    einsum path is ordinary XLA the partitioner shards by itself."""
+    if head_axis is not None and mesh.shape.get(head_axis, 1) <= 1:
+        head_axis = None
+    spec = P(batch_axes, None, head_axis, None)
+
+    def _impl(q, k, v, mask):
+        if flash_kernel_refusal(q, k, mask):
+            return flash_attention(q, k, v, mask)
+        return jax.shard_map(
+            lambda q_, k_, v_: flash_attention(q_, k_, v_, None),
+            mesh=mesh,
+            in_specs=(spec, spec, spec),
+            out_specs=spec,
+            check_vma=False,
+        )(q, k, v)
+
+    return _impl

@@ -19,11 +19,12 @@ position vs `seq_lens[s]`), so a slot 3 pages into a 64-page span pays 3
 page DMAs, not 64. Positions inside the last live page are masked by global
 position exactly like the dense reference.
 
-Interpret-mode parity is the portability contract (ROADMAP: every Pallas
-kernel must run interpret-mode until the real-TPU relay returns): the same
-kernel runs `interpret=True` on CPU CI, pinned against the dense `KVCache`
-reference in tests/test_serving.py. Selection lives in models/paged_kv.py
-(`MODAL_TPU_PAGED_KERNEL`); this module only provides the op.
+The same kernel runs `interpret=True` on CPU CI, pinned against the dense
+`KVCache` reference in tests/test_serving.py, and compiled under Mosaic on a
+TPU, where it matches the gather path (tests/test_ops.py TPU-gated test;
+`chip_smoke.py` compares decode-step logits). Selection lives in
+models/paged_kv.py (`MODAL_TPU_PAGED_KERNEL`); this module only provides
+the op.
 """
 
 from __future__ import annotations

@@ -191,6 +191,12 @@ def create_sharded_state(
         from .ring_attention import make_ring_attention_impl
 
         attn_impl = make_ring_attention_impl(mesh, "seq", batch_axes=("data", "fsdp"))
+    elif not pipe:
+        # forward()'s default flash dispatch, with the Mosaic kernels kept
+        # per-device (the pipeline's stage scan uses the einsum reference)
+        from ..ops.attention import make_sharded_flash_attention
+
+        attn_impl = make_sharded_flash_attention(mesh)
 
     @partial(jax.jit, out_shardings=p_shardings)
     def _init(key):
@@ -225,7 +231,7 @@ def create_sharded_state(
 
 
 def train_demo(
-    cfg_name: str = "tiny",
+    cfg_name: Any = "tiny",  # anything models.llama.get_config accepts
     mesh_axes: Optional[dict] = None,
     steps: int = 2,
     per_device_batch: int = 1,
@@ -256,7 +262,11 @@ def train_demo(
         )
         from ..observability.device_telemetry import StepTimer, sample_device_memory
 
+        # which attention path this shape compiled to is read off the lowered
+        # step, not assumed: Mosaic kernels appear as tpu_custom_call
+        mosaic_calls = step_fn.lower(state, tokens).as_text().count("tpu_custom_call")
         metrics = {}
+        losses = []
         timer = StepTimer("train")
         for _ in range(steps):
             state, metrics = step_fn(state, tokens)
@@ -265,5 +275,14 @@ def train_demo(
             # still includes trace+compile — that's the honest cold step)
             jax.block_until_ready(metrics)
             timer.mark()
+            losses.append(float(metrics["loss"]))
         sample_device_memory()
-        return {k: float(v) for k, v in metrics.items()}
+        out = {k: float(v) for k, v in metrics.items()}
+        out["losses"] = losses
+        out["mosaic_custom_calls"] = mosaic_calls
+        # per-device residency (empty where the backend reports none, e.g.
+        # CPU): shows the state is spread over the mesh, not on device 0
+        out["device_bytes_in_use"] = [
+            (d.memory_stats() or {}).get("bytes_in_use") for d in mesh.devices.flat
+        ]
+        return out

@@ -361,12 +361,9 @@ def normalize_cache_keys() -> None:
     keys. An explicit user env override wins (they asked for it)."""
     if os.environ.get("JAX_PERSISTENT_CACHE_ENABLE_XLA_CACHES") is not None:
         return
-    try:
-        import jax
+    import jax
 
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "")
-    except Exception:  # noqa: BLE001 — config drift: worst case is fleet misses
-        pass
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "")
 
 
 def install_fleet_cache() -> bool:
@@ -385,26 +382,23 @@ def install_fleet_cache() -> bool:
 
     if "jax" not in sys.modules:
         return False
-    try:
-        from jax._src import compilation_cache as cc
-    except Exception:  # noqa: BLE001 — private-module drift: degrade to local-only
-        return False
+    # jax 0.9.0's private cache module (the one installation this repo runs
+    # on): a rename there must raise here, not silently drop the fleet tier
+    from jax._src import compilation_cache as cc
+
     normalize_cache_keys()
     with _install_lock:
-        current = getattr(cc, "_cache", None)
+        current = cc._cache
         if isinstance(current, TieredJaxCache):
             return True
-        try:
-            if current is None:
-                # force jax's own (possibly dir-less) initialization first so
-                # we wrap whatever local cache it would have used
-                cc._initialize_cache()
-                current = cc._cache
-            cc._cache = TieredJaxCache(current, fleet)
-            with cc._cache_initialized_mutex:
-                cc._cache_initialized = True
-        except Exception:  # noqa: BLE001 — any internals drift: leave jax untouched
-            return False
+        if current is None:
+            # force jax's own (possibly dir-less) initialization first so
+            # we wrap whatever local cache it would have used
+            cc._initialize_cache()
+            current = cc._cache
+        cc._cache = TieredJaxCache(current, fleet)
+        with cc._cache_initialized_mutex:
+            cc._cache_initialized = True
     return True
 
 
@@ -414,11 +408,9 @@ def uninstall_fleet_cache() -> None:
 
     if "jax" not in sys.modules:
         return
-    try:
-        from jax._src import compilation_cache as cc
-    except Exception:  # noqa: BLE001
-        return
+    from jax._src import compilation_cache as cc
+
     with _install_lock:
-        current = getattr(cc, "_cache", None)
+        current = cc._cache
         if isinstance(current, TieredJaxCache):
             cc._cache = current._inner

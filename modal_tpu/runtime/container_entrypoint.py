@@ -35,7 +35,7 @@ from ..observability import tracing
 tracing.maybe_configure_from_env()
 
 from ..client import _Client
-from ..config import config, logger, tune_switch_interval
+from ..config import compile_cache_dir, config, logger, tune_switch_interval
 from ..exception import ExecutionError
 from ..proto import api_pb2
 from .._utils.grpc_utils import retry_transient_errors
@@ -61,14 +61,13 @@ def load_container_arguments() -> api_pb2.ContainerArguments:
 
 def setup_compilation_cache() -> None:
     """Persistent XLA compilation cache: compiled executables survive across
-    container restarts (cold-start elimination, SURVEY §7 hard part 2)."""
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or config["compilation_cache_dir"]
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
-        os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-    except OSError:
-        pass
+    container restarts (cold-start elimination, SURVEY §7 hard part 2). The
+    worker has already pinned the directory into this process's env
+    (config.compile_cache_dir); a container started by hand gets the same."""
+    cache_dir = compile_cache_dir()
+    os.makedirs(cache_dir, exist_ok=True)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
 
 async def initialize_clustered(container_args: api_pb2.ContainerArguments, client: _Client) -> Optional[Any]:
@@ -702,10 +701,11 @@ def _pool_preimport() -> None:
             logger.warning(f"warm pool pre-import of {mod!r} failed: {exc}")
     if os.environ.get("MODAL_TPU_WARM_POOL_PREINIT") == "1":
         # Opt-in: initialize the jax backend and prime the dispatch/compile
-        # machinery while parked. ONLY safe when every placement's device
-        # topology equals the pool's spawn default — device flags applied at
-        # adoption cannot take effect once the backend exists (the bench CPU
-        # path sets this; the chip-pinning TPU path must NOT).
+        # machinery while parked. The pool spawns interpreters on the CPU
+        # platform (server/warm_pool.py), so this cannot take a chip. ONLY
+        # safe when every placement's device count equals the pool's spawn
+        # default — device flags applied at adoption cannot take effect once
+        # the backend exists.
         t0 = time.time()
         try:
             import jax

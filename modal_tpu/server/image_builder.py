@@ -54,7 +54,7 @@ import sys
 from dataclasses import dataclass, field
 from typing import Optional
 
-from ..config import logger
+from ..config import compile_cache_dir, logger
 from ..proto import api_pb2
 
 
@@ -339,6 +339,8 @@ class ImageBuilder:
             def shell_env() -> dict[str, str]:
                 env = dict(os.environ)
                 env.update(built.env)
+                # build steps compile into the same cache containers read
+                env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir()
                 env["PATH"] = venv_bin + os.pathsep + env.get("PATH", "")
                 env["VIRTUAL_ENV"] = venv_dir
                 env["MODAL_TPU_IMAGE_ROOT"] = rootfs
@@ -420,16 +422,14 @@ class ImageBuilder:
         """Execute a run_function() build step with the image's python
         (reference _image.py:2175 — bake weights/caches at build time).
 
-        #PREWARM layers (Image.prewarm, docs/COLDSTART.md) additionally point
-        the persistent XLA compilation cache inside the image rootfs before
-        the function runs: the jit entry points it traces are compiled at
-        BUILD time, and the cache dir is recorded as image env so every
-        container launched from this image starts with a warm cache."""
+        #PREWARM layers (Image.prewarm, docs/COLDSTART.md): the jit entry
+        points the function traces are compiled at BUILD time into the one
+        persistent XLA cache every container on this host reads
+        (config.compile_cache_dir), so a container launched from this image
+        starts with a warm cache."""
         prewarm = any(c.strip() == "#PREWARM" for c in image.dockerfile_commands)
         if prewarm:
-            cache_dir = os.path.join(built.rootfs, "cache", "jax")
-            os.makedirs(cache_dir, exist_ok=True)
-            built.env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+            os.makedirs(compile_cache_dir(), exist_ok=True)
             # cache even millisecond compiles: the whole point is that NO
             # first-input compile happens in the container
             built.env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
@@ -483,7 +483,7 @@ class ImageBuilder:
         await run_shell(f"{shlex.quote(built.python_bin)} {shlex.quote(script)}", env, built.workdir)
         if prewarm:
             self._merge_prewarm_compile_events(telemetry_out)
-            self._publish_prewarm_cache(built.env.get("JAX_COMPILATION_CACHE_DIR", ""))
+            self._publish_prewarm_cache(compile_cache_dir())
 
     def _publish_prewarm_cache(self, cache_dir: str) -> None:
         """Tentpole (c): push the bake's persistent-cache entries into the

@@ -722,6 +722,9 @@ class ModalTPUServicer:
         if fn is None:
             await context.abort(grpc.StatusCode.NOT_FOUND, "function not found")
         fn.web_url = request.web_url
+        task = self.s.tasks.get(request.task_id)
+        if task is not None:
+            task.web_url = request.web_url
         async with fn.input_condition:
             fn.input_condition.notify_all()
         return api_pb2.FunctionSetWebUrlResponse()

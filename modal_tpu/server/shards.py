@@ -391,6 +391,12 @@ class ShardedSupervisor:
     async def start(self) -> None:
         os.makedirs(self.state_dir, exist_ok=True)
         os.makedirs(self.blob_dir, exist_ok=True)
+        # every shard runs at least one worker on THIS host
+        from .worker import chips_per_worker
+
+        self.worker_chips = await chips_per_worker(
+            max(self.num_workers, self.num_shards), self.worker_chips
+        )
         for i in range(self.num_shards):
             await self._start_shard(i)
         await self._start_director()
@@ -450,6 +456,10 @@ class ShardedSupervisor:
             for knob in ("MODAL_TPU_CHAOS_SHARD_KILL_AFTER", "MODAL_TPU_CHAOS_SHARD_PARTITION"):
                 env.pop(knob, None)
             env["MODAL_TPU_SHARDS"] = "1"  # a shard is a monolith internally
+            if self.worker_chips is not None:
+                # the inventory start() settled on, not one probe per shard
+                env.setdefault("MODAL_TPU_WORKER_TPU_TYPE", self.worker_tpu_type or "")
+                env.setdefault("MODAL_TPU_WORKER_NUM_CHIPS", str(self.worker_chips))
             proc = subprocess.Popen(
                 [
                     sys.executable,

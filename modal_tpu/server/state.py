@@ -181,6 +181,9 @@ class TaskState_:
     result: Optional[api_pb2.GenericResult] = None
     tpu_chip_ids: list[int] = field(default_factory=list)
     container_address: str = ""
+    # this replica's own web endpoint (FunctionSetWebUrl); the function-level
+    # web_url is whichever replica registered last
+    web_url: str = ""
     router_token: str = ""  # bearer token for the worker's command router
     # trace context of the input whose backlog caused this launch: the
     # container's boot/import spans parent here (cold-start attribution)

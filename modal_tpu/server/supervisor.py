@@ -26,7 +26,7 @@ from .journal import IdempotencyCache, Journal, recover_state
 from .scheduler import Scheduler
 from .services import ModalTPUServicer
 from .state import ServerState
-from .worker import WorkerAgent
+from .worker import WorkerAgent, chips_per_worker
 
 
 def _journal_enabled() -> bool:
@@ -271,10 +271,11 @@ class LocalSupervisor:
             # attributable to the exact injected fault sequence
             CHAOS_SEED.set(float(self.chaos.seed))
         await self._start_control_plane(self.port)
+        worker_chips = await chips_per_worker(self.num_workers, self.worker_chips)
         for i in range(self.num_workers):
             worker = WorkerAgent(
                 self.server_url,
-                num_chips=self.worker_chips,
+                num_chips=worker_chips,
                 tpu_type=self.worker_tpu_type,
                 state_dir=self.state_dir,
                 slice_index=(i // self.hosts_per_slice) if self.hosts_per_slice else 0,

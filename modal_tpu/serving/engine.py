@@ -388,6 +388,18 @@ class ServingEngine:
         self.max_waiting = max_waiting
         self.allocator = PageAllocator(num_pages, page_size)
         self.cache = PagedKVCache.create(cfg, max_slots, num_pages, page_size, pages_per_slot)
+        # what this engine actually runs on, as jax reports it — /v1/stats
+        # carries it so a client never has to assume the device
+        import jax
+
+        devices = jax.devices()
+        self.device = {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "count": len(devices),
+            # which of the host's chips the worker pinned this process to
+            "visible_chips": os.environ.get("TPU_VISIBLE_DEVICES", ""),
+        }
         # ISSUE 12 capability knobs, each individually degradable -----------
         self.attn_impl = resolve_attn_impl()  # "gather" | "kernel" | "kernel_interpret"
         self.sampling_enabled = _env_on(SAMPLING_ENV)
@@ -1596,6 +1608,8 @@ class ServingEngine:
         return [prefix_digest(key) for key in keys[:limit]]
 
     def stats(self) -> dict:
+        from ..observability.device_telemetry import telemetry_summary
+
         with self._lock:
             active = sum(1 for s in self.slots if s is not None)
             waiting = len(self.waiting)
@@ -1616,6 +1630,8 @@ class ServingEngine:
             "kv_pages_high_water": self.allocator.high_water,
             "kv_pool_bytes": self.cache.pool_bytes(),
             "attn_impl": self.attn_impl,
+            "device": self.device,
+            "compile": telemetry_summary(),
             "sampling_enabled": self.sampling_enabled,
             "prefix_cache_entries": len(self.prefix_cache) if self.prefix_cache else 0,
             "prefix_cache_pages": self.prefix_cache.held_pages if self.prefix_cache else 0,

@@ -9,7 +9,7 @@ sizes replicas with from pushed serving telemetry.
 
     app = modal_tpu.App("llm")
     Service = modal_tpu.serving.llm_service(
-        app, model="llama3-8b", tpu="v5e-8", checkpoint="/vol/ckpt",
+        app, model="llama3-8b", tpu="v5e-1", checkpoint="/vol/ckpt",
         max_slots=32, target_ttft_ms=500,
     )
     # deploy; POST {url}/v1/generate with {"prompt": [...], "stream": true}
@@ -23,7 +23,10 @@ from typing import Any, Optional
 def llm_service(
     app: Any,
     *,
-    model: str = "tiny",
+    # anything models.llama.get_config accepts: a preset name, or
+    # {"name": preset, **LlamaConfig overrides} (e.g. a depth cut) — a form
+    # the caller can build without importing jax
+    model: Any = "tiny",
     checkpoint: Optional[str] = None,  # volume/local path for weights.load_params
     quantize_int8: bool = False,
     seed: int = 0,
@@ -57,6 +60,17 @@ def llm_service(
     """Register a serving class on `app` and return it (an `@app.cls`
     result: instantiate + `.get_web_url()` under a run, or deploy it)."""
     import modal_tpu
+    from modal_tpu.tpu_config import parse_tpu_config
+
+    # ServingEngine has no mesh: params, KV pool and every step live on the
+    # default device, so a multi-chip placement would hold chips it never uses
+    spec = parse_tpu_config(cls_kwargs.get("tpu"))
+    if spec is not None and spec.chips > 1:
+        raise ValueError(
+            f"llm_service(tpu={cls_kwargs['tpu']!r}): the serving engine runs on one chip and "
+            f"would leave {spec.chips - 1} of {spec.chips} idle; use a one-chip type and "
+            "min_containers/max_containers for more replicas"
+        )
 
     opts = dict(
         serialized=True,
@@ -75,7 +89,13 @@ def llm_service(
             import jax
 
             from modal_tpu.models.llama import get_config, init_params
+            from modal_tpu.observability import device_telemetry
 
+            # the container attached these at import time only if user code
+            # had imported jax by then; this class imports it here, and the
+            # compiles below must be counted (/v1/stats "compile")
+            device_telemetry.install_compile_hooks()
+            device_telemetry.maybe_install_fleet_cache()
             cfg = get_config(model)
             if checkpoint:
                 from modal_tpu.models.weights import load_params

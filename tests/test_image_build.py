@@ -144,7 +144,7 @@ def test_builder_epochs_known_and_pinned():
     from modal_tpu import builder as epochs
 
     versions = epochs.known_versions()
-    assert "2026.04" in versions and "2026.07" in versions
+    assert versions == ("2026.07",)  # the one epoch that matches this installation
     pins = epochs.load_requirements("2026.07")
     assert pins["jax"].startswith("jax==")
     assert pins["orbax-checkpoint"].startswith("orbax-checkpoint==")
@@ -152,17 +152,17 @@ def test_builder_epochs_known_and_pinned():
         epochs.load_requirements("1999.01")
 
 
-def test_epoch_changes_image_chain_hash():
-    """Same image definition under two epochs hashes differently — the pin
-    set participates in the content address, so epoch bumps rebuild."""
+def test_epoch_content_changes_image_chain_hash(monkeypatch):
+    """The epoch's pin set participates in the content address: editing an
+    epoch file (or bumping the epoch) rebuilds every image under it."""
+    from modal_tpu import builder as epochs
     from modal_tpu.proto import api_pb2
     from modal_tpu.server.image_builder import chain_hash
 
-    def chain(version):
-        return [api_pb2.Image(dockerfile_commands=["FROM python:3.12"], version=version)]
-
-    h_old, h_new = chain_hash(chain("2026.04")), chain_hash(chain("2026.07"))
-    assert h_old != h_new
+    chain = [api_pb2.Image(dockerfile_commands=["FROM python:3.12"], version="2026.07")]
+    h_before = chain_hash(chain)
+    monkeypatch.setattr(epochs, "epoch_content_hash", lambda version: "an-edited-pin-set")
+    assert chain_hash(chain) != h_before
 
 
 def test_pip_install_gets_epoch_pin():
@@ -193,21 +193,29 @@ def test_unknown_epoch_fails_build_loudly(supervisor, monkeypatch):
             probe.remote(1)
 
 
-def test_epoch_env_lands_in_container(supervisor, tmp_path):
+def test_epoch_env_lands_in_container(supervisor, tmp_path, monkeypatch):
     """The epoch's base tpu_env is applied to built images (a real layer
-    forces a build; trivial chains run the host venv untouched)."""
+    forces a build; trivial chains run the host venv untouched) — except the
+    compile cache directory, which no image may move: a built image's
+    container keeps it where the program's own environment says."""
     import modal_tpu
+    from modal_tpu import builder as epochs
 
-    image = modal_tpu.Image.debian_slim().env({"IMG_MARK": "1"})
+    outer = str(tmp_path / "outer_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outer)
+    # an image that tries to name its own cache dir loses to the outer setting
+    image = modal_tpu.Image.debian_slim().env({"IMG_MARK": "1", "JAX_COMPILATION_CACHE_DIR": "/cache/jax"})
     app = modal_tpu.App("img-epoch-env")
 
     def read_env():
         import os
 
-        return os.environ.get("JAX_COMPILATION_CACHE_DIR", ""), os.environ.get("IMG_MARK")
+        return {k: os.environ.get(k, "") for k in ("JAX_COMPILATION_CACHE_DIR", "IMG_MARK", "LIBTPU_INIT_ARGS")}
 
     f = app.function(image=image, serialized=True)(read_env)
     with app.run():
-        cache_dir, mark = f.remote()
-    assert mark == "1"
-    assert cache_dir  # from builder/base_images.json tpu_env for the epoch
+        env = f.remote()
+    assert env["IMG_MARK"] == "1"
+    epoch_env = epochs.base_image_config("2026.07")["tpu_env"]
+    assert epoch_env and env["LIBTPU_INIT_ARGS"] == epoch_env["LIBTPU_INIT_ARGS"]
+    assert env["JAX_COMPILATION_CACHE_DIR"] == outer

@@ -662,10 +662,13 @@ def test_kill_and_delete_journal_dir_replica_takeover(sharded, tmp_path):
     # the disk is gone too: no corpse journal to replay from
     shutil.rmtree(os.path.join(str(tmp_path / "state"), f"shard-{home}", "journal"))
 
+    # the takeover RECORD, not just the remap: the director appends it only
+    # after rehoming the workers, a few ms after assignments change
     _wait_for(
-        lambda: sharded.assignments[home] != home,
+        lambda: any(e["dead_shard"] == home for e in sharded.takeover_log),
         what=f"replica takeover of partition {home}",
     )
+    assert sharded.assignments[home] != home
     (entry,) = [e for e in sharded.takeover_log if e["dead_shard"] == home]
     assert entry["mode"] == "replica", "takeover replayed a journal that no longer exists?"
     assert entry["report"]["records_applied"] > 0, "replica adoption replayed nothing"

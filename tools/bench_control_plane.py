@@ -55,7 +55,8 @@ import time
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a CPU micro-bench of the control plane, whatever the environment says
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("MODAL_TPU_AUTO_LOCAL_SERVER", "0")
 
 

@@ -165,6 +165,8 @@ def main() -> None:
     parser.add_argument("--skip-blob", action="store_true")
     args = parser.parse_args()
 
+    # a CPU micro-bench of the host data plane, whatever the environment says
+    os.environ["JAX_PLATFORMS"] = "cpu"
     result: dict = {"size_mb": args.size_mb}
     result.update(bench_serialization(args.size_mb))
 
