@@ -29,10 +29,13 @@ This process never imports jax — a process that has touched jax holds the
 chip and its containers could not. Everything about the device is read from
 a child process (the probe) or from the server (`/v1/stats`).
 
-Exit status 0 and a last stdout line `{"ok": true, "device": {...}, ...}`
-only if every phase passed on a TPU (or on the CPU under --cpu). No TPU and
-no --cpu: non-zero, and no result line. Nothing here is a measurement: the
-summary ends with `"claim": null`.
+Standard output ends with two lines: `summary: {...}`, what every phase saw,
+and last the verdict `{"ok": true, "device": {"platform": ..., "kind": ...,
+"count": ...}}` with exactly those keys and the device as jax reports it.
+Exit status 0 and `"ok": true` only if every phase passed on a TPU (or on the
+CPU under --cpu); a failed phase exits 1 with `"ok": false` and no summary.
+No TPU and no --cpu: exit 2, and no result line at all. Nothing here is a
+measurement: the summary ends with `"claim": null`.
 """
 
 from __future__ import annotations
@@ -594,18 +597,18 @@ def main() -> int:
     if "jax" in sys.modules:
         ok, error = False, "the parent process imported jax (it would hold the chip)"
         sys.stderr.write(f"chip_smoke: FAILED: {error}\n")
-    if not ok:
-        return 1
-    summary = {
-        "ok": True,
-        "device": device,
-        "mode": f"{'cpu dry run' if args.cpu else 'chip'}, --chips {args.chips}",
-        "wall_s": round(time.monotonic() - t0, 1),
-        **result,
-        "claim": None,
-    }
-    print(json.dumps(summary), flush=True)
-    return 0
+    if ok:
+        summary = {
+            "mode": f"{'cpu dry run' if args.cpu else 'chip'}, --chips {args.chips}",
+            "wall_s": round(time.monotonic() - t0, 1),
+            **result,
+            "claim": None,
+        }
+        log(f"summary: {json.dumps(summary)}")
+    # the verdict, last and alone on its line, with exactly these keys: the
+    # driver's accelerator check parses it and refuses anything else
+    log(json.dumps({"ok": ok, "device": device}))
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

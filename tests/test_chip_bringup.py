@@ -205,8 +205,13 @@ def test_chip_smoke_explicit_cpu_dry_run_passes_on_tiny():
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = proc.stdout.strip().splitlines()
     assert lines[0].startswith("device: platform=cpu")
-    summary = json.loads(lines[-1])
-    assert summary["ok"] is True and summary["device"]["platform"] == "cpu"
+    # last, the verdict with exactly the keys the driver's check accepts
+    verdict = json.loads(lines[-1])
+    assert set(verdict) == {"ok", "device"} and set(verdict["device"]) == {"platform", "kind", "count"}
+    assert verdict["ok"] is True and verdict["device"]["platform"] == "cpu"
+    assert isinstance(verdict["device"]["kind"], str) and type(verdict["device"]["count"]) is int
+    assert lines[-2].startswith("summary: ")
+    summary = json.loads(lines[-2][len("summary: "):])
     assert summary["model"]["name"] == "tiny"
     boot1, boot2 = summary["served"]["boot1"], summary["served"]["boot2"]
     assert boot1["requests_sent"] == boot1["requests_succeeded"] >= 8 and boot1["streamed"] >= 1
