@@ -324,25 +324,30 @@ def _paged_layer(cfg, x, layer, positions, write_page_ids, write_offsets, mask, 
 
     s, sq, d = x.shape
     hd = cfg.head_dim
-    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = qmm(h, layer["wq"]).reshape(s, sq, cfg.n_heads, hd)
-    k = qmm(h, layer["wk"]).reshape(s, sq, cfg.n_kv_heads, hd)
-    v = qmm(h, layer["wv"]).reshape(s, sq, cfg.n_kv_heads, hd)
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
-    kp, vp = _scatter_kv(
-        kp, vp,
-        k.reshape(s * sq, cfg.n_kv_heads, hd),
-        v.reshape(s * sq, cfg.n_kv_heads, hd),
-        write_page_ids, write_offsets,
-    )
-    attn_out = _paged_attention(
-        q, kp, vp, page_table, mask, positions=positions[:, 0], attn_impl=attn_impl
-    )
-    x = x + qmm(attn_out.reshape(s, sq, cfg.n_heads * hd), layer["wo"])
-    h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    gated = jax.nn.silu(qmm(h, layer["w_gate"]).astype(jnp.float32)).astype(x.dtype) * qmm(h, layer["w_up"])
-    x = x + qmm(gated, layer["w_down"])
+    # the scopes are names only (HLO metadata, profiler traces): nothing
+    # computed changes
+    with jax.named_scope("paged_attention"):
+        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q = qmm(h, layer["wq"]).reshape(s, sq, cfg.n_heads, hd)
+        k = qmm(h, layer["wk"]).reshape(s, sq, cfg.n_kv_heads, hd)
+        v = qmm(h, layer["wv"]).reshape(s, sq, cfg.n_kv_heads, hd)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
+        with jax.named_scope("kv_write"):
+            kp, vp = _scatter_kv(
+                kp, vp,
+                k.reshape(s * sq, cfg.n_kv_heads, hd),
+                v.reshape(s * sq, cfg.n_kv_heads, hd),
+                write_page_ids, write_offsets,
+            )
+        attn_out = _paged_attention(
+            q, kp, vp, page_table, mask, positions=positions[:, 0], attn_impl=attn_impl
+        )
+        x = x + qmm(attn_out.reshape(s, sq, cfg.n_heads * hd), layer["wo"])
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        gated = jax.nn.silu(qmm(h, layer["w_gate"]).astype(jnp.float32)).astype(x.dtype) * qmm(h, layer["w_up"])
+        x = x + qmm(gated, layer["w_down"])
     return x, kp, vp
 
 
@@ -364,8 +369,9 @@ def _run_layers(params, cfg, x, positions, write_page_ids, write_offsets, mask, 
 def _logits(params, cfg, x_last):
     from .quant import qmm
 
-    x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
-    return qmm(x_last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("logits"):
+        x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
+        return qmm(x_last, params["lm_head"]).astype(jnp.float32)
 
 
 # -- public jitted entry points ----------------------------------------------

@@ -610,7 +610,9 @@ SPAN_CATALOG: dict[str, str] = {
     "debug.bundle": "crash-forensics collection: postmortem rings gathered + merged timeline rendered",
     "serving.admit": "serving-tier admission: queue wait → decode-slot + KV pages",
     "serving.prefill": "serving-tier prompt prefill (chunked; ends at the first token)",
-    "serving.prefill_chunk": "one prefill chunk's device compute (per-request timeline detail)",
+    "serving.prefill_chunk": "the launch of one prefill chunk (the asynchronous paged_prefill call until it returns; "
+    "its device time is jit_paged_prefill in the profiler's trace, its place on the loop's timeline is "
+    "engine.prefill_dispatch with the same request_id)",
     "serving.decode": "periodic decode progress mark (every N tokens; batch occupancy + KV pages attrs)",
     "serving.preempt": "KV-pool-pressure preemption: slot freed, request requeued with its prefix",
     "serving.spec_verify": "one speculative round: draft proposals → target verify → acceptance (ISSUE 12)",
@@ -618,6 +620,58 @@ SPAN_CATALOG: dict[str, str] = {
     "serving.stream": "one SSE token stream: open → done/reset (serving/api.py)",
     "serving.route": "fleet router dispatch: prefix-map/affinity/cold pick → replica call (ISSUE 18)",
     "serving.kv_ship": "KV-page shipment leg: export off the prefill replica / import on the decode replica",
+}
+
+
+# -- engine loop phases (ISSUE 26) ---------------------------------------------
+# The serving engine's thread is in exactly ONE of these at any time
+# (serving/engine.py `_LoopPhases`: one call ends the open phase and starts
+# the next). Each is recorded twice from that call: as a
+# jax.profiler.TraceAnnotation named `engine.<phase>` (the profiler's clock,
+# beside the device's operations) and as seconds under `/v1/stats`
+# `loop.phase_seconds`. name -> (what it covers, its kind: `wait` is
+# `loop.wait_seconds`, `host` is `loop.host_seconds`, `sync` is the rest of
+# `loop.work_seconds`). What the device can be doing in each is in
+# docs/OBSERVABILITY.md "Engine loop phases".
+ENGINE_PHASES: dict[str, tuple[str, str]] = {
+    "wait_work": ("_run blocked on the condition: no request waiting, no slot live", "wait"),
+    "admit": (
+        "_admit from the moment a waiting request has a free slot: prefix lookup, page allocation, "
+        "assign_pages, the serving.admit span, a shipment's import (request_id)",
+        "host",
+    ),
+    "prefill_prep": (
+        "_prefill_one up to the launch: _cow_range, the padded array, the scalars "
+        "(request_id, chunk_tokens, offset, bucket; draft=1 for the draft pool's chunk)",
+        "host",
+    ),
+    "prefill_dispatch": (
+        "the paged_prefill(...) call until it returns (request_id; draft=1 for the draft pool's chunk)",
+        "host",
+    ),
+    "prefill_sync": ("int(next_tok) (and sample_step) when the chunk completes the prompt (request_id)", "sync"),
+    "decode_prep": (
+        "_grow_pages, the slot scan, the tokens/active arrays; in a speculative round also a group's "
+        "sampling arrays",
+        "host",
+    ),
+    "decode_dispatch": (
+        "paged_decode_step(...) (and sample_step) until it returns; in a speculative round a group's "
+        "draft chain, verify and target sampling (batch)",
+        "host",
+    ),
+    "decode_sync": (
+        "np.asarray(next_tokens); in a speculative round the group's proposals and targets (batch)",
+        "sync",
+    ),
+    "emit": (
+        "the host's bookkeeping once a launch has returned or a result has arrived: appending tokens, waking "
+        "streams, serving.* spans and gauges, _maybe_finish with release_slot, _note_rate; after a chunk's "
+        "launch its counters, the serving.prefill_chunk span and the prefix-cache insert of a finished prompt "
+        "(tokens=0); after a prefill's sync the export, the serving.prefill span and _emit_first; in a "
+        "speculative round the seq_lens roll too (tokens; request_id after a prefill)",
+        "host",
+    ),
 }
 
 

@@ -229,9 +229,24 @@ def remove_span_tap(tap) -> None:
         pass
 
 
+# what writing spans to the sink cost this process (ISSUE 26; `/v1/stats`
+# `tracing.span_write_seconds`): the seconds every thread spent in `_write`
+# on spans that reached the sink's file, taps included. Updated under the
+# sink's lock; a process with no sink pays nothing for it.
+_span_write_seconds = 0.0
+
+
+def span_write_seconds() -> float:
+    return _span_write_seconds
+
+
 def _write(span: Span) -> None:
-    global _sink_bytes
-    for tap in list(_span_taps):
+    global _sink_bytes, _span_write_seconds
+    taps = list(_span_taps)
+    if _sink_file is None and not taps:
+        return
+    started = time.perf_counter()
+    for tap in taps:
         try:
             tap(span)
         except Exception:
@@ -249,6 +264,7 @@ def _write(span: Span) -> None:
                 _sink_bytes += len(line) + 1
                 if _sink_bytes >= _sink_max_bytes():
                     _rotate_locked()
+                _span_write_seconds += time.perf_counter() - started
             except (OSError, ValueError):
                 pass
 

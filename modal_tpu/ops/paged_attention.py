@@ -130,4 +130,5 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, n_kv, n_rep, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",  # the kernel's name in HLO metadata and profiler traces
     )(page_table, seq_lens, q, k_pages, v_pages)

@@ -39,6 +39,7 @@ from typing import Any, Optional
 from ..config import logger
 from ..observability import tracing
 from ..observability.catalog import (
+    ENGINE_PHASES,
     KV_PAGES_ALLOCATED,
     KV_PAGES_COW,
     KV_PAGES_FREE,
@@ -171,6 +172,44 @@ def _span_mark_tokens() -> int:
 
 class EngineStopped(RuntimeError):
     pass
+
+
+class _LoopPhases:
+    """The engine thread's clock-switch: `switch(name)` ENDS the phase that
+    was open and STARTS the next, so the phases (observability/catalog.py
+    ENGINE_PHASES) partition the thread's time by construction. Each phase
+    is recorded twice from that one call: as a profiler annotation named
+    `engine.<phase>` (in the same trace as the device's operations when a
+    profiler session is on, a no-op otherwise) and as seconds for
+    `/v1/stats`. Only the engine thread calls `switch`/`close`; any thread
+    may call `snapshot` (one atomic copy of a dict of floats)."""
+
+    def __init__(self, annotation: Any):
+        self._annotation = annotation  # jax.profiler.TraceAnnotation
+        self._seconds: dict[str, float] = dict.fromkeys(ENGINE_PHASES, 0.0)
+        self._open: Optional[tuple] = None  # (name, started, annotation)
+
+    def switch(self, name: str, **attrs: Any) -> None:
+        now = time.perf_counter()
+        ended = self._open
+        if ended is not None:
+            ended[2].__exit__(None, None, None)
+        # the annotation takes its start time when it is made: made right
+        # after the last one's end, a trace shows no gap between two phases
+        annotation = self._annotation("engine." + name, **attrs)
+        annotation.__enter__()
+        self._open = (name, now, annotation)
+        if ended is not None:
+            self._seconds[ended[0]] += now - ended[1]
+
+    def close(self) -> None:
+        ended, self._open = self._open, None
+        if ended is not None:
+            ended[2].__exit__(None, None, None)
+            self._seconds[ended[0]] += time.perf_counter() - ended[1]
+
+    def snapshot(self) -> dict[str, float]:
+        return dict(self._seconds)
 
 
 class GenRequest:
@@ -391,7 +430,10 @@ class ServingEngine:
         # what this engine actually runs on, as jax reports it — /v1/stats
         # carries it so a client never has to assume the device
         import jax
+        import jax.profiler
 
+        self._phases = _LoopPhases(jax.profiler.TraceAnnotation)
+        self._phase = self._phases.switch
         devices = jax.devices()
         self.device = {
             "platform": devices[0].platform,
@@ -454,6 +496,19 @@ class ServingEngine:
         self.requests_completed = 0
         self.preemptions = 0
         self.cow_copies = 0
+        # what the loop did, for /v1/stats (ISSUE 26): prompt tokens are the
+        # target model's chunks (a prefix-cache hit is not in them, a
+        # re-prefill after a preemption is)
+        self.loop_iterations = 0
+        self.requests_admitted = 0
+        self.queue_wait_seconds = 0.0
+        self.prompt_tokens_prefilled = 0
+        self.prefill_chunks = 0
+        self.prefill_bucket_tokens = 0
+        # the per-request timeline knobs, read once: an engine keeps the
+        # setting it was built under
+        self.spans_on = _spans_enabled()
+        self.span_mark_tokens = _span_mark_tokens()
         # speculative acceptance over a trailing window (the accept-ratio
         # gauge the heartbeat pushes per replica)
         self._spec_window: deque[tuple[int, int]] = deque(maxlen=200)  # (accepted, proposed)
@@ -567,7 +622,7 @@ class ServingEngine:
         )
         req._export = bool(export)
         req._shipment = shipment
-        if _spans_enabled():
+        if self.spans_on:
             # per-request timeline root (ISSUE 11): parents under the
             # ambient context when one exists (a .remote() chain), else
             # starts its own trace — either way every lifecycle span below
@@ -682,21 +737,27 @@ class ServingEngine:
             f"serving engine up: slots={self.max_slots} pages={self.allocator.num_pages - 1} "
             f"page_size={self.page_size} pool={self.cache.pool_bytes() / 1e6:.1f}MB"
         )
-        while True:
-            with self._work:
-                while not self._stop and not self.waiting and not any(self.slots):
-                    self._work.wait(timeout=0.5)
-                if self._stop:
-                    return
-            try:
-                if self.chaos_step_delay > 0:
-                    time.sleep(self.chaos_step_delay)
-                self._admit()
-                self._prefill_one()
-                self._decode_step()
-            except Exception as exc:  # noqa: BLE001 — loop must survive
-                logger.exception(f"serving loop iteration failed: {exc}")
-                self._fail_all(f"engine loop error: {type(exc).__name__}: {exc}")
+        try:
+            while True:
+                with self._work:
+                    if not self._stop and not self.waiting and not any(self.slots):
+                        self._phase("wait_work")
+                        while not self._stop and not self.waiting and not any(self.slots):
+                            self._work.wait(timeout=0.5)
+                    if self._stop:
+                        return
+                self.loop_iterations += 1
+                try:
+                    if self.chaos_step_delay > 0:
+                        time.sleep(self.chaos_step_delay)
+                    self._admit()
+                    self._prefill_one()
+                    self._decode_step()
+                except Exception as exc:  # noqa: BLE001 — loop must survive
+                    logger.exception(f"serving loop iteration failed: {exc}")
+                    self._fail_all(f"engine loop error: {type(exc).__name__}: {exc}")
+        finally:
+            self._phases.close()
 
     def _fail_all(self, message: str) -> None:
         with self._lock:
@@ -762,6 +823,7 @@ class ServingEngine:
                 if free_idx is None:
                     return
                 req = self.waiting[0]
+                self._phase("admit", request_id=req.id)
                 prefill_tokens = req.prompt + req.tokens  # preempted: regen prefix too
                 need = self.allocator.pages_for(len(prefill_tokens) + 1)
                 shipment = req._shipment
@@ -842,6 +904,8 @@ class ServingEngine:
                     self.draft_cache, free_idx, 0, jnp.asarray(drow, jnp.int32)
                 )
             req.admitted_at = time.time()
+            self.requests_admitted += 1
+            self.queue_wait_seconds += req.admitted_at - req.queue_from
             self._sync_page_gauges()
             if req.trace_context is not None:
                 # queue segment: creation (or last preemption) → slot grant
@@ -889,7 +953,7 @@ class ServingEngine:
         slot.prefill_done = len(slot.prefill_tokens)
         slot.pos = len(req.prompt)
         self.remote_prefills += 1
-        if req.trace_context is not None and _spans_enabled():
+        if req.trace_context is not None and self.spans_on:
             tracing.record_span(
                 "serving.kv_ship",
                 start=t0,
@@ -900,6 +964,7 @@ class ServingEngine:
         if self.prefix_cache is not None and len(req.prompt) >= self.page_size:
             self.prefix_cache.insert(req.prompt, slot.pages)
             self._sync_page_gauges()
+        self._phase("emit", request_id=req.id, tokens=1)
         self._emit_first(idx, slot, int(shipment["first_token"]))
 
     def _emit_first(self, idx: int, slot: _Slot, tok: int) -> None:
@@ -984,6 +1049,11 @@ class ServingEngine:
         t0 = time.time()
         if slot.prefill_done < total:
             chunk = slot.prefill_tokens[slot.prefill_done : slot.prefill_done + self.prefill_chunk]
+            bucket = prefill_bucket(len(chunk), self.max_context)
+            self._phase(
+                "prefill_prep", request_id=req.id, chunk_tokens=len(chunk),
+                offset=slot.prefill_done, bucket=bucket,
+            )
             if not self._cow_range(idx, slot, slot.prefill_done, slot.prefill_done + len(chunk)):
                 # CoW starved for a page: free capacity the hard way and retry
                 # next iteration. The needy slot itself is a valid victim — if
@@ -991,19 +1061,19 @@ class ServingEngine:
                 # is the only move that ever unsticks the loop
                 self._preempt_youngest(exclude=())
                 return
-            bucket = prefill_bucket(len(chunk), self.max_context)
             padded = np.zeros((bucket,), np.int32)
             padded[: len(chunk)] = chunk
+            tokens_j, length_j = jnp.asarray(padded), jnp.int32(len(chunk))
+            slot_j, start_j = jnp.int32(idx), jnp.int32(slot.prefill_done)
+            self._phase("prefill_dispatch", request_id=req.id)
             logits, next_tok, self.cache = paged_prefill(
-                self.params,
-                self.cfg,
-                jnp.asarray(padded),
-                jnp.int32(len(chunk)),
-                self.cache,
-                jnp.int32(idx),
-                jnp.int32(slot.prefill_done),
+                self.params, self.cfg, tokens_j, length_j, self.cache, slot_j, start_j
             )
-            if req.trace_context is not None and _spans_enabled():
+            self._phase("emit", request_id=req.id, tokens=0)
+            self.prompt_tokens_prefilled += len(chunk)
+            self.prefill_chunks += 1
+            self.prefill_bucket_tokens += bucket
+            if req.trace_context is not None and self.spans_on:
                 tracing.record_span(
                     "serving.prefill_chunk",
                     start=t0,
@@ -1027,17 +1097,19 @@ class ServingEngine:
                 slot.draft_prefill_done : slot.draft_prefill_done + self.prefill_chunk
             ]
             dbucket = prefill_bucket(len(dchunk), self.max_context)
+            self._phase(
+                "prefill_prep", request_id=req.id, chunk_tokens=len(dchunk),
+                offset=slot.draft_prefill_done, bucket=dbucket, draft=1,
+            )
             dpadded = np.zeros((dbucket,), np.int32)
             dpadded[: len(dchunk)] = dchunk
+            tokens_j, length_j = jnp.asarray(dpadded), jnp.int32(len(dchunk))
+            slot_j, start_j = jnp.int32(idx), jnp.int32(slot.draft_prefill_done)
+            self._phase("prefill_dispatch", request_id=req.id, draft=1)
             _dl, _dn, self.draft_cache = paged_prefill(
-                self.draft_params,
-                self.draft_cfg,
-                jnp.asarray(dpadded),
-                jnp.int32(len(dchunk)),
-                self.draft_cache,
-                jnp.int32(idx),
-                jnp.int32(slot.draft_prefill_done),
+                self.draft_params, self.draft_cfg, tokens_j, length_j, self.draft_cache, slot_j, start_j
             )
+            self._phase("emit", request_id=req.id, tokens=0)
             slot.draft_prefill_done += len(dchunk)
             if slot.draft_prefill_done >= total:
                 if self.draft_prefix_cache is not None and len(req.prompt) >= self.page_size:
@@ -1060,6 +1132,7 @@ class ServingEngine:
                 # by exact prompt content inside insert)
                 self.prefix_cache.insert(req.prompt, slot.pages)
                 self._sync_page_gauges()
+            self._phase("prefill_sync", request_id=req.id)
             if req.temperature > 0:
                 # first/continuation token sampled with the request's own
                 # (seed, token-index) key — companion-independent by
@@ -1074,11 +1147,14 @@ class ServingEngine:
                     jnp.asarray([req.top_k], jnp.int32),
                     jnp.asarray([req.top_p], jnp.float32),
                 )
-                next_tok = int(tok_arr[0])
                 self.sampled_tokens += 1
                 SERVING_SAMPLED_TOKENS.inc()
+                first_tok = int(tok_arr[0])
+            else:
+                first_tok = int(next_tok)
+            self._phase("emit", request_id=req.id, tokens=1)
             if req._export:
-                self._export_shipment(slot, int(next_tok))
+                self._export_shipment(slot, first_tok)
             if req.trace_context is not None:
                 tracing.record_span(
                     "serving.prefill",
@@ -1087,7 +1163,7 @@ class ServingEngine:
                     parent=req.trace_context,
                     attrs={"request_id": req.id, "prompt_tokens": len(slot.prefill_tokens)},
                 )
-            self._emit_first(idx, slot, int(next_tok))
+            self._emit_first(idx, slot, first_tok)
 
     def _export_shipment(self, slot: _Slot, first_token: int) -> None:
         """Pull the slot's prompt-covering pages off the device and attach
@@ -1111,7 +1187,7 @@ class ServingEngine:
         self.kv_pages_shipped += n_ship
         KV_PAGES_SHIPPED.inc(n_ship)
         KV_SHIP_SECONDS.observe(dt)
-        if req.trace_context is not None and _spans_enabled():
+        if req.trace_context is not None and self.spans_on:
             tracing.record_span(
                 "serving.kv_ship",
                 start=t0,
@@ -1241,7 +1317,7 @@ class ServingEngine:
         SERVING_PREEMPTIONS.inc()
         self._sync_page_gauges()
         now = time.time()
-        if req.trace_context is not None and _spans_enabled():
+        if req.trace_context is not None and self.spans_on:
             # flush the open decode interval, then mark the preemption; the
             # NEXT serving.admit span (anchored at queue_from) covers the
             # requeue wait as `queue` in the attribution
@@ -1291,6 +1367,7 @@ class ServingEngine:
 
         from ..models.paged_kv import paged_decode_step
 
+        self._phase("decode_prep")
         if self.spec_k:
             return self._spec_round()
         if not self._grow_pages():
@@ -1306,9 +1383,10 @@ class ServingEngine:
         for i, s in decoding:
             tokens[i] = s.cur_token
             active[i] = True
+        tokens_j, active_j = jnp.asarray(tokens), jnp.asarray(active)
+        self._phase("decode_dispatch", batch=len(decoding))
         logits, next_tokens, self.cache = paged_decode_step(
-            self.params, self.cfg, jnp.asarray(tokens), self.cache, jnp.asarray(active),
-            self.attn_impl,
+            self.params, self.cfg, tokens_j, self.cache, active_j, self.attn_impl
         )
         if any(s.request.temperature > 0 for _i, s in decoding):
             # one extra fixed-shape dispatch ONLY when a sampling request is
@@ -1325,12 +1403,13 @@ class ServingEngine:
             n_sampled = sum(1 for _i, s in decoding if s.request.temperature > 0)
             self.sampled_tokens += n_sampled
             SERVING_SAMPLED_TOKENS.inc(n_sampled)
+        self._phase("decode_sync", batch=len(decoding))
         next_host = np.asarray(next_tokens)
+        self._phase("emit", tokens=len(decoding))
         self.step_count += 1
         SERVING_BATCH_OCCUPANCY.observe(float(len(decoding)))
         emitted = 0
-        spans_on = _spans_enabled()
-        mark_every = _span_mark_tokens()
+        spans_on, mark_every = self.spans_on, self.span_mark_tokens
         for i, s in decoding:
             s.pos += 1  # the fed token was written at its position
             tok = int(next_host[i])
@@ -1422,7 +1501,7 @@ class ServingEngine:
         if n_sampled:
             self.sampled_tokens += n_sampled
             SERVING_SAMPLED_TOKENS.inc(n_sampled)
-        if _spans_enabled():
+        if self.spans_on:
             rep = min(decoding, key=lambda t: t[1].admitted_step)[1].request
             if rep.trace_context is not None:
                 tracing.record_span(
@@ -1453,6 +1532,7 @@ class ServingEngine:
         from ..models.sampling import sample_step
 
         k, k1 = self.spec_k, self.spec_k + 1
+        self._phase("decode_prep")
         cur = np.zeros((self.max_slots,), np.int32)
         active = np.zeros((self.max_slots,), bool)
         for i, s in group:
@@ -1462,6 +1542,7 @@ class ServingEngine:
         seeds, indices, temps, top_ks, top_ps = self._sampling_arrays(group, np)
         seeds_j, temps_j = jnp.asarray(seeds), jnp.asarray(temps)
         top_ks_j, top_ps_j = jnp.asarray(top_ks), jnp.asarray(top_ps)
+        self._phase("decode_dispatch", batch=len(group))
 
         # 1) draft chain: propose k tokens with the SAME (seed, index) keys
         # the target will sample with — a good draft then agrees often even
@@ -1515,10 +1596,11 @@ class ServingEngine:
 
         k, k1 = self.spec_k, self.spec_k + 1
         proposals_dev, targets_dev = pending
+        self._phase("decode_sync", batch=len(group))
         proposals = np.asarray(proposals_dev)  # [slots, k] — THE host sync
         targets = np.asarray(targets_dev).reshape(self.max_slots, k1)
-        spans_on = _spans_enabled()
-        mark_every = _span_mark_tokens()
+        self._phase("emit", tokens=len(group))
+        spans_on, mark_every = self.spans_on, self.span_mark_tokens
         new_lens = np.zeros((self.max_slots,), np.int32)
         update = np.zeros((self.max_slots,), bool)
         total_emitted = 0
@@ -1615,7 +1697,29 @@ class ServingEngine:
             waiting = len(self.waiting)
         acc = sum(a for a, _p in self._spec_window)
         prop = sum(p for _a, p in self._spec_window)
+        # the loop's own account of its time, from ONE snapshot of the phase
+        # table: wait (nothing to do) + work = seconds, and of the work host
+        # (not waiting on the device) + the two syncs (waiting on it)
+        phases = self._phases.snapshot()
+        by_kind = {"wait": 0.0, "sync": 0.0, "host": 0.0}
+        for name, seconds in phases.items():
+            by_kind[ENGINE_PHASES[name][1]] += seconds
+        work_seconds = by_kind["host"] + by_kind["sync"]
         return {
+            "loop": {
+                "iterations": self.loop_iterations,
+                "seconds": work_seconds + by_kind["wait"],
+                "wait_seconds": by_kind["wait"],
+                "work_seconds": work_seconds,
+                "host_seconds": by_kind["host"],
+                "phase_seconds": phases,
+            },
+            "requests_admitted": self.requests_admitted,
+            "queue_wait_seconds": self.queue_wait_seconds,
+            "prompt_tokens_prefilled": self.prompt_tokens_prefilled,
+            "prefill_chunks": self.prefill_chunks,
+            "prefill_bucket_tokens": self.prefill_bucket_tokens,
+            "tracing": {"span_write_seconds": tracing.span_write_seconds()},
             "max_slots": self.max_slots,
             "active_slots": active,
             "waiting": waiting,
