@@ -42,6 +42,9 @@ from jax import lax
 from .llama import LlamaConfig, apply_rope, repeat_kv, rms_norm, rope_frequencies
 
 DEFAULT_PAGE_SIZE = 16
+# positions of a slot's page row that a prefill chunk's attention visits in one
+# turn of its loop (`_prefill_attention`); a constant of the code, not an option
+PREFILL_KV_BLOCK = 512
 
 
 class PagePoolExhausted(Exception):
@@ -277,16 +280,18 @@ def _scatter_kv(k_pages, v_pages, k, v, page_ids, offsets):
 
 
 def _paged_attention(q, k_pages, v_pages, page_table, mask, positions=None, attn_impl="gather"):
-    """Attend each slot's page span. q: [S, Sq, H, hd]; k_pages/v_pages:
-    [P, page, n_kv, hd]; page_table: [S, pages_per_slot]; mask: [S, 1, Sq, K]
-    additive. Returns [S, Sq, H, hd].
+    """Attend each slot's page span: what `paged_decode_step` and
+    `paged_verify_step` run (a prefill chunk has `_prefill_attention`).
+    q: [S, Sq, H, hd]; k_pages/v_pages: [P, page, n_kv, hd]; page_table:
+    [S, pages_per_slot]; mask: [S, 1, Sq, K] additive. Returns [S, Sq, H, hd].
 
-    attn_impl (static at trace time): "gather" materializes the span via
-    `k_pages[page_table]` and runs the einsum reference; "kernel" /
-    "kernel_interpret" stream pages HBM→VMEM with the Pallas decode kernel
-    (ops/paged_attention.py) — decode only (Sq == 1, `positions` = each
-    slot's token position); multi-token calls (prefill/verify) always take
-    the gather path."""
+    attn_impl (static at trace time): "gather" materializes the WHOLE span via
+    `k_pages[page_table]`, live or not, and runs the einsum reference (the
+    CPU's decode step, every verify step, and what the prefill loop is tested
+    against); "kernel" / "kernel_interpret" stream pages HBM→VMEM with the
+    Pallas decode kernel (ops/paged_attention.py) — decode only (Sq == 1,
+    `positions` = each slot's token position); the verify step's multi-token
+    call always takes the gather path."""
     s, sq, h, hd = q.shape
     if attn_impl in ("kernel", "kernel_interpret") and sq == 1 and positions is not None:
         from ..ops.paged_attention import paged_decode_attention
@@ -317,9 +322,81 @@ def _paged_attention(q, k_pages, v_pages, page_table, mask, positions=None, attn
     return jnp.einsum("shqk,skhd->sqhd", probs, v_att)
 
 
-def _paged_layer(cfg, x, layer, positions, write_page_ids, write_offsets, mask, inv_freq, page_table, kp, vp, attn_impl="gather"):
+def prefill_kv_block_pages(pages_per_slot: int, page_size: int) -> int:
+    """Pages in one KV block of the prefill loop: `PREFILL_KV_BLOCK`
+    positions, never more than the slot's row holds."""
+    return max(1, min(PREFILL_KV_BLOCK // page_size, pages_per_slot))
+
+
+def prefill_kv_attended(live: int, pages_per_slot: int, page_size: int) -> int:
+    """Positions the prefill loop visits for a chunk whose last token sits at
+    `live - 1` (`start_pos + length` = live): whole blocks, so the host can
+    count what the device walks (`/v1/stats` `prefill_kv_attended`)."""
+    block = prefill_kv_block_pages(pages_per_slot, page_size) * page_size
+    return math.ceil(live / block) * block
+
+
+def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages):
+    """One slot's prefill chunk against the slot's LIVE prefix: a flash
+    forward over KV blocks of the page row. q: [Sq, H, hd] at positions q_pos
+    [Sq]; k_pages/v_pages: [P, page, n_kv, hd], the chunk's own K/V already
+    written; row: [pages_per_slot]; live: [] int32 = start_pos + length, the
+    positions that hold something. Returns [Sq, H, hd].
+
+    The trip count `ceil(live / block)` is data, so one executable serves
+    every prefix length and nothing of the span's size (mask, gathered K/V,
+    scores) exists. Each turn gathers `block_pages` pages, masks causally from
+    q_pos and the block's positions, and folds the block into a running max,
+    sum and float32 accumulator; query heads contract against their own KV
+    head (head = kv * n_rep + rep, `repeat_kv`'s order). Scores, max, sum and
+    accumulator are float32, the probabilities go to q's dtype for P·V: the
+    gather path's arithmetic with the sum reassociated. Every row sees
+    position 0 in block 0, so its running max is finite from the first turn
+    and rows past `length` (garbage, never read) cannot make a NaN."""
+    sq, h, hd = q.shape
+    page, n_kv = k_pages.shape[1], k_pages.shape[2]
+    n_rep = h // n_kv
+    block = block_pages * page
+    # a row the block does not divide is padded with the scratch page: those
+    # positions lie past the span, so past every row that is read
+    row = jnp.pad(row, (0, -row.shape[0] % block_pages))
+    qg = q.reshape(sq, n_kv, n_rep, hd)
+    scale = 1.0 / math.sqrt(hd)
+    offsets = jnp.arange(block, dtype=jnp.int32)
+
+    def fold(b, carry):
+        m, l, acc = carry
+        ids = lax.dynamic_slice_in_dim(row, b * block_pages, block_pages)
+        k_blk = k_pages[ids].reshape(block, n_kv, hd)
+        v_blk = v_pages[ids].reshape(block, n_kv, hd)
+        s = jnp.einsum("qgrd,kgd->grqk", qg, k_blk, preferred_element_type=jnp.float32) * scale
+        seen = (b * block + offsets)[None, :] <= q_pos[:, None]  # [Sq, block]
+        s = jnp.where(seen[None, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        shrink = jnp.exp(m - m_new)
+        l = shrink * l + p.sum(axis=-1)
+        acc = shrink[..., None] * acc + jnp.einsum(
+            "grqk,kgd->grqd", p.astype(q.dtype), v_blk, preferred_element_type=jnp.float32
+        )
+        return m_new, l, acc
+
+    init = (
+        jnp.full((n_kv, n_rep, sq), -jnp.inf, jnp.float32),
+        jnp.zeros((n_kv, n_rep, sq), jnp.float32),
+        jnp.zeros((n_kv, n_rep, sq, hd), jnp.float32),
+    )
+    _m, l, acc = lax.fori_loop(0, (live + block - 1) // block, fold, init)
+    out = (acc / l[..., None]).astype(q.dtype)  # [n_kv, n_rep, Sq, hd]
+    return out.transpose(2, 0, 1, 3).reshape(sq, h, hd)
+
+
+def _paged_layer(cfg, x, layer, positions, write_page_ids, write_offsets, inv_freq, kp, vp, attend):
     """One transformer layer over paged KV. x: [S, Sq, D]; positions:
-    [S, Sq]; write_page_ids/offsets: flat [S*Sq] scatter targets."""
+    [S, Sq]; write_page_ids/offsets: flat [S*Sq] scatter targets; attend:
+    (q [S, Sq, H, hd], k_pages, v_pages) -> [S, Sq, H, hd] over the pool as
+    this layer has just written it — the caller's own (`_paged_attention`
+    for decode and verify, `_prefill_attention` for a prefill chunk)."""
     from .quant import qmm
 
     s, sq, d = x.shape
@@ -340,9 +417,7 @@ def _paged_layer(cfg, x, layer, positions, write_page_ids, write_offsets, mask, 
                 v.reshape(s * sq, cfg.n_kv_heads, hd),
                 write_page_ids, write_offsets,
             )
-        attn_out = _paged_attention(
-            q, kp, vp, page_table, mask, positions=positions[:, 0], attn_impl=attn_impl
-        )
+        attn_out = attend(q, kp, vp)
         x = x + qmm(attn_out.reshape(s, sq, cfg.n_heads * hd), layer["wo"])
     with jax.named_scope("ffn"):
         h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
@@ -351,14 +426,13 @@ def _paged_layer(cfg, x, layer, positions, write_page_ids, write_offsets, mask, 
     return x, kp, vp
 
 
-def _run_layers(params, cfg, x, positions, write_page_ids, write_offsets, mask, page_table, cache, attn_impl="gather"):
+def _run_layers(params, cfg, x, positions, write_page_ids, write_offsets, cache, attend):
     inv_freq = rope_frequencies(cfg)
 
     def body(x_carry, layer_and_pages):
         layer, kp, vp = layer_and_pages
         x_out, kp, vp = _paged_layer(
-            cfg, x_carry, layer, positions, write_page_ids, write_offsets,
-            mask, inv_freq, page_table, kp, vp, attn_impl,
+            cfg, x_carry, layer, positions, write_page_ids, write_offsets, inv_freq, kp, vp, attend
         )
         return x_out, (kp, vp)
 
@@ -394,7 +468,11 @@ def paged_prefill(
     page and are never attended. Returns (last_logits [V], next_token [],
     cache); chunked callers ignore logits until the final chunk.
 
-    One executable per (cfg, S_pad): callers bucket prompt lengths
+    Each layer writes the chunk's K/V into the pool and then attends the
+    slot's live prefix, `start_pos + length` positions, in KV blocks of its
+    page row (`_prefill_attention`): the chunk's cost follows what the slot
+    holds, not `kv_span`. The live length is data, so it is still one
+    executable per (cfg, S_pad): callers bucket prompt lengths
     (PREFILL_BUCKETS) so arbitrary prompts hit a handful of compiles."""
     from .quant import qembed
 
@@ -406,17 +484,16 @@ def paged_prefill(
     row = cache.page_table[slot]  # [pages_per_slot]
     write_page_ids = jnp.where(valid, row[jnp.clip(positions // page, 0, row.shape[0] - 1)], 0)
     write_offsets = jnp.where(valid, positions % page, 0)
+    block_pages = prefill_kv_block_pages(row.shape[0], page)
+
+    def attend(q, k_pages, v_pages):
+        # causal within the live prefix: q at position p sees kv_pos <= p; rows
+        # past `length` are garbage but their outputs are never read
+        return _prefill_attention(q[0], k_pages, v_pages, row, positions, start_pos + length, block_pages)[None]
 
     x = qembed(params["embed"], tokens[None, :])  # [1, S_pad, D]
-    # causal within the slot's whole span: q at position p sees kv_pos <= p;
-    # rows past `length` are garbage but their outputs are never read
-    kv_pos = jnp.arange(cache.kv_span, dtype=jnp.int32)[None, None, None, :]
-    q_pos = positions[None, None, :, None]
-    mask = jnp.where(kv_pos <= q_pos, 0.0, -jnp.inf).astype(jnp.float32)  # [1,1,S_pad,K]
-
     x, k_pages, v_pages = _run_layers(
-        params, cfg, x, positions[None, :], write_page_ids, write_offsets,
-        mask, cache.page_table[slot][None, :], cache,
+        params, cfg, x, positions[None, :], write_page_ids, write_offsets, cache, attend
     )
     last = lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)  # [D]
     logits = _logits(params, cfg, last)
@@ -460,8 +537,8 @@ def paged_decode_step(
     mask = jnp.where(kv_pos <= positions[:, None, None, None], 0.0, -jnp.inf).astype(jnp.float32)
 
     x, k_pages, v_pages = _run_layers(
-        params, cfg, x, positions[:, None], write_page_ids, write_offsets, mask, rows, cache,
-        attn_impl,
+        params, cfg, x, positions[:, None], write_page_ids, write_offsets, cache,
+        partial(_paged_attention, page_table=rows, mask=mask, positions=positions, attn_impl=attn_impl),
     )
     logits = _logits(params, cfg, x[:, 0, :])  # [slots, V]
     cache = cache._replace(
@@ -511,7 +588,7 @@ def paged_verify_step(
 
     x, k_pages, v_pages = _run_layers(
         params, cfg, x, positions, write_page_ids.reshape(-1), write_offsets.reshape(-1),
-        mask, rows, cache,
+        cache, partial(_paged_attention, page_table=rows, mask=mask),
     )
     logits = _logits(params, cfg, x)  # [slots, K1, V]
     cache = cache._replace(k_pages=k_pages, v_pages=v_pages)

@@ -505,6 +505,8 @@ class ServingEngine:
         self.prompt_tokens_prefilled = 0
         self.prefill_chunks = 0
         self.prefill_bucket_tokens = 0
+        self.prefill_kv_attended = 0  # positions the chunks' attention loops visited
+        self.prefill_kv_span = 0  # max_context a chunk: what a span-wide attention would visit
         # the per-request timeline knobs, read once: an engine keeps the
         # setting it was built under
         self.spans_on = _spans_enabled()
@@ -1032,7 +1034,7 @@ class ServingEngine:
         import jax.numpy as jnp
         import numpy as np
 
-        from ..models.paged_kv import paged_prefill, prefill_bucket
+        from ..models.paged_kv import paged_prefill, prefill_bucket, prefill_kv_attended
 
         with self._lock:
             candidates = [
@@ -1073,6 +1075,10 @@ class ServingEngine:
             self.prompt_tokens_prefilled += len(chunk)
             self.prefill_chunks += 1
             self.prefill_bucket_tokens += bucket
+            self.prefill_kv_attended += prefill_kv_attended(
+                slot.prefill_done + len(chunk), self.pages_per_slot, self.page_size
+            )
+            self.prefill_kv_span += self.max_context
             if req.trace_context is not None and self.spans_on:
                 tracing.record_span(
                     "serving.prefill_chunk",
@@ -1719,6 +1725,8 @@ class ServingEngine:
             "prompt_tokens_prefilled": self.prompt_tokens_prefilled,
             "prefill_chunks": self.prefill_chunks,
             "prefill_bucket_tokens": self.prefill_bucket_tokens,
+            "prefill_kv_attended": self.prefill_kv_attended,
+            "prefill_kv_span": self.prefill_kv_span,
             "tracing": {"span_write_seconds": tracing.span_write_seconds()},
             "max_slots": self.max_slots,
             "active_slots": active,

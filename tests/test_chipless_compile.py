@@ -1,8 +1,10 @@
-"""Compiles for the chip without the chip: the kernel of the served path at
-the benchmark's real shapes, for a TPU v5e that is described and not attached
-(the TPU's compiler is installed where the tests run). Nothing runs, so this
-says nothing about results or times: it guards that Mosaic still takes the
-kernel as written and that the kernel keeps its stable name.
+"""Compiles for the chip without the chip: the kernel of the served path and
+the prefill chunk at the benchmark's real shapes, for a TPU v5e that is
+described and not attached (the TPU's compiler is installed where the tests
+run). Nothing runs, so this says nothing about results or times: it guards
+that Mosaic still takes the kernel as written, that the kernel and the
+prefill program keep their stable names, and that nothing of the slot
+span's size is left in a prefill chunk.
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process may load the TPU's library, each xdist worker imports every
@@ -10,6 +12,7 @@ test file, and only the worker that runs THIS file may make the call. Keep
 every such compile in this one file.
 """
 
+import math
 import os
 import re
 
@@ -20,6 +23,11 @@ import pytest
 CELL_SHAPES = {
     "mistral-7b-v0.3-serve-1chip": (32, 512, 8, 4, 3072),
     "yi-1.5-6b-serve-1chip": (24, 256, 4, 8, 6144),
+}
+# (vocabulary, FFN width) of the same two; hidden 4096, 16 layers, chunks of 128 tokens
+CELL_WIDTHS = {
+    "mistral-7b-v0.3-serve-1chip": (32768, 14336),
+    "yi-1.5-6b-serve-1chip": (64000, 11008),
 }
 
 
@@ -76,3 +84,52 @@ def test_paged_decode_attention_compiles_for_v5e_under_its_name(config, one_chip
     # (without `name=` on the pallas_call XLA calls it `closed_call.<n>`)
     calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1 and re.search(r"%paged_decode_attention(\.\d+)? = ", calls[0]), calls
+
+
+@pytest.mark.parametrize("config", sorted(CELL_SHAPES))
+def test_paged_prefill_compiles_for_v5e_with_nothing_of_the_span_s_size(config, one_chip, no_compile_cache):
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models.llama import get_config, init_params
+    from modal_tpu.models.paged_kv import PagedKVCache, paged_prefill
+
+    slots, pages_per_slot, n_kv, n_rep, pool_pages = CELL_SHAPES[config]
+    vocab, ffn = CELL_WIDTHS[config]
+    s_pad, page = 128, 16
+    kv_span = pages_per_slot * page
+    cfg = get_config(
+        "llama3-8b", vocab_size=vocab, n_layers=16, n_heads=n_kv * n_rep, n_kv_heads=n_kv, ffn_dim=ffn, max_seq_len=kv_span
+    )
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = described(jax.eval_shape(lambda: PagedKVCache.create(cfg, slots, pool_pages, page)))
+    assert cache.page_table.shape == (slots, pages_per_slot)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((s_pad,), jnp.int32, sharding=one_chip)
+    text = paged_prefill.lower(params, cfg, tokens, scalar, cache, scalar, scalar).compile().as_text()
+    # the name `prefill_chunk_ms` and the breakdown find the program by
+    assert text.startswith("HloModule jit_paged_prefill,")
+    # every array an instruction makes or a computation takes: `type[dims]`. The scores are float32
+    # and a mask float32, integer or boolean: no such array may be as large as the span-wide ones
+    # were, but a weight where a fusion reads it converted (the pool and the weights are bfloat16)
+    weights = {a.shape[skip:] for a in jax.tree.leaves(params) for skip in (0, 1)}
+    shapes = {
+        (dtype, tuple(int(d) for d in dims.split(",")))
+        for dtype, dims in re.findall(r"\b([a-z]+\d+|pred)\[([\d,]+)\]", text)
+    }
+    assert len(shapes) > 50 and ("bf16", (s_pad, cfg.dim)) in shapes and ("f32", (vocab,)) in shapes
+    scores = cfg.n_heads * s_pad * kv_span
+    for dtype, shape in shapes:
+        assert dtype == "bf16" or math.prod(shape) < scores or shape in weights, (dtype, shape)
+    # the pool is gathered a block of pages at a time, never a row's whole span
+    pool_gathers = [
+        int(pages)
+        for pages in re.findall(rf"= bf16\[(\d+),{page},{n_kv},128\]\S* gather\([^\n]*slice_sizes={{1,{page},{n_kv},128}}", text)
+    ]
+    assert len(pool_gathers) == 2 and all(pages * page <= 1024 < kv_span for pages in pool_gathers), pool_gathers
+    # the loop over KV blocks is there, with a trip count that is data: a `while` inside the layers' `while`
+    assert len(re.findall(r" while\(", text)) >= 2

@@ -133,6 +133,108 @@ def test_paged_prefill_matches_dense(tiny_model):
     assert int(cache.seq_lens[0]) == 11
 
 
+# (query heads a KV head, pages a slot, pages a block, start_pos, length, S_pad): pages of 16. A
+# block of `b` pages is 16*b positions; unassigned row entries are the scratch page 0
+PREFILL_ATTENTION_CASES = {
+    "first-chunk-one-block": (2, 8, 2, 0, 10, 16),
+    "mid-page-start-ends-inside-block": (2, 8, 2, 21, 16, 16),
+    "page-aligned-ends-at-block-end": (2, 8, 2, 32, 32, 32),
+    "beyond-one-block-short-length": (2, 8, 2, 70, 9, 16),
+    "block-does-not-divide-row-full-span": (2, 7, 2, 96, 16, 16),
+    "block-does-not-divide-row-live-in-last": (2, 5, 3, 50, 7, 16),
+    "block-clamped-to-row": (2, 4, None, 20, 12, 16),
+    "one-page-blocks": (2, 8, 1, 37, 16, 16),
+    "gqa-1": (1, 8, 2, 21, 16, 16),
+    "gqa-4": (4, 8, 2, 21, 16, 16),
+    "gqa-8": (8, 8, 2, 21, 16, 16),
+    "gqa-8-row-not-divided": (8, 7, 2, 90, 20, 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREFILL_ATTENTION_CASES))
+def test_prefill_attention_matches_the_gather_reference(case):
+    """The prefill chunk's KV-block loop against the span-wide gather path
+    (`_paged_attention`, what verify and the CPU's decode run) on the same pool
+    and page row: the same arithmetic with the sum reassociated, so float32
+    agrees to rounding and bfloat16 within the logit tolerance; every row that
+    is read is finite though masked positions and the scratch page hold
+    garbage."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from modal_tpu.models.paged_kv import PREFILL_KV_BLOCK, _paged_attention, _prefill_attention, prefill_kv_block_pages
+
+    n_rep, pages_per_slot, block_pages, start_pos, length, s_pad = PREFILL_ATTENTION_CASES[case]
+    if block_pages is None:
+        block_pages = prefill_kv_block_pages(pages_per_slot, PAGE)
+        assert block_pages == pages_per_slot < PREFILL_KV_BLOCK // PAGE
+    n_kv, hd, pool = 2, 16, 12
+    live = start_pos + length
+    span = pages_per_slot * PAGE
+    assert live <= span
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    held = -(-live // PAGE)
+    # the slot holds `held` pages in no order; the rest of its row is the scratch page
+    row = jnp.zeros((pages_per_slot,), jnp.int32).at[:held].set(jax.random.permutation(keys[0], pool - 1)[:held] + 1)
+    q_pos = start_pos + jnp.arange(s_pad, dtype=jnp.int32)
+    mask = jnp.where(jnp.arange(span)[None, :] <= q_pos[:, None], 0.0, -jnp.inf)[None, None].astype(jnp.float32)
+    for dtype, atol in ((jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)):
+        q = jax.random.normal(keys[1], (s_pad, n_kv * n_rep, hd), dtype)
+        # page 0 (scratch) holds values that would swamp a row if a masked position leaked in
+        k_pages = jax.random.normal(keys[2], (pool, PAGE, n_kv, hd), dtype).at[0].set(30.0)
+        v_pages = jax.random.normal(keys[3], (pool, PAGE, n_kv, hd), dtype).at[0].set(1e4)
+        got = _prefill_attention(q, k_pages, v_pages, row, q_pos, jnp.int32(live), block_pages)
+        ref = _paged_attention(q[None], k_pages, v_pages, row[None], mask)[0]
+        assert got.shape == ref.shape and got.dtype == ref.dtype
+        got, ref = np.asarray(got[:length], np.float32), np.asarray(ref[:length], np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, ref, atol=atol, rtol=0)
+
+
+@pytest.mark.parametrize("chunk", ["first-chunk", "mid-page-beyond-one-block", "page-aligned-ends-at-block-end"])
+@pytest.mark.parametrize("n_rep", [1, 4, 8])
+def test_paged_prefill_logits_match_the_span_wide_path(n_rep, chunk):
+    """`paged_prefill` (the KV-block loop, at the block size the code ships:
+    512 positions of a 640-position row it does not divide) against
+    `paged_verify_step` (the gather path over the whole span) on the same
+    pool: the chunk's last logits within the dense test's tolerance."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from modal_tpu.models.llama import get_config, init_params
+    from modal_tpu.models.paged_kv import PagedKVCache, assign_pages, paged_prefill, paged_verify_step
+
+    start_pos, length, s_pad = {
+        "first-chunk": (0, 10, 16),
+        "mid-page-beyond-one-block": (521, 20, 32),
+        "page-aligned-ends-at-block-end": (480, 32, 32),
+    }[chunk]
+    heads = max(4, n_rep)
+    cfg = dataclasses.replace(get_config("tiny"), n_heads=heads, n_kv_heads=heads // n_rep)
+    params = init_params(cfg, jax.random.PRNGKey(n_rep))
+    slots, pages_per_slot, pool = 2, 40, 48
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (start_pos + s_pad,), 0, cfg.vocab_size).astype(jnp.int32)
+    cache = PagedKVCache.create(cfg, slots, pool, PAGE, pages_per_slot)
+    held = -(-(start_pos + length) // PAGE)
+    cache = assign_pages(cache, 0, 0, jnp.arange(pool - held, pool, dtype=jnp.int32))  # the rest of the row: scratch
+    if start_pos:
+        prefix = jnp.zeros((544,), jnp.int32).at[:start_pos].set(tokens[:start_pos])
+        _l, _t, cache = paged_prefill(params, cfg, prefix, jnp.int32(start_pos), cache, jnp.int32(0), jnp.int32(0))
+    chunk_tokens = tokens[start_pos:].at[length:].set(0)
+    wide, _ = paged_verify_step(
+        params, cfg, jnp.zeros((slots, s_pad), jnp.int32).at[0].set(chunk_tokens),
+        jax.tree.map(jnp.copy, cache), jnp.asarray([True, False]),
+    )
+    logits, tok, cache = paged_prefill(params, cfg, chunk_tokens, jnp.int32(length), cache, jnp.int32(0), jnp.int32(start_pos))
+    logits = np.asarray(logits)
+    assert np.isfinite(logits).all() and int(tok) == logits.argmax() and int(cache.seq_lens[0]) == start_pos + length
+    np.testing.assert_allclose(logits, np.asarray(wide[0, length - 1]), atol=3e-2, rtol=0)
+
+
 def test_total_kv_bytes_bounded_by_pool_not_requests(tiny_model):
     """The acceptance inequality: engine KV bytes are the POOL's, and the
     pool is smaller than dense per-request max_len caches for the same
@@ -671,6 +773,64 @@ def test_prefix_cache_share_cow_and_eviction(tiny_model):
         eng.stop()
     # stop() clears the cache: every page accounted for, no refcount leaks
     assert eng.allocator.free_pages == PAGES - 1
+
+
+def test_prefix_hit_with_cow_streams_the_tokens_of_a_cold_prefill(tiny_model):
+    """A follower whose prompt hits the prefix cache mid-page, past the first
+    KV block of its row (its one prefill chunk starts at position 524 of a
+    640-position row, in a page it must copy before writing), streams what
+    the same prompt streams on an engine without the cache."""
+    import numpy as np
+
+    params, cfg = tiny_model
+    rng = np.random.default_rng(27)
+    sysprompt = rng.integers(0, cfg.vocab_size, size=523).tolist()
+    follower = sysprompt + [5, 9, 9, 9, 9]
+    geometry = dict(pages_per_slot=40, num_pages=100, prefill_chunk=128)
+    eng = _engine(params, cfg, prefix_cache=True, **geometry).start()
+    try:
+        eng.submit(sysprompt + [5, 6], max_new_tokens=4).result(timeout=120)
+        before = eng.stats()
+        warm = eng.submit(follower, max_new_tokens=12).result(timeout=120)
+        after = eng.stats()
+    finally:
+        eng.stop()
+    assert after["prefix_cache_hits"] == before["prefix_cache_hits"] + 1
+    assert after["kv_pages_cow_copies"] > before["kv_pages_cow_copies"]
+    # the hit covered 524 tokens: one chunk of 4 was computed, its loop walked both blocks of 512
+    assert after["prompt_tokens_prefilled"] - before["prompt_tokens_prefilled"] == 4
+    assert after["prefill_kv_attended"] - before["prefill_kv_attended"] == 1024
+    cold_eng = _engine(params, cfg, prefix_cache=False, **geometry).start()
+    try:
+        cold = cold_eng.submit(follower, max_new_tokens=12).result(timeout=120)
+        assert cold_eng.stats()["prefix_cache_hits"] == 0
+    finally:
+        cold_eng.stop()
+    assert warm == cold and len(warm) == 12
+
+
+def test_stats_count_what_the_prefill_loop_attends_against_the_span(tiny_model):
+    """`/v1/stats` `prefill_kv_attended` / `prefill_kv_span`: whole KV blocks
+    the chunks' attention walked (from the live prefix, on the host) and
+    `max_context` a chunk. A prompt of 530 tokens in chunks of 512 and 18 on
+    rows of 1,024 positions walks one block of 512, then two."""
+    from modal_tpu.models.paged_kv import PREFILL_KV_BLOCK, prefill_kv_attended
+
+    assert PREFILL_KV_BLOCK == 512
+    assert [prefill_kv_attended(n, 64, PAGE) for n in (1, 512, 513, 1024)] == [512, 512, 1024, 1024]
+    assert prefill_kv_attended(100, PAGES_PER_SLOT, PAGE) == PAGES_PER_SLOT * PAGE  # a block is at most the row
+    params, cfg = tiny_model
+    eng = _engine(params, cfg, pages_per_slot=64, num_pages=80, prefill_chunk=512, prefix_cache=False).start()
+    try:
+        before = eng.stats()
+        assert before["prefill_kv_attended"] == 0 and before["prefill_kv_span"] == 0
+        eng.submit([i % 500 + 1 for i in range(530)], max_new_tokens=2).result(timeout=120)
+        after = eng.stats()
+    finally:
+        eng.stop()
+    assert after["prefill_chunks"] == 2
+    assert after["prefill_kv_attended"] == 512 + 1024
+    assert after["prefill_kv_span"] == 2 * 1024 == 2 * eng.max_context
 
 
 def test_prefix_cache_cow_refcounts_under_preemption(tiny_model):
