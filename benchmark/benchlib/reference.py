@@ -23,6 +23,25 @@ logit lies below the reference's best at its position. With
 operand rounded to float8 (e4m3, scaled per channel / per token) and the
 gap of the token THAT pass puts first is read against the reference: the
 control that has to come out as not correct.
+
+What is architecture here is `model_shapes`, `init_weights`, `_layer` and
+`Reference`. The rest is for every architecture: a configuration whose key
+`reference` names a file of its own (a sibling of this one; the harness
+puts the benchmark's directory on the child's path) brings `init_weights`
+and the forward pass and takes the comparison from here:
+
+    from benchlib.reference import _fp8, _mm, main, padded
+    class Reference: ...   # __init__(cfg, seed, pad_to), logits(tokens, positions, low=False)
+    if __name__ == "__main__":
+        sys.exit(main(sys.argv[1:], Reference))
+
+The child's contract, whatever the file: `python <file> job.json out.json`.
+The job holds `config`, `seed`, `control` ("" or "fp8"), `require_platform`,
+`pad_to` and `requests` ([{index, prompt, tokens}]); the output holds
+`logit_gap_max`, `logit_gap_mean`, `tokens_compared`, `requests_compared`,
+`per_request`, `platform`, `init_s`, `compare_s`, under `control: "fp8"`
+also `control_logit_gap_max` and `control_logit_gap_mean`; exit 3 where
+jax is not on `require_platform`.
 """
 
 from __future__ import annotations
@@ -181,20 +200,28 @@ class Reference:
         return self._forward[low]
 
     def logits(self, tokens: list, positions: list, low: bool = False):
-        """float32 logits [len(positions), vocab] of the sequence `tokens`.
-        Sequences and position lists are padded (to `pad_to`, to 512s) so
-        that a cell's reference is one compiled shape; a padded position
-        comes after the real ones and, being causal, changes none of them."""
+        """float32 logits [len(positions), vocab] of the sequence `tokens`."""
         import jax
         import numpy as np
 
-        n, m = len(tokens), len(positions)
-        ids = np.zeros((max(self.pad_to, -(-n // PAD_TO) * PAD_TO),), np.int32)
-        ids[:n] = tokens
-        pos = np.zeros((-(-m // PAD_TO) * PAD_TO,), np.int32)
-        pos[:m] = positions
+        ids, pos = padded(tokens, positions, self.pad_to)
         with jax.default_matmul_precision("highest"):
-            return np.asarray(self._program(low)(self.weights, ids, pos))[:m]
+            return np.asarray(self._program(low)(self.weights, ids, pos))[: len(positions)]
+
+
+def padded(tokens: list, positions: list, pad_to: int = 0) -> tuple:
+    """The padding rule: sequences and position lists are padded with zeros
+    (to `pad_to`, to 512s) so that a cell's reference is one compiled
+    shape; a padded position comes after the real ones and, the model being
+    causal, changes none of them."""
+    import numpy as np
+
+    n, m = len(tokens), len(positions)
+    ids = np.zeros((max(int(pad_to), -(-n // PAD_TO) * PAD_TO),), np.int32)
+    ids[:n] = tokens
+    pos = np.zeros((-(-m // PAD_TO) * PAD_TO,), np.int32)
+    pos[:m] = positions
+    return ids, pos
 
 
 def served_gap(logits, served: list) -> list:
@@ -206,8 +233,11 @@ def served_gap(logits, served: list) -> list:
     return [float(g) for g in (best - mine)]
 
 
-def compare(ref: Reference, requests: list, control: str = "") -> dict:
-    """The numbers of `correct` for the sampled requests."""
+def compare(ref, requests: list, control: str = "") -> dict:
+    """The numbers of `correct` for the sampled requests. `ref` is any
+    object with `logits(tokens, positions, low=False)`: float32 logits
+    [len(positions), vocab] of the sequence `tokens`, in float8 operands
+    where `low`. Nothing else of it is touched."""
     import numpy as np
 
     gaps, control_gaps, per_request = [], [], []
@@ -240,7 +270,9 @@ def compare(ref: Reference, requests: list, control: str = "") -> dict:
     return out
 
 
-def main(argv: list) -> int:
+def main(argv: list, make_reference=None) -> int:
+    """`make_reference(config, seed, pad_to)` builds the object `compare`
+    takes: another architecture's file passes its own class."""
     job_path, out_path = argv[0], argv[1]
     with open(job_path) as f:
         job = json.load(f)
@@ -254,7 +286,7 @@ def main(argv: list) -> int:
     if job.get("require_platform") and device.platform != job["require_platform"]:
         sys.stderr.write(f"reference: jax is on {device.platform!r}, wanted {job['require_platform']!r}\n")
         return 3
-    ref = Reference(job["config"], job["seed"], job.get("pad_to", 0))
+    ref = (make_reference or Reference)(job["config"], job["seed"], job.get("pad_to", 0))
     t1 = time.monotonic()
     out = compare(ref, job["requests"], job.get("control", ""))
     out.update(platform=device.platform, init_s=t1 - t0, compare_s=time.monotonic() - t1)

@@ -16,8 +16,8 @@ A length distribution is {"dist": "lognormal", "median", "sigma", "min",
 Every `--seed` gets the same sizes and gaps in the same order (drawn from
 `shape_seed` and the window's length) with other token ids (and the service
 other weights): two seeds do the same work, and a difference between runs
-is the system's, not the draw's. A window here holds some twenty requests,
-so a tail over them is an order statistic of two or three: their order is
+is the system's, not the draw's. A window here holds some sixty requests,
+so a tail over them is an order statistic of a handful: their order is
 part of the work (PERF.md section 4 has the readings).
 """
 

@@ -15,7 +15,8 @@ def shapes(cfg: dict) -> dict:
     d = int(cfg["hidden_size"])
     h = int(cfg["num_attention_heads"])
     kv = int(cfg["num_key_value_heads"])
-    hd = d // h
+    # a published `head_dim` need not be hidden_size / heads; where the config gives none, it is
+    hd = int(cfg["head_dim"]) if cfg.get("head_dim") else d // h
     return {
         "d": d, "h": h, "kv": kv, "hd": hd, "ffn": int(cfg["intermediate_size"]),
         "layers": int(cfg["num_hidden_layers"]), "vocab": int(cfg["vocab_size"]),
@@ -88,3 +89,9 @@ def paged_decode_kernel_bytes(cfg: dict, active_slots: float, live_kv_tokens: fl
     s = shapes(cfg)
     per_layer_kv = live_kv_tokens * kv_bytes_per_token(cfg) / s["layers"]
     return per_layer_kv + 2 * active_slots * s["h"] * s["hd"] * s["bytes"]
+
+
+def paged_decode_kernel_flops(cfg: dict, active_slots: float, live_kv_tokens: float) -> float:
+    """Operations the paged decode attention of ONE layer needs: each live
+    key and value meets its slot's query once (QK^T and PV)."""
+    return attention_flops(cfg, 0, 1) * live_kv_tokens / cfg["num_hidden_layers"]

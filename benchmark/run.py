@@ -10,7 +10,8 @@
       -> the window: the traffic file's schedule, POST /v1/generate streamed
       -> /v1/stats at both ends, /bench/device, (traced: /bench/trace/*)
       -> the app is stopped, the container exits, the chip is free
-      -> benchlib/reference.py in a child: the plain reference over a sample
+      -> the configuration's reference (its key `reference`, default
+         benchlib/reference.py) in a child: the plain reference over a sample
          of the requests the window finished decides `correct`
       -> one last line on standard output, built by benchlib/emit.py
 
@@ -29,6 +30,7 @@ PROCESS_START = time.monotonic()
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
 import importlib  # noqa: E402
+import inspect  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import random  # noqa: E402
@@ -42,6 +44,7 @@ REPO_ROOT = os.path.dirname(BENCH_DIR)
 sys.path.insert(0, BENCH_DIR)
 
 from benchlib import client, emit as emit_mod, stats, traffic  # noqa: E402
+from kernels import counts_for  # noqa: E402
 
 
 class RunFailed(Exception):
@@ -63,12 +66,14 @@ def load_json(path: str) -> dict:
 
 def load_cell(root: str, workload: str) -> dict:
     """The cell's entry, configuration, traffic and metrics, found by name.
-    `root` holds BENCHMARK.json and the data files it names."""
+    `root` holds BENCHMARK.json, the configuration's file and the traffic;
+    what a configuration or a metric names in turn is this directory's."""
     bench = load_json(os.path.join(root, "BENCHMARK.json"))
     cell = next((w for w in bench["workloads"] if w["name"] == workload), None)
     if cell is None:
         raise RunFailed(f"BENCHMARK.json has no workload {workload!r}")
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    config = load_json(os.path.join(root, conf["file"]))
     e2e = {m["name"]: m for m in bench["end_to_end"] if workload in m.get("workloads", [workload])}
     # a per-layer metric without a list of cells belongs to every cell that
     # reports the end-to-end metric it moves
@@ -79,18 +84,53 @@ def load_cell(root: str, workload: str) -> dict:
     return {
         "bench": bench,
         "cell": cell,
-        "config": load_json(os.path.join(root, conf["file"])),
+        "config": config,
+        "reference": check_names(conf["file"], config, per_layer),
         "traffic": traffic.load(traffic.find(os.path.join(root, bench["paths"][0], "traffic"), cell["traffic"])),
         "end_to_end": e2e,
         "per_layer": per_layer,
     }
 
 
-def read_layer_metric(name: str, ctx: dict):
+def check_names(conf_file: str, config: dict, per_layer: dict) -> str:
+    """Everything the cell finds by a NAME in a file, found now, before
+    anything boots: the configuration's reference and counts (files of this
+    directory; another architecture brings its own) and, for each of the
+    cell's per-layer metrics, the functions and the configuration key its
+    reader will look up. A name that finds nothing ends the run, naming the
+    file and the key: never a fall-back to the default, never an error
+    after the window. Returns the reference's path."""
+    named = {
+        "reference": config.get("reference", "benchlib/reference.py"),
+        "counts": os.path.join("kernels", config.get("counts", "counts") + ".py"),
+    }
+    for key, rel in named.items():
+        if not os.path.isfile(os.path.join(BENCH_DIR, rel)):
+            raise RunFailed(f"{conf_file}: key {key!r} names {config.get(key)!r}: no file {os.path.join(BENCH_DIR, rel)}")
+    counts = counts_for(config)
+    for name in per_layer:
+        reader, args = layer_metric(name)
+        takes = inspect.signature(reader.read).parameters
+        for key in ("bytes_fn", "flops_fn", "calls_key"):  # the reader's default where the file names none
+            if key not in takes:
+                continue
+            value = args.get(key, takes[key].default)
+            found = value in config if key == "calls_key" else callable(getattr(counts, value, None))
+            if not found:
+                where = conf_file if key == "calls_key" else named["counts"] + " (" + conf_file + ")"
+                raise RunFailed(f"layer_metrics/{name}.json: {key} names {value!r}: not in {where}")
+    return os.path.join(BENCH_DIR, named["reference"])
+
+
+def layer_metric(name: str) -> tuple:
     """A per-layer metric is a file of its own naming a reader of its own."""
     spec = load_json(os.path.join(BENCH_DIR, "layer_metrics", name + ".json"))
-    reader = importlib.import_module("readers." + spec["reader"])
-    return reader.read(ctx, **spec.get("args", {}))
+    return importlib.import_module("readers." + spec["reader"]), spec.get("args", {})
+
+
+def read_layer_metric(name: str, ctx: dict):
+    reader, args = layer_metric(name)
+    return reader.read(ctx, **args)
 
 
 # -- the device, from a child ---------------------------------------------------
@@ -248,7 +288,7 @@ def run_reference(cell: dict, seed: int, sample: list, state_dir: str, control: 
     job_path, out_path = os.path.join(state_dir, "reference_job.json"), os.path.join(state_dir, "reference_out.json")
     with open(job_path, "w") as f:
         json.dump(job, f)
-    run_child([os.path.join(BENCH_DIR, "benchlib", "reference.py"), job_path, out_path], timeout_s=300)
+    run_child([cell["reference"], job_path, out_path], timeout_s=300)
     return load_json(out_path)
 
 
@@ -449,7 +489,7 @@ def measure(args, root: str = REPO_ROOT, state_root: str = "") -> dict:
         extra["control"] = args.control
     if extra["compiles_in_window"]:
         log(f"WARNING: {extra['compiles_in_window']} compile event(s) inside the window")
-    breakdown, required, reported = None, e2e_required, e2e
+    breakdown, required, reported, ctx = None, e2e_required, e2e, None
     if args.trace:
         ctx = {
             "config": cell["config"], "peaks": peaks[device["kind"]], "chips": chips, "timings": timings,
@@ -475,7 +515,7 @@ def measure(args, root: str = REPO_ROOT, state_root: str = "") -> dict:
         device=out_device, traced=bool(args.trace), compared=compared, breakdown=breakdown, extra=extra,
     )
     if args.dump:
-        dump(args.dump, state_dir, line, result, numbers, reference, timings)
+        dump(args.dump, state_dir, line, result, numbers, reference, timings, ctx)
     return line
 
 
@@ -484,8 +524,10 @@ def compile_events(stats_: dict) -> int:
     return int(sum(v for k, v in events.items() if "cache" in k or "compile" in k))
 
 
-def dump(path: str, state_dir: str, line: dict, result: dict, numbers: dict, reference: dict, timings: dict) -> None:
-    """Development only: everything a run saw, for the output directory."""
+def dump(path: str, state_dir: str, line: dict, result: dict, numbers: dict, reference: dict, timings: dict, ctx: dict | None) -> None:
+    """Development only: everything a run saw, for the output directory.
+    `ctx` (traced runs) is what the readers read: fed to another tree's
+    readers it shows, offline, whether a per-layer metric moved."""
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
     recs = [
         {"index": r.index, "prompt_len": r.prompt_len, "max_new_tokens": r.max_new_tokens, "due": r.due - result["t0"],
@@ -494,12 +536,10 @@ def dump(path: str, state_dir: str, line: dict, result: dict, numbers: dict, ref
          "error": r.error, "cut": r.cut, "server_ttft_s": r.server_ttft_s}
         for r in result["records"]
     ]
-    summary = os.path.join(state_dir, "trace_summary.json")
-    trace = load_json(summary) if os.path.isfile(summary) else {}
     with open(path, "w") as f:
         json.dump({
             "line": line, "records": recs, "stats_start": result["stats_start"], "stats_end": result["stats_end"],
-            "device": result["device"], "reference": reference, "timings": timings, "trace": trace,
+            "device": result["device"], "reference": reference, "timings": timings, "ctx": ctx,
             "ttft_ms": sorted(numbers["ttft_ms"]), "itl_ms_percentiles": {q: stats.percentile(numbers["itl_ms"], q) for q in (50, 90, 99)} if numbers["itl_ms"] else {},
         }, f)
     table = os.path.join(state_dir, "trace_table.json")

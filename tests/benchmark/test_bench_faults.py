@@ -10,38 +10,10 @@ import os
 import pytest
 
 from . import _paths
+from ._drive import alter_a_token, skip_the_chip_look
 import run as bench_run
 
 TINYROOT = os.path.join(_paths.FIXTURES, "tinyroot")
-
-
-def fake_probe(_peaks, _chips):
-    return {"platform": "cpu", "kind": "cpu", "count": 1}
-
-
-def alter_a_token(built):
-    """Break the program under the harness: the class `llm_service` hands
-    back gets a load() that is its own, after which every stream's fourth
-    token is off by one at the point where the engine hands it to the
-    stream. (The class is local, so it travels to the container by value.)"""
-    import modal_tpu
-
-    class AlterToken(built._user_cls):
-        @modal_tpu.enter(snap=True)
-        def load(self):
-            parent = super().load  # a partial here, the bound method in the container
-            parent.raw_f(self) if hasattr(parent, "raw_f") else parent()
-            from modal_tpu.serving.engine import GenRequest
-
-            original = GenRequest._append
-
-            def altered(req, token):
-                original(req, (token + 1) % 512 if len(req.tokens) == 3 else token)
-
-            GenRequest._append = altered
-
-    built._user_cls = AlterToken
-    return built
 
 
 @pytest.fixture
@@ -50,16 +22,7 @@ def bench_env(supervisor, tmp_path, monkeypatch):
     for key in ("MODAL_TPU_STATE_DIR", "JAX_COMPILATION_CACHE_DIR", "PYTHONPATH"):
         monkeypatch.setenv(key, os.environ.get(key, ""))
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jit_cache"))
-    # the look for a chip is skipped, and a CPU backend reports no memory statistics
-    monkeypatch.setattr(bench_run, "probe_device", fake_probe)
-    serve = bench_run.serve_and_measure
-
-    def serve_with_a_memory_reading(*args):
-        result = serve(*args)
-        result["device"]["memory_peak_bytes"] = result["device"]["memory_peak_bytes"] or 1
-        return result
-
-    monkeypatch.setattr(bench_run, "serve_and_measure", serve_with_a_memory_reading)
+    skip_the_chip_look(bench_run, monkeypatch.setattr)
     return str(tmp_path / "bench_state")
 
 
