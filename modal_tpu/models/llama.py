@@ -30,6 +30,20 @@ from jax import lax
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer is, for the served path: its attention (KV heads, a
+    window or none, a sink or none, its rotary base) and its FFN (dense, or
+    the routed experts the config describes). Hashable: part of a jit key."""
+
+    n_kv_heads: int
+    window: int = 0  # 0 = full causal attention
+    sink: bool = False
+    rope_theta: float = 500_000.0
+    experts: bool = False
+    attn_name: str = ""  # "", "full" or "swa": suffix of the scope's and the decode kernel's name
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     name: str
     vocab_size: int
@@ -48,16 +62,126 @@ class LlamaConfig:
     n_experts: int = 0
     capacity_factor: float = 1.25
     moe_aux_coef: float = 0.01
+    # -- what a layer is, where the layers are not all alike (served path:
+    # models/paged_kv.py; `layer_kinds` below turns these into one LayerKind
+    # a layer). Every default is "the dense block, every layer alike".
+    qk_head_dim: int = 0  # query/key head width; 0 = dim // n_heads
+    v_head_dim: int = 0  # value head width; 0 = the query/key width
+    rope_fraction: float = 1.0  # rotary covers the first int(head_dim * fraction) dims of a head
+    value_scale: float = 1.0  # v is multiplied by this before it is cached
+    attn_pattern: tuple = ()  # a layer: 0 full attention, 1 sliding window; () = all full
+    window: int = 0  # a window layer's query at p sees keys p-window+1 .. p
+    window_kv_heads: int = 0  # KV heads of a window layer; 0 = n_kv_heads
+    window_rope_theta: float = 0.0  # rotary base of a window layer; 0 = rope_theta
+    window_sink: bool = False  # a learned logit a query head joins the window softmax's denominator
+    ffn_pattern: tuple = ()  # a layer: 0 dense SwiGLU, 1 routed experts; () = all dense
+    n_routed_experts: int = 0  # the router's width (sigmoid scores, top-k, weights renormalised)
+    experts_per_token: int = 0
+    expert_dim: int = 0  # one routed expert's SwiGLU width
+    # the share of the routed experts that lives here (expert parallelism's
+    # cut): experts experts_held_start .. +n_experts_held; 0 = all of them
+    n_experts_held: int = 0
+    experts_held_start: int = 0
+
+    def __post_init__(self):
+        for field in ("attn_pattern", "ffn_pattern"):
+            # a depth cut keeps the first n_layers of a longer pattern: the
+            # published one, so {"name": preset, "n_layers": 7} is a cut
+            pattern = tuple(int(v) for v in getattr(self, field))
+            if pattern and len(pattern) < self.n_layers:
+                raise ValueError(f"{field} names {len(pattern)} layers, n_layers is {self.n_layers}")
+            object.__setattr__(self, field, pattern[: self.n_layers])  # a tuple: a list (JSON) would not hash under jit
+        if any(self.attn_pattern) and self.window < 1:
+            raise ValueError("attn_pattern has window layers but window is 0")
+        if any(self.ffn_pattern):
+            lo, n = self.experts_held
+            if not (0 < self.experts_per_token <= self.n_routed_experts and self.expert_dim > 0):
+                raise ValueError("ffn_pattern has expert layers: n_routed_experts, experts_per_token and expert_dim must be set")
+            if not (0 <= lo and n > 0 and lo + n <= self.n_routed_experts):
+                raise ValueError(f"experts held {lo}..{lo + n} lie outside the {self.n_routed_experts} routed experts")
+        if self.rope_dim % 2:
+            raise ValueError(f"rotary width int({self.head_dim} * {self.rope_fraction}) = {self.rope_dim} must be even")
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.qk_head_dim or self.dim // self.n_heads
+
+    @property
+    def v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        return self.head_dim if self.rope_fraction == 1.0 else int(self.head_dim * self.rope_fraction)
 
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def experts_held(self) -> tuple:
+        """(first, count) of the routed experts whose weights live here."""
+        return self.experts_held_start, self.n_experts_held or self.n_routed_experts
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """One LayerKind a layer: the description the served path runs by."""
+        kinds = []
+        for i in range(self.n_layers):
+            windowed = bool(self.attn_pattern and self.attn_pattern[i])
+            routed = bool(self.ffn_pattern and self.ffn_pattern[i])
+            kinds.append(LayerKind(
+                n_kv_heads=(self.window_kv_heads or self.n_kv_heads) if windowed else self.n_kv_heads,
+                window=self.window if windowed else 0,
+                sink=windowed and self.window_sink,
+                rope_theta=(self.window_rope_theta or self.rope_theta) if windowed else self.rope_theta,
+                experts=routed,
+                # the dense models' scope and kernel keep their names; a model
+                # with a layer pattern tells its kinds apart in a trace
+                attn_name=("swa" if windowed else "full") if self.attn_pattern else "",
+            ))
+        return tuple(kinds)
+
+    @property
+    def layer_groups(self) -> tuple:
+        """Runs of consecutive like layers, (kind, first, count) each: one
+        compiled body and one stacked parameter tree a group."""
+        groups: list = []
+        for i, kind in enumerate(self.layer_kinds):
+            if groups and groups[-1][0] == kind:
+                groups[-1][2] += 1
+            else:
+                groups.append([kind, i, 1])
+        return tuple((k, first, n) for k, first, n in groups)
+
+    @property
+    def uniform(self) -> bool:
+        """Every layer alike with a dense FFN: `params["layers"]` is ONE
+        stacked tree and the KV pool one array (the dense presets). Otherwise
+        both are tuples, one entry a group of `layer_groups`."""
+        return not (self.attn_pattern or self.ffn_pattern)
+
+    @property
+    def has_window(self) -> bool:
+        return any(k.window for k in self.layer_kinds)
+
+    @property
+    def has_experts(self) -> bool:
+        return any(k.experts for k in self.layer_kinds)
+
     def param_count(self) -> int:
+        if not self.uniform:
+            # what is HELD here: the experts this share holds, the router whole
+            hd, vd, (_lo, held) = self.head_dim, self.v_dim, self.experts_held
+            total = 2 * self.vocab_size * self.dim + self.dim
+            for k in self.layer_kinds:
+                total += self.dim * (self.n_heads * hd + k.n_kv_heads * (hd + vd)) + self.n_heads * vd * self.dim
+                total += 2 * self.dim + (self.n_heads if k.sink else 0)
+                if k.experts:
+                    total += (self.dim + 1) * self.n_routed_experts + 3 * held * self.dim * self.expert_dim
+                else:
+                    total += 3 * self.dim * self.ffn_dim
+            return total
         embed = self.vocab_size * self.dim
         if self.is_moe:
             ffn = self.dim * self.n_experts + 2 * self.n_experts * self.dim * self.ffn_dim
@@ -105,6 +229,31 @@ CONFIGS: dict[str, LlamaConfig] = {
         name="llama3-8x7b-proxy", vocab_size=128_256, dim=4096, n_layers=32, n_heads=32,
         n_kv_heads=8, ffn_dim=14336, max_seq_len=8192, n_experts=8,
     ),
+    # MiMo-V2-Flash as published (XiaomiMiMo/MiMo-V2-Flash config.json): 5
+    # window layers (128 positions, a learned sink a head, 8 KV heads, rotary
+    # base 1e4) to 1 full (4 KV heads, base 5e6), keys 192 wide beside values
+    # 128 wide, rotary on the first 64, a leading dense layer and then 256
+    # routed experts, 8 a token, sigmoid scores with a selection bias. Whole it
+    # is 309 B parameters: a chip serves a depth cut and its share of the
+    # experts and of the vocabulary (n_layers + the two patterns, n_experts_held,
+    # vocab_size; benchmark/configs/mimo-v2-flash-serve-1chip-ep16.json).
+    "mimo-v2-flash": LlamaConfig(
+        name="mimo-v2-flash", vocab_size=152_576, dim=4096, n_layers=48, n_heads=64,
+        n_kv_heads=4, ffn_dim=16384, norm_eps=1e-5, rope_theta=5_000_000.0, max_seq_len=262_144,
+        qk_head_dim=192, v_head_dim=128, rope_fraction=0.334, value_scale=0.707,
+        attn_pattern=tuple(0 if i % 6 == 5 or i == 0 else 1 for i in range(48)),
+        window=128, window_kv_heads=8, window_rope_theta=10_000.0, window_sink=True,
+        ffn_pattern=(0,) + (1,) * 47, n_routed_experts=256, experts_per_token=8, expert_dim=2048,
+    ),
+    # the same description at a size the CPU tests hold: the three layer kinds
+    # in the same order, 8 of 32 experts held, top-4, keys 24 / values 16 wide
+    "tiny-mimo": LlamaConfig(
+        name="tiny-mimo", vocab_size=512, dim=64, n_layers=7, n_heads=8, n_kv_heads=2,
+        ffn_dim=128, max_seq_len=256, qk_head_dim=24, v_head_dim=16, rope_fraction=0.334,
+        value_scale=0.707, attn_pattern=(0, 1, 1, 1, 1, 0, 1), window=8, window_kv_heads=4,
+        window_rope_theta=10_000.0, window_sink=True, ffn_pattern=(0, 1, 1, 1, 1, 1, 1),
+        n_routed_experts=32, experts_per_token=4, expert_dim=32, n_experts_held=8,
+    ),
 }
 
 
@@ -131,10 +280,65 @@ def get_config(name: Any, **overrides: Any) -> LlamaConfig:
 # pass scans over them with one compiled body.
 
 
+def _init_kind(cfg: LlamaConfig, kind: LayerKind, k: jax.Array) -> dict:
+    """One layer of a model whose layers are not all alike, from its key:
+    ten keys (q, k, v, o; gate, up, down or the experts'; router, selection
+    bias, sink), every matrix normal(0, 0.02) in cfg.dtype, the sinks and the
+    bias too (so that both take part in what a test compares). A routed
+    expert's weights are a function of (layer key, expert index) alone, so
+    every share of the experts draws the same expert e."""
+    init = jax.nn.initializers.normal(stddev=0.02)
+    ks = jax.random.split(k, 10)
+    hd, vd = cfg.head_dim, cfg.v_dim
+    layer = {
+        "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
+        "wq": init(ks[0], (cfg.dim, cfg.n_heads * hd), cfg.dtype),
+        "wk": init(ks[1], (cfg.dim, kind.n_kv_heads * hd), cfg.dtype),
+        "wv": init(ks[2], (cfg.dim, kind.n_kv_heads * vd), cfg.dtype),
+        "wo": init(ks[3], (cfg.n_heads * vd, cfg.dim), cfg.dtype),
+        "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype),
+    }
+    if kind.sink:
+        layer["sink"] = init(ks[9], (cfg.n_heads,), cfg.dtype)
+    if not kind.experts:
+        layer.update({
+            "w_gate": init(ks[4], (cfg.dim, cfg.ffn_dim), cfg.dtype),
+            "w_up": init(ks[5], (cfg.dim, cfg.ffn_dim), cfg.dtype),
+            "w_down": init(ks[6], (cfg.ffn_dim, cfg.dim), cfg.dtype),
+        })
+        return layer
+    lo, held = cfg.experts_held
+
+    def held_experts(k_e: jax.Array, shape: tuple) -> jax.Array:
+        keys = jax.random.split(k_e, cfg.n_routed_experts)[lo : lo + held]
+        return jax.vmap(lambda ke: init(ke, shape, cfg.dtype))(keys)
+
+    layer.update({
+        "router": init(ks[7], (cfg.dim, cfg.n_routed_experts), cfg.dtype),
+        "router_bias": init(ks[8], (cfg.n_routed_experts,), cfg.dtype),
+        "w_gate": held_experts(ks[4], (cfg.dim, cfg.expert_dim)),  # [held, D, F]
+        "w_up": held_experts(ks[5], (cfg.dim, cfg.expert_dim)),
+        "w_down": held_experts(ks[6], (cfg.expert_dim, cfg.dim)),  # [held, F, D]
+    })
+    return layer
+
+
 def init_params(cfg: LlamaConfig, key: jax.Array) -> dict:
     k_embed, k_layers, k_out = jax.random.split(key, 3)
     hd = cfg.head_dim
     init = jax.nn.initializers.normal(stddev=0.02)
+    if not cfg.uniform:
+        layer_keys = jax.random.split(k_layers, cfg.n_layers)
+        return {
+            "embed": init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
+            # one stacked tree a group of like layers (cfg.layer_groups)
+            "layers": tuple(
+                jax.vmap(partial(_init_kind, cfg, kind))(layer_keys[first : first + n])
+                for kind, first, n in cfg.layer_groups
+            ),
+            "final_norm": jnp.ones((cfg.dim,), cfg.dtype),
+            "lm_head": init(k_out, (cfg.dim, cfg.vocab_size), cfg.dtype),
+        }
 
     def layer_init(k: jax.Array) -> dict:
         ks = jax.random.split(k, 7)
@@ -187,11 +391,13 @@ def rms_norm(x: jax.Array, gamma: jax.Array, eps: float) -> jax.Array:
     return (x32 * lax.rsqrt(var + eps)).astype(x.dtype) * gamma
 
 
-def rope_frequencies(cfg: LlamaConfig) -> jax.Array:
-    """[head_dim/2] inverse frequencies."""
-    hd = cfg.head_dim
-    exponents = jnp.arange(0, hd, 2, dtype=jnp.float32) / hd
-    return 1.0 / (cfg.rope_theta**exponents)
+def rope_frequencies(cfg: LlamaConfig, theta: Optional[float] = None) -> jax.Array:
+    """[rope_dim/2] inverse frequencies (rope_dim = head_dim unless the
+    config turns only a head's first dims); `theta` where a layer kind has a
+    base of its own."""
+    rd = cfg.rope_dim
+    exponents = jnp.arange(0, rd, 2, dtype=jnp.float32) / rd
+    return 1.0 / ((theta or cfg.rope_theta) ** exponents)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array) -> jax.Array:

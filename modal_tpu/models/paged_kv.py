@@ -32,14 +32,14 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .llama import LlamaConfig, apply_rope, repeat_kv, rms_norm, rope_frequencies
+from .llama import LayerKind, LlamaConfig, apply_rope, repeat_kv, rms_norm, rope_frequencies
 
 DEFAULT_PAGE_SIZE = 16
 # positions of a slot's page row that a prefill chunk's attention visits in one
@@ -135,15 +135,57 @@ class PageAllocator:
                 self._free.append(p)
 
 
+def k_cache_dim(cfg: LlamaConfig) -> int:
+    """The width a key is STORED at. A head wider than 128 that is no
+    multiple of 128 (192) is padded with zeros to the next one (256): for a
+    192-wide minor dimension XLA's TPU layout puts the pages dimension
+    innermost, and the decode kernel, which needs a page's keys contiguous,
+    would be handed a transposed copy of the whole pool at every call
+    (PERF.md section 6, PR 29). The zeros add nothing to q.k; scores scale by
+    the model's own width."""
+    hd = cfg.head_dim
+    return hd if hd <= 128 or hd % 128 == 0 else -(-hd // 128) * 128
+
+
+def window_pages_per_slot(window: int, page_size: int) -> int:
+    """Pages of a window layer's pool one decoding slot can hold: the window
+    (the query's own position included) and the page being written."""
+    return math.ceil(window / page_size) + 1
+
+
+def default_window_num_pages(cfg: LlamaConfig, slots: int, page_size: int, prefill_chunk: int) -> int:
+    """A window pool no admission pattern can exhaust: every slot's window,
+    one chunk's headroom for the slot in prefill (a chunk holds itself and the
+    window before it, and gives back all but the window at its end), and the
+    scratch page."""
+    per_slot = window_pages_per_slot(cfg.window, page_size)
+    in_chunk = math.ceil((prefill_chunk + cfg.window - 1) / page_size) + 1
+    return 1 + slots * per_slot + max(0, in_chunk - per_slot)
+
+
 class PagedKVCache(NamedTuple):
     """Device state of the shared pool (one per serving engine, NOT per
     request). All shapes static — one compiled decode executable serves
-    every admission pattern."""
+    every admission pattern.
 
-    k_pages: jax.Array  # [n_layers, num_pages, page_size, n_kv, hd]
-    v_pages: jax.Array
+    Every layer alike (`cfg.uniform`, the dense presets): ONE pool, k_pages
+    and v_pages `[n_layers, num_pages, page_size, n_kv, hd]`. Layers of more
+    than one kind: k_pages and v_pages are tuples, one array a group of
+    `cfg.layer_groups`, `[layers in the group, pages of its kind's pool,
+    page_size, its n_kv, stored key width | value width]`; full-attention
+    groups share the page ids of `page_table` (a pool that grows with the
+    context), window groups those of `window_table` (a pool bounded by the
+    window: a page behind it goes back to its free list while the request
+    lives, and its stale table entry is never addressed)."""
+
+    k_pages: Any
+    v_pages: Any
     page_table: jax.Array  # [slots, pages_per_slot] int32 (0 = scratch)
     seq_lens: jax.Array  # [slots] int32 — tokens written per slot
+    window_table: Optional[jax.Array] = None  # [slots, pages_per_slot]: the window layers' rows
+    # (token, expert) pairs the expert layers computed on held experts, summed
+    # over every step so far; uint32, wraps (the host reads differences)
+    moe_pairs: Optional[jax.Array] = None
 
     @staticmethod
     def create(
@@ -152,19 +194,34 @@ class PagedKVCache(NamedTuple):
         num_pages: int,
         page_size: int = DEFAULT_PAGE_SIZE,
         pages_per_slot: Optional[int] = None,
+        window_num_pages: Optional[int] = None,
     ) -> "PagedKVCache":
         pages_per_slot = pages_per_slot or math.ceil(cfg.max_seq_len / page_size)
-        shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-        return PagedKVCache(
-            k_pages=jnp.zeros(shape, cfg.dtype),
-            v_pages=jnp.zeros(shape, cfg.dtype),
+        tables = dict(
             page_table=jnp.zeros((slots, pages_per_slot), jnp.int32),
             seq_lens=jnp.zeros((slots,), jnp.int32),
+        )
+        if cfg.uniform:
+            shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+            return PagedKVCache(k_pages=jnp.zeros(shape, cfg.dtype), v_pages=jnp.zeros(shape, cfg.dtype), **tables)
+        if cfg.has_window and window_num_pages is None:
+            raise ValueError("a model with window layers needs window_num_pages, its second pool's size")
+        k_pages, v_pages = [], []
+        for kind, _first, n in cfg.layer_groups:
+            pool = (n, window_num_pages if kind.window else num_pages, page_size, kind.n_kv_heads)
+            k_pages.append(jnp.zeros(pool + (k_cache_dim(cfg),), cfg.dtype))
+            v_pages.append(jnp.zeros(pool + (cfg.v_dim,), cfg.dtype))
+        return PagedKVCache(
+            k_pages=tuple(k_pages),
+            v_pages=tuple(v_pages),
+            window_table=jnp.zeros((slots, pages_per_slot), jnp.int32) if cfg.has_window else None,
+            moe_pairs=jnp.zeros((), jnp.uint32) if cfg.has_experts else None,
+            **tables,
         )
 
     @property
     def page_size(self) -> int:
-        return self.k_pages.shape[2]
+        return jax.tree_util.tree_leaves(self.k_pages)[0].shape[2]
 
     @property
     def num_slots(self) -> int:
@@ -176,7 +233,19 @@ class PagedKVCache(NamedTuple):
         return self.page_table.shape[1] * self.page_size
 
     def pool_bytes(self) -> int:
-        return int(self.k_pages.size + self.v_pages.size) * self.k_pages.dtype.itemsize
+        """Bytes of every K and V pool."""
+        return sum(int(a.size) * a.dtype.itemsize for a in jax.tree_util.tree_leaves((self.k_pages, self.v_pages)))
+
+
+def pool_bytes_by_kind(cfg: LlamaConfig, cache: PagedKVCache) -> tuple[int, int]:
+    """(bytes of the pool that grows with the context, bytes of the window
+    layers' pool): what `page_table` and `window_table` address."""
+    if cfg.uniform:
+        return cache.pool_bytes(), 0
+    sizes = [0, 0]
+    for (kind, _first, _n), k, v in zip(cfg.layer_groups, cache.k_pages, cache.v_pages):
+        sizes[bool(kind.window)] += int(k.size) * k.dtype.itemsize + int(v.size) * v.dtype.itemsize
+    return sizes[0], sizes[1]
 
 
 # -- host-side table maintenance (small jitted updates between steps) --------
@@ -192,13 +261,27 @@ def assign_pages(cache: PagedKVCache, slot: int, start_index: int, pages: jax.Ar
 
 
 @partial(jax.jit, donate_argnums=(0,))
+def assign_window_pages(cache: PagedKVCache, slots: jax.Array, indices: jax.Array, pages: jax.Array) -> PagedKVCache:
+    """Write page ids of the window layers' pool into `window_table` at
+    (slots[i], indices[i]): one call for everything a step's bookkeeping
+    allocated. The three arrays have one fixed length a caller (one
+    executable); an unused entry names slot `num_slots`, out of range, and is
+    dropped. Entries behind a window are left as they are: stale, never
+    addressed."""
+    return cache._replace(window_table=cache.window_table.at[slots, indices].set(pages, mode="drop"))
+
+
+@partial(jax.jit, donate_argnums=(0,))
 def release_slot(cache: PagedKVCache, slot: int) -> PagedKVCache:
     """Point the slot back at scratch and zero its length (the host frees
     the pages on the allocator side)."""
-    return cache._replace(
+    cache = cache._replace(
         page_table=cache.page_table.at[slot].set(0),
         seq_lens=cache.seq_lens.at[slot].set(0),
     )
+    if cache.window_table is not None:
+        cache = cache._replace(window_table=cache.window_table.at[slot].set(0))
+    return cache
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -230,6 +313,14 @@ def set_seq_lens(cache: PagedKVCache, new_lens: jax.Array, update: jax.Array) ->
 # -- KV-page shipment (prefill/decode disaggregation, ISSUE 18) ---------------
 
 
+def _one_pool(cache: PagedKVCache, what: str) -> None:
+    if isinstance(cache.k_pages, tuple):
+        raise ValueError(
+            f"{what} ships pages of ONE pool; this model keeps a pool a layer kind (full and window "
+            "attention side by side), and a shipment format over two pools does not exist yet"
+        )
+
+
 def export_pages(cache: PagedKVCache, page_ids: list[int]) -> dict:
     """Pull the named pages off the device as host arrays, ready to ride a
     blob-plane frame to another replica. Shapes: k/v are
@@ -237,6 +328,7 @@ def export_pages(cache: PagedKVCache, page_ids: list[int]) -> dict:
     whole pages, so positions past the holder's seq_len travel as garbage
     and stay unattended on the importer too. Read-only: exporting pages
     that are refcount-shared with the prefix cache is safe."""
+    _one_pool(cache, "export_pages")
     idx = jnp.asarray(page_ids, jnp.int32)
     return {
         "k": np.asarray(cache.k_pages[:, idx]),
@@ -259,6 +351,7 @@ def import_pages(cache: PagedKVCache, page_ids: list[int], data: dict) -> PagedK
     practice. The caller owns the page allocation/table wiring; dtype is
     cast to the pool's (a bf16 pool importing from a bf16 pool is a
     no-op cast)."""
+    _one_pool(cache, "import_pages")
     idx = jnp.asarray(page_ids, jnp.int32)
     dtype = cache.k_pages.dtype
     return _import_pages(
@@ -279,7 +372,10 @@ def _scatter_kv(k_pages, v_pages, k, v, page_ids, offsets):
     )
 
 
-def _paged_attention(q, k_pages, v_pages, page_table, mask, positions=None, attn_impl="gather"):
+def _paged_attention(
+    q, k_pages, v_pages, page_table, mask, positions=None, attn_impl="gather",
+    window=0, sink=None, scale=None, kernel_name="paged_decode_attention",
+):
     """Attend each slot's page span: what `paged_decode_step` and
     `paged_verify_step` run (a prefill chunk has `_prefill_attention`).
     q: [S, Sq, H, hd]; k_pages/v_pages: [P, page, n_kv, hd]; page_table:
@@ -291,8 +387,17 @@ def _paged_attention(q, k_pages, v_pages, page_table, mask, positions=None, attn
     against); "kernel" / "kernel_interpret" stream pages HBM→VMEM with the
     Pallas decode kernel (ops/paged_attention.py) — decode only (Sq == 1,
     `positions` = each slot's token position); the verify step's multi-token
-    call always takes the gather path."""
+    call always takes the gather path.
+
+    What a layer kind adds, each off where the dense models leave it: values
+    narrower than keys (v_pages' own last dimension); `scale` where q and the
+    stored keys are padded past the model's width; a `window` (the mask the
+    caller passes carries it, the kernel walks only the window's pages); a
+    `sink` [H], one logit a query head that joins the softmax's denominator
+    and takes no value."""
     s, sq, h, hd = q.shape
+    vd = v_pages.shape[-1]
+    scale = scale or 1.0 / math.sqrt(hd)
     if attn_impl in ("kernel", "kernel_interpret") and sq == 1 and positions is not None:
         from ..ops.paged_attention import paged_decode_attention
 
@@ -304,21 +409,30 @@ def _paged_attention(q, k_pages, v_pages, page_table, mask, positions=None, attn
             v_pages,
             page_table,
             positions,
+            window=window,
+            sink=None if sink is None else sink.reshape(n_kv, n_rep),
+            scale=scale,
+            name=kernel_name,
             interpret=(attn_impl == "kernel_interpret"),
         )
-        return out.reshape(s, sq, h, hd)
+        return out.reshape(s, sq, h, vd)
     page = k_pages.shape[1]
     n_kv = k_pages.shape[2]
     k_span = page_table.shape[1] * page
     # [S, pages_per_slot, page, n_kv, hd] -> [S, K, n_kv, hd]
     k_att = k_pages[page_table].reshape(s, k_span, n_kv, hd)
-    v_att = v_pages[page_table].reshape(s, k_span, n_kv, hd)
+    v_att = v_pages[page_table].reshape(s, k_span, n_kv, vd)
     n_rep = h // n_kv
     k_att = repeat_kv(k_att, n_rep)
     v_att = repeat_kv(v_att, n_rep)
-    scale = 1.0 / math.sqrt(hd)
     logits = jnp.einsum("sqhd,skhd->shqk", q, k_att, preferred_element_type=jnp.float32) * scale
-    probs = jax.nn.softmax((logits + mask).astype(jnp.float32), axis=-1).astype(q.dtype)
+    logits = (logits + mask).astype(jnp.float32)
+    if sink is None:
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    else:
+        # one more column in the denominator, dropped before P.V
+        column = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, None, None], logits.shape[:-1] + (1,))
+        probs = jax.nn.softmax(jnp.concatenate([logits, column], axis=-1), axis=-1)[..., :-1].astype(q.dtype)
     return jnp.einsum("shqk,skhd->sqhd", probs, v_att)
 
 
@@ -336,7 +450,7 @@ def prefill_kv_attended(live: int, pages_per_slot: int, page_size: int) -> int:
     return math.ceil(live / block) * block
 
 
-def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages):
+def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, window=0, sink=None, scale=None):
     """One slot's prefill chunk against the slot's LIVE prefix: a flash
     forward over KV blocks of the page row. q: [Sq, H, hd] at positions q_pos
     [Sq]; k_pages/v_pages: [P, page, n_kv, hd], the chunk's own K/V already
@@ -352,29 +466,45 @@ def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages):
     accumulator are float32, the probabilities go to q's dtype for P·V: the
     gather path's arithmetic with the sum reassociated. Every row sees
     position 0 in block 0, so its running max is finite from the first turn
-    and rows past `length` (garbage, never read) cannot make a NaN."""
+    and rows past `length` (garbage, never read) cannot make a NaN.
+
+    A window layer (`window` > 0: the query at p sees keys p-window+1 .. p)
+    starts at the block that holds the FIRST query's oldest key and masks
+    from below too; a later row may then see nothing in a block, so its
+    running max is kept finite by hand. A `sink` [H] joins each row's sum
+    once, after the last block. Values may be narrower than keys, and
+    `scale` is the model's own where q and the stored keys are padded."""
     sq, h, hd = q.shape
-    page, n_kv = k_pages.shape[1], k_pages.shape[2]
+    page, n_kv, vd = k_pages.shape[1], k_pages.shape[2], v_pages.shape[-1]
     n_rep = h // n_kv
     block = block_pages * page
     # a row the block does not divide is padded with the scratch page: those
     # positions lie past the span, so past every row that is read
     row = jnp.pad(row, (0, -row.shape[0] % block_pages))
     qg = q.reshape(sq, n_kv, n_rep, hd)
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
     offsets = jnp.arange(block, dtype=jnp.int32)
 
     def fold(b, carry):
         m, l, acc = carry
         ids = lax.dynamic_slice_in_dim(row, b * block_pages, block_pages)
         k_blk = k_pages[ids].reshape(block, n_kv, hd)
-        v_blk = v_pages[ids].reshape(block, n_kv, hd)
+        v_blk = v_pages[ids].reshape(block, n_kv, vd)
         s = jnp.einsum("qgrd,kgd->grqk", qg, k_blk, preferred_element_type=jnp.float32) * scale
-        seen = (b * block + offsets)[None, :] <= q_pos[:, None]  # [Sq, block]
+        kv_pos = (b * block + offsets)[None, :]
+        seen = kv_pos <= q_pos[:, None]  # [Sq, block]
+        if window:
+            seen = seen & (kv_pos > q_pos[:, None] - window)
         s = jnp.where(seen[None, None], s, -jnp.inf)
         m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        shrink = jnp.exp(m - m_new)
+        if window:
+            # a row that has seen no key yet: exp(-inf - 0) = 0, not exp(nan)
+            m_ref = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+            p = jnp.exp(s - m_ref[..., None])
+            shrink = jnp.exp(m - m_ref)
+        else:
+            p = jnp.exp(s - m_new[..., None])
+            shrink = jnp.exp(m - m_new)
         l = shrink * l + p.sum(axis=-1)
         acc = shrink[..., None] * acc + jnp.einsum(
             "grqk,kgd->grqd", p.astype(q.dtype), v_blk, preferred_element_type=jnp.float32
@@ -384,60 +514,135 @@ def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages):
     init = (
         jnp.full((n_kv, n_rep, sq), -jnp.inf, jnp.float32),
         jnp.zeros((n_kv, n_rep, sq), jnp.float32),
-        jnp.zeros((n_kv, n_rep, sq, hd), jnp.float32),
+        jnp.zeros((n_kv, n_rep, sq, vd), jnp.float32),
     )
-    _m, l, acc = lax.fori_loop(0, (live + block - 1) // block, fold, init)
-    out = (acc / l[..., None]).astype(q.dtype)  # [n_kv, n_rep, Sq, hd]
-    return out.transpose(2, 0, 1, 3).reshape(sq, h, hd)
+    first_block = jnp.maximum(q_pos[0] - (window - 1), 0) // block if window else 0
+    m, l, acc = lax.fori_loop(first_block, (live + block - 1) // block, fold, init)
+    if sink is not None:
+        l = l + jnp.exp(sink.astype(jnp.float32).reshape(n_kv, n_rep)[..., None] - m)
+    out = (acc / l[..., None]).astype(q.dtype)  # [n_kv, n_rep, Sq, vd]
+    return out.transpose(2, 0, 1, 3).reshape(sq, h, vd)
 
 
-def _paged_layer(cfg, x, layer, positions, write_page_ids, write_offsets, inv_freq, kp, vp, attend):
-    """One transformer layer over paged KV. x: [S, Sq, D]; positions:
-    [S, Sq]; write_page_ids/offsets: flat [S*Sq] scatter targets; attend:
-    (q [S, Sq, H, hd], k_pages, v_pages) -> [S, Sq, H, hd] over the pool as
-    this layer has just written it — the caller's own (`_paged_attention`
-    for decode and verify, `_prefill_attention` for a prefill chunk)."""
+def _rope(cfg, x, positions, inv_freq):
+    """Rotary positions on the first `cfg.rope_dim` dims of each head; the
+    rest pass through (all of them turn in the dense models)."""
+    rd = cfg.rope_dim
+    if rd == x.shape[-1]:
+        return apply_rope(x, positions, inv_freq)
+    return jnp.concatenate([apply_rope(x[..., :rd], positions, inv_freq), x[..., rd:]], axis=-1)
+
+
+def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, inv_freq, kp, vp, attend, valid=None):
+    """One transformer layer of `kind` over paged KV. x: [S, Sq, D];
+    positions: [S, Sq]; write_page_ids/offsets: flat [S*Sq] scatter targets
+    in this kind's pool; attend: (kind, q [S, Sq, H, hd], k_pages, v_pages,
+    sink) -> [S, Sq, H, vd] over the pool as this layer has just written it —
+    the caller's own (`_paged_attention` for decode and verify,
+    `_prefill_attention` for a prefill chunk). Returns (x, kp, vp, pairs):
+    the (token, expert) pairs an expert layer computed for the `valid` [S*Sq]
+    tokens, 0 for a dense FFN."""
     from .quant import qmm
 
     s, sq, d = x.shape
-    hd = cfg.head_dim
+    hd, vd, n_kv = cfg.head_dim, cfg.v_dim, kind.n_kv_heads
     # the scopes are names only (HLO metadata, profiler traces): nothing
     # computed changes
-    with jax.named_scope("paged_attention"):
+    with jax.named_scope(kind.attn_name + "_attention" if kind.attn_name else "paged_attention"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = qmm(h, layer["wq"]).reshape(s, sq, cfg.n_heads, hd)
-        k = qmm(h, layer["wk"]).reshape(s, sq, cfg.n_kv_heads, hd)
-        v = qmm(h, layer["wv"]).reshape(s, sq, cfg.n_kv_heads, hd)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+        k = qmm(h, layer["wk"]).reshape(s, sq, n_kv, hd)
+        v = qmm(h, layer["wv"]).reshape(s, sq, n_kv, vd)
+        q = _rope(cfg, q, positions, inv_freq)
+        k = _rope(cfg, k, positions, inv_freq)
+        if cfg.value_scale != 1.0:
+            v = v * jnp.asarray(cfg.value_scale, v.dtype)
+        stored = kp.shape[-1]  # k_cache_dim: zeros past the model's width
+        if stored != hd:
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, stored - hd)))
+            k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, stored - hd)))
         with jax.named_scope("kv_write"):
             kp, vp = _scatter_kv(
                 kp, vp,
-                k.reshape(s * sq, cfg.n_kv_heads, hd),
-                v.reshape(s * sq, cfg.n_kv_heads, hd),
+                k.reshape(s * sq, n_kv, stored),
+                v.reshape(s * sq, n_kv, vd),
                 write_page_ids, write_offsets,
             )
-        attn_out = attend(q, kp, vp)
-        x = x + qmm(attn_out.reshape(s, sq, cfg.n_heads * hd), layer["wo"])
-    with jax.named_scope("ffn"):
+        attn_out = attend(kind, q, kp, vp, layer.get("sink"))
+        x = x + qmm(attn_out.reshape(s, sq, cfg.n_heads * vd), layer["wo"])
+    pairs = jnp.zeros((), jnp.uint32)
+    if kind.experts:
+        from .experts import routed_experts
+
         h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        gated = jax.nn.silu(qmm(h, layer["w_gate"]).astype(jnp.float32)).astype(x.dtype) * qmm(h, layer["w_up"])
-        x = x + qmm(gated, layer["w_down"])
-    return x, kp, vp
+        y, pairs = routed_experts(cfg, h.reshape(s * sq, d), layer, valid)
+        x = x + y.reshape(s, sq, d)
+    else:
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+            gated = jax.nn.silu(qmm(h, layer["w_gate"]).astype(jnp.float32)).astype(x.dtype) * qmm(h, layer["w_up"])
+            x = x + qmm(gated, layer["w_down"])
+    return x, kp, vp, pairs
 
 
-def _run_layers(params, cfg, x, positions, write_page_ids, write_offsets, cache, attend):
-    inv_freq = rope_frequencies(cfg)
+def _run_layers(params, cfg, x, positions, writes, cache, attend, valid=None):
+    """Every layer in turn. `writes`: the flat scatter targets (page ids,
+    offsets), and a second pair in the window layers' pool where the model
+    has them. Returns (x, k_pages, v_pages, pairs)."""
+    if cfg.uniform:
+        # every layer alike: one scan over the stacked layers and the one pool
+        kind = cfg.layer_kinds[0]
+        inv_freq = rope_frequencies(cfg)
+        write_page_ids, write_offsets = writes[0]
 
-    def body(x_carry, layer_and_pages):
-        layer, kp, vp = layer_and_pages
-        x_out, kp, vp = _paged_layer(
-            cfg, x_carry, layer, positions, write_page_ids, write_offsets, inv_freq, kp, vp, attend
-        )
-        return x_out, (kp, vp)
+        def body(x_carry, layer_and_pages):
+            layer, kp, vp = layer_and_pages
+            x_out, kp, vp, _pairs = _paged_layer(
+                cfg, kind, x_carry, layer, positions, write_page_ids, write_offsets, inv_freq, kp, vp, attend
+            )
+            return x_out, (kp, vp)
 
-    x, (k_pages, v_pages) = lax.scan(body, x, (params["layers"], cache.k_pages, cache.v_pages))
-    return x, k_pages, v_pages
+        x, (k_pages, v_pages) = lax.scan(body, x, (params["layers"], cache.k_pages, cache.v_pages))
+        return x, k_pages, v_pages, None
+
+    # layers of more than one kind: a group of like layers at a time, a scan
+    # over the group's stacked layers and its own pool where it has several,
+    # the body itself where it has one (no stacked copy of a pool that holds
+    # one layer: its scatter stays in place on the donated buffer)
+    pairs = jnp.zeros((), jnp.uint32)
+    k_pages, v_pages = [], []
+    for (kind, _first, n), layers, kp, vp in zip(cfg.layer_groups, params["layers"], cache.k_pages, cache.v_pages):
+        inv_freq = rope_frequencies(cfg, kind.rope_theta)
+        write_page_ids, write_offsets = writes[bool(kind.window)]
+
+        def body(carry, layer_and_pages, kind=kind, inv_freq=inv_freq, ids=write_page_ids, offsets=write_offsets):
+            x_carry, pairs_carry = carry
+            layer, kp_l, vp_l = layer_and_pages
+            x_out, kp_l, vp_l, used = _paged_layer(
+                cfg, kind, x_carry, layer, positions, ids, offsets, inv_freq, kp_l, vp_l, attend, valid
+            )
+            return (x_out, pairs_carry + used), (kp_l, vp_l)
+
+        if n == 1:
+            one = jax.tree_util.tree_map(lambda a: a[0], (layers, kp, vp))
+            (x, pairs), (kp, vp) = body((x, pairs), one)
+            kp, vp = kp[None], vp[None]
+        else:
+            (x, pairs), (kp, vp) = lax.scan(body, (x, pairs), (layers, kp, vp))
+        k_pages.append(kp)
+        v_pages.append(vp)
+    return x, tuple(k_pages), tuple(v_pages), pairs
+
+
+def _kernel_name(kind: LayerKind) -> str:
+    return "paged_decode_attention" + ("_" + kind.attn_name if kind.attn_name else "")
+
+
+def _advance(cache, k_pages, v_pages, pairs, seq_lens):
+    cache = cache._replace(k_pages=k_pages, v_pages=v_pages, seq_lens=seq_lens)
+    if cache.moe_pairs is not None:
+        cache = cache._replace(moe_pairs=cache.moe_pairs + pairs)
+    return cache
 
 
 def _logits(params, cfg, x_last):
@@ -449,8 +654,9 @@ def _logits(params, cfg, x_last):
 
 
 # -- public jitted entry points ----------------------------------------------
-# MoE configs route through the dense path (moe_ffn assumes full-batch
-# dispatch); the serving engine rejects them at construction.
+# The trainer's switch-style MoE (`cfg.n_experts`, parallel/moe.py) is not
+# in this path; the serving engine refuses it at construction. Routed experts
+# for serving are a layer kind (`cfg.ffn_pattern`, models/experts.py).
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
@@ -485,23 +691,26 @@ def paged_prefill(
     write_page_ids = jnp.where(valid, row[jnp.clip(positions // page, 0, row.shape[0] - 1)], 0)
     write_offsets = jnp.where(valid, positions % page, 0)
     block_pages = prefill_kv_block_pages(row.shape[0], page)
+    writes, rows = [(write_page_ids, write_offsets)], [row]
+    if cache.window_table is not None:
+        rows.append(cache.window_table[slot])
+        writes.append((jnp.where(valid, rows[1][jnp.clip(positions // page, 0, row.shape[0] - 1)], 0), write_offsets))
+    scale = 1.0 / math.sqrt(cfg.head_dim)
 
-    def attend(q, k_pages, v_pages):
-        # causal within the live prefix: q at position p sees kv_pos <= p; rows
-        # past `length` are garbage but their outputs are never read
-        return _prefill_attention(q[0], k_pages, v_pages, row, positions, start_pos + length, block_pages)[None]
+    def attend(kind, q, k_pages, v_pages, sink):
+        # causal within the live prefix: q at position p sees kv_pos <= p (a
+        # window layer: and > p - window); rows past `length` are garbage but
+        # their outputs are never read
+        return _prefill_attention(
+            q[0], k_pages, v_pages, rows[bool(kind.window)], positions, start_pos + length, block_pages,
+            window=kind.window, sink=sink, scale=scale,
+        )[None]
 
     x = qembed(params["embed"], tokens[None, :])  # [1, S_pad, D]
-    x, k_pages, v_pages = _run_layers(
-        params, cfg, x, positions[None, :], write_page_ids, write_offsets, cache, attend
-    )
+    x, k_pages, v_pages, pairs = _run_layers(params, cfg, x, positions[None, :], writes, cache, attend, valid)
     last = lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)  # [D]
     logits = _logits(params, cfg, last)
-    cache = cache._replace(
-        k_pages=k_pages,
-        v_pages=v_pages,
-        seq_lens=cache.seq_lens.at[slot].set(start_pos + length),
-    )
+    cache = _advance(cache, k_pages, v_pages, pairs, cache.seq_lens.at[slot].set(start_pos + length))
     return logits, jnp.argmax(logits).astype(jnp.int32), cache
 
 
@@ -535,17 +744,25 @@ def paged_decode_step(
     x = qembed(params["embed"], tokens[:, None])  # [slots, 1, D]
     kv_pos = jnp.arange(cache.kv_span, dtype=jnp.int32)[None, None, None, :]
     mask = jnp.where(kv_pos <= positions[:, None, None, None], 0.0, -jnp.inf).astype(jnp.float32)
+    writes, tables, masks = [(write_page_ids, write_offsets)], [rows], [mask]
+    if cache.window_table is not None:
+        tables.append(cache.window_table)
+        window_ids = jnp.take_along_axis(tables[1], page_idx[:, None], axis=1)[:, 0]
+        writes.append((jnp.where(active, window_ids, 0), write_offsets))
+        behind = kv_pos <= positions[:, None, None, None] - cfg.window
+        masks.append(jnp.where(behind, -jnp.inf, mask))
+    scale = 1.0 / math.sqrt(cfg.head_dim)
 
-    x, k_pages, v_pages = _run_layers(
-        params, cfg, x, positions[:, None], write_page_ids, write_offsets, cache,
-        partial(_paged_attention, page_table=rows, mask=mask, positions=positions, attn_impl=attn_impl),
-    )
+    def attend(kind, q, k_pages, v_pages, sink):
+        w = bool(kind.window)
+        return _paged_attention(
+            q, k_pages, v_pages, tables[w], masks[w], positions, attn_impl,
+            window=kind.window, sink=sink, scale=scale, kernel_name=_kernel_name(kind),
+        )
+
+    x, k_pages, v_pages, pairs = _run_layers(params, cfg, x, positions[:, None], writes, cache, attend, active)
     logits = _logits(params, cfg, x[:, 0, :])  # [slots, V]
-    cache = cache._replace(
-        k_pages=k_pages,
-        v_pages=v_pages,
-        seq_lens=jnp.where(active, cache.seq_lens + 1, cache.seq_lens),
-    )
+    cache = _advance(cache, k_pages, v_pages, pairs, jnp.where(active, cache.seq_lens + 1, cache.seq_lens))
     return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
 
@@ -572,6 +789,11 @@ def paged_verify_step(
     depth is a config, not a shape that churns compiles."""
     from .quant import qembed
 
+    if not cfg.uniform:
+        raise ValueError(
+            "paged_verify_step runs one pool of one layer kind: a window layer's bounded pool cannot keep "
+            "k+1 speculative positions it may have to roll back, and routed experts are not in the verify path"
+        )
     slots, k1 = tokens.shape
     page = cache.page_size
     rows = cache.page_table  # [slots, pages_per_slot]
@@ -586,9 +808,11 @@ def paged_verify_step(
         kv_pos <= positions[:, None, :, None], 0.0, -jnp.inf
     ).astype(jnp.float32)  # [S, 1, K1, K]
 
-    x, k_pages, v_pages = _run_layers(
-        params, cfg, x, positions, write_page_ids.reshape(-1), write_offsets.reshape(-1),
-        cache, partial(_paged_attention, page_table=rows, mask=mask),
+    def attend(_kind, q, k_pages, v_pages, _sink):
+        return _paged_attention(q, k_pages, v_pages, rows, mask)
+
+    x, k_pages, v_pages, _pairs = _run_layers(
+        params, cfg, x, positions, [(write_page_ids.reshape(-1), write_offsets.reshape(-1))], cache, attend
     )
     logits = _logits(params, cfg, x)  # [slots, K1, V]
     cache = cache._replace(k_pages=k_pages, v_pages=v_pages)

@@ -14,6 +14,12 @@ layout, and no `[S, K]` score or gathered-KV intermediate ever exists in HBM.
 GQA: q arrives `[slots, n_kv, n_rep, hd]` (grouped by kv head) so one grid
 cell contracts one kv head's page against its `n_rep` query heads.
 
+Keys and values may differ in width (`k_pages` [.., hd], `v_pages` [.., vd]),
+a window layer walks only the pages its window can touch, starting at its
+first live position, and a layer with a learned sink adds one logit a head
+to the softmax's denominator; the dense models use none of the three and
+compile to the kernel they always had.
+
 Pages past the slot's live length are skipped (`pl.when` on the page's base
 position vs `seq_lens[s]`), so a slot 3 pages into a 64-page span pays 3
 page DMAs, not 64. Positions inside the last live page are masked by global
@@ -47,16 +53,18 @@ def _paged_decode_kernel(
     # blocks
     q_ref,  # [1, n_kv, n_rep, hd] — this slot's single query token
     k_ref,  # [1, page, n_kv, hd] — the page the index map DMA'd in
-    v_ref,  # [1, page, n_kv, hd]
-    o_ref,  # [1, n_kv, n_rep, hd]
-    # VMEM scratch (persist across the page-dimension grid steps)
-    m_ref,  # [n_kv, n_rep, 1] running max
-    l_ref,  # [n_kv, n_rep, 1] running sum
-    acc_ref,  # [n_kv, n_rep, hd] weighted-value accumulator
-    *,
+    v_ref,  # [1, page, n_kv, vd] — values may be narrower than keys
+    *rest,  # (sink_ref [n_kv, n_rep, 1] where the layer has one,) o_ref, m_ref, l_ref, acc_ref
     page: int,
-    pages_per_slot: int,
+    pages_walked: int,
+    window: int,
+    scale: float,
 ):
+    # o_ref [1, n_kv, n_rep, vd]; VMEM scratch (persists across the page-
+    # dimension grid steps): m_ref, l_ref [n_kv, n_rep, 1] running max and
+    # sum, acc_ref [n_kv, n_rep, vd] weighted-value accumulator
+    sink_ref = rest[0] if len(rest) == 5 else None
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     s = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -67,16 +75,21 @@ def _paged_decode_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q_pos = seq_lens_ref[s]  # the decode token's position (kv <= q_pos attended)
+    walked = p
+    if window:
+        p = _first_live_page(q_pos, window, page) + p  # the p-th page of the window
 
     @pl.when(p * page <= q_pos)
     def _accumulate():
         q = q_ref[0].astype(jnp.float32)  # [n_kv, n_rep, hd]
         k = k_ref[0].astype(jnp.float32)  # [page, n_kv, hd]
         v = v_ref[0].astype(jnp.float32)
-        scale = 1.0 / math.sqrt(q.shape[-1])
         s_log = jnp.einsum("knd,pkd->knp", q, k) * scale  # [n_kv, n_rep, page]
         kv_pos = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
-        s_log = jnp.where(kv_pos <= q_pos, s_log, NEG_INF)
+        seen = kv_pos <= q_pos
+        if window:
+            seen = seen & (kv_pos > q_pos - window)
+        s_log = jnp.where(seen, s_log, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s_log, axis=-1, keepdims=True))
         p_exp = jnp.exp(s_log - m_new)
@@ -85,50 +98,88 @@ def _paged_decode_kernel(
         acc_ref[...] = acc_ref[...] * corr + jnp.einsum("knp,pkd->knd", p_exp, v)
         m_ref[...] = m_new
 
-    @pl.when(p == pages_per_slot - 1)
+    @pl.when(walked == pages_walked - 1)
     def _finalize():
-        l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+        if sink_ref is None:
+            l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+        else:
+            # the sink joins the denominator and takes no value (l > 0 with it)
+            l_safe = l_ref[...] + jnp.exp(sink_ref[...] - m_ref[...])
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+def _first_live_page(q_pos, window: int, page: int):
+    """The page that holds the oldest key a window layer's query at q_pos
+    sees (position q_pos - window + 1, or 0)."""
+    return jnp.maximum(q_pos - (window - 1), 0) // page
 
 
 def paged_decode_attention(
     q: jax.Array,  # [S, n_kv, n_rep, hd]
     k_pages: jax.Array,  # [P, page, n_kv, hd]
-    v_pages: jax.Array,
+    v_pages: jax.Array,  # [P, page, n_kv, vd]
     page_table: jax.Array,  # [S, pages_per_slot] int32
     seq_lens: jax.Array,  # [S] int32 — each slot's decode position
     *,
+    window: int = 0,  # > 0: the query sees its last `window` positions only
+    sink: jax.Array | None = None,  # [n_kv, n_rep] float32: a logit a head in the denominator
+    scale: float | None = None,  # 1/sqrt(hd) unless q and the keys are stored padded past the model's width
+    name: str = "paged_decode_attention",  # the kernel's name in HLO metadata and profiler traces
     interpret: bool = False,
 ) -> jax.Array:
-    """One decode step's attention over paged KV. Returns [S, n_kv, n_rep, hd]
-    (same layout as q). Numerics match the dense gather+softmax reference
-    (fp32 statistics); inactive/scratch slots produce garbage that callers
-    must not read — identical contract to the gather path."""
+    """One decode step's attention over paged KV. Returns [S, n_kv, n_rep, vd]
+    (q's layout at the values' width). Numerics match the dense
+    gather+softmax reference (fp32 statistics); inactive/scratch slots
+    produce garbage that callers must not read — identical contract to the
+    gather path.
+
+    A window layer's grid walks the pages its window can touch (the window
+    and the page being written: `paged_kv.window_pages_per_slot`), starting
+    at the page of its first live position, and not the slot's whole row: pages behind the window may have gone back to
+    the pool (the table entry is stale) and are never addressed."""
     s, n_kv, n_rep, hd = q.shape
-    page = k_pages.shape[1]
+    page, vd = k_pages.shape[1], v_pages.shape[-1]
     pages_per_slot = page_table.shape[1]
+    pages_walked = min(pages_per_slot, -(-window // page) + 1) if window else pages_per_slot
+
+    def page_of(si, pi, pt, lens):
+        if window:
+            pi = jnp.minimum(_first_live_page(lens[si], window, page) + pi, pages_per_slot - 1)
+        return (pt[si, pi], 0, 0, 0)
+
+    def whole(si, pi, pt, lens):
+        return (si, 0, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, n_kv, n_rep, hd), whole),
+        # the paged part: the index map dereferences the prefetched page
+        # table, so the pipeline DMAs page `page_table[s, p]` and only
+        # that page for grid step (s, p)
+        pl.BlockSpec((1, page, n_kv, hd), page_of),
+        pl.BlockSpec((1, page, n_kv, vd), page_of),
+    ]
+    operands = [q, k_pages, v_pages]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((n_kv, n_rep, 1), lambda si, pi, pt, lens: (0, 0, 0)))
+        operands.append(sink.astype(jnp.float32).reshape(n_kv, n_rep, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, pages_per_slot),
-        in_specs=[
-            pl.BlockSpec((1, n_kv, n_rep, hd), lambda si, pi, pt, lens: (si, 0, 0, 0)),
-            # the paged part: the index map dereferences the prefetched page
-            # table, so the pipeline DMAs page `page_table[s, p]` and only
-            # that page for grid step (s, p)
-            pl.BlockSpec((1, page, n_kv, hd), lambda si, pi, pt, lens: (pt[si, pi], 0, 0, 0)),
-            pl.BlockSpec((1, page, n_kv, hd), lambda si, pi, pt, lens: (pt[si, pi], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n_kv, n_rep, hd), lambda si, pi, pt, lens: (si, 0, 0, 0)),
+        grid=(s, pages_walked),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, n_kv, n_rep, vd), whole),
         scratch_shapes=[
             pltpu.VMEM((n_kv, n_rep, 1), jnp.float32),
             pltpu.VMEM((n_kv, n_rep, 1), jnp.float32),
-            pltpu.VMEM((n_kv, n_rep, hd), jnp.float32),
+            pltpu.VMEM((n_kv, n_rep, vd), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_paged_decode_kernel, page=page, pages_per_slot=pages_per_slot),
+        functools.partial(
+            _paged_decode_kernel, page=page, pages_walked=pages_walked, window=window,
+            scale=scale or 1.0 / math.sqrt(hd),
+        ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, n_kv, n_rep, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, n_kv, n_rep, vd), q.dtype),
         interpret=interpret,
-        name="paged_decode_attention",  # the kernel's name in HLO metadata and profiler traces
-    )(page_table, seq_lens, q, k_pages, v_pages)
+        name=name,
+    )(page_table, seq_lens, *operands)

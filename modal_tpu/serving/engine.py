@@ -174,6 +174,44 @@ class EngineStopped(RuntimeError):
     pass
 
 
+def _refuse_unservable(cfg: Any, params: dict, speculative: bool, role: str, prefix_cache: Optional[bool]) -> None:
+    """What the paged path cannot run yet, refused at construction with the
+    mechanism in the message (never by a model's name)."""
+    import jax
+
+    if getattr(cfg, "is_moe", False):
+        raise ValueError(
+            "n_experts is the mesh trainer's switch layer (parallel/moe.py): top-1 routing with a capacity "
+            "that drops tokens, dispatched over the whole batch; the paged path serves routed experts as a "
+            "layer kind (ffn_pattern, models/experts.py) and never drops a token"
+        )
+    if getattr(cfg, "uniform", True):
+        return
+    kinds = "window layers" if cfg.has_window else "layers of more than one kind"
+    if speculative:
+        raise ValueError(
+            f"speculative decoding (a draft, paged_verify_step) over a model with {kinds} or routed experts: "
+            "the verify step writes k+1 positions it may roll back, which a window layer's bounded pool "
+            "cannot keep, and the draft mirror assumes one pool of one layer kind"
+        )
+    if role in ("prefill", "decode"):
+        raise ValueError(
+            f"role={role!r} ships KV pages between replicas (export_pages / import_pages), which address ONE "
+            "pool; this model keeps a pool a layer kind and a shipment over two pools does not exist yet"
+        )
+    if cfg.has_window and prefix_cache:
+        raise ValueError(
+            "prefix_cache=True with window layers: a prefix hit hands over the full-attention pages only, and "
+            "a window layer needs its last `window` positions too, which the pool gave back behind the window"
+        )
+    if cfg.has_experts and any(leaf.dtype == "int8" for leaf in jax.tree_util.tree_leaves(params)):
+        raise ValueError(
+            "quantize_int8 over routed-expert weights: models/quant.py scales a matrix per output channel "
+            "over its second-to-last axis and models/experts.py contracts stacked [expert, in, out] weights "
+            "without a dequantizing read"
+        )
+
+
 class _LoopPhases:
     """The engine thread's clock-switch: `switch(name)` ENDS the phase that
     was open and STARTS the next, so the phases (observability/catalog.py
@@ -364,6 +402,11 @@ class GenRequest:
 class _Slot:
     request: GenRequest
     pages: list[int] = field(default_factory=list)
+    # the window layers' pool (a model with window attention): the live pages,
+    # consecutive in the slot's row from index window_first on; pages behind
+    # the window have gone back to the pool
+    window_pages: list[int] = field(default_factory=list)
+    window_first: int = 0
     draft_pages: list[int] = field(default_factory=list)  # speculative: draft pool mirror
     pos: int = 0  # tokens written to the slot's pages (mirrors seq_lens)
     prefill_tokens: list[int] = field(default_factory=list)  # prompt (+ regenerated prefix)
@@ -396,8 +439,12 @@ class ServingEngine:
         max_waiting: int = 1024,
         draft: Optional[tuple] = None,  # (draft_params, draft_cfg) → speculative decoding
         spec_k: int = 3,  # draft tokens proposed per speculative round
-        prefix_cache: Optional[bool] = None,  # None = env default (on)
+        prefix_cache: Optional[bool] = None,  # None = env default (on; off with window layers)
         role: Optional[str] = None,  # prefill | decode | both; None = env default
+        # pages of the window layers' pool (a model with window attention);
+        # None = every slot's window + one chunk's headroom, which no
+        # admission pattern exhausts (paged_kv.default_window_num_pages)
+        window_num_pages: Optional[int] = None,
     ):
         import math
 
@@ -406,13 +453,32 @@ class ServingEngine:
             PageAllocator,
             PagedKVCache,
             PrefixCache,
+            default_window_num_pages,
+            pool_bytes_by_kind,
             resolve_attn_impl,
+            window_pages_per_slot,
         )
 
-        if getattr(cfg, "is_moe", False):
-            raise ValueError("MoE configs are not paged-servable yet (dense FFN only)")
         page_size = page_size or DEFAULT_PAGE_SIZE
         pages_per_slot = pages_per_slot or math.ceil(cfg.max_seq_len / page_size)
+        role = role if role in ("prefill", "decode", "both") else resolve_role()
+        speculative = draft is not None and _env_on(SPEC_ENV)
+        _refuse_unservable(cfg, params, speculative, role, prefix_cache)
+        self.window = cfg.window if cfg.has_window else 0
+        if self.window:
+            prefix_cache = False  # a hit would need the window layers' last positions too
+            if window_num_pages is None:
+                window_num_pages = default_window_num_pages(cfg, max_slots, page_size, prefill_chunk)
+            if window_num_pages - 1 < math.ceil((prefill_chunk + self.window - 1) / page_size) + 1:
+                raise ValueError(
+                    f"window_num_pages={window_num_pages} cannot hold one prefill chunk of {prefill_chunk} "
+                    f"tokens and the window of {self.window} before it"
+                )
+            self.window_slot_pages = window_pages_per_slot(self.window, page_size)
+            self.window_allocator = PageAllocator(window_num_pages, page_size)
+            # one fixed length for every assign_window_pages call (one executable)
+            self._window_assign_len = max(max_slots, math.ceil(prefill_chunk / page_size) + 1)
+        self.window_pages_released = 0
         if num_pages is None:
             # default pool: half of what dense per-slot max_len caches would
             # take — the whole point is sharing
@@ -426,7 +492,16 @@ class ServingEngine:
         self.max_context = pages_per_slot * page_size
         self.max_waiting = max_waiting
         self.allocator = PageAllocator(num_pages, page_size)
-        self.cache = PagedKVCache.create(cfg, max_slots, num_pages, page_size, pages_per_slot)
+        self.cache = PagedKVCache.create(cfg, max_slots, num_pages, page_size, pages_per_slot, window_num_pages)
+        self.kv_pool_bytes, self.kv_window_pool_bytes = pool_bytes_by_kind(cfg, self.cache)
+        # routed experts: pairs routed and expert-layer calls are counted here,
+        # from what the loop launches; the pairs the held experts computed come
+        # back from the device with a step's tokens (cache.moe_pairs)
+        self.moe_expert_layers = sum(1 for k in cfg.layer_kinds if k.experts)
+        self.moe_assignments = 0
+        self.moe_local_assignments = 0
+        self.moe_expert_calls = 0
+        self._moe_pairs_seen = 0
         # what this engine actually runs on, as jax reports it — /v1/stats
         # carries it so a client never has to assume the device
         import jax
@@ -450,14 +525,13 @@ class ServingEngine:
         self.draft_params: Optional[dict] = None
         self.draft_cfg: Optional[Any] = None
         self.spec_k = 0
-        if draft is not None and _env_on(SPEC_ENV):
+        if speculative:
             draft_params, draft_cfg = draft
             if draft_cfg.vocab_size != cfg.vocab_size:
                 raise ValueError(
                     f"draft vocab ({draft_cfg.vocab_size}) != target vocab ({cfg.vocab_size})"
                 )
-            if getattr(draft_cfg, "is_moe", False):
-                raise ValueError("MoE draft configs are not paged-servable")
+            _refuse_unservable(draft_cfg, draft_params, True, "both", None)
             self.draft_params = draft_params
             self.draft_cfg = draft_cfg
             self.spec_k = max(1, int(spec_k))
@@ -480,7 +554,7 @@ class ServingEngine:
             PrefixCache(self.draft_allocator) if (want_prefix and self.spec_k) else None
         )
         # ISSUE 18 fleet mode: advertised role + overlapped spec rounds
-        self.role = role if role in ("prefill", "decode", "both") else resolve_role()
+        self.role = role
         SERVING_ROLE.set(float(ROLE_GAUGE_VALUES[self.role]))
         self.spec_overlap = _env_on(SPEC_OVERLAP_ENV)
         self.kv_pages_shipped = 0
@@ -664,11 +738,19 @@ class ServingEngine:
         token; its slot (and pages, once the prefix-cache entry is the only
         holder) free immediately, so a prefill replica's pool turns over at
         admission rate, not at generation length."""
+        self._one_pool_only("prefill_export")
         return self.submit(
             prompt, max_new_tokens=1, request_id=request_id,
             temperature=temperature, top_k=top_k, top_p=top_p, seed=seed,
             export=True,
         )
+
+    def _one_pool_only(self, what: str) -> None:
+        if not self.cfg.uniform:
+            raise ValueError(
+                f"{what} ships KV pages of ONE pool; this model keeps a pool a layer kind, and a shipment "
+                "over two pools does not exist yet"
+            )
 
     def submit_prefilled(
         self,
@@ -692,6 +774,8 @@ class ServingEngine:
         chaos knob MODAL_TPU_CHAOS_KV_SHIP_DROP eats — degrades to a plain
         `submit` (full local prefill): token streams are identical either
         way, only TTFT pays (docs/SERVING.md degradation matrix)."""
+        if shipment is not None:
+            self._one_pool_only("submit_prefilled")
         if shipment is None:
             # no bundle at all (unreadable kv_ref upstream): plain admission
             return self.submit(
@@ -770,12 +854,82 @@ class ServingEngine:
             for s in victims:
                 self._retired.append(s.request.id)
         for s in victims:
-            self.allocator.free(s.pages)
-            if s.draft_pages:
-                self.draft_allocator.free(s.draft_pages)
+            self._free_slot_pages(s)
             s.request._finish(error=message)
             SERVING_REQUESTS.inc(outcome="error")
         self._sync_page_gauges()
+
+    def _free_slot_pages(self, slot: _Slot) -> None:
+        """Everything a slot holds goes back: every pool frees together."""
+        self.allocator.free(slot.pages)
+        if slot.window_pages:
+            self.window_allocator.free(slot.window_pages)
+            slot.window_pages = []
+        if slot.draft_pages:
+            self.draft_allocator.free(slot.draft_pages)
+
+    # -- the window layers' pool ---------------------------------------------
+    # A slot's row in `cache.window_table` is indexed like its row in
+    # `page_table` (position p lives at index p // page_size), but only the
+    # indices the window can touch hold a live page.
+
+    def _window_first_index(self, pos: int) -> int:
+        """Row index of the oldest position a query at `pos` sees."""
+        return max(0, pos - (self.window - 1)) // self.page_size
+
+    def _window_reserve(self, wants: list) -> bool:
+        """wants: [(slot index, slot, last position to be written)]. Give
+        each slot the pages its row lacks up to that position, all or none,
+        and write them into the device table in one call. False where the
+        pool lacks room (the caller preempts)."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from ..models.paged_kv import assign_window_pages
+
+        need = []
+        for idx, slot, last_pos in wants:
+            have = slot.window_first + len(slot.window_pages)
+            need.append(max(0, last_pos // self.page_size + 1 - have))
+        if not self.window_allocator.can_alloc(sum(need)):
+            return False
+        entries = []
+        for (idx, slot, _last), n in zip(wants, need):
+            for page in self.window_allocator.alloc(n):
+                entries.append((idx, slot.window_first + len(slot.window_pages), page))
+                slot.window_pages.append(page)
+        for at in range(0, len(entries), self._window_assign_len):
+            part = entries[at : at + self._window_assign_len]
+            arr = np.zeros((3, self._window_assign_len), np.int32)
+            arr[0] = self.max_slots  # out of range: dropped
+            arr[:, : len(part)] = np.asarray(part, np.int32).T
+            self.cache = assign_window_pages(self.cache, jnp.asarray(arr[0]), jnp.asarray(arr[1]), jnp.asarray(arr[2]))
+        return True
+
+    def _window_release(self, slot: _Slot, next_pos: int) -> None:
+        """The pages that lie wholly behind the window of the NEXT query (at
+        `next_pos`) go back to the pool while the request lives; their table
+        entries go stale and are never addressed again."""
+        first = self._window_first_index(next_pos)
+        dead = min(len(slot.window_pages), first - slot.window_first)
+        if dead > 0:
+            self.window_allocator.free(slot.window_pages[:dead])
+            del slot.window_pages[:dead]
+            slot.window_first += dead
+            self.window_pages_released += dead
+        if not slot.window_pages:
+            slot.window_first = max(slot.window_first, first)
+
+    def _note_moe_pairs(self, cumulative: Any) -> None:
+        """`cache.moe_pairs` as it came back with a step's tokens: a uint32
+        that wraps, so what counts is how far it moved."""
+        now = int(cumulative)
+        self.moe_local_assignments += (now - self._moe_pairs_seen) & 0xFFFFFFFF
+        self._moe_pairs_seen = now
+
+    def _note_moe_call(self, tokens: int) -> None:
+        self.moe_assignments += tokens * self.cfg.experts_per_token * self.moe_expert_layers
+        self.moe_expert_calls += self.moe_expert_layers * self.cfg.experts_held[1]
 
     def _sync_page_gauges(self) -> None:
         KV_PAGES_ALLOCATED.set(float(self.allocator.allocated_pages))
@@ -851,7 +1005,14 @@ class ServingEngine:
                     self._evict_prefix_for(fresh_need - self.allocator.free_pages)
                 if self.spec_k and not self.draft_allocator.can_alloc(draft_need):
                     self._evict_draft_prefix_for(draft_need - self.draft_allocator.free_pages)
-                if not self.allocator.can_alloc(fresh_need) or (
+                # both pools must hold it. The window layers' pool is asked for
+                # the first chunk's room and gives the pages chunk by chunk, to the
+                # one slot whose chunk runs: a slot that waits its turn holds none
+                first_chunk_last = min(len(prefill_tokens), self.prefill_chunk) - 1
+                window_short = self.window and not self.window_allocator.can_alloc(
+                    first_chunk_last // self.page_size + 1
+                )
+                if window_short or not self.allocator.can_alloc(fresh_need) or (
                     self.spec_k and not self.draft_allocator.can_alloc(draft_need)
                 ):
                     if shared_pages:
@@ -1056,11 +1217,15 @@ class ServingEngine:
                 "prefill_prep", request_id=req.id, chunk_tokens=len(chunk),
                 offset=slot.prefill_done, bucket=bucket,
             )
-            if not self._cow_range(idx, slot, slot.prefill_done, slot.prefill_done + len(chunk)):
-                # CoW starved for a page: free capacity the hard way and retry
-                # next iteration. The needy slot itself is a valid victim — if
-                # it alone holds the pool, preempting it (requeue, pages freed)
-                # is the only move that ever unsticks the loop
+            if not self._cow_range(idx, slot, slot.prefill_done, slot.prefill_done + len(chunk)) or (
+                # the window layers' pool holds the chunk and the window before it
+                self.window and not self._window_reserve([(idx, slot, slot.prefill_done + len(chunk) - 1)])
+            ):
+                # CoW (or the window pool) starved for a page: free capacity
+                # the hard way and retry next iteration. The needy slot itself
+                # is a valid victim — if it alone holds the pool, preempting it
+                # (requeue, pages freed) is the only move that ever unsticks
+                # the loop
                 self._preempt_youngest(exclude=())
                 return
             padded = np.zeros((bucket,), np.int32)
@@ -1072,6 +1237,11 @@ class ServingEngine:
                 self.params, self.cfg, tokens_j, length_j, self.cache, slot_j, start_j
             )
             self._phase("emit", request_id=req.id, tokens=0)
+            if self.window:
+                # the chunk gives back all but the window at its end
+                self._window_release(slot, slot.prefill_done + len(chunk))
+            if self.moe_expert_layers:
+                self._note_moe_call(len(chunk))
             self.prompt_tokens_prefilled += len(chunk)
             self.prefill_chunks += 1
             self.prefill_bucket_tokens += bucket
@@ -1156,6 +1326,12 @@ class ServingEngine:
                 self.sampled_tokens += 1
                 SERVING_SAMPLED_TOKENS.inc()
                 first_tok = int(tok_arr[0])
+            elif self.moe_expert_layers:
+                import jax
+
+                first_tok, pairs = jax.device_get((next_tok, self.cache.moe_pairs))  # one fetch
+                first_tok = int(first_tok)
+                self._note_moe_pairs(pairs)
             else:
                 first_tok = int(next_tok)
             self._phase("emit", request_id=req.id, tokens=1)
@@ -1294,6 +1470,23 @@ class ServingEngine:
                 return self._grow_pages()  # geometry changed; re-run
         return True
 
+    def _grow_window_pages(self) -> bool:
+        """Before a decode step: every decoding slot gives back the pages
+        that fell behind its window and gets the page its token is written
+        to. A dry window pool preempts the youngest slot and retries. Returns
+        False if nothing can decode."""
+        while True:
+            with self._lock:
+                decoding = [
+                    (i, s) for i, s in enumerate(self.slots) if s is not None and s.state == "decode"
+                ]
+            for _i, s in decoding:
+                self._window_release(s, s.pos)
+            if self._window_reserve([(i, s, s.pos) for i, s in decoding]):
+                return True
+            if not self._preempt_youngest(exclude=()):
+                return False
+
     def _preempt_youngest(self, exclude: tuple[int, ...]) -> bool:
         """Free the most-recently-admitted slot's pages and requeue its
         request (generated prefix preserved: re-admission re-prefills
@@ -1312,10 +1505,9 @@ class ServingEngine:
             self.slots[idx] = None
             self.waiting.appendleft(slot.request)
             SERVING_QUEUE_DEPTH.set(float(len(self.waiting)))
-        self.allocator.free(slot.pages)
+        self._free_slot_pages(slot)
         self.cache = release_slot(self.cache, idx)
         if slot.draft_pages:
-            self.draft_allocator.free(slot.draft_pages)
             self.draft_cache = release_slot(self.draft_cache, idx)
         req = slot.request
         req.preemptions += 1
@@ -1376,7 +1568,7 @@ class ServingEngine:
         self._phase("decode_prep")
         if self.spec_k:
             return self._spec_round()
-        if not self._grow_pages():
+        if not self._grow_pages() or (self.window and not self._grow_window_pages()):
             return
         with self._lock:
             decoding = [
@@ -1410,7 +1602,15 @@ class ServingEngine:
             self.sampled_tokens += n_sampled
             SERVING_SAMPLED_TOKENS.inc(n_sampled)
         self._phase("decode_sync", batch=len(decoding))
-        next_host = np.asarray(next_tokens)
+        if self.moe_expert_layers:
+            import jax
+
+            # the held experts' pair count rides with the step's tokens: one fetch
+            next_host, pairs = jax.device_get((next_tokens, self.cache.moe_pairs))
+            self._note_moe_pairs(pairs)
+            self._note_moe_call(len(decoding))
+        else:
+            next_host = np.asarray(next_tokens)
         self._phase("emit", tokens=len(decoding))
         self.step_count += 1
         SERVING_BATCH_OCCUPANCY.observe(float(len(decoding)))
@@ -1671,10 +1871,9 @@ class ServingEngine:
         with self._lock:
             self.slots[idx] = None
             self._retired.append(req.id)
-        self.allocator.free(slot.pages)
+        self._free_slot_pages(slot)
         self.cache = release_slot(self.cache, idx)
         if slot.draft_pages:
-            self.draft_allocator.free(slot.draft_pages)
             self.draft_cache = release_slot(self.draft_cache, idx)
         self.requests_completed += 1
         SERVING_REQUESTS.inc(outcome="ok")
@@ -1694,6 +1893,25 @@ class ServingEngine:
 
         keys = list(self.prefix_cache._index.keys())  # atomic snapshot (GIL)
         return [prefix_digest(key) for key in keys[:limit]]
+
+    def _second_pool_stats(self) -> dict:
+        """Keys only a model with window layers or routed experts has: the
+        `kv_pages_*` keys keep meaning the pool that grows with the context."""
+        out: dict = {}
+        if self.window:
+            out.update(
+                kv_window_pages_total=self.window_allocator.num_pages - 1,
+                kv_window_pages_high_water=self.window_allocator.high_water,
+                kv_window_pages_released=self.window_pages_released,
+                kv_window_pool_bytes=self.kv_window_pool_bytes,
+            )
+        if self.moe_expert_layers:
+            out["moe"] = {
+                "assignments": self.moe_assignments,
+                "local_assignments": self.moe_local_assignments,
+                "expert_calls": self.moe_expert_calls,
+            }
+        return out
 
     def stats(self) -> dict:
         from ..observability.device_telemetry import telemetry_summary
@@ -1740,7 +1958,8 @@ class ServingEngine:
             "kv_pages_allocated": self.allocator.allocated_pages,
             "kv_pages_free": self.allocator.free_pages,
             "kv_pages_high_water": self.allocator.high_water,
-            "kv_pool_bytes": self.cache.pool_bytes(),
+            "kv_pool_bytes": self.kv_pool_bytes,
+            **self._second_pool_stats(),
             "attn_impl": self.attn_impl,
             "device": self.device,
             "compile": telemetry_summary(),

@@ -53,8 +53,11 @@ def llm_service(
     draft_config: Optional[str] = None,
     draft_weights: Optional[str] = None,
     spec_k: int = 3,
-    prefix_cache: Optional[bool] = None,  # None = env default (on)
+    prefix_cache: Optional[bool] = None,  # None = env default (on; off for a model with window layers)
     role: Optional[str] = None,  # prefill|decode|both (None = env/both)
+    # a model with window attention keeps a second, bounded KV pool for those
+    # layers: its size in pages (None = every slot's window + one chunk)
+    window_num_pages: Optional[int] = None,
     **cls_kwargs: Any,
 ) -> Any:
     """Register a serving class on `app` and return it (an `@app.cls`
@@ -132,6 +135,7 @@ def llm_service(
                 spec_k=spec_k,
                 prefix_cache=prefix_cache,
                 role=role,
+                window_num_pages=window_num_pages,
             ).start()
 
         @modal_tpu.exit()

@@ -133,3 +133,48 @@ def test_paged_prefill_compiles_for_v5e_with_nothing_of_the_span_s_size(config, 
     assert len(pool_gathers) == 2 and all(pages * page <= 1024 < kv_span for pages in pool_gathers), pool_gathers
     # the loop over KV blocks is there, with a trip count that is data: a `while` inside the layers' `while`
     assert len(re.findall(r" while\(", text)) >= 2
+
+
+# (KV heads, query heads a KV head, pool pages, window, sink, name): the two decode kernels of the
+# configuration with window and full attention side by side (benchmark/configs/
+# mimo-v2-flash-serve-1chip-ep16.json): 128 slots, 512 pages a slot, values 128 wide
+TWO_WIDTH_KERNELS = {
+    "full": (4, 16, 16385, 0, False, "paged_decode_attention_full"),
+    "swa": (8, 8, 1169, 128, True, "paged_decode_attention_swa"),
+}
+
+
+@pytest.mark.parametrize("stored", [256, 192], ids=["keys-stored-256", "keys-192-as-published"])
+@pytest.mark.parametrize("kind", sorted(TWO_WIDTH_KERNELS))
+def test_the_two_width_decode_kernels_compile_for_v5e_and_only_padded_keys_spare_the_pool_a_copy(kind, stored, one_chip, no_compile_cache):
+    """Mosaic takes keys wider than values, a window and a sink as written.
+    With keys stored 192 wide XLA lays the pool out pages-innermost and hands
+    the kernel a transposed COPY of it at every call; stored 256 wide
+    (`paged_kv.k_cache_dim`) the pool goes in as it lies."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models.llama import get_config
+    from modal_tpu.models.paged_kv import k_cache_dim
+    from modal_tpu.ops.paged_attention import paged_decode_attention
+
+    n_kv, n_rep, pool, window, sink, name = TWO_WIDTH_KERNELS[kind]
+    assert k_cache_dim(get_config("mimo-v2-flash")) == 256 and k_cache_dim(get_config("llama3-8b")) == 128
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [
+        shaped((128, n_kv, n_rep, stored)), shaped((pool, 16, n_kv, stored)), shaped((pool, 16, n_kv, 128)),
+        shaped((128, 512), jnp.int32), shaped((128,), jnp.int32),
+    ]
+    if sink:
+        args.append(shaped((n_kv, n_rep), jnp.float32))
+
+    def call(q, k, v, table, lens, sinks=None):
+        return paged_decode_attention(q, k, v, table, lens, window=window, sink=sinks, scale=192**-0.5, name=name)
+
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert re.search(rf"%{name}[.\d]* = bf16\[128,{n_kv},{n_rep},128\]", text), "the kernel lost its name or its output width"
+    pool_copies = re.findall(rf"= bf16\[{pool},16,{n_kv},{stored}\]\S* copy\(", text)
+    assert bool(pool_copies) == (stored == 192)

@@ -1,0 +1,293 @@
+"""A model whose layers are not all alike in the serving engine: two kinds of
+KV cache under one page manager (a pool that grows with the context, a pool
+bounded by a window), the decode kernel with a window, a sink and keys wider
+than values, and what the engine refuses, by mechanism. `tiny-mimo` on the
+CPU; the logits against the plain reference are in
+tests/benchmark/test_bench_mimo_v2.py."""
+
+import math
+
+import numpy as np
+import pytest
+
+PAGE, CHUNK, WINDOW = 4, 16, 8
+WINDOW_SLOT_PAGES = math.ceil(WINDOW / PAGE) + 1  # the window and the page being written
+
+
+@pytest.fixture(scope="module")
+def model():
+    import jax
+
+    from modal_tpu.models.llama import get_config, init_params
+
+    cfg = get_config("tiny-mimo")
+    return init_params(cfg, jax.random.PRNGKey(0)), cfg
+
+
+def engine_of(model, **overrides):
+    from modal_tpu.serving.engine import ServingEngine
+
+    params, cfg = model
+    kwargs = dict(max_slots=3, page_size=PAGE, prefill_chunk=CHUNK, num_pages=120)
+    kwargs.update(overrides)
+    return ServingEngine(params, cfg, **kwargs)
+
+
+def prompts_of(seed, lengths):
+    rng = np.random.default_rng(seed)
+    return [[int(x) for x in rng.integers(0, 512, size=n)] for n in lengths]
+
+
+def test_a_decoding_slot_s_window_row_never_exceeds_the_window_and_one_page(model):
+    engine = engine_of(model)
+    seen = {"decode": 0, "prefill": 0}
+    grow = engine._grow_window_pages
+
+    def watched():
+        ok = grow()
+        for s in engine.slots:
+            if s is not None:
+                seen[s.state] = max(seen[s.state], len(s.window_pages))
+        return ok
+
+    engine._grow_window_pages = watched
+    engine.start()
+    try:
+        handles = [engine.submit(p, 40) for p in prompts_of(1, (70, 5, 30, 100, 17))]
+        for h in handles:
+            assert len(h.result(timeout=300)) == 40
+        stats = engine.stats()
+    finally:
+        engine.stop()
+    assert 0 < seen["decode"] <= WINDOW_SLOT_PAGES
+    # between its chunks a slot in prefill holds no more than the window either
+    assert seen["prefill"] <= WINDOW_SLOT_PAGES
+    assert stats["kv_window_pages_released"] > 0
+    assert stats["kv_window_pages_high_water"] <= stats["kv_window_pages_total"]
+    # the window pool is bounded by the window, not by the context: 5 layers x 14 pages (3 slots x 3 + a chunk's 4 + scratch)
+    assert stats["kv_window_pool_bytes"] == 5 * 14 * PAGE * 4 * (24 + 16) * 2
+    assert stats["kv_pool_bytes"] == 2 * 120 * PAGE * 2 * (24 + 16) * 2
+    assert engine.window_allocator.free_pages == engine.window_allocator.num_pages - 1  # everything came back
+    assert engine.allocator.free_pages == engine.allocator.num_pages - 1
+
+
+def test_a_page_behind_the_window_goes_to_another_slot_at_once(model):
+    from modal_tpu.serving.engine import GenRequest, _Slot
+
+    engine = engine_of(model)  # not started: the bookkeeping alone
+    a, b = _Slot(request=GenRequest([1], 1)), _Slot(request=GenRequest([1], 1))
+    assert engine._window_reserve([(0, a, 15)]) and len(a.window_pages) == 4  # positions 0..15
+    first_pages = list(a.window_pages)
+    engine._window_release(a, 16)  # the next query, at 16, sees 9..16: pages 0 and 1 are dead
+    assert (a.window_first, a.window_pages) == (2, first_pages[2:]) and engine.window_pages_released == 2
+    free_before = engine.window_allocator.free_pages
+    assert engine._window_reserve([(1, b, 7)])
+    assert sorted(b.window_pages) == sorted(first_pages[:2])  # the very pages a gave back
+    assert engine.window_allocator.free_pages == free_before - 2
+    # the device's table: a's stale entries stay (never addressed), b's row points at the same pages
+    table = np.asarray(engine.cache.window_table)
+    assert list(table[0, :4]) == first_pages and list(table[1, :2]) == b.window_pages
+    # a keeps decoding: one more page at 16, and never more than the bound
+    for pos in range(16, 60):
+        engine._window_release(a, pos)
+        assert engine._window_reserve([(0, a, pos)])
+        assert len(a.window_pages) <= WINDOW_SLOT_PAGES
+    engine._free_slot_pages(a)
+    engine._free_slot_pages(b)
+    assert engine.window_allocator.free_pages == engine.window_allocator.num_pages - 1
+
+
+@pytest.mark.parametrize("pool", ["full", "window"])
+def test_admission_waits_while_either_pool_lacks_room(model, pool):
+    engine = engine_of(model, max_slots=2)  # not started: _admit by hand
+    allocator = engine.allocator if pool == "full" else engine.window_allocator
+    taken = allocator.alloc(allocator.free_pages)  # the pool is dry
+    req = engine.submit(prompts_of(2, (20,))[0], 8)
+    engine._admit()
+    assert engine.slots == [None, None] and list(engine.waiting) == [req]
+    other = engine.window_allocator if pool == "full" else engine.allocator
+    assert other.free_pages == other.num_pages - 1  # nothing was taken from the pool that had room
+    allocator.free(taken)
+    engine._admit()
+    assert engine.slots[0] is not None and not engine.waiting
+    assert engine.slots[0].window_pages == []  # its chunk's pages come when its chunk runs
+    engine._prefill_one()
+    assert len(engine.slots[0].window_pages) == WINDOW // PAGE  # 16 of 20 tokens in: what the query at 16 still sees
+    engine._free_slot_pages(engine.slots[0])
+
+
+def test_preemption_frees_both_pools_and_the_streams_do_not_change(model):
+    prompts = prompts_of(3, (60, 44, 70, 25))
+    roomy = engine_of(model).start()
+    try:
+        want = [h.result(timeout=300) for h in [roomy.submit(p, 50) for p in prompts]]
+    finally:
+        roomy.stop()
+    # a full pool of 44 pages = 176 positions cannot hold three of these to their ends
+    tight = engine_of(model, num_pages=45).start()
+    try:
+        got = [h.result(timeout=300) for h in [tight.submit(p, 50) for p in prompts]]
+        stats = tight.stats()
+    finally:
+        tight.stop()
+    assert stats["preemptions"] > 0
+    assert got == want  # a preempted request re-prefills through both pools and loses no token
+    assert tight.allocator.free_pages == 44 and tight.window_allocator.free_pages == tight.window_allocator.num_pages - 1
+
+
+def test_a_dry_window_pool_preempts_and_still_finishes_every_request(model):
+    # room for one chunk and the window before it, and little else
+    smallest = math.ceil((CHUNK + WINDOW - 1) / PAGE) + 1
+    engine = engine_of(model, window_num_pages=smallest + 2).start()
+    try:
+        handles = [engine.submit(p, 30) for p in prompts_of(4, (40, 33, 50))]
+        assert all(len(h.result(timeout=300)) == 30 for h in handles)
+        assert engine.stats()["preemptions"] > 0
+    finally:
+        engine.stop()
+    assert engine.window_allocator.free_pages == smallest + 1
+    with pytest.raises(ValueError, match="cannot hold one prefill chunk"):
+        engine_of(model, window_num_pages=smallest)
+
+
+REFUSALS = {
+    "a draft over window layers and experts": (dict(draft="self"), "speculative decoding"),
+    "a prefill role over two pools": (dict(role="prefill"), "ships KV pages"),
+    "a decode role over two pools": (dict(role="decode"), "ships KV pages"),
+    "a prefix cache across a window layer": (dict(prefix_cache=True), "prefix_cache=True with window layers"),
+    "int8 over expert weights": (dict(quantized=True), "quantize_int8 over routed-expert weights"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSALS))
+def test_the_engine_refuses_by_mechanism(model, case):
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.serving.engine import ServingEngine
+
+    params, cfg = model
+    kwargs, message = REFUSALS[case]
+    kwargs = dict(kwargs)
+    if kwargs.pop("draft", None):
+        kwargs["draft"] = (params, cfg)
+    if kwargs.pop("quantized", None):
+        params = jax.tree_util.tree_map(lambda a: a.astype(jnp.int8) if a.ndim == 4 else a, params)  # the experts' stacks
+    with pytest.raises(ValueError, match=message) as refused:
+        ServingEngine(params, cfg, page_size=PAGE, prefill_chunk=CHUNK, **kwargs)
+    assert "mimo" not in str(refused.value).lower()  # the mechanism, never a model's name
+
+
+def test_the_trainer_s_switch_layer_is_refused_in_the_paged_path_and_as_a_draft():
+    import jax
+
+    from modal_tpu.models.llama import get_config, init_params
+    from modal_tpu.serving.engine import ServingEngine
+
+    moe = get_config("tiny-moe")
+    with pytest.raises(ValueError, match="top-1 routing with a capacity that drops tokens"):
+        ServingEngine({}, moe)
+    tiny = get_config("tiny")
+    params = init_params(tiny, jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="mesh trainer's switch layer"):
+        ServingEngine(params, tiny, draft=({}, moe))
+
+
+def test_shipping_pages_and_the_verify_step_say_why_they_cannot(model):
+    import jax.numpy as jnp
+
+    from modal_tpu.models import paged_kv as pk
+
+    params, cfg = model
+    engine = engine_of(model)
+    with pytest.raises(ValueError, match="ONE pool"):
+        engine.prefill_export([1, 2, 3])
+    with pytest.raises(ValueError, match="ONE pool"):
+        engine.submit_prefilled([1, 2, 3], {"prompt": [1, 2, 3]})
+    with pytest.raises(ValueError, match="one pool of one layer kind"):
+        pk.paged_verify_step(params, cfg, jnp.zeros((3, 2), jnp.int32), engine.cache, jnp.ones((3,), bool))
+    with pytest.raises(ValueError, match="needs window_num_pages"):
+        pk.PagedKVCache.create(cfg, 2, 16, PAGE)
+
+
+def test_a_depth_cut_keeps_the_first_layers_of_the_published_pattern():
+    from modal_tpu.models.llama import get_config
+
+    cut = get_config({"name": "mimo-v2-flash", "n_layers": 7, "n_experts_held": 16, "vocab_size": 19072})
+    assert cut.attn_pattern == (0, 1, 1, 1, 1, 0, 1) and cut.ffn_pattern == (0, 1, 1, 1, 1, 1, 1)
+    kinds = [(k.n_kv_heads, k.window, k.sink, k.rope_theta, k.experts, k.attn_name) for k in cut.layer_kinds]
+    assert kinds[0] == (4, 0, False, 5_000_000.0, False, "full")
+    assert kinds[1] == (8, 128, True, 10_000.0, True, "swa") and kinds[5] == (4, 0, False, 5_000_000.0, True, "full")
+    assert [(first, n) for _k, first, n in cut.layer_groups] == [(0, 1), (1, 4), (5, 1), (6, 1)]
+    assert (cut.head_dim, cut.v_dim, cut.rope_dim, cut.experts_held) == (192, 128, 64, (0, 16))
+    assert get_config("mimo-v2-flash").param_count() == 308_778_780_864  # whole, as published
+    # the dense presets are the case "every layer alike" of the same description
+    dense = get_config("llama3-8b")
+    assert dense.uniform and len(dense.layer_groups) == 1 and dense.layer_kinds[0].attn_name == ""
+    assert (dense.head_dim, dense.v_dim, dense.rope_dim) == (128, 128, 128)
+    with pytest.raises(ValueError, match="names 3 layers"):
+        get_config("tiny-mimo", attn_pattern=(0, 1, 1))
+    with pytest.raises(ValueError, match="outside the 32 routed experts"):
+        get_config("tiny-mimo", experts_held_start=30)
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["no-sink", "sink"])
+@pytest.mark.parametrize("window", [0, 8, 5], ids=["full", "window-8", "window-5"])
+def test_the_decode_kernel_matches_the_gather_path_with_a_window_a_sink_and_two_widths(window, sink):
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models.paged_kv import _paged_attention
+
+    slots, heads, n_kv, hd, vd, pages_per_slot, pool = 3, 8, 2, 24, 16, 6, 20
+    keys = jax.random.split(jax.random.PRNGKey(window + 10 * sink), 5)
+    q = jax.random.normal(keys[0], (slots, 1, heads, hd), jnp.float32)
+    k_pages = jax.random.normal(keys[1], (pool, PAGE, n_kv, hd), jnp.float32)
+    v_pages = jax.random.normal(keys[2], (pool, PAGE, n_kv, vd), jnp.float32)
+    table = jax.random.permutation(keys[3], jnp.arange(1, pool))[: slots * pages_per_slot].reshape(slots, pages_per_slot)
+    positions = jnp.asarray([0, 9, 22], jnp.int32)  # inside the first page, across pages, near the row's end
+    sinks = jax.random.normal(keys[4], (heads,), jnp.float32) if sink else None
+    kv_pos = jnp.arange(pages_per_slot * PAGE)[None, None, None, :]
+    seen = kv_pos <= positions[:, None, None, None]
+    if window:
+        seen = seen & (kv_pos > positions[:, None, None, None] - window)
+    mask = jnp.where(seen, 0.0, -jnp.inf)
+    if window:
+        # pages behind a window are stale in a served row: the kernel must never address them
+        first_live = jnp.maximum(positions - (window - 1), 0) // PAGE
+        stale = jnp.arange(pages_per_slot)[None, :] < first_live[:, None]
+        table_kernel = jnp.where(stale, 10_000, table)  # an id far outside the pool
+    else:
+        table_kernel = table
+    args = dict(window=window, sink=sinks, scale=0.2)
+    want = _paged_attention(q, k_pages, v_pages, table, mask, positions, "gather", **args)
+    got = _paged_attention(q, k_pages, v_pages, table_kernel, mask, positions, "kernel_interpret", kernel_name="k", **args)
+    assert got.shape == (slots, 1, heads, vd)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=0)
+
+
+def test_stats_of_a_dense_model_carry_no_second_pool(model):
+    import jax
+
+    from modal_tpu.models.llama import get_config, init_params
+    from modal_tpu.serving.engine import ServingEngine
+
+    cfg = get_config("tiny")
+    stats = ServingEngine(init_params(cfg, jax.random.PRNGKey(0)), cfg).stats()
+    assert not [k for k in stats if k.startswith("kv_window") or k == "moe"]
+    assert stats["kv_pool_bytes"] > 0
+    two = engine_of(model).stats()
+    assert {"kv_window_pages_total", "kv_window_pages_high_water", "kv_window_pages_released", "kv_window_pool_bytes", "moe"} <= set(two)
+    assert two["moe"] == {"assignments": 0, "local_assignments": 0, "expert_calls": 0}
+
+
+def test_llm_service_takes_the_second_pool_s_size_and_hands_it_to_the_engine():
+    import inspect
+
+    from modal_tpu.serving import llm_service
+    from modal_tpu.serving.engine import ServingEngine
+
+    assert inspect.signature(llm_service).parameters["window_num_pages"].default is None
+    assert inspect.signature(ServingEngine.__init__).parameters["window_num_pages"].default is None
+    with open(inspect.getsourcefile(llm_service)) as f:
+        assert "window_num_pages=window_num_pages" in f.read()
