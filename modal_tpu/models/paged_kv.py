@@ -176,7 +176,15 @@ class PagedKVCache(NamedTuple):
     groups share the page ids of `page_table` (a pool that grows with the
     context), window groups those of `window_table` (a pool bounded by the
     window: a page behind it goes back to its free list while the request
-    lives, and its stale table entry is never addressed)."""
+    lives, and its stale table entry is never addressed).
+
+    A page id names the same page in every layer of a pool: the tables, the
+    allocators, `copy_page` and the page shipment index `[:, id]`. The jitted
+    steps do not slice a layer out to use it: their layer loop carries a pool
+    whole, viewed as `[layers * P, page, n_kv, width]`, and layer l reads and
+    writes page `l * P + id` (`_run_layers`), so the donated buffers are
+    updated in place and a step holds no second pool. Page 0 of EVERY layer
+    is that layer's scratch page."""
 
     k_pages: Any
     v_pages: Any
@@ -478,7 +486,8 @@ def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, windo
     page, n_kv, vd = k_pages.shape[1], k_pages.shape[2], v_pages.shape[-1]
     n_rep = h // n_kv
     block = block_pages * page
-    # a row the block does not divide is padded with the scratch page: those
+    # a row the block does not divide is padded with page 0 (a scratch page,
+    # of the pool's first layer where the ids are a later layer's): those
     # positions lie past the span, so past every row that is read
     row = jnp.pad(row, (0, -row.shape[0] % block_pages))
     qg = q.reshape(sq, n_kv, n_rep, hd)
@@ -533,10 +542,12 @@ def _rope(cfg, x, positions, inv_freq):
     return jnp.concatenate([apply_rope(x[..., :rd], positions, inv_freq), x[..., rd:]], axis=-1)
 
 
-def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, inv_freq, kp, vp, attend, valid=None):
+def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, table, inv_freq, kp, vp, attend, valid=None):
     """One transformer layer of `kind` over paged KV. x: [S, Sq, D];
-    positions: [S, Sq]; write_page_ids/offsets: flat [S*Sq] scatter targets
-    in this kind's pool; attend: (kind, q [S, Sq, H, hd], k_pages, v_pages,
+    positions: [S, Sq]; kp/vp: [pages, page, n_kv, width], the pool this
+    layer's pages lie in; write_page_ids/offsets: flat [S*Sq] scatter targets
+    in it; table: the page ids this layer reads, as the caller's `attend`
+    takes them; attend: (kind, q [S, Sq, H, hd], k_pages, v_pages, table,
     sink) -> [S, Sq, H, vd] over the pool as this layer has just written it —
     the caller's own (`_paged_attention` for decode and verify,
     `_prefill_attention` for a prefill chunk). Returns (x, kp, vp, pairs):
@@ -568,7 +579,7 @@ def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, 
                 v.reshape(s * sq, n_kv, vd),
                 write_page_ids, write_offsets,
             )
-        attn_out = attend(kind, q, kp, vp, layer.get("sink"))
+        attn_out = attend(kind, q, kp, vp, table, layer.get("sink"))
         x = x + qmm(attn_out.reshape(s, sq, cfg.n_heads * vd), layer["wo"])
     pairs = jnp.zeros((), jnp.uint32)
     if kind.experts:
@@ -585,52 +596,53 @@ def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, 
     return x, kp, vp, pairs
 
 
-def _run_layers(params, cfg, x, positions, writes, cache, attend, valid=None):
-    """Every layer in turn. `writes`: the flat scatter targets (page ids,
-    offsets), and a second pair in the window layers' pool where the model
-    has them. Returns (x, k_pages, v_pages, pairs)."""
-    if cfg.uniform:
-        # every layer alike: one scan over the stacked layers and the one pool
-        kind = cfg.layer_kinds[0]
-        inv_freq = rope_frequencies(cfg)
-        write_page_ids, write_offsets = writes[0]
+def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None):
+    """Every layer in turn: a group of like layers at a time (`cfg.layer_groups`;
+    the dense presets are one group), one scan over the group's stacked weights.
+    `pools`: (write page ids, write offsets, page table) for the pool that
+    grows with the context, and a second triple for the window layers' pool
+    where the model has them; ids and table count pages within ONE layer.
+    Returns (x, k_pages, v_pages, pairs).
 
-        def body(x_carry, layer_and_pages):
-            layer, kp, vp = layer_and_pages
-            x_out, kp, vp, _pairs = _paged_layer(
-                cfg, kind, x_carry, layer, positions, write_page_ids, write_offsets, inv_freq, kp, vp, attend
-            )
-            return x_out, (kp, vp)
-
-        x, (k_pages, v_pages) = lax.scan(body, x, (params["layers"], cache.k_pages, cache.v_pages))
-        return x, k_pages, v_pages, None
-
-    # layers of more than one kind: a group of like layers at a time, a scan
-    # over the group's stacked layers and its own pool where it has several,
-    # the body itself where it has one (no stacked copy of a pool that holds
-    # one layer: its scatter stays in place on the donated buffer)
+    The group's pool is the scan's CARRY, whole, viewed as
+    `[layers * P, page, n_kv, width]` (a bitcast of the stored
+    `[layers, P, ...]`), and layer l addresses its pages in it at `l * P + id`:
+    the write ids and the table are shifted by `l * P`, so page 0 of each layer
+    stays that layer's scratch page and every reader (`attend`, the kernel)
+    runs as over a pool of one layer. Nothing of a layer's or a pool's size is
+    sliced out or written back: the scatter of the new rows lands in place in
+    the donated buffer. (A pool that is a scanned input and a stacked output
+    is sliced a layer at a time, written back into a second pool and copied
+    whole after the loop, in every step: PERF.md section 6, PR 31.)"""
+    groups = (params["layers"], cache.k_pages, cache.v_pages)
+    if cfg.uniform:  # one group, kept bare and not as tuples of one
+        groups = tuple((g,) for g in groups)
     pairs = jnp.zeros((), jnp.uint32)
     k_pages, v_pages = [], []
-    for (kind, _first, n), layers, kp, vp in zip(cfg.layer_groups, params["layers"], cache.k_pages, cache.v_pages):
+    for (kind, _first, n), layers, kp, vp in zip(cfg.layer_groups, *groups):
         inv_freq = rope_frequencies(cfg, kind.rope_theta)
-        write_page_ids, write_offsets = writes[bool(kind.window)]
+        write_page_ids, write_offsets, table = pools[bool(kind.window)]
+        pool_pages = kp.shape[1]
 
-        def body(carry, layer_and_pages, kind=kind, inv_freq=inv_freq, ids=write_page_ids, offsets=write_offsets):
-            x_carry, pairs_carry = carry
-            layer, kp_l, vp_l = layer_and_pages
-            x_out, kp_l, vp_l, used = _paged_layer(
-                cfg, kind, x_carry, layer, positions, ids, offsets, inv_freq, kp_l, vp_l, attend, valid
+        def body(carry, layer_and_index):
+            x_carry, pairs_carry, kp_all, vp_all = carry
+            layer, index = layer_and_index
+            first_page = index * pool_pages
+            x_out, kp_all, vp_all, used = _paged_layer(
+                cfg, kind, x_carry, layer, positions, write_page_ids + first_page, write_offsets,
+                table + first_page, inv_freq, kp_all, vp_all, attend, valid,
             )
-            return (x_out, pairs_carry + used), (kp_l, vp_l)
+            return (x_out, pairs_carry + used, kp_all, vp_all), None
 
-        if n == 1:
-            one = jax.tree_util.tree_map(lambda a: a[0], (layers, kp, vp))
-            (x, pairs), (kp, vp) = body((x, pairs), one)
-            kp, vp = kp[None], vp[None]
-        else:
-            (x, pairs), (kp, vp) = lax.scan(body, (x, pairs), (layers, kp, vp))
-        k_pages.append(kp)
-        v_pages.append(vp)
+        (x, pairs, kp_all, vp_all), _ = lax.scan(
+            body,
+            (x, pairs, kp.reshape((-1,) + kp.shape[2:]), vp.reshape((-1,) + vp.shape[2:])),
+            (layers, jnp.arange(n, dtype=jnp.int32)),
+        )
+        k_pages.append(kp_all.reshape(kp.shape))
+        v_pages.append(vp_all.reshape(vp.shape))
+    if cfg.uniform:
+        return x, k_pages[0], v_pages[0], pairs
     return x, tuple(k_pages), tuple(v_pages), pairs
 
 
@@ -691,23 +703,24 @@ def paged_prefill(
     write_page_ids = jnp.where(valid, row[jnp.clip(positions // page, 0, row.shape[0] - 1)], 0)
     write_offsets = jnp.where(valid, positions % page, 0)
     block_pages = prefill_kv_block_pages(row.shape[0], page)
-    writes, rows = [(write_page_ids, write_offsets)], [row]
+    pools = [(write_page_ids, write_offsets, row)]
     if cache.window_table is not None:
-        rows.append(cache.window_table[slot])
-        writes.append((jnp.where(valid, rows[1][jnp.clip(positions // page, 0, row.shape[0] - 1)], 0), write_offsets))
+        window_row = cache.window_table[slot]
+        window_ids = jnp.where(valid, window_row[jnp.clip(positions // page, 0, row.shape[0] - 1)], 0)
+        pools.append((window_ids, write_offsets, window_row))
     scale = 1.0 / math.sqrt(cfg.head_dim)
 
-    def attend(kind, q, k_pages, v_pages, sink):
+    def attend(kind, q, k_pages, v_pages, row, sink):
         # causal within the live prefix: q at position p sees kv_pos <= p (a
         # window layer: and > p - window); rows past `length` are garbage but
         # their outputs are never read
         return _prefill_attention(
-            q[0], k_pages, v_pages, rows[bool(kind.window)], positions, start_pos + length, block_pages,
+            q[0], k_pages, v_pages, row, positions, start_pos + length, block_pages,
             window=kind.window, sink=sink, scale=scale,
         )[None]
 
     x = qembed(params["embed"], tokens[None, :])  # [1, S_pad, D]
-    x, k_pages, v_pages, pairs = _run_layers(params, cfg, x, positions[None, :], writes, cache, attend, valid)
+    x, k_pages, v_pages, pairs = _run_layers(params, cfg, x, positions[None, :], pools, cache, attend, valid)
     last = lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)  # [D]
     logits = _logits(params, cfg, last)
     cache = _advance(cache, k_pages, v_pages, pairs, cache.seq_lens.at[slot].set(start_pos + length))
@@ -744,23 +757,21 @@ def paged_decode_step(
     x = qembed(params["embed"], tokens[:, None])  # [slots, 1, D]
     kv_pos = jnp.arange(cache.kv_span, dtype=jnp.int32)[None, None, None, :]
     mask = jnp.where(kv_pos <= positions[:, None, None, None], 0.0, -jnp.inf).astype(jnp.float32)
-    writes, tables, masks = [(write_page_ids, write_offsets)], [rows], [mask]
+    pools, masks = [(write_page_ids, write_offsets, rows)], [mask]
     if cache.window_table is not None:
-        tables.append(cache.window_table)
-        window_ids = jnp.take_along_axis(tables[1], page_idx[:, None], axis=1)[:, 0]
-        writes.append((jnp.where(active, window_ids, 0), write_offsets))
+        window_ids = jnp.take_along_axis(cache.window_table, page_idx[:, None], axis=1)[:, 0]
+        pools.append((jnp.where(active, window_ids, 0), write_offsets, cache.window_table))
         behind = kv_pos <= positions[:, None, None, None] - cfg.window
         masks.append(jnp.where(behind, -jnp.inf, mask))
     scale = 1.0 / math.sqrt(cfg.head_dim)
 
-    def attend(kind, q, k_pages, v_pages, sink):
-        w = bool(kind.window)
+    def attend(kind, q, k_pages, v_pages, table, sink):
         return _paged_attention(
-            q, k_pages, v_pages, tables[w], masks[w], positions, attn_impl,
+            q, k_pages, v_pages, table, masks[bool(kind.window)], positions, attn_impl,
             window=kind.window, sink=sink, scale=scale, kernel_name=_kernel_name(kind),
         )
 
-    x, k_pages, v_pages, pairs = _run_layers(params, cfg, x, positions[:, None], writes, cache, attend, active)
+    x, k_pages, v_pages, pairs = _run_layers(params, cfg, x, positions[:, None], pools, cache, attend, active)
     logits = _logits(params, cfg, x[:, 0, :])  # [slots, V]
     cache = _advance(cache, k_pages, v_pages, pairs, jnp.where(active, cache.seq_lens + 1, cache.seq_lens))
     return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
@@ -808,11 +819,11 @@ def paged_verify_step(
         kv_pos <= positions[:, None, :, None], 0.0, -jnp.inf
     ).astype(jnp.float32)  # [S, 1, K1, K]
 
-    def attend(_kind, q, k_pages, v_pages, _sink):
-        return _paged_attention(q, k_pages, v_pages, rows, mask)
+    def attend(_kind, q, k_pages, v_pages, table, _sink):
+        return _paged_attention(q, k_pages, v_pages, table, mask)
 
     x, k_pages, v_pages, _pairs = _run_layers(
-        params, cfg, x, positions, [(write_page_ids.reshape(-1), write_offsets.reshape(-1))], cache, attend
+        params, cfg, x, positions, [(write_page_ids.reshape(-1), write_offsets.reshape(-1), rows)], cache, attend
     )
     logits = _logits(params, cfg, x)  # [slots, K1, V]
     cache = cache._replace(k_pages=k_pages, v_pages=v_pages)

@@ -1,10 +1,12 @@
 """Compiles for the chip without the chip: the kernel of the served path and
-the prefill chunk at the benchmark's real shapes, for a TPU v5e that is
+the two jitted steps at the benchmark's real shapes, for a TPU v5e that is
 described and not attached (the TPU's compiler is installed where the tests
 run). Nothing runs, so this says nothing about results or times: it guards
-that Mosaic still takes the kernel as written, that the kernel and the
-prefill program keep their stable names, and that nothing of the slot
-span's size is left in a prefill chunk.
+that Mosaic still takes the kernel as written, that the kernel and the two
+programs keep their stable names, that nothing of the slot span's size is
+left in a prefill chunk, and that nothing of a KV pool's or of one layer's
+pool's size is made in either step but the in-place scatter of the new rows
+(the layer loop carries the pool: `paged_kv._run_layers`).
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process may load the TPU's library, each xdist worker imports every
@@ -86,31 +88,64 @@ def test_paged_decode_attention_compiles_for_v5e_under_its_name(config, one_chip
     assert len(calls) == 1 and re.search(r"%paged_decode_attention(\.\d+)? = ", calls[0]), calls
 
 
-@pytest.mark.parametrize("config", sorted(CELL_SHAPES))
-def test_paged_prefill_compiles_for_v5e_with_nothing_of_the_span_s_size(config, one_chip, no_compile_cache):
+PAGE = 16
+# the third configuration's engine (benchmark/configs/mimo-v2-flash-serve-1chip-ep16.json): 128 slots,
+# a pool a layer group, the four consecutive window layers' stacked as [4, 1169, ...]
+MIMO_CELL = dict(
+    model=dict(name="mimo-v2-flash", n_layers=7, vocab_size=19072, max_seq_len=8192, n_experts_held=16),
+    slots=128, pool_pages=16385, window_pool_pages=1169,
+)
+
+
+def lower_step(program, config, sharding):
+    """`paged_prefill` (one bucket: a chunk of 128) or `paged_decode_step`
+    (the Pallas kernel) of a cell, lowered for the described chip over shapes
+    alone: what `llm_service` would hold there, nothing allocated. Returns
+    (cfg, params, cache, lowered)."""
     import jax
     import jax.numpy as jnp
 
+    from modal_tpu.models import paged_kv
     from modal_tpu.models.llama import get_config, init_params
-    from modal_tpu.models.paged_kv import PagedKVCache, paged_prefill
+
+    if config == "mimo-v2-flash-serve-1chip-ep16":
+        cfg = get_config(MIMO_CELL["model"])
+        slots, pool_pages, window_pool_pages = MIMO_CELL["slots"], MIMO_CELL["pool_pages"], MIMO_CELL["window_pool_pages"]
+    else:
+        slots, pages_per_slot, n_kv, n_rep, pool_pages = CELL_SHAPES[config]
+        vocab, ffn = CELL_WIDTHS[config]
+        cfg = get_config(
+            "llama3-8b", vocab_size=vocab, n_layers=16, n_heads=n_kv * n_rep, n_kv_heads=n_kv, ffn_dim=ffn,
+            max_seq_len=pages_per_slot * PAGE,
+        )
+        window_pool_pages = None
+
+    def shaped(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    def described(make):
+        return jax.tree.map(lambda a: shaped(a.shape, a.dtype), jax.eval_shape(make))
+
+    params = described(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    cache = described(lambda: paged_kv.PagedKVCache.create(cfg, slots, pool_pages, PAGE, window_num_pages=window_pool_pages))
+    if program == "paged_prefill":
+        lowered = paged_kv.paged_prefill.lower(params, cfg, shaped((128,)), shaped(()), cache, shaped(()), shaped(()))
+    else:
+        lowered = paged_kv.paged_decode_step.lower(params, cfg, shaped((slots,)), cache, shaped((slots,), jnp.bool_), attn_impl="kernel")
+    return cfg, params, cache, lowered
+
+
+@pytest.mark.parametrize("config", sorted(CELL_SHAPES))
+def test_paged_prefill_compiles_for_v5e_with_nothing_of_the_span_s_size(config, one_chip, no_compile_cache):
+    import jax
 
     slots, pages_per_slot, n_kv, n_rep, pool_pages = CELL_SHAPES[config]
-    vocab, ffn = CELL_WIDTHS[config]
-    s_pad, page = 128, 16
+    vocab, _ffn = CELL_WIDTHS[config]
+    s_pad, page = 128, PAGE
     kv_span = pages_per_slot * page
-    cfg = get_config(
-        "llama3-8b", vocab_size=vocab, n_layers=16, n_heads=n_kv * n_rep, n_kv_heads=n_kv, ffn_dim=ffn, max_seq_len=kv_span
-    )
-
-    def described(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
-
-    params = described(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
-    cache = described(jax.eval_shape(lambda: PagedKVCache.create(cfg, slots, pool_pages, page)))
+    cfg, params, cache, lowered = lower_step("paged_prefill", config, one_chip)
     assert cache.page_table.shape == (slots, pages_per_slot)
-    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    tokens = jax.ShapeDtypeStruct((s_pad,), jnp.int32, sharding=one_chip)
-    text = paged_prefill.lower(params, cfg, tokens, scalar, cache, scalar, scalar).compile().as_text()
+    text = lowered.compile().as_text()
     # the name `prefill_chunk_ms` and the breakdown find the program by
     assert text.startswith("HloModule jit_paged_prefill,")
     # every array an instruction makes or a computation takes: `type[dims]`. The scores are float32
@@ -133,6 +168,65 @@ def test_paged_prefill_compiles_for_v5e_with_nothing_of_the_span_s_size(config, 
     assert len(pool_gathers) == 2 and all(pages * page <= 1024 < kv_span for pages in pool_gathers), pool_gathers
     # the loop over KV blocks is there, with a trip count that is data: a `while` inside the layers' `while`
     assert len(re.findall(r" while\(", text)) >= 2
+
+
+# what may have a result as large as a KV pool, or as one layer's share of one: the program's own
+# arguments and the loop's plumbing (no bytes move), and the scatter of the new rows, in place
+POOL_SIZED_MAY_BE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while", "scatter", "fusion"}
+CARRIED_POOL_CASES = [
+    (config, program) for config in sorted(CELL_SHAPES) for program in ("paged_decode_step", "paged_prefill")
+] + [("mimo-v2-flash-serve-1chip-ep16", "paged_decode_step")]
+
+
+@pytest.mark.parametrize("config,program", CARRIED_POOL_CASES)
+def test_the_jitted_steps_compile_for_v5e_with_no_pool_sized_copy(config, program, one_chip, no_compile_cache):
+    """The layer loop carries the KV pool and a layer's pages are addressed
+    in it (`paged_kv._run_layers`): the compiled step holds no copy, slice or
+    update-slice of a pool's or of one layer's pool's size, fused or not, only
+    the scatter of the new rows on the donated buffer; its temporaries are a
+    sliver of a pool and its outputs alias the pools. Scanned as inputs and
+    stacked as outputs, the pools cost four whole-pool operations a program
+    and a second pool of temporaries (PERF.md section 6, PR 31). The third
+    configuration has a group of four window layers among single ones."""
+    import jax
+
+    cfg, _params, cache, lowered = lower_step(program, config, one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    # (c) the names the benchmark's readers find the program and the kernel by
+    assert text.startswith(f"HloModule jit_{program},")
+    if program == "paged_decode_step":
+        calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+        names = {re.search(r"%([a-z_]+)[.\d]* = ", line).group(1) for line in calls}
+        assert names == ({"paged_decode_attention"} if cfg.uniform else {"paged_decode_attention_full", "paged_decode_attention_swa"}), names
+
+    # (a) every instruction, in fusions too: `%name = type[dims]{layout} opcode(`, or a tuple of such types
+    pools = jax.tree.leaves((cache.k_pages, cache.v_pages))
+    pool_sized = {math.prod(a.shape) for a in pools} | {math.prod(a.shape[1:]) for a in pools}
+    pool_bytes = sum(math.prod(a.shape) * a.dtype.itemsize for a in pools)
+    seen = set()
+    for line in text.splitlines():
+        found = re.match(r"\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([a-z\-]+)\(", line)
+        if not found:
+            continue
+        name, result, opcode = found.groups()
+        sizes = {math.prod(int(d) for d in dims.split(",")) for dims in re.findall(r"\w+\[([\d,]+)\]", result)}
+        if not sizes & pool_sized:
+            continue
+        seen.add(opcode)
+        assert opcode in POOL_SIZED_MAY_BE, f"{name}: a {opcode} as large as a KV pool or one layer of it: {line[:200]}"
+        if opcode == "fusion":  # the row scatter and nothing else: named by its scope, in place on its first operand
+            assert "kv_write/scatter" in line and '"aliasing_operands":{"lists":[{"indices":["0"' in line, line[:400]
+    assert {"parameter", "while", "scatter"} <= seen, seen  # the pools were found at all
+
+    # (b) no second pool among the temporaries, and the outputs are the donated pools
+    memory = compiled.memory_analysis()
+    one_pool = max(math.prod(a.shape) * a.dtype.itemsize for a in pools)
+    if cfg.uniform:
+        assert memory.temp_size_in_bytes < one_pool / 100, memory.temp_size_in_bytes
+    else:  # XLA still transposes this model's wq a layer (PERF.md section 7): 100 MB, a pool is 268 MB
+        assert memory.temp_size_in_bytes < 0.2e9, memory.temp_size_in_bytes
+    assert memory.alias_size_in_bytes >= pool_bytes, (memory.alias_size_in_bytes, pool_bytes)
 
 
 # (KV heads, query heads a KV head, pool pages, window, sink, name): the two decode kernels of the

@@ -235,6 +235,115 @@ def test_paged_prefill_logits_match_the_span_wide_path(n_rep, chunk):
     np.testing.assert_allclose(logits, np.asarray(wide[0, length - 1]), atol=3e-2, rtol=0)
 
 
+# (program's config overrides, the program after the prefill, the decode step's attention):
+# the layer loop carries a group's pool whole and shifts a layer's page ids by `layer * P`
+CARRIED_POOL_CASES = {
+    "uniform-two-layers": ({}, "decode", "gather"),
+    "uniform-two-layers-kernel": ({}, "decode", "kernel_interpret"),
+    # full, a group of THREE window layers, full: a window wider than anything written sees what
+    # full attention sees, so the dense forward of five like layers is the reference
+    "two-kinds-a-group-of-three": (dict(n_layers=5, attn_pattern=(0, 1, 1, 1, 0), window=64), "decode", "gather"),
+    "two-kinds-a-group-of-three-kernel": (dict(n_layers=5, attn_pattern=(0, 1, 1, 1, 0), window=64), "decode", "kernel_interpret"),
+    "verify-step": ({}, "verify", "gather"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CARRIED_POOL_CASES))
+def test_the_carried_pool_takes_layer_l_s_rows_in_layer_l_s_pages_and_nowhere_else(case):
+    """`paged_prefill` (two chunks), then `paged_decode_step` or
+    `paged_verify_step`: after each program every row of every layer's pool
+    is bit-equal to before except the slot's written positions and the
+    layer's own scratch row, the written rows are the DENSE cache's rows of
+    the same layer (a wrong `layer * P` shift passes a logits test on one
+    layer and corrupts a deep model), and the logits are the dense ones."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from modal_tpu.models.llama import KVCache, get_config, init_params
+    from modal_tpu.models.paged_kv import (
+        PagedKVCache, assign_pages, assign_window_pages, paged_decode_step, paged_prefill, paged_verify_step,
+    )
+    from modal_tpu.models.sampling import decode_step, prefill
+
+    overrides, then, impl = CARRIED_POOL_CASES[case]
+    cfg = get_config("tiny", **overrides)
+    params = init_params(cfg, jax.random.PRNGKey(5))
+    dense_cfg, dense_params = cfg, params
+    if not cfg.uniform:  # the same weights as ONE stack of like layers
+        dense_cfg = dataclasses.replace(cfg, attn_pattern=(), window=0)
+        dense_params = {**params, "layers": jax.tree.map(lambda *a: jnp.concatenate(a), *params["layers"])}
+    slot, n_prompt, n_new = 1, 21, 3 if then == "verify" else 1
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (n_prompt + n_new,), 0, cfg.vocab_size).astype(jnp.int32)
+
+    dense = KVCache.create(dense_cfg, 1, PAGES_PER_SLOT * PAGE)
+    dlogits, dense = prefill(dense_params, dense_cfg, tokens[None, :n_prompt], dense)
+    want = [np.asarray(dlogits[0])]
+    for j in range(n_new):
+        step_logits, dense = decode_step(dense_params, dense_cfg, tokens[None, n_prompt + j : n_prompt + j + 1], dense)
+        want.append(np.asarray(step_logits[0]))
+    dense_k, dense_v = np.asarray(dense.k, np.float32)[:, 0], np.asarray(dense.v, np.float32)[:, 0]  # [layers, pos, n_kv, hd]
+    assert np.abs(dense_k[0, :n_prompt] - dense_k[1, :n_prompt]).max() > 0.1  # layers are told apart by their rows
+
+    # every pool starts as noise, so a row written where it should not be shows; the slot's rows
+    # in the two tables name different pages, out of order
+    cache = PagedKVCache.create(cfg, SLOTS, PAGES, PAGE, PAGES_PER_SLOT, window_num_pages=None if cfg.uniform else 12)
+    noise = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+    cache = cache._replace(
+        k_pages=jax.tree.map(lambda a: jax.random.normal(next(noise), a.shape, a.dtype), cache.k_pages),
+        v_pages=jax.tree.map(lambda a: jax.random.normal(next(noise), a.shape, a.dtype), cache.v_pages),
+    )
+    rows = {False: [7, 3], True: [9, 4]}  # by "is a window layer": page of positions 0-15, of 16-31
+    cache = assign_pages(cache, slot, 0, jnp.asarray(rows[False], jnp.int32))
+    if not cfg.uniform:
+        cache = assign_window_pages(cache, jnp.asarray([slot, slot]), jnp.asarray([0, 1]), jnp.asarray(rows[True]))
+
+    def pools(c):
+        """[(first layer, is a window group, K [n, P, page, n_kv, hd], V)] as float32 on the host"""
+        ks, vs = (c.k_pages,), (c.v_pages,)
+        if not cfg.uniform:
+            ks, vs = c.k_pages, c.v_pages
+        return [
+            (first, bool(kind.window), np.asarray(k, np.float32), np.asarray(v, np.float32))
+            for (kind, first, _n), k, v in zip(cfg.layer_groups, ks, vs)
+        ]
+
+    def check_written(before, after, positions):
+        for (first, windowed, k0, v0), (_f, _w, k1, v1) in zip(before, after):
+            untouched = np.ones(k0.shape[1:3], bool)
+            untouched[0, 0] = False  # the layer's scratch row: padded positions, idle slots
+            for pos in positions:
+                untouched[rows[windowed][pos // PAGE], pos % PAGE] = False
+            for j in range(k0.shape[0]):
+                assert np.array_equal(k1[j][untouched], k0[j][untouched]) and np.array_equal(v1[j][untouched], v0[j][untouched]), (first + j, "a row outside the slot's written positions changed")
+                for pos in positions:
+                    page, offset = rows[windowed][pos // PAGE], pos % PAGE
+                    np.testing.assert_allclose(k1[j, page, offset], dense_k[first + j, pos], atol=3e-2, rtol=0)
+                    np.testing.assert_allclose(v1[j, page, offset], dense_v[first + j, pos], atol=3e-2, rtol=0)
+
+    before = pools(cache)
+    for start, length in ((0, 16), (16, n_prompt - 16)):
+        chunk = jnp.zeros((16,), jnp.int32).at[:length].set(tokens[start : start + length])
+        logits, _tok, cache = paged_prefill(params, cfg, chunk, jnp.int32(length), cache, jnp.int32(slot), jnp.int32(start))
+        after = pools(cache)
+        check_written(before, after, range(start, start + length))
+        before = after
+    got = [np.asarray(logits)]
+    active = jnp.zeros((SLOTS,), bool).at[slot].set(True)
+    if then == "verify":
+        fed = jnp.zeros((SLOTS, n_new), jnp.int32).at[slot].set(tokens[n_prompt:])
+        step_logits, cache = paged_verify_step(params, cfg, fed, cache, active)
+        got.extend(np.asarray(step_logits[slot]))
+    else:
+        fed = jnp.zeros((SLOTS,), jnp.int32).at[slot].set(tokens[n_prompt])
+        step_logits, _next, cache = paged_decode_step(params, cfg, fed, cache, active, impl)
+        got.append(np.asarray(step_logits[slot]))
+    check_written(before, pools(cache), range(n_prompt, n_prompt + n_new))
+    np.testing.assert_allclose(np.stack(got), np.stack(want), atol=3e-2, rtol=0)
+
+
 def test_total_kv_bytes_bounded_by_pool_not_requests(tiny_model):
     """The acceptance inequality: engine KV bytes are the POOL's, and the
     pool is smaller than dense per-request max_len caches for the same
