@@ -277,6 +277,7 @@ def parity_in_container(model, seed: int) -> dict:
     from modal_tpu.models import paged_kv as pk
     from modal_tpu.models.llama import get_config, init_params
     from modal_tpu.models.sampling import host_sync
+    from modal_tpu.serving.pages import PageAllocator
 
     devices = jax.devices()
     on_tpu = devices[0].platform == "tpu"
@@ -288,7 +289,7 @@ def parity_in_container(model, seed: int) -> dict:
     pps = _math.ceil(cfg.max_seq_len / page)
     num_pages = 1 + max(2 * slots, (slots * pps) // 2)
     cache = pk.PagedKVCache.create(cfg, slots, num_pages, page, pps)
-    alloc = pk.PageAllocator(num_pages, page)
+    alloc = PageAllocator(num_pages, page)
     rng = np.random.default_rng(seed)
     # four of eight slots live, lengths that end mid-page, on a page boundary
     # and after several prefill chunks; the rest stay inactive (scratch page)

@@ -196,15 +196,10 @@ KNOB_CATALOG: dict[str, Knob] = dict(
            "per-request serving timeline spans (queue/prefill/decode/stream)", gate=True),
         _k("MODAL_TPU_SERVING_SPAN_TOKENS", "int", "8", "docs/SERVING.md",
            "decode-span granularity (tokens per span mark)"),
-        _k("MODAL_TPU_PAGED_KERNEL", "enum(auto|1|interpret|0)", "auto", "docs/SERVING.md",
-           "Pallas paged-attention kernel selection; 0/off forces the gather path", gate=True),
         _k("MODAL_TPU_SERVING_ROUTER", "bool", "1", "docs/SERVING.md",
            "prefix-aware fleet routing; off → seeded-random replica choice", gate=True),
         _k("MODAL_TPU_SERVING_ROLE", "enum(both|prefill|decode)", "both", "docs/SERVING.md",
            "disaggregation role of this replica (prefill exports KV pages, decode imports)"),
-        _k("MODAL_TPU_SPEC_OVERLAP", "bool", "1", "docs/SERVING.md",
-           "overlap draft-propose with in-flight target verify across slot groups; "
-           "off → PR 11 sequential spec rounds", gate=True),
         # -- cold start (docs/COLDSTART.md) ---------------------------------
         _k("MODAL_TPU_WARM_POOL", "int", "0", "docs/COLDSTART.md",
            "baseline pre-forked parked interpreters per worker (config.py 'warm_pool')"),

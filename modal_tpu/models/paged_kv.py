@@ -11,10 +11,11 @@ allocating (and compiling for) a new cache. Here the pool is allocated ONCE:
   `num_pages × page_size`, never by `num_requests × max_len`.
 - **page table**: `[slots, pages_per_slot]` int32 — slot s's token position p
   lives in page `page_table[s, p // page_size]` at offset `p % page_size`.
-- **block allocator** (`PageAllocator`, host-side): a free list handing out
-  pages one at a time as sequences grow. Fragmentation is structural-zero:
+- **block allocator** (`serving/pages.py`, host-side): a free list handing
+  out pages one at a time as sequences grow. Fragmentation is structural-zero:
   any free page serves any slot (no contiguity requirement), so alloc/free
-  churn from heterogeneous lengths can't strand capacity.
+  churn from heterogeneous lengths can't strand capacity. This module is the
+  device side alone: the pools, the tables, their jitted updates, the steps.
 
 Page 0 is reserved as a **scratch page**: inactive slots' writes are routed
 there, which keeps `paged_decode_step` a single fixed-shape executable (the
@@ -45,94 +46,6 @@ DEFAULT_PAGE_SIZE = 16
 # positions of a slot's page row that a prefill chunk's attention visits in one
 # turn of its loop (`_prefill_attention`); a constant of the code, not an option
 PREFILL_KV_BLOCK = 512
-
-
-class PagePoolExhausted(Exception):
-    """The shared page pool has no free pages (caller should preempt or
-    queue — never a crash; docs/SERVING.md degradation matrix)."""
-
-
-class PageAllocator:
-    """Host-side free-list block allocator over the page pool, with
-    per-page refcounts for shared-prefix reuse (ISSUE 12).
-
-    Pages are interchangeable (the page table adds the indirection), so this
-    is exact-fit by construction: `can_alloc(n)` ⇔ `len(free) >= n`, no
-    matter how fragmented the alloc/free history was. Page 0 is reserved as
-    the scratch page and never handed out.
-
-    Refcounts make one physical page serveable to many readers: `alloc`
-    hands a page out at refcount 1, `share` adds a holder, `free` drops one
-    holder and only returns the page to the free list when the last holder
-    lets go. A page with refcount > 1 is copy-on-write for whoever wants to
-    mutate it (`shared()` is the engine's write-barrier predicate)."""
-
-    def __init__(self, num_pages: int, page_size: int = DEFAULT_PAGE_SIZE):
-        if num_pages < 2:
-            raise ValueError("need >= 2 pages (page 0 is the reserved scratch page)")
-        self.num_pages = num_pages
-        self.page_size = page_size
-        self._free: list[int] = list(range(num_pages - 1, 0, -1))  # pop() yields 1, 2, ...
-        self._refs: dict[int, int] = {}  # page -> live holder count
-        self.high_water = 0
-
-    @property
-    def free_pages(self) -> int:
-        return len(self._free)
-
-    @property
-    def allocated_pages(self) -> int:
-        return (self.num_pages - 1) - len(self._free)
-
-    def pages_for(self, num_tokens: int) -> int:
-        return max(1, math.ceil(num_tokens / self.page_size))
-
-    def can_alloc(self, n: int) -> bool:
-        return len(self._free) >= n
-
-    def alloc(self, n: int) -> list[int]:
-        if n > len(self._free):
-            raise PagePoolExhausted(
-                f"need {n} pages, {len(self._free)} free (pool {self.num_pages - 1})"
-            )
-        pages = [self._free.pop() for _ in range(n)]
-        for p in pages:
-            self._refs[p] = 1
-        self.high_water = max(self.high_water, self.allocated_pages)
-        return pages
-
-    def share(self, pages: list[int]) -> None:
-        """Add one holder to each page (prefix-cache entries and follower
-        slots each count as a holder)."""
-        for p in pages:
-            if self._refs.get(p, 0) <= 0:
-                raise ValueError(f"share of unallocated page {p}")
-            self._refs[p] += 1
-
-    def refcount(self, page: int) -> int:
-        return self._refs.get(page, 0)
-
-    def shared(self, page: int) -> bool:
-        """True when more than one holder references the page — any write
-        must copy first (the CoW barrier)."""
-        return self._refs.get(page, 0) > 1
-
-    def free(self, pages: list[int]) -> None:
-        """Drop one holder per page; the page returns to the free list only
-        at refcount zero. Double frees (more drops than holders) still fail
-        loudly — the refcount IS the detector."""
-        if len(set(pages)) != len(pages):
-            raise ValueError(f"double free within one batch: {pages}")
-        for p in pages:
-            if not 0 < p < self.num_pages:
-                raise ValueError(f"page {p} out of range")
-            if self._refs.get(p, 0) <= 0:
-                raise ValueError(f"double free of page {p}")
-        for p in pages:
-            self._refs[p] -= 1
-            if self._refs[p] == 0:
-                del self._refs[p]
-                self._free.append(p)
 
 
 def k_cache_dim(cfg: LlamaConfig) -> int:
@@ -830,190 +743,15 @@ def paged_verify_step(
     return logits, cache
 
 
-# -- shared-prefix KV reuse (ISSUE 12) ----------------------------------------
-
-
-class PrefixCacheEntry:
-    """One cached prefix: the exact token prefix and the pages holding its
-    KV. The entry is a page holder (allocator refcount), so its pages stay
-    live after the inserting request completes — that is the whole point:
-    a fleet-wide system prompt prefilled once keeps serving followers."""
-
-    __slots__ = ("tokens", "pages", "last_used", "hits")
-
-    def __init__(self, tokens: tuple, pages: list[int]):
-        self.tokens = tokens
-        self.pages = pages
-        self.last_used = 0.0
-        self.hits = 0
-
-
-class PrefixCache:
-    """Content-keyed prefix → KV-pages lookup over the shared pool.
-
-    Keys are page-granular: an entry for prompt T is indexed under every
-    full-page prefix `T[:j*page]`, so a follower whose prompt extends T (the
-    system-prompt fleet case) finds the longest full-page match in
-    O(pages-in-prompt) dict probes. A hit can extend token-granular into the
-    entry's next, partially-matching page — that page is then refcount-shared
-    and the follower's first write into it triggers copy-on-write
-    (`copy_page`), never a mutation of cached bytes.
-
-    The cache is a holder like any slot: `lookup` refs pages for the caller,
-    `insert` refs them for the entry, `evict_lru`/`clear` un-ref. Pool
-    pressure evicts entries before the engine resorts to preempting live
-    requests (serving/engine.py)."""
-
-    def __init__(self, allocator: PageAllocator):
-        self.allocator = allocator
-        self.page_size = allocator.page_size
-        self._entries: dict[tuple, PrefixCacheEntry] = {}  # full-token key -> entry
-        self._index: dict[tuple, PrefixCacheEntry] = {}  # page-granular prefix -> entry
-        self._clock = 0.0
-        self.hits = 0
-        self.misses = 0
-
-    def __len__(self) -> int:
-        return len(self._entries)
-
-    @property
-    def held_pages(self) -> int:
-        return sum(len(e.pages) for e in self._entries.values())
-
-    def _tick(self) -> float:
-        self._clock += 1.0
-        return self._clock
-
-    def lookup(
-        self, tokens: list, allow_partial: bool = True
-    ) -> Optional[tuple[list[int], int, "PrefixCacheEntry"]]:
-        """Longest cached prefix of `tokens` covering at most len(tokens)-1
-        positions (the suffix must still prefill to produce last-token
-        logits). Returns (pages, covered_tokens, entry) with one holder ref
-        taken on every returned page — the caller owns the release — or
-        None. `covered` may end mid-page; that last page arrives
-        refcount-shared and must be CoW'd before the caller writes into it.
-
-        `allow_partial=False` stops coverage at the full-page boundary: the
-        caller then never writes into a shared page at all, so no CoW
-        machinery is needed on its pool. This is the draft-pool mode (ISSUE
-        18): the draft mirror has no `_cow_range`, so it may only share
-        pages it will never touch.
-
-        Deliberately side-effect-free beyond the refs: hit/miss counters and
-        the entry's LRU clock move at `commit_use`/`note_miss` — a dry-pool
-        admission retried every loop iteration must not inflate hit stats or
-        keep the contested entry artificially hot against eviction."""
-        page = self.page_size
-        max_cover = len(tokens) - 1
-        for j in range(max_cover // page, 0, -1):
-            entry = self._index.get(tuple(tokens[: j * page]))
-            if entry is None:
-                continue
-            covered = j * page
-            pages = list(entry.pages[:j])
-            # token-granular extension into the entry's next (partial) page
-            if allow_partial and len(entry.tokens) > covered and len(entry.pages) > j:
-                limit = min(page, len(entry.tokens) - covered, max_cover - covered)
-                extra = 0
-                while extra < limit and entry.tokens[covered + extra] == tokens[covered + extra]:
-                    extra += 1
-                if extra > 0:
-                    pages.append(entry.pages[j])
-                    covered += extra
-            self.allocator.share(pages)
-            return pages, covered, entry
-        return None
-
-    def commit_use(self, entry: "PrefixCacheEntry") -> None:
-        """Count a real reuse (the admission actually went through) and
-        refresh the entry's LRU position."""
-        entry.last_used = self._tick()
-        entry.hits += 1
-        self.hits += 1
-
-    def note_miss(self) -> None:
-        self.misses += 1
-
-    def insert(self, tokens: list, pages: list[int], full_pages_only: bool = False) -> bool:
-        """Cache `tokens`' prefix KV. `pages` is the holding slot's page list
-        (only the prompt-covering prefix is taken); the entry refs them, so
-        they outlive the slot. Needs at least one full page to be indexable.
-        Returns True if a new entry was created.
-
-        `full_pages_only=True` publishes only the full-page prompt prefix
-        (the partial last page stays private to the slot) — paired with
-        `lookup(allow_partial=False)` for pools without CoW support: a
-        shared page is then guaranteed write-free on both sides."""
-        page = self.page_size
-        full = len(tokens) // page
-        if full < 1:
-            return False
-        if full_pages_only:
-            tokens = list(tokens[: full * page])
-        key = tuple(tokens)
-        if key in self._entries:
-            return False
-        n_pages = math.ceil(len(tokens) / page)
-        if n_pages > len(pages):
-            return False  # caller's pages don't cover the prompt (shouldn't happen)
-        entry = PrefixCacheEntry(key, list(pages[:n_pages]))
-        self.allocator.share(entry.pages)
-        entry.last_used = self._tick()
-        self._entries[key] = entry
-        for j in range(1, full + 1):
-            # first inserter wins a contested page-prefix key: stable, and
-            # the loser's entry still serves its own exact-match lookups
-            self._index.setdefault(tuple(tokens[: j * page]), entry)
-        return True
-
-    def _drop(self, entry: PrefixCacheEntry) -> None:
-        self._entries.pop(entry.tokens, None)
-        for k in [k for k, e in self._index.items() if e is entry]:
-            del self._index[k]
-        self.allocator.free(entry.pages)
-
-    def evict_lru(self) -> int:
-        """Evict the least-recently-used entry; returns how many of its
-        pages this released (pages still shared with live slots stay
-        allocated — eviction drops the cache's ref, never a reader's)."""
-        if not self._entries:
-            return 0
-        entry = min(self._entries.values(), key=lambda e: e.last_used)
-        released = sum(1 for p in entry.pages if self.allocator.refcount(p) == 1)
-        self._drop(entry)
-        return released
-
-    def clear(self) -> None:
-        for entry in list(self._entries.values()):
-            self._drop(entry)
-
-
-# -- Pallas kernel selection (MODAL_TPU_PAGED_KERNEL; ops/paged_attention.py) --
-
-PAGED_KERNEL_ENV = "MODAL_TPU_PAGED_KERNEL"
+# -- which decode attention a process runs (ops/paged_attention.py) ------------
 
 
 def resolve_attn_impl() -> str:
-    """Map the env knob to a static attn_impl for `paged_decode_step`:
-
-    - auto (default): the Pallas page-streaming kernel on real TPU, the
-      gather path everywhere else (CPU CI keeps the proven einsum path hot);
-    - 1/on/kernel: force the kernel — interpret-mode off-TPU (parity runs);
-    - interpret: force interpret-mode even on TPU (kernel debugging);
-    - 0/off/gather: force the gather path (the degradation knob)."""
-    import os
-
-    val = os.environ.get(PAGED_KERNEL_ENV, "auto").strip().lower()
-    if val in ("0", "off", "false", "no", "gather"):
-        return "gather"
-    if val == "interpret":
-        return "kernel_interpret"
-    on_tpu = jax.default_backend() == "tpu"
-    if val in ("1", "on", "true", "yes", "kernel"):
-        return "kernel" if on_tpu else "kernel_interpret"
-    # auto
-    return "kernel" if on_tpu else "gather"
+    """The static `attn_impl` of `paged_decode_step` that an engine runs: the
+    Pallas page-streaming kernel on a TPU, the gather path everywhere else.
+    `kernel_interpret` (the kernel's body run by the interpreter, for parity
+    runs off the chip) is a value only a caller of the step passes."""
+    return "kernel" if jax.default_backend() == "tpu" else "gather"
 
 
 # prompt-length buckets: one prefill executable per bucket serves every

@@ -636,12 +636,13 @@ SPAN_CATALOG: dict[str, str] = {
 ENGINE_PHASES: dict[str, tuple[str, str]] = {
     "wait_work": ("_run blocked on the condition: no request waiting, no slot live", "wait"),
     "admit": (
-        "_admit from the moment a waiting request has a free slot: prefix lookup, page allocation, "
-        "assign_pages, the serving.admit span, a shipment's import (request_id)",
+        "_admit from the moment a waiting request has a free slot: the page manager's lookup, can_admit and "
+        "admit (assign_pages), the serving.admit span, a shipment's import (request_id)",
         "host",
     ),
     "prefill_prep": (
-        "_prefill_one up to the launch: _cow_range, the padded array, the scalars "
+        "_prefill_one up to the launch: the page manager's reserve (copy-on-write, the window pool's pages), "
+        "the padded array, the scalars "
         "(request_id, chunk_tokens, offset, bucket; draft=1 for the draft pool's chunk)",
         "host",
     ),
@@ -651,7 +652,8 @@ ENGINE_PHASES: dict[str, tuple[str, str]] = {
     ),
     "prefill_sync": ("int(next_tok) (and sample_step) when the chunk completes the prompt (request_id)", "sync"),
     "decode_prep": (
-        "_grow_pages, the slot scan, the tokens/active arrays; in a speculative round also a group's "
+        "_reserve_decode (the page manager's reserve: grown pages, copy-on-write, the window pool's turn-over), "
+        "the slot scan, the tokens/active arrays; in a speculative round also a group's "
         "sampling arrays",
         "host",
     ),

@@ -33,9 +33,9 @@ TPU, where it matches the gather path (tests/test_ops.py TPU-gated test;
 Who calls it, and over what: only `paged_decode_step`, through
 `paged_kv._paged_attention`, where its static `attn_impl` says "kernel" or
 "kernel_interpret" (the serving engine asks `paged_kv.resolve_attn_impl()`
-once at construction: the kernel on a TPU, the gather path elsewhere, and
-`MODAL_TPU_PAGED_KERNEL` overrides either way); a prefill chunk and the
-verify step never run it. The layer loop hands it a layer GROUP's pool whole,
+once at construction: the kernel on a TPU, the gather path elsewhere); a
+prefill chunk and the verify step never run it. The layer loop hands it a
+layer GROUP's pool whole,
 `[layers * P, page, n_kv, hd]`, and a page table whose ids are already
 shifted to the layer's place in it (`paged_kv._run_layers`): to this module
 that is a pool and a table like any other, so nothing here knows of layers

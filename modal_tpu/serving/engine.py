@@ -59,6 +59,7 @@ from ..observability.catalog import (
     SERVING_TTFT,
     SERVING_TTFT_P95,
 )
+from .pages import ModelPages
 
 _req_counter = itertools.count()
 _replica_id_cache: dict = {}
@@ -97,7 +98,6 @@ CHAOS_STEP_DELAY_ENV = "MODAL_TPU_CHAOS_SERVING_STEP_DELAY_S"
 SAMPLING_ENV = "MODAL_TPU_SERVING_SAMPLING"  # 0 → greedy-only engine
 PREFIX_CACHE_ENV = "MODAL_TPU_SERVING_PREFIX_CACHE"  # 0 → no shared-prefix reuse
 SPEC_ENV = "MODAL_TPU_SERVING_SPEC"  # 0 → ignore any configured draft model
-# (the Pallas kernel knob MODAL_TPU_PAGED_KERNEL lives in models/paged_kv.py)
 
 # ISSUE 18 fleet knobs (docs/SERVING.md degradation matrix):
 # - role: what this replica does in a disaggregated fleet. "prefill" replicas
@@ -107,11 +107,6 @@ SPEC_ENV = "MODAL_TPU_SERVING_SPEC"  # 0 → ignore any configured draft model
 #   the router/autoscaler, so a mis-set role degrades to slower routing, not
 #   to refused requests.
 ROLE_ENV = "MODAL_TPU_SERVING_ROLE"  # prefill | decode | both (unset → both)
-# - overlap: run draft-propose for one half of the decode batch while the
-#   other half's target verify is still in flight. 0 → the PR 11 sequential
-#   round (byte-identical token streams either way; this is dispatch
-#   pipelining, not an algorithm change).
-SPEC_OVERLAP_ENV = "MODAL_TPU_SPEC_OVERLAP"
 # chaos (ISSUE 18): drop the next N inbound KV-page shipments at the decode
 # boundary — exactly what a prefill replica dying mid-ship looks like. The
 # decode side must fall back to a full local prefill with zero token loss.
@@ -152,7 +147,6 @@ def resolve_role() -> str:
 # the serving_role gauge encodes the role as a number (gauges carry floats
 # over the heartbeat); history._replica_rows maps it back for `modal_tpu top`
 ROLE_GAUGE_VALUES = {"both": 0, "prefill": 1, "decode": 2}
-ROLE_GAUGE_NAMES = {v: k for k, v in ROLE_GAUGE_VALUES.items()}
 
 
 def _env_on(name: str, default: str = "1") -> bool:
@@ -174,9 +168,10 @@ class EngineStopped(RuntimeError):
     pass
 
 
-def _refuse_unservable(cfg: Any, params: dict, speculative: bool, role: str, prefix_cache: Optional[bool]) -> None:
+def _refuse_unservable(cfg: Any, params: dict, speculative: bool, role: str) -> None:
     """What the paged path cannot run yet, refused at construction with the
-    mechanism in the message (never by a model's name)."""
+    mechanism in the message (never by a model's name). What a model's pools
+    cannot hold, the page manager refuses (serving/pages.py)."""
     import jax
 
     if getattr(cfg, "is_moe", False):
@@ -196,13 +191,8 @@ def _refuse_unservable(cfg: Any, params: dict, speculative: bool, role: str, pre
         )
     if role in ("prefill", "decode"):
         raise ValueError(
-            f"role={role!r} ships KV pages between replicas (export_pages / import_pages), which address ONE "
-            "pool; this model keeps a pool a layer kind and a shipment over two pools does not exist yet"
-        )
-    if cfg.has_window and prefix_cache:
-        raise ValueError(
-            "prefix_cache=True with window layers: a prefix hit hands over the full-attention pages only, and "
-            "a window layer needs its last `window` positions too, which the pool gave back behind the window"
+            f"role={role!r} ships KV pages between replicas, and a shipment addresses ONE pool; this model "
+            "keeps a pool a layer kind and a shipment over two pools does not exist yet"
         )
     if cfg.has_experts and any(leaf.dtype == "int8" for leaf in jax.tree_util.tree_leaves(params)):
         raise ValueError(
@@ -400,14 +390,8 @@ class GenRequest:
 
 @dataclass
 class _Slot:
+    # what the slot holds of the KV pools is the page manager's, by slot index
     request: GenRequest
-    pages: list[int] = field(default_factory=list)
-    # the window layers' pool (a model with window attention): the live pages,
-    # consecutive in the slot's row from index window_first on; pages behind
-    # the window have gone back to the pool
-    window_pages: list[int] = field(default_factory=list)
-    window_first: int = 0
-    draft_pages: list[int] = field(default_factory=list)  # speculative: draft pool mirror
     pos: int = 0  # tokens written to the slot's pages (mirrors seq_lens)
     prefill_tokens: list[int] = field(default_factory=list)  # prompt (+ regenerated prefix)
     prefill_done: int = 0  # tokens of prefill_tokens already written (target pool)
@@ -448,37 +432,13 @@ class ServingEngine:
     ):
         import math
 
-        from ..models.paged_kv import (
-            DEFAULT_PAGE_SIZE,
-            PageAllocator,
-            PagedKVCache,
-            PrefixCache,
-            default_window_num_pages,
-            pool_bytes_by_kind,
-            resolve_attn_impl,
-            window_pages_per_slot,
-        )
+        from ..models.paged_kv import DEFAULT_PAGE_SIZE, resolve_attn_impl
 
         page_size = page_size or DEFAULT_PAGE_SIZE
         pages_per_slot = pages_per_slot or math.ceil(cfg.max_seq_len / page_size)
         role = role if role in ("prefill", "decode", "both") else resolve_role()
         speculative = draft is not None and _env_on(SPEC_ENV)
-        _refuse_unservable(cfg, params, speculative, role, prefix_cache)
-        self.window = cfg.window if cfg.has_window else 0
-        if self.window:
-            prefix_cache = False  # a hit would need the window layers' last positions too
-            if window_num_pages is None:
-                window_num_pages = default_window_num_pages(cfg, max_slots, page_size, prefill_chunk)
-            if window_num_pages - 1 < math.ceil((prefill_chunk + self.window - 1) / page_size) + 1:
-                raise ValueError(
-                    f"window_num_pages={window_num_pages} cannot hold one prefill chunk of {prefill_chunk} "
-                    f"tokens and the window of {self.window} before it"
-                )
-            self.window_slot_pages = window_pages_per_slot(self.window, page_size)
-            self.window_allocator = PageAllocator(window_num_pages, page_size)
-            # one fixed length for every assign_window_pages call (one executable)
-            self._window_assign_len = max(max_slots, math.ceil(prefill_chunk / page_size) + 1)
-        self.window_pages_released = 0
+        _refuse_unservable(cfg, params, speculative, role)
         if num_pages is None:
             # default pool: half of what dense per-slot max_len caches would
             # take — the whole point is sharing
@@ -491,9 +451,17 @@ class ServingEngine:
         self.prefill_chunk = prefill_chunk
         self.max_context = pages_per_slot * page_size
         self.max_waiting = max_waiting
-        self.allocator = PageAllocator(num_pages, page_size)
-        self.cache = PagedKVCache.create(cfg, max_slots, num_pages, page_size, pages_per_slot, window_num_pages)
-        self.kv_pool_bytes, self.kv_window_pool_bytes = pool_bytes_by_kind(cfg, self.cache)
+        # shared-prefix KV reuse: content-keyed lookup + CoW pages; off where a
+        # window layer would need its last positions from a hit too
+        if prefix_cache is None:
+            prefix_cache = _env_on(PREFIX_CACHE_ENV) and not cfg.has_window
+        geometry = dict(
+            max_slots=max_slots, num_pages=num_pages, page_size=page_size,
+            pages_per_slot=pages_per_slot, prefill_chunk=prefill_chunk, prefix_cache=bool(prefix_cache),
+        )
+        # the page manager of the model served (serving/pages.py): its device
+        # cache goes into a step as `self.pages.cache` and the step's comes back
+        self.pages = ModelPages(cfg, window_num_pages=window_num_pages, **geometry)
         # routed experts: pairs routed and expert-layer calls are counted here,
         # from what the loop launches; the pairs the held experts computed come
         # back from the device with a step's tokens (cache.moe_pairs)
@@ -518,12 +486,13 @@ class ServingEngine:
             "visible_chips": os.environ.get("TPU_VISIBLE_DEVICES", ""),
         }
         # ISSUE 12 capability knobs, each individually degradable -----------
-        self.attn_impl = resolve_attn_impl()  # "gather" | "kernel" | "kernel_interpret"
+        self.attn_impl = resolve_attn_impl()  # "kernel" on a TPU, "gather" elsewhere
         self.sampling_enabled = _env_on(SAMPLING_ENV)
         # speculative decoding: a small-config draft proposes spec_k tokens,
         # the target verifies them in ONE multi-token step
         self.draft_params: Optional[dict] = None
         self.draft_cfg: Optional[Any] = None
+        self.draft_pages: Optional[ModelPages] = None
         self.spec_k = 0
         if speculative:
             draft_params, draft_cfg = draft
@@ -531,32 +500,20 @@ class ServingEngine:
                 raise ValueError(
                     f"draft vocab ({draft_cfg.vocab_size}) != target vocab ({cfg.vocab_size})"
                 )
-            _refuse_unservable(draft_cfg, draft_params, True, "both", None)
+            _refuse_unservable(draft_cfg, draft_params, True, "both")
             self.draft_params = draft_params
             self.draft_cfg = draft_cfg
             self.spec_k = max(1, int(spec_k))
-            # the draft mirrors the target's slot/page geometry 1:1 (same
-            # allocator arithmetic ⇒ the pools can never disagree on fit)
-            self.draft_allocator = PageAllocator(num_pages, page_size)
-            self.draft_cache = PagedKVCache.create(
-                draft_cfg, max_slots, num_pages, page_size, pages_per_slot
-            )
-        # shared-prefix KV reuse: content-keyed lookup + CoW pages. ISSUE 18
-        # lifts the old spec-mode exclusion: the draft pool now runs its OWN
-        # prefix cache in full-page-only mode (no partial-page sharing ⇒ no
-        # CoW machinery needed on a pool that has none), so a prefix-skipping
-        # target prefill can no longer desync from the draft.
-        want_prefix = _env_on(PREFIX_CACHE_ENV) if prefix_cache is None else bool(prefix_cache)
-        self.prefix_cache: Optional[PrefixCache] = (
-            PrefixCache(self.allocator) if want_prefix else None
-        )
-        self.draft_prefix_cache: Optional[PrefixCache] = (
-            PrefixCache(self.draft_allocator) if (want_prefix and self.spec_k) else None
-        )
-        # ISSUE 18 fleet mode: advertised role + overlapped spec rounds
+            # the draft mirrors the target's slot/page geometry 1:1, with a
+            # prefix cache of its OWN over full pages only (ISSUE 18): nothing
+            # shared is ever written there, so a prefix-skipping target
+            # prefill cannot desync from the draft and the draft copies nothing
+            self.draft_pages = ModelPages(draft_cfg, partial_pages=False, **geometry)
+        # every model's pages, the target's first: what both do, the loop does over these
+        self._models = [self.pages] + ([self.draft_pages] if self.draft_pages is not None else [])
+        # ISSUE 18 fleet mode: the advertised role
         self.role = role
         SERVING_ROLE.set(float(ROLE_GAUGE_VALUES[self.role]))
-        self.spec_overlap = _env_on(SPEC_OVERLAP_ENV)
         self.kv_pages_shipped = 0
         self.kv_ship_drops = 0
         self.remote_prefills = 0
@@ -569,7 +526,6 @@ class ServingEngine:
         self.sampled_tokens = 0
         self.requests_completed = 0
         self.preemptions = 0
-        self.cow_copies = 0
         # what the loop did, for /v1/stats (ISSUE 26): prompt tokens are the
         # target model's chunks (a prefix-cache hit is not in them, a
         # re-prefill after a preemption is)
@@ -628,11 +584,9 @@ class ServingEngine:
             SERVING_REQUESTS.inc(outcome="stopped")
         # release the prefix caches' page holds (their entries are the one
         # thing that outlives completed requests by design)
-        if self.prefix_cache is not None:
-            self.prefix_cache.clear()
-            self._sync_page_gauges()
-        if self.draft_prefix_cache is not None:
-            self.draft_prefix_cache.clear()
+        for model in self._models:
+            model.clear_prefixes()
+        self._sync_page_gauges()
 
     # -- submission ---------------------------------------------------------
 
@@ -684,10 +638,9 @@ class ServingEngine:
                 + (f" − spec_k ({self.spec_k})" if self.spec_k else "")
                 + ")"
             )
-        total_pages = self.allocator.num_pages - 1
-        if self.allocator.pages_for(len(prompt) + max_new_tokens) > total_pages:
+        if self.pages.pages_for(len(prompt) + max_new_tokens) > self.pages.total_pages:
             raise ValueError(
-                f"request needs more KV pages than the whole pool ({total_pages})"
+                f"request needs more KV pages than the whole pool ({self.pages.total_pages})"
             )
         if not self.sampling_enabled:
             temperature = 0.0  # degrade: greedy-only engine (SAMPLING_ENV=0)
@@ -738,19 +691,12 @@ class ServingEngine:
         token; its slot (and pages, once the prefix-cache entry is the only
         holder) free immediately, so a prefill replica's pool turns over at
         admission rate, not at generation length."""
-        self._one_pool_only("prefill_export")
+        self.pages.check_ships("prefill_export")
         return self.submit(
             prompt, max_new_tokens=1, request_id=request_id,
             temperature=temperature, top_k=top_k, top_p=top_p, seed=seed,
             export=True,
         )
-
-    def _one_pool_only(self, what: str) -> None:
-        if not self.cfg.uniform:
-            raise ValueError(
-                f"{what} ships KV pages of ONE pool; this model keeps a pool a layer kind, and a shipment "
-                "over two pools does not exist yet"
-            )
 
     def submit_prefilled(
         self,
@@ -775,25 +721,18 @@ class ServingEngine:
         `submit` (full local prefill): token streams are identical either
         way, only TTFT pays (docs/SERVING.md degradation matrix)."""
         if shipment is not None:
-            self._one_pool_only("submit_prefilled")
+            self.pages.check_ships("submit_prefilled")
         if shipment is None:
             # no bundle at all (unreadable kv_ref upstream): plain admission
             return self.submit(
                 prompt, max_new_tokens, request_id=request_id, eos_token_id=eos_token_id,
                 temperature=temperature, top_k=top_k, top_p=top_p, seed=seed,
             )
-        page = self.page_size
-        n_ship = -(-len(prompt) // page) if prompt else 0
-        ok = bool(prompt) and list(shipment.get("prompt", ())) == list(prompt)
-        k_arr, v_arr = shipment.get("k"), shipment.get("v")
-        if ok:
-            ok = (
-                k_arr is not None
-                and v_arr is not None
-                and getattr(k_arr, "shape", None) == getattr(v_arr, "shape", None)
-                and k_arr.shape[:3] == (self.cfg.n_layers, n_ship, page)
-            )
-        if not ok:
+        if not (
+            prompt
+            and list(shipment.get("prompt", ())) == list(prompt)
+            and self.pages.shipment_fits(len(prompt), shipment)
+        ):
             raise ValueError("shipment does not match this prompt/engine geometry")
         if _consume_kv_ship_drop():
             # chaos: the prefill replica "died mid-ship" — import nothing,
@@ -820,8 +759,8 @@ class ServingEngine:
 
     def _run(self) -> None:
         logger.debug(
-            f"serving engine up: slots={self.max_slots} pages={self.allocator.num_pages - 1} "
-            f"page_size={self.page_size} pool={self.cache.pool_bytes() / 1e6:.1f}MB"
+            f"serving engine up: slots={self.max_slots} pages={self.pages.total_pages} "
+            f"page_size={self.page_size} pool={self.pages.cache.pool_bytes() / 1e6:.1f}MB"
         )
         try:
             while True:
@@ -847,78 +786,18 @@ class ServingEngine:
 
     def _fail_all(self, message: str) -> None:
         with self._lock:
-            victims = [s for s in self.slots if s is not None]
+            victims = [(i, s) for i, s in enumerate(self.slots) if s is not None]
             self.slots = [None] * self.max_slots
             # error-finished requests must still age out of the registry
             # (the retirement queue is what _retire_requests evicts from)
-            for s in victims:
+            for _i, s in victims:
                 self._retired.append(s.request.id)
-        for s in victims:
-            self._free_slot_pages(s)
+        for i, s in victims:
+            for model in self._models:
+                model.release(i, on_device=False)  # the failed step may have taken the cache with it
             s.request._finish(error=message)
             SERVING_REQUESTS.inc(outcome="error")
         self._sync_page_gauges()
-
-    def _free_slot_pages(self, slot: _Slot) -> None:
-        """Everything a slot holds goes back: every pool frees together."""
-        self.allocator.free(slot.pages)
-        if slot.window_pages:
-            self.window_allocator.free(slot.window_pages)
-            slot.window_pages = []
-        if slot.draft_pages:
-            self.draft_allocator.free(slot.draft_pages)
-
-    # -- the window layers' pool ---------------------------------------------
-    # A slot's row in `cache.window_table` is indexed like its row in
-    # `page_table` (position p lives at index p // page_size), but only the
-    # indices the window can touch hold a live page.
-
-    def _window_first_index(self, pos: int) -> int:
-        """Row index of the oldest position a query at `pos` sees."""
-        return max(0, pos - (self.window - 1)) // self.page_size
-
-    def _window_reserve(self, wants: list) -> bool:
-        """wants: [(slot index, slot, last position to be written)]. Give
-        each slot the pages its row lacks up to that position, all or none,
-        and write them into the device table in one call. False where the
-        pool lacks room (the caller preempts)."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        from ..models.paged_kv import assign_window_pages
-
-        need = []
-        for idx, slot, last_pos in wants:
-            have = slot.window_first + len(slot.window_pages)
-            need.append(max(0, last_pos // self.page_size + 1 - have))
-        if not self.window_allocator.can_alloc(sum(need)):
-            return False
-        entries = []
-        for (idx, slot, _last), n in zip(wants, need):
-            for page in self.window_allocator.alloc(n):
-                entries.append((idx, slot.window_first + len(slot.window_pages), page))
-                slot.window_pages.append(page)
-        for at in range(0, len(entries), self._window_assign_len):
-            part = entries[at : at + self._window_assign_len]
-            arr = np.zeros((3, self._window_assign_len), np.int32)
-            arr[0] = self.max_slots  # out of range: dropped
-            arr[:, : len(part)] = np.asarray(part, np.int32).T
-            self.cache = assign_window_pages(self.cache, jnp.asarray(arr[0]), jnp.asarray(arr[1]), jnp.asarray(arr[2]))
-        return True
-
-    def _window_release(self, slot: _Slot, next_pos: int) -> None:
-        """The pages that lie wholly behind the window of the NEXT query (at
-        `next_pos`) go back to the pool while the request lives; their table
-        entries go stale and are never addressed again."""
-        first = self._window_first_index(next_pos)
-        dead = min(len(slot.window_pages), first - slot.window_first)
-        if dead > 0:
-            self.window_allocator.free(slot.window_pages[:dead])
-            del slot.window_pages[:dead]
-            slot.window_first += dead
-            self.window_pages_released += dead
-        if not slot.window_pages:
-            slot.window_first = max(slot.window_first, first)
 
     def _note_moe_pairs(self, cumulative: Any) -> None:
         """`cache.moe_pairs` as it came back with a step's tokens: a uint32
@@ -932,31 +811,20 @@ class ServingEngine:
         self.moe_expert_calls += self.moe_expert_layers * self.cfg.experts_held[1]
 
     def _sync_page_gauges(self) -> None:
-        KV_PAGES_ALLOCATED.set(float(self.allocator.allocated_pages))
-        KV_PAGES_FREE.set(float(self.allocator.free_pages))
+        """The Prometheus gauges track the target's growing pool."""
+        KV_PAGES_ALLOCATED.set(float(self.pages.allocated_pages))
+        KV_PAGES_FREE.set(float(self.pages.free_pages))
 
-    def _evict_prefix_for(self, shortage: int) -> int:
-        """Drop LRU prefix-cache entries until `shortage` pages came free (or
-        the cache is empty). Cached prefixes are strictly cheaper to lose
-        than live requests — this always runs before a preemption."""
-        released = 0
-        while released < shortage and self.prefix_cache is not None and len(self.prefix_cache):
-            released += self.prefix_cache.evict_lru()
-        if released:
-            self._sync_page_gauges()
-        return released
-
-    def _evict_draft_prefix_for(self, shortage: int) -> int:
-        """Draft-pool twin of `_evict_prefix_for` (the KV page gauges track
-        the target pool only, so no gauge sync here)."""
-        released = 0
-        while (
-            released < shortage
-            and self.draft_prefix_cache is not None
-            and len(self.draft_prefix_cache)
-        ):
-            released += self.draft_prefix_cache.evict_lru()
-        return released
+    def _reserve(self, wants: list) -> bool:
+        """wants: [(slot index, first position, last position)] of the next
+        step's writes. Every model's pools hold them, each model all or none
+        (`ModelPages.reserve`); False is the cue to preempt and ask again."""
+        copies = self.pages.cow_copies
+        ok = all(model.reserve(wants) for model in self._models)
+        if self.pages.cow_copies > copies:
+            KV_PAGES_COW.inc(self.pages.cow_copies - copies)
+        self._sync_page_gauges()
+        return ok
 
     def _admit(self) -> None:
         """Move waiting requests into free slots while pages allow. FIFO —
@@ -967,10 +835,6 @@ class ServingEngine:
         prefilled prefix, and only the suffix pays prefill — the fleet-wide
         system-prompt case prefills once, then every follower's TTFT is the
         suffix's."""
-        import jax.numpy as jnp
-
-        from ..models.paged_kv import PagePoolExhausted, assign_pages
-
         while True:
             with self._lock:
                 if not self.waiting:
@@ -981,91 +845,33 @@ class ServingEngine:
                 req = self.waiting[0]
                 self._phase("admit", request_id=req.id)
                 prefill_tokens = req.prompt + req.tokens  # preempted: regen prefix too
-                need = self.allocator.pages_for(len(prefill_tokens) + 1)
                 shipment = req._shipment
-                shared_pages: list[int] = []
-                covered = 0
-                hit_entry = None
-                if shipment is None and self.prefix_cache is not None:
-                    hit = self.prefix_cache.lookup(prefill_tokens)
-                    if hit is not None:
-                        shared_pages, covered, hit_entry = hit
-                fresh_need = max(0, need - len(shared_pages))
-                # draft mirror: full-page-only prefix reuse from the draft
-                # pool's own cache (no partial pages ⇒ no CoW needed there)
-                draft_shared: list[int] = []
-                draft_covered = 0
-                draft_entry = None
-                if self.draft_prefix_cache is not None:
-                    dhit = self.draft_prefix_cache.lookup(prefill_tokens, allow_partial=False)
-                    if dhit is not None:
-                        draft_shared, draft_covered, draft_entry = dhit
-                draft_need = max(0, need - len(draft_shared)) if self.spec_k else 0
-                if not self.allocator.can_alloc(fresh_need):
-                    self._evict_prefix_for(fresh_need - self.allocator.free_pages)
-                if self.spec_k and not self.draft_allocator.can_alloc(draft_need):
-                    self._evict_draft_prefix_for(draft_need - self.draft_allocator.free_pages)
-                # both pools must hold it. The window layers' pool is asked for
-                # the first chunk's room and gives the pages chunk by chunk, to the
-                # one slot whose chunk runs: a slot that waits its turn holds none
-                first_chunk_last = min(len(prefill_tokens), self.prefill_chunk) - 1
-                window_short = self.window and not self.window_allocator.can_alloc(
-                    first_chunk_last // self.page_size + 1
-                )
-                if window_short or not self.allocator.can_alloc(fresh_need) or (
-                    self.spec_k and not self.draft_allocator.can_alloc(draft_need)
-                ):
-                    if shared_pages:
-                        self.allocator.free(shared_pages)  # drop the lookup's refs
-                    if draft_shared:
-                        self.draft_allocator.free(draft_shared)
+                # each model looks the prompt up in its own prefix cache (the
+                # draft's shares full pages only); a shipment brings the
+                # target's pages filled, so there is nothing to look up there
+                hits = [
+                    None if shipment is not None and model is self.pages else model.lookup(prefill_tokens)
+                    for model in self._models
+                ]
+                # every pool of every model must hold it, cached prefixes evicted first
+                fits = [model.can_admit(len(prefill_tokens), hit) for model, hit in zip(self._models, hits)]
+                if not all(fits):
+                    for model, hit in zip(self._models, hits):
+                        model.drop(hit)  # the lookup's refs
+                    self._sync_page_gauges()
                     return  # pool dry; decode-side preemption or completions will free
                 self.waiting.popleft()
                 SERVING_QUEUE_DEPTH.set(float(len(self.waiting)))
-                try:
-                    pages = shared_pages + self.allocator.alloc(fresh_need)
-                    draft_pages = (
-                        draft_shared + self.draft_allocator.alloc(draft_need)
-                        if self.spec_k
-                        else []
-                    )
-                except PagePoolExhausted:  # pragma: no cover — guarded above
-                    self.waiting.appendleft(req)
-                    return
-                slot = _Slot(
-                    request=req,
-                    pages=pages,
-                    draft_pages=draft_pages,
-                    prefill_tokens=prefill_tokens,
-                    prefill_done=covered,
-                    draft_prefill_done=draft_covered,
-                    pos=covered,
-                    admitted_step=self.step_count,
-                )
+                slot = _Slot(request=req, prefill_tokens=prefill_tokens, admitted_step=self.step_count)
                 self.slots[free_idx] = slot
-                if self.prefix_cache is not None and shipment is None:
-                    # counted at admission commit, not per dry-pool retry —
-                    # cache stats, LRU clock, and Prometheus stay consistent
-                    # (a remote-prefill import is neither hit nor miss: the
-                    # prefix work happened on another replica)
-                    if hit_entry is not None and covered:
-                        self.prefix_cache.commit_use(hit_entry)
-                        SERVING_PREFIX_HITS.inc()
-                    else:
-                        self.prefix_cache.note_miss()
-                        SERVING_PREFIX_MISSES.inc()
-                if draft_entry is not None and draft_covered:
-                    self.draft_prefix_cache.commit_use(draft_entry)
-            # pad the row to pages_per_slot: assign_pages keys an executable
-            # on the page-array SHAPE, so padded admissions all share one
-            # compile (growth adds single pages — one more shape, total two)
-            row = pages + [0] * (self.pages_per_slot - len(pages))
-            self.cache = assign_pages(self.cache, free_idx, 0, jnp.asarray(row, jnp.int32))
-            if draft_pages:
-                drow = draft_pages + [0] * (self.pages_per_slot - len(draft_pages))
-                self.draft_cache = assign_pages(
-                    self.draft_cache, free_idx, 0, jnp.asarray(drow, jnp.int32)
-                )
+            # the commit: pages handed out, the rows written, and the hit or miss
+            # counted once — not per dry-pool retry above (a remote-prefill
+            # import is neither: the prefix work happened on another replica)
+            covered = [model.admit(free_idx, len(prefill_tokens), hit) for model, hit in zip(self._models, hits)]
+            slot.prefill_done = slot.pos = covered[0]
+            slot.draft_prefill_done = covered[1] if self.spec_k else 0
+            if hits[0] is not None:
+                (SERVING_PREFIX_HITS if covered[0] else SERVING_PREFIX_MISSES).inc()
             req.admitted_at = time.time()
             self.requests_admitted += 1
             self.queue_wait_seconds += req.admitted_at - req.queue_from
@@ -1080,9 +886,9 @@ class ServingEngine:
                     attrs={
                         "request_id": req.id,
                         "slot": free_idx,
-                        "pages": len(pages),
-                        "prefix_tokens": covered,
-                        "draft_prefix_tokens": draft_covered,
+                        "pages": len(self.pages.pages[free_idx]),
+                        "prefix_tokens": covered[0],
+                        "draft_prefix_tokens": slot.draft_prefill_done,
                         "remote_prefill": shipment is not None,
                         "requeue": req.preemptions > 0,
                     },
@@ -1101,18 +907,17 @@ class ServingEngine:
         import jax.numpy as jnp
         import numpy as np
 
-        from ..models.paged_kv import import_pages, set_seq_lens
+        from ..models.paged_kv import set_seq_lens
 
         req = slot.request
         req._shipment = None  # consumed: a later preemption re-prefills locally
-        n_ship = -(-len(req.prompt) // self.page_size)
         t0 = time.time()
-        self.cache = import_pages(self.cache, slot.pages[:n_ship], shipment)
+        n_ship = self.pages.import_shipment(idx, len(req.prompt), shipment)
         lens = np.zeros((self.max_slots,), np.int32)
         upd = np.zeros((self.max_slots,), bool)
         lens[idx] = len(req.prompt)
         upd[idx] = True
-        self.cache = set_seq_lens(self.cache, jnp.asarray(lens), jnp.asarray(upd))
+        self.pages.cache = set_seq_lens(self.pages.cache, jnp.asarray(lens), jnp.asarray(upd))
         slot.prefill_done = len(slot.prefill_tokens)
         slot.pos = len(req.prompt)
         self.remote_prefills += 1
@@ -1124,9 +929,7 @@ class ServingEngine:
                 parent=req.trace_context,
                 attrs={"request_id": req.id, "side": "import", "pages": n_ship},
             )
-        if self.prefix_cache is not None and len(req.prompt) >= self.page_size:
-            self.prefix_cache.insert(req.prompt, slot.pages)
-            self._sync_page_gauges()
+        self.pages.publish(idx, req.prompt)
         self._phase("emit", request_id=req.id, tokens=1)
         self._emit_first(idx, slot, int(shipment["first_token"]))
 
@@ -1148,37 +951,6 @@ class ServingEngine:
         self.tokens_generated += 1
         self._note_rate(1)
         self._maybe_finish(idx, slot)
-
-    def _cow_range(self, idx: int, slot: _Slot, start_pos: int, end_pos: int) -> bool:
-        """Copy-on-write barrier: before any write to positions
-        [start_pos, end_pos), every refcount-shared page in that range is
-        copied into a private page (`copy_page`) and the shared original's
-        ref dropped — cached/shared prefix bytes are never mutated. Returns
-        False if a copy needed a page the pool couldn't provide (caller
-        preempts and retries)."""
-        import jax.numpy as jnp
-
-        from ..models.paged_kv import copy_page
-
-        page = self.page_size
-        for t_idx in range(start_pos // page, (max(start_pos, end_pos - 1)) // page + 1):
-            if t_idx >= len(slot.pages):
-                break  # growth's job, not CoW's
-            pid = slot.pages[t_idx]
-            if not self.allocator.shared(pid):
-                continue
-            if not self.allocator.can_alloc(1):
-                self._evict_prefix_for(1)
-            if not self.allocator.can_alloc(1):
-                return False
-            new_page = self.allocator.alloc(1)[0]
-            self.cache = copy_page(self.cache, idx, t_idx, jnp.int32(new_page))
-            self.allocator.free([pid])  # drop this slot's ref; other holders keep it
-            slot.pages[t_idx] = new_page
-            self.cow_copies += 1
-            KV_PAGES_COW.inc()
-            self._sync_page_gauges()
-        return True
 
     def _prefill_one(self) -> None:
         """Advance the oldest prefilling slot by one chunk. One chunk per
@@ -1217,29 +989,26 @@ class ServingEngine:
                 "prefill_prep", request_id=req.id, chunk_tokens=len(chunk),
                 offset=slot.prefill_done, bucket=bucket,
             )
-            if not self._cow_range(idx, slot, slot.prefill_done, slot.prefill_done + len(chunk)) or (
-                # the window layers' pool holds the chunk and the window before it
-                self.window and not self._window_reserve([(idx, slot, slot.prefill_done + len(chunk) - 1)])
-            ):
-                # CoW (or the window pool) starved for a page: free capacity
+            if not self._reserve([(idx, slot.prefill_done, slot.prefill_done + len(chunk) - 1)]):
+                # a copy-on-write (or the window pool, which holds the chunk
+                # and the window before it) starved for a page: free capacity
                 # the hard way and retry next iteration. The needy slot itself
                 # is a valid victim — if it alone holds the pool, preempting it
                 # (requeue, pages freed) is the only move that ever unsticks
                 # the loop
-                self._preempt_youngest(exclude=())
+                self._preempt_youngest()
                 return
             padded = np.zeros((bucket,), np.int32)
             padded[: len(chunk)] = chunk
             tokens_j, length_j = jnp.asarray(padded), jnp.int32(len(chunk))
             slot_j, start_j = jnp.int32(idx), jnp.int32(slot.prefill_done)
             self._phase("prefill_dispatch", request_id=req.id)
-            logits, next_tok, self.cache = paged_prefill(
-                self.params, self.cfg, tokens_j, length_j, self.cache, slot_j, start_j
+            logits, next_tok, self.pages.cache = paged_prefill(
+                self.params, self.cfg, tokens_j, length_j, self.pages.cache, slot_j, start_j
             )
             self._phase("emit", request_id=req.id, tokens=0)
-            if self.window:
-                # the chunk gives back all but the window at its end
-                self._window_release(slot, slot.prefill_done + len(chunk))
+            # a window pool's chunk gives back all but the window at its end
+            self.pages.trim(idx, slot.prefill_done + len(chunk))
             if self.moe_expert_layers:
                 self._note_moe_call(len(chunk))
             self.prompt_tokens_prefilled += len(chunk)
@@ -1282,18 +1051,13 @@ class ServingEngine:
             tokens_j, length_j = jnp.asarray(dpadded), jnp.int32(len(dchunk))
             slot_j, start_j = jnp.int32(idx), jnp.int32(slot.draft_prefill_done)
             self._phase("prefill_dispatch", request_id=req.id, draft=1)
-            _dl, _dn, self.draft_cache = paged_prefill(
-                self.draft_params, self.draft_cfg, tokens_j, length_j, self.draft_cache, slot_j, start_j
+            _dl, _dn, self.draft_pages.cache = paged_prefill(
+                self.draft_params, self.draft_cfg, tokens_j, length_j, self.draft_pages.cache, slot_j, start_j
             )
             self._phase("emit", request_id=req.id, tokens=0)
             slot.draft_prefill_done += len(dchunk)
             if slot.draft_prefill_done >= total:
-                if self.draft_prefix_cache is not None and len(req.prompt) >= self.page_size:
-                    # publish the draft's full-page prompt prefix (partial
-                    # last page stays private: the draft pool has no CoW)
-                    self.draft_prefix_cache.insert(
-                        req.prompt, slot.draft_pages, full_pages_only=True
-                    )
+                self.draft_pages.publish(idx, req.prompt)
                 if slot.first_emitted and slot.state == "prefill":
                     slot.state = "decode"  # target finished earlier (import)
         if target_done_now:
@@ -1302,12 +1066,7 @@ class ServingEngine:
             # (TTFT); for a preempted-and-readmitted one the next one
             # (already-emitted tokens re-entered via prefill_tokens and are
             # never re-appended — the continuation after them is new)
-            if self.prefix_cache is not None and len(req.prompt) >= self.page_size:
-                # the prompt's KV is now resident — publish it for followers
-                # (entry refs the pages, so they outlive this request; dedup
-                # by exact prompt content inside insert)
-                self.prefix_cache.insert(req.prompt, slot.pages)
-                self._sync_page_gauges()
+            self.pages.publish(idx, req.prompt)  # the prompt's KV is resident: followers may share it
             self._phase("prefill_sync", request_id=req.id)
             if req.temperature > 0:
                 # first/continuation token sampled with the request's own
@@ -1329,14 +1088,14 @@ class ServingEngine:
             elif self.moe_expert_layers:
                 import jax
 
-                first_tok, pairs = jax.device_get((next_tok, self.cache.moe_pairs))  # one fetch
+                first_tok, pairs = jax.device_get((next_tok, self.pages.cache.moe_pairs))  # one fetch
                 first_tok = int(first_tok)
                 self._note_moe_pairs(pairs)
             else:
                 first_tok = int(next_tok)
             self._phase("emit", request_id=req.id, tokens=1)
             if req._export:
-                self._export_shipment(slot, first_tok)
+                self._export_shipment(idx, slot, first_tok)
             if req.trace_context is not None:
                 tracing.record_span(
                     "serving.prefill",
@@ -1347,17 +1106,14 @@ class ServingEngine:
                 )
             self._emit_first(idx, slot, first_tok)
 
-    def _export_shipment(self, slot: _Slot, first_token: int) -> None:
+    def _export_shipment(self, idx: int, slot: _Slot, first_token: int) -> None:
         """Pull the slot's prompt-covering pages off the device and attach
         them to the request as a shipment bundle (prefill_export path). Runs
         BEFORE the emission below can finish/free the slot — the pages must
         still be live to read."""
-        from ..models.paged_kv import export_pages
-
         req = slot.request
-        n_ship = -(-len(req.prompt) // self.page_size)
         t0 = time.time()
-        data = export_pages(self.cache, slot.pages[:n_ship])
+        data, n_ship = self.pages.export_shipment(idx, len(req.prompt))
         dt = time.time() - t0
         req.shipment = {
             "prompt": list(req.prompt),
@@ -1397,118 +1153,35 @@ class ServingEngine:
         span = max(1e-3, now - self._rate_window[0][0]) if len(self._rate_window) > 1 else 1.0
         SERVING_TOKENS_PER_S.set(sum(c for _, c in self._rate_window) / span)
 
-    def _grow_pages(self) -> bool:
-        """Before a decode step, every active slot whose upcoming writes
-        (one token, or k+1 in a speculative round) would cross its page
-        coverage gets fresh pages; shared pages in the write range are CoW'd.
-        A dry pool evicts cached prefixes first, then preempts the youngest
-        slot and retries. Returns False if nothing can decode."""
-        import jax.numpy as jnp
-
-        from ..models.paged_kv import assign_pages
-
-        lookahead = (self.spec_k + 1) if self.spec_k else 1  # positions written per round
-        span = self.page_size
+    def _reserve_decode(self) -> list:
+        """Before a decode step: the pools hold every decoding slot's
+        upcoming writes (one token, or k+1 in a speculative round). A dry
+        pool has evicted its cached prefixes by the time `_reserve` says no;
+        then the youngest slot is preempted and the rest ask again. Returns
+        the slots that decode, none if nothing can."""
+        ahead = self.spec_k  # positions written past the fed token's
         while True:
             with self._lock:
-                decoding = [
-                    (i, s)
-                    for i, s in enumerate(self.slots)
-                    if s is not None and s.state == "decode"
-                ]
-            needy = [
-                (i, s, -(-(s.pos + lookahead) // span) - len(s.pages))
-                for i, s in decoding
-                if s.pos + lookahead > len(s.pages) * span
-            ]
-            if not needy:
-                break
-            short = sum(n for _i, _s, n in needy) - self.allocator.free_pages
-            if short > 0:
-                self._evict_prefix_for(short)
-                short = sum(n for _i, _s, n in needy) - self.allocator.free_pages
-            if self.spec_k:
-                d_short = sum(n for _i, _s, n in needy) - self.draft_allocator.free_pages
-                if d_short > 0:
-                    self._evict_draft_prefix_for(d_short)
-            if short > 0 or (
-                self.spec_k
-                and sum(n for _i, _s, n in needy) > self.draft_allocator.free_pages
-            ):
-                if not self._preempt_youngest(exclude=()):
-                    return False  # nothing left to preempt
-                continue
-            for i, s, n in needy:
-                pages = self.allocator.alloc(n)
-                for p in pages:
-                    s.pages.append(p)
-                    self.cache = assign_pages(
-                        self.cache, i, len(s.pages) - 1, jnp.asarray([p], jnp.int32)
-                    )
-                if self.spec_k:
-                    dpages = self.draft_allocator.alloc(n)
-                    for p in dpages:
-                        s.draft_pages.append(p)
-                        self.draft_cache = assign_pages(
-                            self.draft_cache, i, len(s.draft_pages) - 1, jnp.asarray([p], jnp.int32)
-                        )
-            self._sync_page_gauges()
-            break
-        # CoW barrier over this round's write window (a slot resuming inside
-        # a shared partial page, or an inserter decoding into the page its
-        # own prompt was published from)
-        with self._lock:
-            decoding = [
-                (i, s)
-                for i, s in enumerate(self.slots)
-                if s is not None and s.state == "decode"
-            ]
-        for i, s in decoding:
-            if not self._cow_range(i, s, s.pos, s.pos + lookahead):
-                if not self._preempt_youngest(exclude=()):
-                    return False
-                return self._grow_pages()  # geometry changed; re-run
-        return True
+                decoding = [(i, s) for i, s in enumerate(self.slots) if s is not None and s.state == "decode"]
+            if not decoding or self._reserve([(i, s.pos, s.pos + ahead) for i, s in decoding]):
+                return decoding
+            if not self._preempt_youngest():
+                return []
 
-    def _grow_window_pages(self) -> bool:
-        """Before a decode step: every decoding slot gives back the pages
-        that fell behind its window and gets the page its token is written
-        to. A dry window pool preempts the youngest slot and retries. Returns
-        False if nothing can decode."""
-        while True:
-            with self._lock:
-                decoding = [
-                    (i, s) for i, s in enumerate(self.slots) if s is not None and s.state == "decode"
-                ]
-            for _i, s in decoding:
-                self._window_release(s, s.pos)
-            if self._window_reserve([(i, s, s.pos) for i, s in decoding]):
-                return True
-            if not self._preempt_youngest(exclude=()):
-                return False
-
-    def _preempt_youngest(self, exclude: tuple[int, ...]) -> bool:
+    def _preempt_youngest(self) -> bool:
         """Free the most-recently-admitted slot's pages and requeue its
         request (generated prefix preserved: re-admission re-prefills
         prompt+tokens, the stream never sees a duplicate)."""
-        from ..models.paged_kv import release_slot
-
         with self._lock:
-            victims = [
-                (i, s)
-                for i, s in enumerate(self.slots)
-                if s is not None and i not in exclude
-            ]
+            victims = [(i, s) for i, s in enumerate(self.slots) if s is not None]
             if not victims:
                 return False
             idx, slot = max(victims, key=lambda t: t[1].admitted_step)
             self.slots[idx] = None
             self.waiting.appendleft(slot.request)
             SERVING_QUEUE_DEPTH.set(float(len(self.waiting)))
-        self._free_slot_pages(slot)
-        self.cache = release_slot(self.cache, idx)
-        if slot.draft_pages:
-            self.draft_cache = release_slot(self.draft_cache, idx)
+        for model in self._models:
+            model.release(idx)
         req = slot.request
         req.preemptions += 1
         self.preemptions += 1
@@ -1568,12 +1241,7 @@ class ServingEngine:
         self._phase("decode_prep")
         if self.spec_k:
             return self._spec_round()
-        if not self._grow_pages() or (self.window and not self._grow_window_pages()):
-            return
-        with self._lock:
-            decoding = [
-                (i, s) for i, s in enumerate(self.slots) if s is not None and s.state == "decode"
-            ]
+        decoding = self._reserve_decode()
         if not decoding:
             return
         tokens = np.zeros((self.max_slots,), np.int32)
@@ -1583,8 +1251,8 @@ class ServingEngine:
             active[i] = True
         tokens_j, active_j = jnp.asarray(tokens), jnp.asarray(active)
         self._phase("decode_dispatch", batch=len(decoding))
-        logits, next_tokens, self.cache = paged_decode_step(
-            self.params, self.cfg, tokens_j, self.cache, active_j, self.attn_impl
+        logits, next_tokens, self.pages.cache = paged_decode_step(
+            self.params, self.cfg, tokens_j, self.pages.cache, active_j, self.attn_impl
         )
         if any(s.request.temperature > 0 for _i, s in decoding):
             # one extra fixed-shape dispatch ONLY when a sampling request is
@@ -1606,7 +1274,7 @@ class ServingEngine:
             import jax
 
             # the held experts' pair count rides with the step's tokens: one fetch
-            next_host, pairs = jax.device_get((next_tokens, self.cache.moe_pairs))
+            next_host, pairs = jax.device_get((next_tokens, self.pages.cache.moe_pairs))
             self._note_moe_pairs(pairs)
             self._note_moe_call(len(decoding))
         else:
@@ -1639,8 +1307,8 @@ class ServingEngine:
                             "request_id": req.id,
                             "tokens": len(req.tokens),
                             "batch_occupancy": len(decoding),
-                            "kv_pages_free": self.allocator.free_pages,
-                            "kv_pages_allocated": self.allocator.allocated_pages,
+                            "kv_pages_free": self.pages.free_pages,
+                            "kv_pages_allocated": self.pages.allocated_pages,
                         },
                     )
                     s.last_mark_t = now
@@ -1664,16 +1332,11 @@ class ServingEngine:
         fold_in(seed, index)-keyed chain the non-speculative path samples.
         Acceptance rate is a throughput knob, never a correctness one.
 
-        With MODAL_TPU_SPEC_OVERLAP on (default) and ≥2 decoding slots, the
-        round is pipelined: `_spec_dispatch` enqueues a slot-group's whole
-        device program without syncing, so group B's draft chain overlaps
-        group A's verify — continuous batching for the verify stage."""
-        if not self._grow_pages():
-            return
-        with self._lock:
-            decoding = [
-                (i, s) for i, s in enumerate(self.slots) if s is not None and s.state == "decode"
-            ]
+        With ≥2 decoding slots the round is pipelined: `_spec_dispatch`
+        enqueues a slot-group's whole device program without syncing, so
+        group B's draft chain overlaps group A's verify — continuous
+        batching for the verify stage."""
+        decoding = self._reserve_decode()
         if not decoding:
             return
         k = self.spec_k
@@ -1684,11 +1347,11 @@ class ServingEngine:
         # Group B's draft steps run while group A's verify is in flight.
         # Per-row ops are batch-composition-independent, and seq_lens rolls
         # are masked per group, so token streams are byte-identical to the
-        # sequential round (test-pinned).
+        # non-speculative engine's (test-pinned).
         self.step_count += 1
         SERVING_BATCH_OCCUPANCY.observe(float(len(decoding)))
         groups = [decoding]
-        if self.spec_overlap and len(decoding) >= 2:
+        if len(decoding) >= 2:
             mid = (len(decoding) + 1) // 2
             groups = [decoding[:mid], decoding[mid:]]
         pendings = [self._spec_dispatch(g) for g in groups]
@@ -1756,8 +1419,8 @@ class ServingEngine:
         props = []
         feed = jnp.asarray(cur)
         for j in range(k):
-            dlogits, _g, self.draft_cache = paged_decode_step(
-                self.draft_params, self.draft_cfg, feed, self.draft_cache, active_j,
+            dlogits, _g, self.draft_pages.cache = paged_decode_step(
+                self.draft_params, self.draft_cfg, feed, self.draft_pages.cache, active_j,
                 self.attn_impl,
             )
             prop = sample_step(
@@ -1767,14 +1430,14 @@ class ServingEngine:
             feed = prop
         # extra feed: write the last proposal's KV so a fully-accepted round
         # leaves the draft cache complete
-        _dl, _dg, self.draft_cache = paged_decode_step(
-            self.draft_params, self.draft_cfg, feed, self.draft_cache, active_j, self.attn_impl
+        _dl, _dg, self.draft_pages.cache = paged_decode_step(
+            self.draft_params, self.draft_cfg, feed, self.draft_pages.cache, active_j, self.attn_impl
         )
 
         # 2) target verifies [cur, d_1..d_k] in one fixed-shape step
         proposals_dev = jnp.stack(props, axis=1)  # [slots, k]
         fed = jnp.concatenate([jnp.asarray(cur)[:, None], proposals_dev], axis=1)
-        vlogits, self.cache = paged_verify_step(self.params, self.cfg, fed, self.cache, active_j)
+        vlogits, self.pages.cache = paged_verify_step(self.params, self.cfg, fed, self.pages.cache, active_j)
 
         # 3) the target's own chain at every verified position
         flat = vlogits.reshape(self.max_slots * k1, vlogits.shape[-1])
@@ -1844,8 +1507,8 @@ class ServingEngine:
                             "tokens": len(req.tokens),
                             "batch_occupancy": batch,
                             "speculative": True,
-                            "kv_pages_free": self.allocator.free_pages,
-                            "kv_pages_allocated": self.allocator.allocated_pages,
+                            "kv_pages_free": self.pages.free_pages,
+                            "kv_pages_allocated": self.pages.allocated_pages,
                         },
                     )
                     s.last_mark_t = now
@@ -1854,27 +1517,23 @@ class ServingEngine:
         # roll both pools' lengths to the accepted frontier — the verify
         # wrote k+1 positions, only pos+emitted of them are real; the draft
         # over-advanced by its k+1 feeds and rolls back to match. BEFORE any
-        # slot release: release_slot zeroes the slot's length, and this roll
-        # must not scribble a stale value back onto a freed slot
-        self.cache = set_seq_lens(self.cache, jnp.asarray(new_lens), jnp.asarray(update))
-        self.draft_cache = set_seq_lens(self.draft_cache, jnp.asarray(new_lens), jnp.asarray(update))
+        # slot release: a release zeroes the slot's length on the device, and
+        # this roll must not scribble a stale value back onto a freed slot
+        for model in self._models:
+            model.cache = set_seq_lens(model.cache, jnp.asarray(new_lens), jnp.asarray(update))
         for i, s in group:
             self._maybe_finish(i, s)
         return total_emitted, total_accepted, n_sampled
 
     def _maybe_finish(self, idx: int, slot: _Slot) -> None:
-        from ..models.paged_kv import release_slot
-
         req = slot.request
         if not req.reached_end():
             return
         with self._lock:
             self.slots[idx] = None
             self._retired.append(req.id)
-        self._free_slot_pages(slot)
-        self.cache = release_slot(self.cache, idx)
-        if slot.draft_pages:
-            self.draft_cache = release_slot(self.draft_cache, idx)
+        for model in self._models:
+            model.release(idx)
         self.requests_completed += 1
         SERVING_REQUESTS.inc(outcome="ok")
         self._sync_page_gauges()
@@ -1887,31 +1546,9 @@ class ServingEngine:
         currently serves, capped (content-blind: a digest identifies a
         prefix without shipping its tokens). The fleet router folds these
         into its prefix→replica map via /v1/stats (serving/router.py)."""
-        if self.prefix_cache is None:
-            return []
         from .router import prefix_digest
 
-        keys = list(self.prefix_cache._index.keys())  # atomic snapshot (GIL)
-        return [prefix_digest(key) for key in keys[:limit]]
-
-    def _second_pool_stats(self) -> dict:
-        """Keys only a model with window layers or routed experts has: the
-        `kv_pages_*` keys keep meaning the pool that grows with the context."""
-        out: dict = {}
-        if self.window:
-            out.update(
-                kv_window_pages_total=self.window_allocator.num_pages - 1,
-                kv_window_pages_high_water=self.window_allocator.high_water,
-                kv_window_pages_released=self.window_pages_released,
-                kv_window_pool_bytes=self.kv_window_pool_bytes,
-            )
-        if self.moe_expert_layers:
-            out["moe"] = {
-                "assignments": self.moe_assignments,
-                "local_assignments": self.moe_local_assignments,
-                "expert_calls": self.moe_expert_calls,
-            }
-        return out
+        return [prefix_digest(key) for key in self.pages.prefix_keys(limit)]
 
     def stats(self) -> dict:
         from ..observability.device_telemetry import telemetry_summary
@@ -1929,6 +1566,12 @@ class ServingEngine:
         for name, seconds in phases.items():
             by_kind[ENGINE_PHASES[name][1]] += seconds
         work_seconds = by_kind["host"] + by_kind["sync"]
+        draft = self.draft_pages.stats() if self.draft_pages is not None else {}
+        moe = {
+            "assignments": self.moe_assignments,
+            "local_assignments": self.moe_local_assignments,
+            "expert_calls": self.moe_expert_calls,
+        }
         return {
             "loop": {
                 "iterations": self.loop_iterations,
@@ -1954,35 +1597,24 @@ class ServingEngine:
             "sampled_tokens": self.sampled_tokens,
             "requests_completed": self.requests_completed,
             "preemptions": self.preemptions,
-            "kv_pages_total": self.allocator.num_pages - 1,
-            "kv_pages_allocated": self.allocator.allocated_pages,
-            "kv_pages_free": self.allocator.free_pages,
-            "kv_pages_high_water": self.allocator.high_water,
-            "kv_pool_bytes": self.kv_pool_bytes,
-            **self._second_pool_stats(),
+            # the pools and the prefix cache of the model served: kv_pages_*,
+            # kv_pool_bytes, prefix_cache_*, kv_pages_cow_copies, and
+            # kv_window_* only where there are window layers
+            **self.pages.stats(),
+            **({"moe": moe} if self.moe_expert_layers else {}),
             "attn_impl": self.attn_impl,
             "device": self.device,
             "compile": telemetry_summary(),
             "sampling_enabled": self.sampling_enabled,
-            "prefix_cache_entries": len(self.prefix_cache) if self.prefix_cache else 0,
-            "prefix_cache_pages": self.prefix_cache.held_pages if self.prefix_cache else 0,
-            "prefix_cache_hits": self.prefix_cache.hits if self.prefix_cache else 0,
-            "prefix_cache_misses": self.prefix_cache.misses if self.prefix_cache else 0,
-            "kv_pages_cow_copies": self.cow_copies,
             "spec_k": self.spec_k,
             "spec_rounds": self.spec_rounds,
             "spec_accept_ratio": round(acc / prop, 4) if prop else None,
-            "spec_overlap": self.spec_overlap,
             "role": self.role,
             "remote_prefills": self.remote_prefills,
             "kv_pages_shipped": self.kv_pages_shipped,
             "kv_ship_drops": self.kv_ship_drops,
-            "draft_prefix_cache_entries": (
-                len(self.draft_prefix_cache) if self.draft_prefix_cache else 0
-            ),
-            "draft_prefix_cache_hits": (
-                self.draft_prefix_cache.hits if self.draft_prefix_cache else 0
-            ),
+            "draft_prefix_cache_entries": draft.get("prefix_cache_entries", 0),
+            "draft_prefix_cache_hits": draft.get("prefix_cache_hits", 0),
             "prefix_digests": self.prefix_digests(),
             "tokens_per_s": SERVING_TOKENS_PER_S.value(),
             "ttft_p95_s": SERVING_TTFT_P95.value(),

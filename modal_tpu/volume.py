@@ -561,8 +561,10 @@ class _Volume(_Object, type_prefix="vo"):
             return b""
         out = bytearray(length)
         written = await self.read_file_range_into(path, offset, length, out)
-        del out[written:]
-        return bytes(out)
+        # not `del out[written:]`: a worker's or a settled task's view of `out`
+        # may outlive the call by a collection, and a bytearray with a live
+        # export cannot be resized (BufferError, one run in some hundreds)
+        return bytes(memoryview(out)[:written])
 
     @live_method
     async def remove_file(self, path: str, recursive: bool = False) -> None:

@@ -770,9 +770,12 @@ def test_serving_sampling_spec_prefix_degrade_off(monkeypatch):
 
 
 def test_paged_kernel_degrades_to_gather(monkeypatch):
+    """The platform alone chooses: the kernel on a TPU, the gather path
+    everywhere else; no environment value reaches the choice."""
+    import jax
+
     from modal_tpu.models.paged_kv import resolve_attn_impl
 
-    monkeypatch.setenv("MODAL_TPU_PAGED_KERNEL", "0")
-    assert resolve_attn_impl() == "gather"
-    monkeypatch.setenv("MODAL_TPU_PAGED_KERNEL", "interpret")
-    assert resolve_attn_impl() == "kernel_interpret"
+    assert jax.default_backend() != "tpu" and resolve_attn_impl() == "gather"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_attn_impl() == "kernel"

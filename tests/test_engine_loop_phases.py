@@ -154,7 +154,7 @@ def test_the_counters_against_a_hand_count_with_one_forced_preemption(tiny_model
     younger = engine.submit(list(range(30, 40)), max_new_tokens=8)  # 10 tokens: bucket 16
     _iterate(engine)  # the younger: admitted, prefilled, and both decode
     assert len(older.tokens) == 3 and len(younger.tokens) == 2
-    assert engine._preempt_youngest(exclude=()) and younger.preemptions == 1 and older.preemptions == 0
+    assert engine._preempt_youngest() and younger.preemptions == 1 and older.preemptions == 0
     time.sleep(0.01)  # requeued: the wait until it is admitted again is queue wait too
     while not (older.done and younger.done):
         _iterate(engine)

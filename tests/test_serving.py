@@ -67,7 +67,7 @@ def _engine(params, cfg, **overrides):
 def test_page_allocator_alloc_free_churn():
     """Exact-fit under arbitrary fragmentation history: any free page serves
     any slot, so churn can never strand capacity."""
-    from modal_tpu.models.paged_kv import PageAllocator, PagePoolExhausted
+    from modal_tpu.serving.pages import PageAllocator, PagePoolExhausted
 
     alloc = PageAllocator(num_pages=9, page_size=16)  # 8 usable (page 0 reserved)
     assert alloc.free_pages == 8
@@ -101,9 +101,8 @@ def test_paged_prefill_matches_dense(tiny_model):
     import numpy as np
 
     from modal_tpu.models.llama import KVCache
-    from modal_tpu.models.paged_kv import (
-        PagedKVCache, PageAllocator, assign_pages, paged_decode_step, paged_prefill,
-    )
+    from modal_tpu.models.paged_kv import PagedKVCache, assign_pages, paged_decode_step, paged_prefill
+    from modal_tpu.serving.pages import PageAllocator
     from modal_tpu.models.sampling import decode_step, prefill
 
     params, cfg = tiny_model
@@ -419,7 +418,7 @@ def test_variable_length_admission_and_limits(tiny_model):
             eng.submit([], max_new_tokens=1)
     finally:
         eng.stop()
-    assert eng.allocator.free_pages == PAGES - 1, "pages leaked across completions"
+    assert eng.pages.free_pages == PAGES - 1, "pages leaked across completions"
 
 
 def test_pool_pressure_preempts_and_requeues_without_token_loss(tiny_model):
@@ -445,8 +444,8 @@ def test_pool_pressure_preempts_and_requeues_without_token_loss(tiny_model):
     assert eng.preemptions > 0, "pool was never exhausted — test geometry wrong"
     for solo, out in zip(solos, outs):
         assert out == solo, "preemption changed or duplicated a token stream"
-    assert eng.allocator.high_water <= PAGES - 1
-    assert eng.allocator.free_pages == PAGES - 1
+    assert eng.pages.allocator.high_water <= PAGES - 1
+    assert eng.pages.free_pages == PAGES - 1
 
 
 def test_engine_matches_direct_paged_loop(tiny_model):
@@ -456,9 +455,8 @@ def test_engine_matches_direct_paged_loop(tiny_model):
     import jax.numpy as jnp
     import numpy as np
 
-    from modal_tpu.models.paged_kv import (
-        PagedKVCache, PageAllocator, assign_pages, paged_decode_step, paged_prefill,
-    )
+    from modal_tpu.models.paged_kv import PagedKVCache, assign_pages, paged_decode_step, paged_prefill
+    from modal_tpu.serving.pages import PageAllocator
 
     params, cfg = tiny_model
     prompt = np.random.default_rng(4).integers(0, cfg.vocab_size, size=7).tolist()
@@ -721,7 +719,7 @@ def test_page_allocator_refcounts_share_and_underflow():
     """CoW substrate: share() adds holders, free() drops one; the page
     returns only at zero, and over-freeing (underflow) fails loudly — the
     refcount IS the double-free detector."""
-    from modal_tpu.models.paged_kv import PageAllocator
+    from modal_tpu.serving.pages import PageAllocator
 
     alloc = PageAllocator(num_pages=9, page_size=16)
     a = alloc.alloc(2)
@@ -747,9 +745,8 @@ def test_pallas_paged_attention_interpret_parity(tiny_model):
     import numpy as np
 
     from modal_tpu.models.llama import KVCache
-    from modal_tpu.models.paged_kv import (
-        PagedKVCache, PageAllocator, assign_pages, paged_decode_step, paged_prefill,
-    )
+    from modal_tpu.models.paged_kv import PagedKVCache, assign_pages, paged_decode_step, paged_prefill
+    from modal_tpu.serving.pages import PageAllocator
     from modal_tpu.models.sampling import decode_step, prefill
 
     params, cfg = tiny_model
@@ -881,7 +878,7 @@ def test_prefix_cache_share_cow_and_eviction(tiny_model):
     finally:
         eng.stop()
     # stop() clears the cache: every page accounted for, no refcount leaks
-    assert eng.allocator.free_pages == PAGES - 1
+    assert eng.pages.free_pages == PAGES - 1
 
 
 def test_prefix_hit_with_cow_streams_the_tokens_of_a_cold_prefill(tiny_model):
@@ -968,10 +965,10 @@ def test_prefix_cache_cow_refcounts_under_preemption(tiny_model):
         assert out == solo, "preemption over shared pages corrupted a stream"
     # nothing leaked and nothing double-freed (an underflow would have
     # raised in the loop and error-finished every request above)
-    assert eng.allocator.free_pages == 16
+    assert eng.pages.free_pages == 16
     # the allocator still detects over-frees after all this churn
     with pytest.raises(ValueError, match="double free"):
-        eng.allocator.free([1])
+        eng.pages.allocator.free([1])
 
 
 def test_speculative_decoding_exact_vs_nonspec():
@@ -1019,8 +1016,8 @@ def test_speculative_decoding_exact_vs_nonspec():
     assert st["spec_accept_ratio"] > 0.8, f"self-draft should accept nearly all: {st}"
     # fewer engine steps than tokens: speculation actually batched them
     assert st["steps"] < st["tokens_generated"]
-    assert spec.allocator.free_pages == PAGES - 1
-    assert spec.draft_allocator.free_pages == PAGES - 1
+    assert spec.pages.free_pages == PAGES - 1
+    assert spec.draft_pages.free_pages == PAGES - 1
 
     # context-boundary pin: spec mode reserves spec_k slack (a verify round
     # on the final token still writes k positions past it; without the

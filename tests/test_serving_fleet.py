@@ -14,7 +14,7 @@ Three layers under test:
   token loss.
 - **overlapped speculative verify**: spec rounds split the batch so group
   B's draft chain runs under group A's in-flight verify; token streams are
-  byte-identical to the sequential rounds (MODAL_TPU_SPEC_OVERLAP=0), and
+  byte-identical to the non-speculative engine's, and
   spec mode no longer disables the prefix cache (the draft pool runs its
   own full-page-only cache).
 
@@ -322,20 +322,15 @@ def _run_spec_batch(params, cfg, draft, prompts, n=10, **overrides):
         eng.stop()
 
 
-def test_spec_overlap_streams_byte_identical_to_sequential(tiny_fp32, monkeypatch):
-    """The overlapped round (group B's draft chain under group A's verify)
-    emits the same bytes as MODAL_TPU_SPEC_OVERLAP=0 sequential rounds —
-    and both match the non-speculative engine (spec is a throughput knob,
-    never a correctness one)."""
+def test_spec_overlap_streams_byte_identical_to_the_plain_engine(tiny_fp32):
+    """The overlapped round (group B's draft chain under group A's verify:
+    the only round there is) emits the same bytes as the non-speculative
+    engine (spec is a throughput knob, never a correctness one)."""
     params, cfg, dp, dc = tiny_fp32
     prompts = [list(range(10 + j, 31 + j)) for j in range(SLOTS)]
 
-    monkeypatch.setenv("MODAL_TPU_SPEC_OVERLAP", "0")
-    seq, st_seq = _run_spec_batch(params, cfg, (dp, dc), prompts)
-    monkeypatch.setenv("MODAL_TPU_SPEC_OVERLAP", "1")
     ovl, st_ovl = _run_spec_batch(params, cfg, (dp, dc), prompts)
-    assert ovl == seq
-    assert st_seq["spec_overlap"] is False and st_ovl["spec_overlap"] is True
+    assert st_ovl["spec_rounds"] > 0 and "spec_overlap" not in st_ovl
 
     plain_eng = _engine(params, cfg).start()
     try:
@@ -352,7 +347,8 @@ def test_spec_mode_keeps_the_prefix_cache_and_reuses_draft_pages(tiny_fp32):
     params, cfg, dp, dc = tiny_fp32
     eng = _engine(params, cfg, draft=(dp, dc), spec_k=2).start()
     try:
-        assert eng.prefix_cache is not None and eng.draft_prefix_cache is not None
+        assert eng.pages.prefix_cache is not None and eng.draft_pages.prefix_cache is not None
+        assert eng.pages.prefix_cache.partial_pages and not eng.draft_pages.prefix_cache.partial_pages
         a = eng.submit(PROMPT, 10).result(timeout=180)
         b = eng.submit(PROMPT, 10).result(timeout=180)
         assert a == b
@@ -561,12 +557,10 @@ def test_router_knob_is_cataloged_with_the_fleet_knobs():
     for knob in (
         "MODAL_TPU_SERVING_ROUTER",
         "MODAL_TPU_SERVING_ROLE",
-        "MODAL_TPU_SPEC_OVERLAP",
         "MODAL_TPU_CHAOS_KV_SHIP_DROP",
     ):
         assert knob in KNOB_CATALOG, knob
     assert KNOB_CATALOG["MODAL_TPU_SERVING_ROUTER"].feature_gate
-    assert KNOB_CATALOG["MODAL_TPU_SPEC_OVERLAP"].feature_gate
 
 
 # ---------------------------------------------------------------------------

@@ -38,19 +38,29 @@ def prompts_of(seed, lengths):
     return [[int(x) for x in rng.integers(0, 512, size=n)] for n in lengths]
 
 
+def pages_of(model, **overrides):
+    """The page manager alone (serving/pages.py): no engine, no loop thread."""
+    from modal_tpu.serving.pages import ModelPages
+
+    kwargs = dict(max_slots=3, page_size=PAGE, prefill_chunk=CHUNK, num_pages=120)
+    kwargs.update(overrides)
+    return ModelPages(model[1], **kwargs)
+
+
 def test_a_decoding_slot_s_window_row_never_exceeds_the_window_and_one_page(model):
     engine = engine_of(model)
+    pages = engine.pages
     seen = {"decode": 0, "prefill": 0}
-    grow = engine._grow_window_pages
+    reserve_decode = engine._reserve_decode
 
     def watched():
-        ok = grow()
-        for s in engine.slots:
+        decoding = reserve_decode()
+        for i, s in enumerate(engine.slots):
             if s is not None:
-                seen[s.state] = max(seen[s.state], len(s.window_pages))
-        return ok
+                seen[s.state] = max(seen[s.state], len(pages.window_pages[i]))
+        return decoding
 
-    engine._grow_window_pages = watched
+    engine._reserve_decode = watched
     engine.start()
     try:
         handles = [engine.submit(p, 40) for p in prompts_of(1, (70, 5, 30, 100, 17))]
@@ -67,53 +77,94 @@ def test_a_decoding_slot_s_window_row_never_exceeds_the_window_and_one_page(mode
     # the window pool is bounded by the window, not by the context: 5 layers x 14 pages (3 slots x 3 + a chunk's 4 + scratch)
     assert stats["kv_window_pool_bytes"] == 5 * 14 * PAGE * 4 * (24 + 16) * 2
     assert stats["kv_pool_bytes"] == 2 * 120 * PAGE * 2 * (24 + 16) * 2
-    assert engine.window_allocator.free_pages == engine.window_allocator.num_pages - 1  # everything came back
-    assert engine.allocator.free_pages == engine.allocator.num_pages - 1
+    assert pages.window_allocator.free_pages == pages.window_allocator.num_pages - 1  # everything came back
+    assert pages.free_pages == pages.total_pages
+    assert pages.pages == [[], [], []] and pages.window_pages == [[], [], []]
 
 
 def test_a_page_behind_the_window_goes_to_another_slot_at_once(model):
-    from modal_tpu.serving.engine import GenRequest, _Slot
-
-    engine = engine_of(model)  # not started: the bookkeeping alone
-    a, b = _Slot(request=GenRequest([1], 1)), _Slot(request=GenRequest([1], 1))
-    assert engine._window_reserve([(0, a, 15)]) and len(a.window_pages) == 4  # positions 0..15
-    first_pages = list(a.window_pages)
-    engine._window_release(a, 16)  # the next query, at 16, sees 9..16: pages 0 and 1 are dead
-    assert (a.window_first, a.window_pages) == (2, first_pages[2:]) and engine.window_pages_released == 2
-    free_before = engine.window_allocator.free_pages
-    assert engine._window_reserve([(1, b, 7)])
-    assert sorted(b.window_pages) == sorted(first_pages[:2])  # the very pages a gave back
-    assert engine.window_allocator.free_pages == free_before - 2
+    pages = pages_of(model)  # the bookkeeping alone
+    a, b = 0, 1
+    assert pages.reserve([(a, 0, 15)]) and len(pages.window_pages[a]) == 4  # positions 0..15
+    first_pages = list(pages.window_pages[a])
+    pages.trim(a, 16)  # the next query, at 16, sees 9..16: pages 0 and 1 are dead
+    assert (pages.window_first[a], pages.window_pages[a]) == (2, first_pages[2:]) and pages.window_pages_released == 2
+    free_before = pages.window_allocator.free_pages
+    assert pages.reserve([(b, 0, 7)])
+    assert sorted(pages.window_pages[b]) == sorted(first_pages[:2])  # the very pages a gave back
+    assert pages.window_allocator.free_pages == free_before - 2
     # the device's table: a's stale entries stay (never addressed), b's row points at the same pages
-    table = np.asarray(engine.cache.window_table)
-    assert list(table[0, :4]) == first_pages and list(table[1, :2]) == b.window_pages
-    # a keeps decoding: one more page at 16, and never more than the bound
+    table = np.asarray(pages.cache.window_table)
+    assert list(table[a, :4]) == first_pages and list(table[b, :2]) == pages.window_pages[b]
+    # a keeps decoding: one more page at 16, and never more than the bound; the
+    # pool that grows with the context keeps every page meanwhile
     for pos in range(16, 60):
-        engine._window_release(a, pos)
-        assert engine._window_reserve([(0, a, pos)])
-        assert len(a.window_pages) <= WINDOW_SLOT_PAGES
-    engine._free_slot_pages(a)
-    engine._free_slot_pages(b)
-    assert engine.window_allocator.free_pages == engine.window_allocator.num_pages - 1
+        assert pages.reserve([(a, pos, pos)])  # gives back what the query at pos no longer sees, then takes its page
+        assert len(pages.window_pages[a]) <= WINDOW_SLOT_PAGES
+        assert len(pages.pages[a]) == pos // PAGE + 1
+    assert list(np.asarray(pages.cache.page_table)[a, :15]) == pages.pages[a]
+    pages.release(a)
+    pages.release(b)
+    assert pages.window_allocator.free_pages == pages.window_allocator.num_pages - 1
+    assert pages.free_pages == pages.total_pages
+    assert not np.asarray(pages.cache.window_table).any() and not np.asarray(pages.cache.page_table).any()
 
 
 @pytest.mark.parametrize("pool", ["full", "window"])
 def test_admission_waits_while_either_pool_lacks_room(model, pool):
     engine = engine_of(model, max_slots=2)  # not started: _admit by hand
-    allocator = engine.allocator if pool == "full" else engine.window_allocator
+    pages = engine.pages
+    allocator = pages.allocator if pool == "full" else pages.window_allocator
     taken = allocator.alloc(allocator.free_pages)  # the pool is dry
     req = engine.submit(prompts_of(2, (20,))[0], 8)
     engine._admit()
     assert engine.slots == [None, None] and list(engine.waiting) == [req]
-    other = engine.window_allocator if pool == "full" else engine.allocator
+    other = pages.window_allocator if pool == "full" else pages.allocator
     assert other.free_pages == other.num_pages - 1  # nothing was taken from the pool that had room
     allocator.free(taken)
     engine._admit()
     assert engine.slots[0] is not None and not engine.waiting
-    assert engine.slots[0].window_pages == []  # its chunk's pages come when its chunk runs
+    assert len(pages.pages[0]) == 6 and pages.window_pages[0] == []  # its chunk's pages come when its chunk runs
     engine._prefill_one()
-    assert len(engine.slots[0].window_pages) == WINDOW // PAGE  # 16 of 20 tokens in: what the query at 16 still sees
-    engine._free_slot_pages(engine.slots[0])
+    assert len(pages.window_pages[0]) == WINDOW // PAGE  # 16 of 20 tokens in: what the query at 16 still sees
+    pages.release(0)
+
+
+@pytest.mark.parametrize("pool", ["full", "window"])
+def test_the_manager_alone_says_whether_an_admission_fits(model, pool):
+    pages = pages_of(model, max_slots=2)
+    allocator, other = (pages.allocator, pages.window_allocator)[:: 1 if pool == "full" else -1]
+    taken = allocator.alloc(allocator.free_pages - 1)  # one page left: no prompt of 20 tokens, no first chunk
+    assert pages.lookup(list(range(20))) is None  # window layers: no prefix is cached
+    assert not pages.can_admit(20, None)
+    assert other.free_pages == other.num_pages - 1 and pages.pages == [[], []]
+    allocator.free(taken)
+    assert pages.can_admit(20, None) and pages.admit(1, 20, None) == 0
+    assert len(pages.pages[1]) == 6 and pages.window_pages[1] == []  # 20 tokens and the first new one's position
+    assert list(np.asarray(pages.cache.page_table)[1, :7]) == pages.pages[1] + [0]
+    assert pages.window_allocator.free_pages == pages.window_allocator.num_pages - 1  # asked, not taken
+    pages.release(1)
+    assert pages.free_pages == pages.total_pages
+
+
+@pytest.mark.parametrize("dry", ["full", "window"])
+def test_reserve_is_all_or_none_over_both_pools_and_every_slot(model, dry):
+    pages = pages_of(model, max_slots=2)
+    assert pages.admit(0, 8, None) == 0 and pages.admit(1, 8, None) == 0  # 3 pages each: positions 0..11
+    wants = [(0, 0, 15), (1, 0, 15)]  # each: a 4th page of the pool that grows, 4 of the window pool
+    allocator = pages.allocator if dry == "full" else pages.window_allocator
+    taken = allocator.alloc(allocator.free_pages - (1 if dry == "full" else 7))  # room for one slot's, not for both
+    free = (pages.free_pages, pages.window_allocator.free_pages)
+    assert not pages.reserve(wants)
+    assert (pages.free_pages, pages.window_allocator.free_pages) == free  # no page was handed out, in either pool
+    assert [len(p) for p in pages.pages] == [3, 3] and pages.window_pages == [[], []]
+    assert pages.reserve(wants[:1])  # one slot's does fit
+    assert len(pages.pages[0]) == 4 and len(pages.window_pages[0]) == 4 and pages.window_pages[1] == []
+    allocator.free(taken)
+    assert pages.reserve(wants)
+    assert [len(p) for p in pages.pages] == [4, 4] and [len(p) for p in pages.window_pages] == [4, 4]
+    table = np.asarray(pages.cache.window_table)
+    assert [list(table[i, :4]) for i in (0, 1)] == pages.window_pages
 
 
 def test_preemption_frees_both_pools_and_the_streams_do_not_change(model):
@@ -132,7 +183,8 @@ def test_preemption_frees_both_pools_and_the_streams_do_not_change(model):
         tight.stop()
     assert stats["preemptions"] > 0
     assert got == want  # a preempted request re-prefills through both pools and loses no token
-    assert tight.allocator.free_pages == 44 and tight.window_allocator.free_pages == tight.window_allocator.num_pages - 1
+    assert tight.pages.free_pages == 44
+    assert tight.pages.window_allocator.free_pages == tight.pages.window_allocator.num_pages - 1
 
 
 def test_a_dry_window_pool_preempts_and_still_finishes_every_request(model):
@@ -145,7 +197,7 @@ def test_a_dry_window_pool_preempts_and_still_finishes_every_request(model):
         assert engine.stats()["preemptions"] > 0
     finally:
         engine.stop()
-    assert engine.window_allocator.free_pages == smallest + 1
+    assert engine.pages.window_allocator.free_pages == smallest + 1
     with pytest.raises(ValueError, match="cannot hold one prefill chunk"):
         engine_of(model, window_num_pages=smallest)
 
@@ -205,7 +257,7 @@ def test_shipping_pages_and_the_verify_step_say_why_they_cannot(model):
     with pytest.raises(ValueError, match="ONE pool"):
         engine.submit_prefilled([1, 2, 3], {"prompt": [1, 2, 3]})
     with pytest.raises(ValueError, match="one pool of one layer kind"):
-        pk.paged_verify_step(params, cfg, jnp.zeros((3, 2), jnp.int32), engine.cache, jnp.ones((3,), bool))
+        pk.paged_verify_step(params, cfg, jnp.zeros((3, 2), jnp.int32), engine.pages.cache, jnp.ones((3,), bool))
     with pytest.raises(ValueError, match="needs window_num_pages"):
         pk.PagedKVCache.create(cfg, 2, 16, PAGE)
 
@@ -266,19 +318,35 @@ def test_the_decode_kernel_matches_the_gather_path_with_a_window_a_sink_and_two_
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=0)
 
 
-def test_stats_of_a_dense_model_carry_no_second_pool(model):
+# what /v1/stats says of pages, a model: the benchmark's readers and `modal_tpu top` go by these names
+PAGE_KEYS = {
+    "kv_pages_total", "kv_pages_allocated", "kv_pages_free", "kv_pages_high_water", "kv_pool_bytes",
+    "kv_pages_cow_copies", "kv_pages_shipped", "kv_ship_drops",
+    "prefix_cache_entries", "prefix_cache_pages", "prefix_cache_hits", "prefix_cache_misses",
+    "draft_prefix_cache_entries", "draft_prefix_cache_hits",
+}
+WINDOW_KEYS = {"kv_window_pages_total", "kv_window_pages_high_water", "kv_window_pages_released", "kv_window_pool_bytes"}
+
+
+@pytest.mark.parametrize("preset", ["tiny", "tiny-mimo"])
+def test_stats_of_a_dense_model_carry_no_second_pool(model, preset):
     import jax
 
     from modal_tpu.models.llama import get_config, init_params
     from modal_tpu.serving.engine import ServingEngine
 
-    cfg = get_config("tiny")
-    stats = ServingEngine(init_params(cfg, jax.random.PRNGKey(0)), cfg).stats()
-    assert not [k for k in stats if k.startswith("kv_window") or k == "moe"]
-    assert stats["kv_pool_bytes"] > 0
-    two = engine_of(model).stats()
-    assert {"kv_window_pages_total", "kv_window_pages_high_water", "kv_window_pages_released", "kv_window_pool_bytes", "moe"} <= set(two)
-    assert two["moe"] == {"assignments": 0, "local_assignments": 0, "expert_calls": 0}
+    if preset == "tiny":
+        cfg = get_config("tiny")
+        stats = ServingEngine(init_params(cfg, jax.random.PRNGKey(0)), cfg).stats()
+        assert not [k for k in stats if k.startswith("kv_window") or k == "moe"]
+        assert stats["kv_pool_bytes"] > 0
+    else:
+        stats = engine_of(model).stats()
+        assert WINDOW_KEYS | {"moe"} <= set(stats)
+        assert stats["moe"] == {"assignments": 0, "local_assignments": 0, "expert_calls": 0}
+    paged = {k for k in stats if k.startswith(("kv_", "prefix_cache_", "draft_prefix_cache_"))}
+    assert paged == PAGE_KEYS | (WINDOW_KEYS if preset == "tiny-mimo" else set())
+    assert {"preemptions", "requests_admitted", "loop", "spec_k", "attn_impl"} <= set(stats) and "spec_overlap" not in stats
 
 
 def test_llm_service_takes_the_second_pool_s_size_and_hands_it_to_the_engine():

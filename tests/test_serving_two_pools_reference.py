@@ -86,11 +86,12 @@ def paged_logits(params, cfg, tokens, n_prompt, impl):
     import jax.numpy as jnp
 
     from modal_tpu.models import paged_kv as pk
+    from modal_tpu.serving.pages import PageAllocator
 
     pages_per_slot, window_pool = 24, pk.default_window_num_pages(cfg, 1, PAGE, CHUNK) if cfg.has_window else None
     cache = pk.PagedKVCache.create(cfg, 2, 40, PAGE, pages_per_slot, window_pool)
     cache = pk.assign_pages(cache, 1, 0, jnp.arange(10, 10 + pages_per_slot, dtype=jnp.int32))
-    pool = pk.PageAllocator(window_pool, PAGE) if cfg.has_window else None
+    pool = PageAllocator(window_pool, PAGE) if cfg.has_window else None
     held, high = {}, 0  # row index -> page of the window pool
 
     def window_row(first_pos, last_pos):
