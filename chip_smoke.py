@@ -23,7 +23,10 @@ container owns its chip until it exits:
   boot 2   the same again in a fresh container: its step programs must come
            from the persistent compile cache (the cache directory is stable)
   parity   in a container on the chip: one decode step's logits with the
-           Pallas kernel against the gather path on the same cache and tokens
+           Pallas kernel against the gather path on the same cache and tokens,
+           at a benchmark cell's engine geometry (32 slots x 512 pages, ragged
+           lengths, a slot that holds a prompt and does not decode, 20 idle)
+           and 12 layers (the gather path's temporaries need the room)
 
 This process never imports jax — a process that has touched jax holds the
 chip and its containers could not. Everything about the device is read from
@@ -64,6 +67,12 @@ STATE_DIR = os.path.join(REPO_ROOT, ".modal_tpu_state", "chip_smoke")
 # and its successor at once (my chip run, PR 21: a 4 GiB pool ran out of HBM
 # on the first step, 3 GiB and 2 GiB ran).
 CHIP_MODEL = {"name": "llama3-8b", "n_layers": 16}
+# the parity phase runs the decode step at a benchmark cell's engine geometry (32 slots x 512 pages),
+# where the GATHER path it compares with makes 5 GB of span-wide temporaries: 12 layers (6.4 GiB of
+# weights, a 2.25 GiB pool; 9.8 GB in use after the phase) leave it room. Not fewer: the two paths'
+# logits differ by 0.159 at 8 layers and 0.182 at 12 (my chip runs, PR 33) against tolerances of
+# 0.162 and 0.205: the difference shrinks more slowly with the depth than the tolerance below does
+PARITY_MODEL = {"name": "llama3-8b", "n_layers": 12}
 CHIP_WIDTHS = {"dim": 4096, "n_heads": 32, "n_kv_heads": 8, "head_dim": 128, "ffn_dim": 14336, "vocab_size": 128256, "dtype": "bfloat16"}
 # the trainer keeps weights, grads and both Adam moments (8 bytes a
 # parameter): tried deepest first, each attempt in a fresh container
@@ -284,20 +293,28 @@ def parity_in_container(model, seed: int) -> dict:
     kernel_impl = "kernel" if on_tpu else "kernel_interpret"
     cfg = get_config(model)
     params = init_params(cfg, jax.random.PRNGKey(seed))
-    # the ServingEngine's default geometry for max_slots=8 (serving/engine.py)
-    slots, page = 8, pk.DEFAULT_PAGE_SIZE
+    # the engine geometry of a benchmark cell (mistral-7b.chat-saturated: 32 slots, 512 pages of 16
+    # tokens a slot, a pool of 3,072 pages), not a toy one; `tiny` (--cpu) keeps the slots and
+    # scales the rest to its context
+    slots, page = 32, pk.DEFAULT_PAGE_SIZE
     pps = _math.ceil(cfg.max_seq_len / page)
-    num_pages = 1 + max(2 * slots, (slots * pps) // 2)
+    num_pages = 1 + min(3071, (slots * pps) // 2)
     cache = pk.PagedKVCache.create(cfg, slots, num_pages, page, pps)
     alloc = PageAllocator(num_pages, page)
     rng = np.random.default_rng(seed)
-    # four of eight slots live, lengths that end mid-page, on a page boundary
-    # and after several prefill chunks; the rest stay inactive (scratch page)
-    lengths = [min(300, cfg.max_seq_len - 70), 17, 130, 64]
+    # (prompt length, decodes this step): ragged, one slot far longer than the rest, lengths that
+    # end mid-page, on a page boundary, on the kernel's block boundary (256 positions at these
+    # widths: the query at 255, 256 and 511) and after several prefill chunks; slot 7 holds a
+    # prompt and does not decode (half prefilled, as the engine sees it); the other 20 slots hold
+    # nothing and stay idle (scratch page)
+    held = [(1100, True), (17, True), (130, True), (64, True), (511, True), (256, True), (255, True), (300, False),
+            (700, True), (33, True), (15, True), (16, True)]
+    held = [(min(n, cfg.max_seq_len - 70), decodes) for n, decodes in held]
+    lengths = [n for n, _decodes in held]
     tokens = np.zeros((slots,), np.int32)
     active = np.zeros((slots,), bool)
     prefill_finite = True
-    for slot, n in enumerate(lengths):
+    for slot, (n, decodes) in enumerate(held):
         pages = alloc.alloc(alloc.pages_for(n + 1))
         row = pages + [0] * (pps - len(pages))
         cache = pk.assign_pages(cache, slot, 0, jnp.asarray(row, jnp.int32))
@@ -310,7 +327,7 @@ def parity_in_container(model, seed: int) -> dict:
                 params, cfg, jnp.asarray(padded), jnp.int32(len(chunk)), cache, jnp.int32(slot), jnp.int32(start)
             )
         prefill_finite = prefill_finite and bool(jnp.isfinite(logits).all())
-        tokens[slot], active[slot] = int(next_tok), True
+        tokens[slot], active[slot] = int(next_tok), decodes
     tokens_j, active_j = jnp.asarray(tokens), jnp.asarray(active)
     start_lens = jnp.asarray(np.asarray(cache.seq_lens))
 
@@ -364,6 +381,7 @@ def parity_in_container(model, seed: int) -> dict:
         },
         "n_layers": cfg.n_layers,
         "kernel_impl": kernel_impl,
+        "geometry": {"slots": slots, "pages_per_slot": pps, "pool_pages": num_pages, "decoding": int(active.sum())},
         "slot_lengths": lengths,
         "logits_max_abs_diff": round(max_abs_diff, 5),
         "logits_rms": round(rms, 4),
@@ -434,11 +452,11 @@ def run_one_chip(cpu: bool) -> dict:
             f"boot2 reports {hits2} persistent-cache hits after boot1 wrote {misses1} entries: "
             "the compile cache directory is not stable between containers"
         )
-    parity = run_parity(model, want, timeout_s=700)
+    parity = run_parity(CPU_MODEL if cpu else PARITY_MODEL, want, timeout_s=700)
     if not cpu and parity["widths"] != CHIP_WIDTHS:
         raise PhaseFailed(f"widths are not Llama-3-8B's: {parity['widths']}")
     return {
-        "model": {"name": "llama3-8b" if not cpu else CPU_MODEL, **parity["widths"], "n_layers": parity["n_layers"]},
+        "model": {"name": "llama3-8b" if not cpu else CPU_MODEL, **parity["widths"], "n_layers": parity["n_layers"] if cpu else CHIP_MODEL["n_layers"]},
         "served": {"boot1": boot1, "boot2": boot2},
         "compile_seconds": {"boot1": compile_s(boot1), "boot2": compile_s(boot2)},
         "persistent_cache_hits_boot2": hits2,

@@ -307,8 +307,10 @@ def _paged_attention(
     CPU's decode step, every verify step, and what the prefill loop is tested
     against); "kernel" / "kernel_interpret" stream pages HBM→VMEM with the
     Pallas decode kernel (ops/paged_attention.py) — decode only (Sq == 1,
-    `positions` = each slot's token position); the verify step's multi-token
-    call always takes the gather path.
+    `positions` = each slot's token position, negative where the slot does
+    not decode this step: the kernel walks nothing for it and its row is
+    zeros no caller reads); the verify step's multi-token call always takes
+    the gather path.
 
     What a layer kind adds, each off where the dense models leave it: values
     narrower than keys (v_pages' own last dimension); `scale` where q and the
@@ -677,10 +679,13 @@ def paged_decode_step(
         behind = kv_pos <= positions[:, None, None, None] - cfg.window
         masks.append(jnp.where(behind, -jnp.inf, mask))
     scale = 1.0 / math.sqrt(cfg.head_dim)
+    # the kernel walks the live pages of the slots that decode and nothing for the rest: a slot
+    # whose prompt is half prefilled has a length and no query this step
+    decoding = jnp.where(active, positions, -1)
 
     def attend(kind, q, k_pages, v_pages, table, sink):
         return _paged_attention(
-            q, k_pages, v_pages, table, masks[bool(kind.window)], positions, attn_impl,
+            q, k_pages, v_pages, table, masks[bool(kind.window)], decoding, attn_impl,
             window=kind.window, sink=sink, scale=scale, kernel_name=_kernel_name(kind),
         )
 

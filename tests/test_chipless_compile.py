@@ -4,9 +4,11 @@ described and not attached (the TPU's compiler is installed where the tests
 run). Nothing runs, so this says nothing about results or times: it guards
 that Mosaic still takes the kernel as written, that the kernel and the two
 programs keep their stable names, that nothing of the slot span's size is
-left in a prefill chunk, and that nothing of a KV pool's or of one layer's
+left in a prefill chunk, that nothing of a KV pool's or of one layer's
 pool's size is made in either step but the in-place scatter of the new rows
-(the layer loop carries the pool: `paged_kv._run_layers`).
+(the layer loop carries the pool: `paged_kv._run_layers`), and that the
+decode step holds one small Mosaic kernel a layer group (a kernel unrolled
+over pages or heads costs seconds at every boot: PERF.md section 6, PR 33).
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process may load the TPU's library, each xdist worker imports every
@@ -240,11 +242,13 @@ TWO_WIDTH_KERNELS = {
 
 @pytest.mark.parametrize("stored", [256, 192], ids=["keys-stored-256", "keys-192-as-published"])
 @pytest.mark.parametrize("kind", sorted(TWO_WIDTH_KERNELS))
-def test_the_two_width_decode_kernels_compile_for_v5e_and_only_padded_keys_spare_the_pool_a_copy(kind, stored, one_chip, no_compile_cache):
-    """Mosaic takes keys wider than values, a window and a sink as written.
-    With keys stored 192 wide XLA lays the pool out pages-innermost and hands
-    the kernel a transposed COPY of it at every call; stored 256 wide
-    (`paged_kv.k_cache_dim`) the pool goes in as it lies."""
+def test_the_two_width_decode_kernels_compile_for_v5e_and_only_padded_keys_go_in_as_they_lie(kind, stored, one_chip, no_compile_cache):
+    """Mosaic takes keys wider than values, a window and a sink as written,
+    and stored 256 wide (`paged_kv.k_cache_dim`) the pool goes in as it lies:
+    no copy of it in the program. Stored 192 wide a page is no whole number
+    of 128-lane tiles: the kernel's page copies are refused (the grid kernel
+    before PR 33 compiled, and XLA handed it a transposed COPY of the whole
+    pool at every call): why keys are stored padded."""
     import jax
     import jax.numpy as jnp
 
@@ -268,7 +272,32 @@ def test_the_two_width_decode_kernels_compile_for_v5e_and_only_padded_keys_spare
     def call(q, k, v, table, lens, sinks=None):
         return paged_decode_attention(q, k, v, table, lens, window=window, sink=sinks, scale=192**-0.5, name=name)
 
+    if stored == 192:
+        with pytest.raises(Exception, match=r"must be aligned to tiling \(128\), but is 192"):
+            jax.jit(call).lower(*args).compile()
+        return
     text = jax.jit(call).lower(*args).compile().as_text()
-    assert re.search(rf"%{name}[.\d]* = bf16\[128,{n_kv},{n_rep},128\]", text), "the kernel lost its name or its output width"
-    pool_copies = re.findall(rf"= bf16\[{pool},16,{n_kv},{stored}\]\S* copy\(", text)
-    assert bool(pool_copies) == (stored == 192)
+    # the kernel takes and gives the query heads as one dimension (the wrapper's reshapes are bitcasts)
+    assert re.search(rf"%{name}[.\d]* = bf16\[128,{n_kv * n_rep},128\]", text), "the kernel lost its name or its output width"
+    assert not re.findall(rf"= bf16\[{pool},16,{n_kv},{stored}\]\S* copy\(", text)
+
+
+# the serialized Mosaic module of one kernel call in the lowered step, in characters of its line: the
+# kernel as written reads 20,808 (dense) to 23,916 (a window and a sink); the grid kernel before it
+# read 9,962 to 12,768. Loops over blocks, pages and chunks are rolled (`fori_loop`); unrolled over the
+# 16 pages of a block or the 8 KV heads the module grows several times over, and every boot pays for
+# tracing, lowering and compiling it, cache or no cache (PERF.md section 6, PR 33, step 1)
+MOSAIC_CALL_CHARS_AT_MOST = 48_000
+
+
+@pytest.mark.parametrize("config", sorted(CELL_SHAPES) + ["mimo-v2-flash-serve-1chip-ep16"])
+def test_the_decode_step_holds_one_small_mosaic_kernel_a_layer_group(config, one_chip):
+    """`paged_decode_step` lowered at a cell's real shapes (nothing compiled):
+    one Mosaic call a layer group (the scan's body is traced once), each under
+    the size the finished kernel reads with headroom, so a rewrite that unrolls
+    its pages or heads in Python, or compiles a variant a bucket of live
+    length, fails here and not as seconds of a boot on the chip."""
+    cfg, _params, _cache, lowered = lower_step("paged_decode_step", config, one_chip)
+    calls = [line for line in lowered.as_text().splitlines() if "@tpu_custom_call" in line]
+    assert len(calls) == len(cfg.layer_groups), (len(calls), cfg.layer_groups)
+    assert all(5_000 < len(line) < MOSAIC_CALL_CHARS_AT_MOST for line in calls), [len(line) for line in calls]

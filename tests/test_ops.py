@@ -162,30 +162,145 @@ def test_flash_attention_tpu_compiled_at_vmem_budget_edge():
     assert np.isfinite(np.asarray(g, np.float32)).all()
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu", reason="TPU-compiled path needs a real chip")
-def test_paged_decode_attention_tpu_compiled_equivalence():
-    """The COMPILED paged decode kernel against the gather reference at
-    Llama-3-8B's head geometry (8 KV heads x 4 query heads of 128, pages of
-    16 bf16 rows), over a shuffled page table and ragged slot lengths."""
-    from modal_tpu.models.paged_kv import _paged_attention
+PAGE = 16
+
+
+def paged_problem(seed, slots, pages_per_slot, n_kv, n_rep, hd, vd, dtype, sink=False):
+    """(q, k_pages, v_pages, table, sinks): a pool of noise, every slot's row a
+    run of pages of its own in no order (page 0, the scratch page, in no row)."""
+    pool = slots * pages_per_slot + 1
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(keys[0], (slots, n_kv, n_rep, hd), dtype)
+    k_pages = jax.random.normal(keys[1], (pool, PAGE, n_kv, hd), dtype)
+    v_pages = jax.random.normal(keys[2], (pool, PAGE, n_kv, vd), dtype)
+    table = (jax.random.permutation(keys[3], pool - 1) + 1).reshape(slots, pages_per_slot).astype(jnp.int32)
+    sinks = jax.random.normal(keys[4], (n_kv, n_rep), jnp.float32) if sink else None
+    return q, k_pages, v_pages, table, sinks
+
+
+def paged_reference(q, k_pages, v_pages, table, positions, window=0, sinks=None, scale=None):
+    """The dense float32 reference of one decode step's attention, a slot at a
+    time on the host: the slot's live positions gathered through its row, one
+    softmax (a sink: one more column in the denominator); zeros for a slot
+    that does not decode (a negative position)."""
+    q, k_pages, v_pages = (np.asarray(a.astype(jnp.float32)) for a in (q, k_pages, v_pages))
+    table, positions = np.asarray(table), np.asarray(positions)
+    scale = scale or 1.0 / math.sqrt(q.shape[-1])
+    out = np.zeros(q.shape[:-1] + (v_pages.shape[-1],), np.float32)
+    for s, pos in enumerate(positions):
+        if pos < 0:
+            continue
+        live = np.arange(max(pos - window + 1, 0) if window else 0, pos + 1)
+        k = k_pages[table[s, live // PAGE], live % PAGE]  # [T, n_kv, hd]
+        v = v_pages[table[s, live // PAGE], live % PAGE]
+        logits = np.einsum("knd,tkd->knt", q[s], k) * scale
+        top = logits.max(-1, keepdims=True) if sinks is None else np.maximum(logits.max(-1, keepdims=True), np.asarray(sinks)[..., None])
+        weights = np.exp(logits - top)
+        total = weights.sum(-1, keepdims=True) + (0.0 if sinks is None else np.exp(np.asarray(sinks)[..., None] - top))
+        out[s] = np.einsum("knt,tkd->knd", weights / total, v)
+    return out
+
+
+# a block of 4 pages (the module's size cut to these widths: 2 KV heads, float32), so a row of 12
+# pages is three blocks and block 0 holds positions 0-63.
+# (positions a slot; negative: the slot does not decode), with what the case adds
+PAGED_DECODE_CASES = {
+    "ragged-one-slot-far-longer-than-the-rest": dict(positions=[3, 190, 17, 40, 9]),
+    "on-page-and-block-boundaries": dict(positions=[15, 16, 31, 32, 63, 64, 127, 128, 191]),
+    "a-slot-that-does-not-decode": dict(positions=[70, -1, 100, 5], idle_slot=1),
+    "every-slot-idle": dict(positions=[-1, -1, -1]),
+    "window-whose-first-live-page-is-not-the-first-with-a-sink": dict(positions=[190, 100, 39, -1, 64, 47], window=40, sink=True),
+    "window-shorter-than-a-page-with-a-sink": dict(positions=[0, 22, 96], window=5, sink=True),
+    "keys-wider-than-values": dict(positions=[5, 77, 130], hd=48, vd=16),
+    "eight-query-heads-a-kv-head": dict(positions=[64, 1, 150], n_rep=8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_DECODE_CASES))
+def test_paged_decode_attention_matches_the_float32_reference(case, monkeypatch):
+    """The paged decode kernel (interpret mode) against the dense float32
+    reference: blocks smaller than a row, so slots end inside a block, on a
+    block's last position and on the next one's first; a page behind a window
+    or past a slot's position is never addressed (its table entry names no
+    page of the pool)."""
+    from modal_tpu.ops import paged_attention
+
+    spec = dict(n_rep=2, hd=24, vd=24, window=0, sink=False, idle_slot=None) | PAGED_DECODE_CASES[case]
+    n_kv, pages_per_slot = 2, 12
+    page_bytes = PAGE * n_kv * (spec["hd"] + spec["vd"]) * 4
+    monkeypatch.setattr(paged_attention, "BLOCK_BYTES", 4 * page_bytes)
+    positions = np.asarray(spec["positions"], np.int32)
+    q, k_pages, v_pages, table, sinks = paged_problem(
+        len(case), len(positions), pages_per_slot, n_kv, spec["n_rep"], spec["hd"], spec["vd"], jnp.float32, spec["sink"]
+    )
+    # what a served row holds outside a slot's live pages is stale: name no page of the pool there
+    first_live = np.maximum(positions - (spec["window"] - 1), 0) // PAGE if spec["window"] else np.zeros_like(positions)
+    pages = np.arange(pages_per_slot)[None, :]
+    dead = (pages < first_live[:, None]) | (pages > positions[:, None] // PAGE) | (positions[:, None] < 0)
+    table_served = jnp.where(jnp.asarray(dead), 1_000_000, table)
+
+    def run(rows, at):
+        return np.asarray(paged_attention.paged_decode_attention(
+            q, k_pages, v_pages, rows, jnp.asarray(at), window=spec["window"], sink=sinks, scale=0.2, interpret=True,
+        ))
+
+    got = run(table_served, positions)
+    want = paged_reference(q, k_pages, v_pages, table, positions, spec["window"], sinks, 0.2)
+    assert got.shape == want.shape and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+    assert not got[positions < 0].any()  # a slot that does not decode: zeros, whatever its row holds
+    if spec["idle_slot"] is not None:
+        # its neighbours' rows are the same to the bit whether it decodes or not
+        other = positions.copy()
+        other[spec["idle_slot"]] = 150
+        decoding = np.arange(len(positions)) != spec["idle_slot"]
+        assert np.array_equal(run(table, other)[decoding], got[decoding])
+
+
+def test_paged_decode_attention_at_a_cell_s_page_bytes_in_bfloat16():
+    """The sizes the module ships with, at the dense cells' head geometry (8 KV
+    heads x 4 query heads of 128, bfloat16 pages of 64 KB): blocks of 16 pages
+    over a row of 40, slots that end inside the first block, on a block's last
+    position, on the next one's first and in the third block, one idle."""
     from modal_tpu.ops.paged_attention import paged_decode_attention
 
-    slots, pps, n_kv, n_rep, hd, page = 8, 64, 8, 4, 128, 16
-    pages = slots * pps + 1
+    positions = np.asarray([9, 255, -1, 639, 256], np.int32)
+    q, k_pages, v_pages, table, _ = paged_problem(3, len(positions), 40, 8, 4, 128, 128, jnp.bfloat16)
+    got = np.asarray(paged_decode_attention(q, k_pages, v_pages, table, jnp.asarray(positions), interpret=True).astype(jnp.float32))
+    want = paged_reference(q, k_pages, v_pages, table, positions)
+    np.testing.assert_allclose(got, want, atol=3e-2, rtol=0)
+    assert not got[2].any()
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu", reason="TPU-compiled path needs a real chip")
+def test_paged_decode_attention_tpu_compiled_equivalence():
+    """The COMPILED paged decode kernel against the dense float32 reference at
+    a benchmark cell's real geometry (mistral-7b.chat-saturated: 32 slots x 512
+    pages of 16 bf16 rows, 8 KV heads x 4 query heads of 128, a pool of 3,072
+    pages), over a shuffled page table, ragged lengths from one token to most
+    of the row, on page and block boundaries, and some slots idle."""
+    from modal_tpu.ops.paged_attention import paged_decode_attention
+
+    slots, pps, n_kv, n_rep, hd, pool = 32, 512, 8, 4, 128, 3072
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(kq, (slots, n_kv, n_rep, hd), jnp.bfloat16)
-    k_pages = jax.random.normal(kk, (pages, page, n_kv, hd), jnp.bfloat16)
-    v_pages = jax.random.normal(kv, (pages, page, n_kv, hd), jnp.bfloat16)
+    k_pages = jax.random.normal(kk, (pool, PAGE, n_kv, hd), jnp.bfloat16)
+    v_pages = jax.random.normal(kv, (pool, PAGE, n_kv, hd), jnp.bfloat16)
     rng = np.random.default_rng(5)
-    table = jnp.asarray(rng.permutation(pages - 1).reshape(slots, pps).astype(np.int32) + 1)
-    lens = jnp.asarray(rng.integers(0, pps * page - 1, size=(slots,)).astype(np.int32))
-    out = jax.jit(paged_decode_attention)(q, k_pages, v_pages, table, lens)
-    kv_pos = jnp.arange(pps * page, dtype=jnp.int32)[None, None, None, :]
-    mask = jnp.where(kv_pos <= lens[:, None, None, None], 0.0, -jnp.inf).astype(jnp.float32)
-    ref = _paged_attention(q.reshape(slots, 1, n_kv * n_rep, hd), k_pages, v_pages, table, mask)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32).reshape(ref.shape), np.asarray(ref, np.float32), rtol=5e-2, atol=5e-2
-    )
+    positions = rng.integers(0, 900, size=(slots,)).astype(np.int32)
+    positions[:8] = [0, 15, 16, 255, 256, 6000, -1, 511]
+    positions[rng.permutation(np.arange(8, slots))[:5]] = -1  # six of 32 do not decode
+    # the live pages of all slots fit the pool; every other entry of a row is stale
+    table = np.full((slots, pps), 1_000_000, np.int32)
+    free = iter(rng.permutation(pool - 1) + 1)
+    for s, pos in enumerate(positions):
+        for p in range(pos // PAGE + 1 if pos >= 0 else 0):
+            table[s, p] = next(free)
+    out = jax.jit(paged_decode_attention)(q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(positions))
+    got = np.asarray(out.astype(jnp.float32))
+    want = paged_reference(q, k_pages, v_pages, table, positions)
+    assert np.isfinite(got).all() and not got[positions < 0].any()
+    np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
 
 
 def test_flash_attention_partial_diagonal_block():

@@ -244,7 +244,14 @@ CARRIED_POOL_CASES = {
     "two-kinds-a-group-of-three": (dict(n_layers=5, attn_pattern=(0, 1, 1, 1, 0), window=64), "decode", "gather"),
     "two-kinds-a-group-of-three-kernel": (dict(n_layers=5, attn_pattern=(0, 1, 1, 1, 0), window=64), "decode", "kernel_interpret"),
     "verify-step": ({}, "verify", "gather"),
+    # the slot beside the one that decodes holds a prompt and does not decode (half prefilled, as
+    # the engine sees it): the kernel is told so and walks nothing for it
+    "uniform-two-layers-kernel-beside-a-prompt-that-does-not-decode": ({}, "decode", "kernel_interpret"),
+    "two-kinds-a-group-of-three-kernel-beside-a-prompt-that-does-not-decode": (
+        dict(n_layers=5, attn_pattern=(0, 1, 1, 1, 0), window=64), "decode", "kernel_interpret",
+    ),
 }
+BESIDE_A_PROMPT = {case for case in CARRIED_POOL_CASES if case.endswith("beside-a-prompt-that-does-not-decode")}
 
 
 @pytest.mark.parametrize("case", sorted(CARRIED_POOL_CASES))
@@ -330,6 +337,15 @@ def test_the_carried_pool_takes_layer_l_s_rows_in_layer_l_s_pages_and_nowhere_el
         check_written(before, after, range(start, start + length))
         before = after
     got = [np.asarray(logits)]
+    if case in BESIDE_A_PROMPT:
+        # slot 0 holds ten positions of its own, in pages of its own, and is not active below
+        cache = assign_pages(cache, 0, 0, jnp.asarray([5], jnp.int32))
+        if not cfg.uniform:
+            cache = assign_window_pages(cache, jnp.asarray([0]), jnp.asarray([0]), jnp.asarray([6]))
+        other = jnp.zeros((16,), jnp.int32).at[:10].set(tokens[:10] + 1)
+        _l, _t, cache = paged_prefill(params, cfg, other, jnp.int32(10), cache, jnp.int32(0), jnp.int32(0))
+        assert int(cache.seq_lens[0]) == 10
+        before = pools(cache)
     active = jnp.zeros((SLOTS,), bool).at[slot].set(True)
     if then == "verify":
         fed = jnp.zeros((SLOTS, n_new), jnp.int32).at[slot].set(tokens[n_prompt:])
