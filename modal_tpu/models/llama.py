@@ -29,16 +29,27 @@ import jax.numpy as jnp
 from jax import lax
 
 
+# the names config.json files give a layer's kinds in their per-layer lists
+_PATTERN_NAMES = {"full_attention": 0, "sliding_attention": 1, "dense": 0, "sparse": 1}
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
-    """What one layer is, for the served path: its attention (KV heads, a
-    window or none, a sink or none, its rotary base) and its FFN (dense, or
-    the routed experts the config describes). Hashable: part of a jit key."""
+    """What one layer is, for the served path: its attention (query and KV
+    heads, a window or none, a sink or none, a gate on its output or none,
+    its rotary rule: how much of a head turns, on what base, YaRN's blend or
+    none) and its FFN (dense, or the routed experts the config describes).
+    Hashable: part of a jit key."""
 
+    n_heads: int
     n_kv_heads: int
     window: int = 0  # 0 = full causal attention
     sink: bool = False
     rope_theta: float = 500_000.0
+    rope_dim: int = 0  # a head's first dims that turn
+    # YaRN: (factor, original positions, beta_fast, beta_slow, attention factor); () = plain rotary
+    yarn: tuple = ()
+    gated: bool = False  # sigmoid(h Wg), one scalar a query head, multiplies that head's attention output before wo
     experts: bool = False
     attn_name: str = ""  # "", "full" or "swa": suffix of the scope's and the decode kernel's name
 
@@ -82,15 +93,28 @@ class LlamaConfig:
     # cut): experts experts_held_start .. +n_experts_held; 0 = all of them
     n_experts_held: int = 0
     experts_held_start: int = 0
+    router_bias: bool = True  # a stored selection bias joins the scores for the top-k (never the weights)
+    routed_scale: float = 1.0  # the renormalised weights of the chosen experts are multiplied by this
+    shared_expert_dim: int = 0  # a SwiGLU every token passes beside the routed experts; 0 = none
+    attn_gate: bool = False  # the attention output is gated, a sigmoid scalar a head, from the layer's normed input
+    n_heads_per_layer: tuple = ()  # query heads a layer; () = n_heads in every layer
+    # a rotary rule a layer type, as config.json files publish it: {"full_attention": {...},
+    # "sliding_attention": {...}} with rope_theta, partial_rotary_factor, rope_type ("default" or
+    # "yarn") and YaRN's numbers; a type it does not name keeps rope_theta / rope_fraction above
+    rope_parameters: Any = ()
 
     def __post_init__(self):
-        for field in ("attn_pattern", "ffn_pattern"):
+        for field in ("attn_pattern", "ffn_pattern", "n_heads_per_layer"):
             # a depth cut keeps the first n_layers of a longer pattern: the
             # published one, so {"name": preset, "n_layers": 7} is a cut
-            pattern = tuple(int(v) for v in getattr(self, field))
+            pattern = tuple(_PATTERN_NAMES[v] if isinstance(v, str) else int(v) for v in getattr(self, field))
             if pattern and len(pattern) < self.n_layers:
                 raise ValueError(f"{field} names {len(pattern)} layers, n_layers is {self.n_layers}")
             object.__setattr__(self, field, pattern[: self.n_layers])  # a tuple: a list (JSON) would not hash under jit
+        rules = self.rope_parameters.items() if isinstance(self.rope_parameters, dict) else self.rope_parameters
+        object.__setattr__(self, "rope_parameters", tuple(
+            (name, tuple(sorted(dict(rule).items()))) for name, rule in rules if not isinstance(rule, (int, float))
+        ))
         if any(self.attn_pattern) and self.window < 1:
             raise ValueError("attn_pattern has window layers but window is 0")
         if any(self.ffn_pattern):
@@ -99,8 +123,11 @@ class LlamaConfig:
                 raise ValueError("ffn_pattern has expert layers: n_routed_experts, experts_per_token and expert_dim must be set")
             if not (0 <= lo and n > 0 and lo + n <= self.n_routed_experts):
                 raise ValueError(f"experts held {lo}..{lo + n} lie outside the {self.n_routed_experts} routed experts")
-        if self.rope_dim % 2:
-            raise ValueError(f"rotary width int({self.head_dim} * {self.rope_fraction}) = {self.rope_dim} must be even")
+        for kind in self.layer_kinds:
+            if kind.rope_dim % 2:
+                raise ValueError(f"rotary width {kind.rope_dim} of a head of {self.head_dim} must be even")
+            if kind.n_heads % kind.n_kv_heads:
+                raise ValueError(f"{kind.n_heads} query heads over {kind.n_kv_heads} KV heads")
 
     @property
     def head_dim(self) -> int:
@@ -112,7 +139,10 @@ class LlamaConfig:
 
     @property
     def rope_dim(self) -> int:
-        return self.head_dim if self.rope_fraction == 1.0 else int(self.head_dim * self.rope_fraction)
+        return self._rope_width(self.rope_fraction)
+
+    def _rope_width(self, fraction: float) -> int:
+        return self.head_dim if fraction == 1.0 else int(self.head_dim * fraction)
 
     @property
     def is_moe(self) -> bool:
@@ -127,14 +157,29 @@ class LlamaConfig:
     def layer_kinds(self) -> tuple:
         """One LayerKind a layer: the description the served path runs by."""
         kinds = []
+        rules = {name: dict(rule) for name, rule in self.rope_parameters}
         for i in range(self.n_layers):
             windowed = bool(self.attn_pattern and self.attn_pattern[i])
             routed = bool(self.ffn_pattern and self.ffn_pattern[i])
+            rule = rules.get("sliding_attention" if windowed else "full_attention", {})
+            if rule.get("rope_type", "default") not in ("default", "yarn"):
+                raise ValueError(f"rope_type {rule['rope_type']!r}: the served path turns heads by plain rotary or YaRN")
+            yarn = ()
+            if rule.get("rope_type") == "yarn":
+                factor = float(rule["factor"])
+                yarn = (
+                    factor, int(rule["original_max_position_embeddings"]), float(rule.get("beta_fast", 32)),
+                    float(rule.get("beta_slow", 1)), float(rule.get("attention_factor") or 0.1 * math.log(factor) + 1.0),
+                )
             kinds.append(LayerKind(
+                n_heads=self.n_heads_per_layer[i] if self.n_heads_per_layer else self.n_heads,
                 n_kv_heads=(self.window_kv_heads or self.n_kv_heads) if windowed else self.n_kv_heads,
                 window=self.window if windowed else 0,
                 sink=windowed and self.window_sink,
-                rope_theta=(self.window_rope_theta or self.rope_theta) if windowed else self.rope_theta,
+                rope_theta=float(rule.get("rope_theta") or ((self.window_rope_theta or self.rope_theta) if windowed else self.rope_theta)),
+                rope_dim=self._rope_width(float(rule.get("partial_rotary_factor", self.rope_fraction))),
+                yarn=yarn,
+                gated=self.attn_gate,
                 experts=routed,
                 # the dense models' scope and kernel keep their names; a model
                 # with a layer pattern tells its kinds apart in a trace
@@ -175,10 +220,11 @@ class LlamaConfig:
             hd, vd, (_lo, held) = self.head_dim, self.v_dim, self.experts_held
             total = 2 * self.vocab_size * self.dim + self.dim
             for k in self.layer_kinds:
-                total += self.dim * (self.n_heads * hd + k.n_kv_heads * (hd + vd)) + self.n_heads * vd * self.dim
-                total += 2 * self.dim + (self.n_heads if k.sink else 0)
+                total += self.dim * (k.n_heads * hd + k.n_kv_heads * (hd + vd)) + k.n_heads * vd * self.dim
+                total += 2 * self.dim + (k.n_heads if k.sink else 0) + (self.dim * k.n_heads if k.gated else 0)
                 if k.experts:
-                    total += (self.dim + 1) * self.n_routed_experts + 3 * held * self.dim * self.expert_dim
+                    total += (self.dim + self.router_bias) * self.n_routed_experts
+                    total += 3 * self.dim * (held * self.expert_dim + self.shared_expert_dim)
                 else:
                     total += 3 * self.dim * self.ffn_dim
             return total
@@ -254,6 +300,46 @@ CONFIGS: dict[str, LlamaConfig] = {
         window_rope_theta=10_000.0, window_sink=True, ffn_pattern=(0, 1, 1, 1, 1, 1, 1),
         n_routed_experts=32, experts_per_token=4, expert_dim=32, n_experts_held=8,
     ),
+    # Laguna-XS.2 as published (poolside/Laguna-XS.2 config.json): 1 full layer
+    # (48 query heads, YaRN x64 on half a head, base 5e5) to 3 sliding ones (64
+    # query heads, a window of 512, plain rotary on the whole head, base 1e4),
+    # 8 KV heads of 128 everywhere, a sigmoid gate a head on the attention output, a
+    # leading dense layer and then 256 routed experts of 512, 8 a token, their
+    # renormalised sigmoid weights times 2.5, beside a shared expert of 512. The
+    # three per-layer lists are kept whole so that {"name": "laguna-xs.2",
+    # "n_layers": 5} is a cut (benchmark/configs/laguna-xs.2-serve-1chip.json,
+    # whose `assumed` says which readings of the row these are).
+    "laguna-xs.2": LlamaConfig(
+        name="laguna-xs.2", vocab_size=100_352, dim=2048, n_layers=40, n_heads=48, n_kv_heads=8,
+        ffn_dim=8192, norm_eps=1e-6, rope_theta=500_000.0, max_seq_len=262_144, qk_head_dim=128,
+        rope_fraction=0.5, attn_pattern=(0, 1, 1, 1) * 10, window=512, n_heads_per_layer=(48, 64, 64, 64) * 10,
+        rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500_000, "factor": 64, "original_max_position_embeddings": 4096,
+                "beta_fast": 64, "beta_slow": 1, "attention_factor": 1.4158883083359672, "partial_rotary_factor": 0.5,
+            },
+            "sliding_attention": {"rope_type": "default", "rope_theta": 10_000, "partial_rotary_factor": 1},
+        },
+        attn_gate=True, ffn_pattern=(0,) + (1,) * 39, n_routed_experts=256, experts_per_token=8, expert_dim=512,
+        shared_expert_dim=512, routed_scale=2.5, router_bias=False,
+    ),
+    # the same description at a size the CPU tests hold: the five layer kinds in
+    # the same order, 6 and 8 query heads a KV head, all 32 experts held, top-4,
+    # a shared expert, YaRN on half a head (a ramp over frequencies 0..3), window 8
+    "tiny-laguna": LlamaConfig(
+        name="tiny-laguna", vocab_size=512, dim=64, n_layers=5, n_heads=12, n_kv_heads=2,
+        ffn_dim=128, norm_eps=1e-6, rope_theta=100.0, max_seq_len=256, qk_head_dim=16,
+        rope_fraction=0.5, attn_pattern=(0, 1, 1, 1, 0), window=8, n_heads_per_layer=(12, 16, 16, 16, 12),
+        rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 100, "factor": 4, "original_max_position_embeddings": 64,
+                "beta_fast": 8, "beta_slow": 1, "partial_rotary_factor": 0.5,
+            },
+            "sliding_attention": {"rope_type": "default", "rope_theta": 10_000, "partial_rotary_factor": 1},
+        },
+        attn_gate=True, ffn_pattern=(0, 1, 1, 1, 1), n_routed_experts=32, experts_per_token=4, expert_dim=32,
+        shared_expert_dim=32, routed_scale=2.5, router_bias=False,
+    ),
 }
 
 
@@ -286,20 +372,27 @@ def _init_kind(cfg: LlamaConfig, kind: LayerKind, k: jax.Array) -> dict:
     bias, sink), every matrix normal(0, 0.02) in cfg.dtype, the sinks and the
     bias too (so that both take part in what a test compares). A routed
     expert's weights are a function of (layer key, expert index) alone, so
-    every share of the experts draws the same expert e."""
+    every share of the experts draws the same expert e. What later kinds
+    added draws from four more keys, split from fold_in(key, 1) so that the
+    ten stay what they were: the attention gate, the shared expert's gate,
+    up and down."""
     init = jax.nn.initializers.normal(stddev=0.02)
     ks = jax.random.split(k, 10)
+    # drawn only where a kind has what they are for: a model without draws, and boots, as it did
+    more = jax.random.split(jax.random.fold_in(k, 1), 4) if kind.gated or cfg.shared_expert_dim else None
     hd, vd = cfg.head_dim, cfg.v_dim
     layer = {
         "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
-        "wq": init(ks[0], (cfg.dim, cfg.n_heads * hd), cfg.dtype),
+        "wq": init(ks[0], (cfg.dim, kind.n_heads * hd), cfg.dtype),
         "wk": init(ks[1], (cfg.dim, kind.n_kv_heads * hd), cfg.dtype),
         "wv": init(ks[2], (cfg.dim, kind.n_kv_heads * vd), cfg.dtype),
-        "wo": init(ks[3], (cfg.n_heads * vd, cfg.dim), cfg.dtype),
+        "wo": init(ks[3], (kind.n_heads * vd, cfg.dim), cfg.dtype),
         "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype),
     }
     if kind.sink:
-        layer["sink"] = init(ks[9], (cfg.n_heads,), cfg.dtype)
+        layer["sink"] = init(ks[9], (kind.n_heads,), cfg.dtype)
+    if kind.gated:
+        layer["wg"] = init(more[0], (cfg.dim, kind.n_heads), cfg.dtype)
     if not kind.experts:
         layer.update({
             "w_gate": init(ks[4], (cfg.dim, cfg.ffn_dim), cfg.dtype),
@@ -315,11 +408,18 @@ def _init_kind(cfg: LlamaConfig, kind: LayerKind, k: jax.Array) -> dict:
 
     layer.update({
         "router": init(ks[7], (cfg.dim, cfg.n_routed_experts), cfg.dtype),
-        "router_bias": init(ks[8], (cfg.n_routed_experts,), cfg.dtype),
         "w_gate": held_experts(ks[4], (cfg.dim, cfg.expert_dim)),  # [held, D, F]
         "w_up": held_experts(ks[5], (cfg.dim, cfg.expert_dim)),
         "w_down": held_experts(ks[6], (cfg.expert_dim, cfg.dim)),  # [held, F, D]
     })
+    if cfg.router_bias:
+        layer["router_bias"] = init(ks[8], (cfg.n_routed_experts,), cfg.dtype)
+    if cfg.shared_expert_dim:
+        layer.update({
+            "shared_gate": init(more[1], (cfg.dim, cfg.shared_expert_dim), cfg.dtype),
+            "shared_up": init(more[2], (cfg.dim, cfg.shared_expert_dim), cfg.dtype),
+            "shared_down": init(more[3], (cfg.shared_expert_dim, cfg.dim), cfg.dtype),
+        })
     return layer
 
 
@@ -391,20 +491,40 @@ def rms_norm(x: jax.Array, gamma: jax.Array, eps: float) -> jax.Array:
     return (x32 * lax.rsqrt(var + eps)).astype(x.dtype) * gamma
 
 
-def rope_frequencies(cfg: LlamaConfig, theta: Optional[float] = None) -> jax.Array:
+def rope_frequencies(cfg: LlamaConfig, kind: Optional[LayerKind] = None) -> jax.Array:
     """[rope_dim/2] inverse frequencies (rope_dim = head_dim unless the
-    config turns only a head's first dims); `theta` where a layer kind has a
-    base of its own."""
-    rd = cfg.rope_dim
+    config turns only a head's first dims); of a layer `kind` where a kind has
+    a rotary width, a base or a scaling rule of its own.
+
+    YaRN (`kind.yarn`), as published: frequency j of rd/2 is a blend of the
+    plain one, 1 / theta^(2j/rd), and that over `factor`, by a linear ramp in
+    j between the two correction dims: the j at which a frequency makes
+    `beta_fast` (below it: plain) and `beta_slow` (above it: divided) whole
+    turns over the original positions, rounded down and up."""
+    rd, theta = (kind.rope_dim, kind.rope_theta) if kind else (cfg.rope_dim, cfg.rope_theta)
     exponents = jnp.arange(0, rd, 2, dtype=jnp.float32) / rd
-    return 1.0 / ((theta or cfg.rope_theta) ** exponents)
+    plain = 1.0 / (theta ** exponents)
+    if not (kind and kind.yarn):
+        return plain
+    factor, original, beta_fast, beta_slow, _attention_factor = kind.yarn
+
+    def correction_dim(turns: float) -> float:
+        return rd * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rd - 1)
+    ramp = jnp.clip((jnp.arange(rd // 2, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array) -> jax.Array:
-    """x: [B, S, H, hd]; positions: [B, S]."""
+def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array, scale: float = 1.0) -> jax.Array:
+    """x: [B, S, H, hd]; positions: [B, S]. `scale` multiplies cos and sin
+    (YaRN's attention factor)."""
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, S, hd/2]
     cos = jnp.cos(angles)[:, :, None, :]  # [B, S, 1, hd/2]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

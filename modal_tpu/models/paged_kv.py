@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .experts import routed_experts
 from .llama import LayerKind, LlamaConfig, apply_rope, repeat_kv, rms_norm, rope_frequencies
 
 DEFAULT_PAGE_SIZE = 16
@@ -107,6 +108,9 @@ class PagedKVCache(NamedTuple):
     # (token, expert) pairs the expert layers computed on held experts, summed
     # over every step so far; uint32, wraps (the host reads differences)
     moe_pairs: Optional[jax.Array] = None
+    # held experts that got at least one of a step's pairs, summed over expert
+    # layers and steps the same way
+    moe_touched: Optional[jax.Array] = None
 
     @staticmethod
     def create(
@@ -137,6 +141,7 @@ class PagedKVCache(NamedTuple):
             v_pages=tuple(v_pages),
             window_table=jnp.zeros((slots, pages_per_slot), jnp.int32) if cfg.has_window else None,
             moe_pairs=jnp.zeros((), jnp.uint32) if cfg.has_experts else None,
+            moe_touched=jnp.zeros((), jnp.uint32) if cfg.has_experts else None,
             **tables,
         )
 
@@ -448,13 +453,14 @@ def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, windo
     return out.transpose(2, 0, 1, 3).reshape(sq, h, vd)
 
 
-def _rope(cfg, x, positions, inv_freq):
-    """Rotary positions on the first `cfg.rope_dim` dims of each head; the
-    rest pass through (all of them turn in the dense models)."""
-    rd = cfg.rope_dim
+def _rope(kind, x, positions, inv_freq):
+    """Rotary positions on the first `kind.rope_dim` dims of each head; the
+    rest pass through (all of them turn in the dense models). Under YaRN the
+    turned dims are scaled by its attention factor."""
+    rd, scale = kind.rope_dim, kind.yarn[4] if kind.yarn else 1.0
     if rd == x.shape[-1]:
-        return apply_rope(x, positions, inv_freq)
-    return jnp.concatenate([apply_rope(x[..., :rd], positions, inv_freq), x[..., rd:]], axis=-1)
+        return apply_rope(x, positions, inv_freq, scale)
+    return jnp.concatenate([apply_rope(x[..., :rd], positions, inv_freq, scale), x[..., rd:]], axis=-1)
 
 
 def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, table, inv_freq, kp, vp, attend, valid=None):
@@ -465,22 +471,22 @@ def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, 
     takes them; attend: (kind, q [S, Sq, H, hd], k_pages, v_pages, table,
     sink) -> [S, Sq, H, vd] over the pool as this layer has just written it —
     the caller's own (`_paged_attention` for decode and verify,
-    `_prefill_attention` for a prefill chunk). Returns (x, kp, vp, pairs):
-    the (token, expert) pairs an expert layer computed for the `valid` [S*Sq]
-    tokens, 0 for a dense FFN."""
+    `_prefill_attention` for a prefill chunk). Returns (x, kp, vp, counts):
+    uint32 [2], the (token, expert) pairs an expert layer computed for the
+    `valid` [S*Sq] tokens and the held experts they touched; 0 for a dense FFN."""
     from .quant import qmm
 
     s, sq, d = x.shape
-    hd, vd, n_kv = cfg.head_dim, cfg.v_dim, kind.n_kv_heads
+    hd, vd, n_heads, n_kv = cfg.head_dim, cfg.v_dim, kind.n_heads, kind.n_kv_heads
     # the scopes are names only (HLO metadata, profiler traces): nothing
     # computed changes
     with jax.named_scope(kind.attn_name + "_attention" if kind.attn_name else "paged_attention"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = qmm(h, layer["wq"]).reshape(s, sq, cfg.n_heads, hd)
+        q = qmm(h, layer["wq"]).reshape(s, sq, n_heads, hd)
         k = qmm(h, layer["wk"]).reshape(s, sq, n_kv, hd)
         v = qmm(h, layer["wv"]).reshape(s, sq, n_kv, vd)
-        q = _rope(cfg, q, positions, inv_freq)
-        k = _rope(cfg, k, positions, inv_freq)
+        q = _rope(kind, q, positions, inv_freq)
+        k = _rope(kind, k, positions, inv_freq)
         if cfg.value_scale != 1.0:
             v = v * jnp.asarray(cfg.value_scale, v.dtype)
         stored = kp.shape[-1]  # k_cache_dim: zeros past the model's width
@@ -495,11 +501,13 @@ def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, 
                 write_page_ids, write_offsets,
             )
         attn_out = attend(kind, q, kp, vp, table, layer.get("sink"))
-        x = x + qmm(attn_out.reshape(s, sq, cfg.n_heads * vd), layer["wo"])
-    pairs = jnp.zeros((), jnp.uint32)
+        if kind.gated:
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(qmm(h, layer["wg"]).astype(jnp.float32))  # [S, Sq, H]: a scalar a head
+                attn_out = (attn_out.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
+        x = x + qmm(attn_out.reshape(s, sq, n_heads * vd), layer["wo"])
+    pairs = jnp.zeros((2,), jnp.uint32)
     if kind.experts:
-        from .experts import routed_experts
-
         h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
         y, pairs = routed_experts(cfg, h.reshape(s * sq, d), layer, valid)
         x = x + y.reshape(s, sq, d)
@@ -532,10 +540,10 @@ def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None):
     groups = (params["layers"], cache.k_pages, cache.v_pages)
     if cfg.uniform:  # one group, kept bare and not as tuples of one
         groups = tuple((g,) for g in groups)
-    pairs = jnp.zeros((), jnp.uint32)
+    pairs = jnp.zeros((2,), jnp.uint32)
     k_pages, v_pages = [], []
     for (kind, _first, n), layers, kp, vp in zip(cfg.layer_groups, *groups):
-        inv_freq = rope_frequencies(cfg, kind.rope_theta)
+        inv_freq = rope_frequencies(cfg, kind)
         write_page_ids, write_offsets, table = pools[bool(kind.window)]
         pool_pages = kp.shape[1]
 
@@ -568,7 +576,7 @@ def _kernel_name(kind: LayerKind) -> str:
 def _advance(cache, k_pages, v_pages, pairs, seq_lens):
     cache = cache._replace(k_pages=k_pages, v_pages=v_pages, seq_lens=seq_lens)
     if cache.moe_pairs is not None:
-        cache = cache._replace(moe_pairs=cache.moe_pairs + pairs)
+        cache = cache._replace(moe_pairs=cache.moe_pairs + pairs[0], moe_touched=cache.moe_touched + pairs[1])
     return cache
 
 

@@ -469,7 +469,9 @@ class ServingEngine:
         self.moe_assignments = 0
         self.moe_local_assignments = 0
         self.moe_expert_calls = 0
+        self.moe_experts_touched = 0
         self._moe_pairs_seen = 0
+        self._moe_touched_seen = 0
         # what this engine actually runs on, as jax reports it — /v1/stats
         # carries it so a client never has to assume the device
         import jax
@@ -800,11 +802,12 @@ class ServingEngine:
         self._sync_page_gauges()
 
     def _note_moe_pairs(self, cumulative: Any) -> None:
-        """`cache.moe_pairs` as it came back with a step's tokens: a uint32
-        that wraps, so what counts is how far it moved."""
-        now = int(cumulative)
-        self.moe_local_assignments += (now - self._moe_pairs_seen) & 0xFFFFFFFF
-        self._moe_pairs_seen = now
+        """`cache.moe_pairs` and `cache.moe_touched` as they came back with a
+        step's tokens: uint32s that wrap, so what counts is how far they moved."""
+        pairs, touched = (int(c) for c in cumulative)
+        self.moe_local_assignments += (pairs - self._moe_pairs_seen) & 0xFFFFFFFF
+        self.moe_experts_touched += (touched - self._moe_touched_seen) & 0xFFFFFFFF
+        self._moe_pairs_seen, self._moe_touched_seen = pairs, touched
 
     def _note_moe_call(self, tokens: int) -> None:
         self.moe_assignments += tokens * self.cfg.experts_per_token * self.moe_expert_layers
@@ -1088,7 +1091,8 @@ class ServingEngine:
             elif self.moe_expert_layers:
                 import jax
 
-                first_tok, pairs = jax.device_get((next_tok, self.pages.cache.moe_pairs))  # one fetch
+                counts = self.pages.cache.moe_pairs, self.pages.cache.moe_touched
+                first_tok, pairs = jax.device_get((next_tok, counts))  # one fetch
                 first_tok = int(first_tok)
                 self._note_moe_pairs(pairs)
             else:
@@ -1274,7 +1278,8 @@ class ServingEngine:
             import jax
 
             # the held experts' pair count rides with the step's tokens: one fetch
-            next_host, pairs = jax.device_get((next_tokens, self.pages.cache.moe_pairs))
+            counts = self.pages.cache.moe_pairs, self.pages.cache.moe_touched
+            next_host, pairs = jax.device_get((next_tokens, counts))
             self._note_moe_pairs(pairs)
             self._note_moe_call(len(decoding))
         else:
@@ -1571,6 +1576,7 @@ class ServingEngine:
             "assignments": self.moe_assignments,
             "local_assignments": self.moe_local_assignments,
             "expert_calls": self.moe_expert_calls,
+            "experts_touched": self.moe_experts_touched,
         }
         return {
             "loop": {

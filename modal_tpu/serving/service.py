@@ -58,6 +58,8 @@ def llm_service(
     # a model with window attention keeps a second, bounded KV pool for those
     # layers: its size in pages (None = every slot's window + one chunk)
     window_num_pages: Optional[int] = None,
+    # requests that may wait for a slot; one more is refused (the engine's own bound)
+    max_waiting: int = 1024,
     **cls_kwargs: Any,
 ) -> Any:
     """Register a serving class on `app` and return it (an `@app.cls`
@@ -136,6 +138,7 @@ def llm_service(
                 prefix_cache=prefix_cache,
                 role=role,
                 window_num_pages=window_num_pages,
+                max_waiting=max_waiting,
             ).start()
 
         @modal_tpu.exit()

@@ -99,6 +99,13 @@ MIMO_CELL = dict(
 )
 
 
+# the fourth configuration's (benchmark/configs/laguna-xs.2-serve-1chip.json): every expert of a layer held
+LAGUNA_CELL = dict(
+    model=dict(name="laguna-xs.2", n_layers=5, max_seq_len=8192), slots=128, pool_pages=8193, window_pool_pages=4241,
+)
+TWO_POOL_CELLS = {"mimo-v2-flash-serve-1chip-ep16": MIMO_CELL, "laguna-xs.2-serve-1chip": LAGUNA_CELL}
+
+
 def lower_step(program, config, sharding):
     """`paged_prefill` (one bucket: a chunk of 128) or `paged_decode_step`
     (the Pallas kernel) of a cell, lowered for the described chip over shapes
@@ -110,9 +117,10 @@ def lower_step(program, config, sharding):
     from modal_tpu.models import paged_kv
     from modal_tpu.models.llama import get_config, init_params
 
-    if config == "mimo-v2-flash-serve-1chip-ep16":
-        cfg = get_config(MIMO_CELL["model"])
-        slots, pool_pages, window_pool_pages = MIMO_CELL["slots"], MIMO_CELL["pool_pages"], MIMO_CELL["window_pool_pages"]
+    if config in TWO_POOL_CELLS:
+        cell = TWO_POOL_CELLS[config]
+        cfg = get_config(cell["model"])
+        slots, pool_pages, window_pool_pages = cell["slots"], cell["pool_pages"], cell["window_pool_pages"]
     else:
         slots, pages_per_slot, n_kv, n_rep, pool_pages = CELL_SHAPES[config]
         vocab, ffn = CELL_WIDTHS[config]
@@ -177,7 +185,7 @@ def test_paged_prefill_compiles_for_v5e_with_nothing_of_the_span_s_size(config, 
 POOL_SIZED_MAY_BE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while", "scatter", "fusion"}
 CARRIED_POOL_CASES = [
     (config, program) for config in sorted(CELL_SHAPES) for program in ("paged_decode_step", "paged_prefill")
-] + [("mimo-v2-flash-serve-1chip-ep16", "paged_decode_step")]
+] + [("mimo-v2-flash-serve-1chip-ep16", "paged_decode_step"), ("laguna-xs.2-serve-1chip", "paged_decode_step"), ("laguna-xs.2-serve-1chip", "paged_prefill")]
 
 
 @pytest.mark.parametrize("config,program", CARRIED_POOL_CASES)
@@ -189,7 +197,9 @@ def test_the_jitted_steps_compile_for_v5e_with_no_pool_sized_copy(config, progra
     sliver of a pool and its outputs alias the pools. Scanned as inputs and
     stacked as outputs, the pools cost four whole-pool operations a program
     and a second pool of temporaries (PERF.md section 6, PR 31). The third
-    configuration has a group of four window layers among single ones."""
+    configuration has a group of four window layers among single ones, the
+    fourth a group of three with 1.6 GB of expert matrices a layer, which
+    XLA's dots read in place from the scanned stack."""
     import jax
 
     cfg, _params, cache, lowered = lower_step(program, config, one_chip)
@@ -226,7 +236,8 @@ def test_the_jitted_steps_compile_for_v5e_with_no_pool_sized_copy(config, progra
     one_pool = max(math.prod(a.shape) * a.dtype.itemsize for a in pools)
     if cfg.uniform:
         assert memory.temp_size_in_bytes < one_pool / 100, memory.temp_size_in_bytes
-    else:  # XLA still transposes this model's wq a layer (PERF.md section 7): 100 MB, a pool is 268 MB
+    else:  # XLA still transposes MiMo's wq a layer (PERF.md section 7): 100 MB, a pool is 268 MB; Laguna's chunk
+        # holds 256 experts' activations (35 MB at 128 rows), a pool is 417 MB and a layer's expert matrices 537 MB each
         assert memory.temp_size_in_bytes < 0.2e9, memory.temp_size_in_bytes
     assert memory.alias_size_in_bytes >= pool_bytes, (memory.alias_size_in_bytes, pool_bytes)
 
@@ -290,7 +301,7 @@ def test_the_two_width_decode_kernels_compile_for_v5e_and_only_padded_keys_go_in
 MOSAIC_CALL_CHARS_AT_MOST = 48_000
 
 
-@pytest.mark.parametrize("config", sorted(CELL_SHAPES) + ["mimo-v2-flash-serve-1chip-ep16"])
+@pytest.mark.parametrize("config", sorted(CELL_SHAPES) + sorted(TWO_POOL_CELLS))
 def test_the_decode_step_holds_one_small_mosaic_kernel_a_layer_group(config, one_chip):
     """`paged_decode_step` lowered at a cell's real shapes (nothing compiled):
     one Mosaic call a layer group (the scan's body is traced once), each under
@@ -301,3 +312,23 @@ def test_the_decode_step_holds_one_small_mosaic_kernel_a_layer_group(config, one
     calls = [line for line in lowered.as_text().splitlines() if "@tpu_custom_call" in line]
     assert len(calls) == len(cfg.layer_groups), (len(calls), cfg.layer_groups)
     assert all(5_000 < len(line) < MOSAIC_CALL_CHARS_AT_MOST for line in calls), [len(line) for line in calls]
+
+
+def test_the_decode_kernel_compiles_for_v5e_at_six_query_heads_a_kv_head(one_chip, no_compile_cache):
+    """48 query heads over 8 KV heads: the `[heads, rows]` score tile and the
+    mask of a head's own KV rows at a head count that is no power of two."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.ops.paged_attention import paged_decode_attention
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(q, k, v, table, lens):
+        return paged_decode_attention(q, k, v, table, lens, name="paged_decode_attention_full")
+
+    text = jax.jit(call).lower(
+        shape((128, 8, 6, 128)), shape((8193, 16, 8, 128)), shape((8193, 16, 8, 128)), shape((128, 512), jnp.int32), shape((128,), jnp.int32)
+    ).compile().as_text()
+    assert re.search(r"%paged_decode_attention_full[.\d]* = bf16\[128,48,128\]", text)

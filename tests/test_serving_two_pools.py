@@ -24,6 +24,20 @@ def model():
     return init_params(cfg, jax.random.PRNGKey(0)), cfg
 
 
+@pytest.fixture(scope="module")
+def models(model):
+    """By preset: `tiny-mimo` (a share of the experts, sinks, keys wider than
+    values) and `tiny-laguna` (every expert held, a shared one, gated
+    attention, two query-head counts): what two pools refuse, they refuse for
+    both, by mechanism."""
+    import jax
+
+    from modal_tpu.models.llama import get_config, init_params
+
+    cfg = get_config("tiny-laguna")
+    return {"tiny-mimo": model, "tiny-laguna": (init_params(cfg, jax.random.PRNGKey(0)), cfg)}
+
+
 def engine_of(model, **overrides):
     from modal_tpu.serving.engine import ServingEngine
 
@@ -212,13 +226,14 @@ REFUSALS = {
 
 
 @pytest.mark.parametrize("case", sorted(REFUSALS))
-def test_the_engine_refuses_by_mechanism(model, case):
+@pytest.mark.parametrize("preset", ["tiny-mimo", "tiny-laguna"])
+def test_the_engine_refuses_by_mechanism(models, preset, case):
     import jax
     import jax.numpy as jnp
 
     from modal_tpu.serving.engine import ServingEngine
 
-    params, cfg = model
+    params, cfg = models[preset]
     kwargs, message = REFUSALS[case]
     kwargs = dict(kwargs)
     if kwargs.pop("draft", None):
@@ -227,7 +242,7 @@ def test_the_engine_refuses_by_mechanism(model, case):
         params = jax.tree_util.tree_map(lambda a: a.astype(jnp.int8) if a.ndim == 4 else a, params)  # the experts' stacks
     with pytest.raises(ValueError, match=message) as refused:
         ServingEngine(params, cfg, page_size=PAGE, prefill_chunk=CHUNK, **kwargs)
-    assert "mimo" not in str(refused.value).lower()  # the mechanism, never a model's name
+    assert not {"mimo", "laguna"} & set(str(refused.value).lower().replace("-", " ").split())  # the mechanism, never a model's name
 
 
 def test_the_trainer_s_switch_layer_is_refused_in_the_paged_path_and_as_a_draft():
@@ -283,15 +298,51 @@ def test_a_depth_cut_keeps_the_first_layers_of_the_published_pattern():
         get_config("tiny-mimo", experts_held_start=30)
 
 
+def test_a_depth_cut_of_per_layer_lists_and_rotary_rules_as_config_files_publish_them():
+    """The lists a config.json names its layers by (strings) and its rotary
+    rules by layer type (a nested object) are taken as published: the preset,
+    and the same keys handed over as a benchmark configuration does."""
+    from modal_tpu.models.llama import get_config
+
+    cut = get_config({"name": "laguna-xs.2", "n_layers": 5, "max_seq_len": 8192})
+    assert cut.attn_pattern == (0, 1, 1, 1, 0) and cut.ffn_pattern == (0, 1, 1, 1, 1) and cut.n_heads_per_layer == (48, 64, 64, 64, 48)
+    kinds = [(k.n_heads, k.n_kv_heads, k.window, k.rope_theta, k.rope_dim, bool(k.yarn), k.gated, k.experts, k.attn_name) for k in cut.layer_kinds]
+    assert kinds[0] == (48, 8, 0, 500_000.0, 64, True, True, False, "full")
+    assert kinds[1] == kinds[2] == kinds[3] == (64, 8, 512, 10_000.0, 128, False, True, True, "swa")
+    assert kinds[4] == (48, 8, 0, 500_000.0, 64, True, True, True, "full")
+    assert [(first, n) for _k, first, n in cut.layer_groups] == [(0, 1), (1, 3), (4, 1)]  # one compiled body a group
+    assert cut.experts_held == (0, 256) and (cut.shared_expert_dim, cut.routed_scale, cut.router_bias) == (512, 2.5, False)
+    assert cut.param_count() == 3_869_857_792  # 7.74 GB in bf16: every expert of five layers and the whole vocabulary
+    assert hash(cut) == hash(get_config({"name": "laguna-xs.2", "n_layers": 5, "max_seq_len": 8192}))  # a jit key
+    published = get_config({
+        "name": "tiny-laguna", "attn_pattern": ["full_attention", "sliding_attention", "sliding_attention", "sliding_attention", "full_attention"],
+        "ffn_pattern": ["dense", "sparse", "sparse", "sparse", "sparse"], "n_heads_per_layer": [12, 16, 16, 16, 12],
+        "rope_parameters": {
+            "full_attention": {"rope_theta": 100, "rope_type": "yarn", "factor": 4, "original_max_position_embeddings": 64, "beta_slow": 1, "beta_fast": 8, "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1},
+            "original_max_position_embeddings": 64,
+        },
+    })
+    assert published == get_config("tiny-laguna") and published.layer_kinds == get_config("tiny-laguna").layer_kinds
+    # MiMo's description reads as it did: one head count, rotary on a third of a head on two bases, no gate
+    mimo = get_config("tiny-mimo")
+    assert {(k.n_heads, k.rope_dim, k.yarn, k.gated) for k in mimo.layer_kinds} == {(8, 8, (), False)}
+    with pytest.raises(ValueError, match="rope_type 'linear'"):
+        get_config("tiny-laguna", rope_parameters={"full_attention": {"rope_type": "linear"}})
+    with pytest.raises(ValueError, match="13 query heads over 2 KV heads"):
+        get_config("tiny-laguna", n_heads_per_layer=(13, 16, 16, 16, 12))
+
+
 @pytest.mark.parametrize("sink", [False, True], ids=["no-sink", "sink"])
 @pytest.mark.parametrize("window", [0, 8, 5], ids=["full", "window-8", "window-5"])
-def test_the_decode_kernel_matches_the_gather_path_with_a_window_a_sink_and_two_widths(window, sink):
+@pytest.mark.parametrize("heads", [8, 12], ids=["4-heads-a-kv-head", "6-heads-a-kv-head"])
+def test_the_decode_kernel_matches_the_gather_path_with_a_window_a_sink_and_two_widths(heads, window, sink):
     import jax
     import jax.numpy as jnp
 
     from modal_tpu.models.paged_kv import _paged_attention
 
-    slots, heads, n_kv, hd, vd, pages_per_slot, pool = 3, 8, 2, 24, 16, 6, 20
+    slots, n_kv, hd, vd, pages_per_slot, pool = 3, 2, 24, 16, 6, 20  # 6 query heads a KV head: no power of two
     keys = jax.random.split(jax.random.PRNGKey(window + 10 * sink), 5)
     q = jax.random.normal(keys[0], (slots, 1, heads, hd), jnp.float32)
     k_pages = jax.random.normal(keys[1], (pool, PAGE, n_kv, hd), jnp.float32)
@@ -343,7 +394,7 @@ def test_stats_of_a_dense_model_carry_no_second_pool(model, preset):
     else:
         stats = engine_of(model).stats()
         assert WINDOW_KEYS | {"moe"} <= set(stats)
-        assert stats["moe"] == {"assignments": 0, "local_assignments": 0, "expert_calls": 0}
+        assert stats["moe"] == {"assignments": 0, "local_assignments": 0, "expert_calls": 0, "experts_touched": 0}
     paged = {k for k in stats if k.startswith(("kv_", "prefix_cache_", "draft_prefix_cache_"))}
     assert paged == PAGE_KEYS | (WINDOW_KEYS if preset == "tiny-mimo" else set())
     assert {"preemptions", "requests_admitted", "loop", "spec_k", "attn_impl"} <= set(stats) and "spec_overlap" not in stats
@@ -357,5 +408,8 @@ def test_llm_service_takes_the_second_pool_s_size_and_hands_it_to_the_engine():
 
     assert inspect.signature(llm_service).parameters["window_num_pages"].default is None
     assert inspect.signature(ServingEngine.__init__).parameters["window_num_pages"].default is None
+    # the bound on waiting requests is the engine's own, handed through (a deployment's queue depth)
+    assert inspect.signature(llm_service).parameters["max_waiting"].default == inspect.signature(ServingEngine.__init__).parameters["max_waiting"].default == 1024
     with open(inspect.getsourcefile(llm_service)) as f:
-        assert "window_num_pages=window_num_pages" in f.read()
+        source = f.read()
+    assert "window_num_pages=window_num_pages" in source and "max_waiting=max_waiting" in source
