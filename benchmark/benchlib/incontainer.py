@@ -9,7 +9,8 @@ it, and `/bench/*` is added:
 
     GET  /bench/device        the device as jax reports it, memory_stats, pid
     POST /bench/trace/start   {"dir": ...}: jax.profiler.start_trace there
-    POST /bench/trace/stop    stop_trace; answers the traced window's length
+    POST /bench/trace/stop    stop_trace; answers this clock between start_trace returning and
+                              this call: a lower bound of the recording (trace_reduce.py)
 
 Only the process that holds the chip can trace it or read its memory, so
 these cannot live in the harness. Nothing here touches the engine.

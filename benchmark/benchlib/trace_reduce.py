@@ -4,13 +4,32 @@ Two steps, so that the second can be checked on a small recorded fixture
 without jax or a chip:
 
     table = load_xplane(path)      # needs jax.profiler.ProfileData
-    summary = reduce_table(table)  # plain python
+    summary = reduce_table(table, clock_window_s)  # plain python
 
 A table is {"planes": [{"name", "lines": [{"name", "events": [[name,
 start_ns, dur_ns], ...]}]}]}. Device planes are named "/device:TPU:<n>";
 their "XLA Ops" line holds one event for each operation the device ran
 (named by its HLO text; a `while` spans the operations of its body), and
 "XLA Modules" one for each run of a compiled program (`jit_<fn>(<id>)`).
+
+The traced window. The device is recorded from somewhere inside
+`start_trace` to somewhere inside `stop_trace`, so the profile is never
+shorter than the container's clock between the first returning and the
+second being called, and where the loop's threads hold the interpreter it
+is longer by tens of milliseconds at both ends. The profile's own stamps
+(plane "Task Environment", `profile_start_time` / `profile_stop_time`, the
+base of every event's `start_ns`) do not bound the recording: the stop
+stamp comes a quarter of a second after the last recorded operation of a
+device that never idles (one real trace read on the chip, PR 36). So the
+window is what the two lower bounds of the recording give:
+
+    window_s = max(clock_window_s, span_s)
+
+`span_s` being the first operation's start to the last one's end over all
+device planes. `busy_s <= span_s <= window_s` then holds for ANY trace. A
+device idle at both edges keeps the clock's window; a device that never
+idles is measured over its own span, which understates its idle share by
+at most what the recording reaches past both (a gap at either edge).
 """
 
 from __future__ import annotations
@@ -112,13 +131,14 @@ def _union(intervals: list) -> tuple:
     return sum(e - s for s, e in merged), merged
 
 
-def reduce_table(table: dict, top: int = 10) -> dict:
-    """busy seconds (mean over the device planes), per-module and per-op
-    durations, and the idle gaps labelled by the modules on either side."""
+def reduce_table(table: dict, clock_window_s: float = 0.0, top: int = 10) -> dict:
+    """busy seconds (mean over the device planes), the traced window (the
+    module's head says how it is made), per-module and per-op durations,
+    and the idle gaps labelled by the modules on either side."""
     planes = [p for p in table["planes"] if DEVICE_PLANE.match(p["name"])]
     if not planes:
         raise ValueError("the trace has no device plane: nothing ran on a TPU while it was taken")
-    busy, spans = [], []
+    busy, first, last = [], [], []
     modules: dict = {}
     ops: dict = {}
     gaps: dict = {}
@@ -132,7 +152,8 @@ def reduce_table(table: dict, top: int = 10) -> dict:
             continue
         covered, _merged = _union([(s, s + d) for _n, s, d in source])
         busy.append(covered / 1e9)
-        spans.append((max(s + d for _n, s, d in source) - min(s for _n, s, _d in source)) / 1e9)
+        first.append(min(s for _n, s, _d in source))
+        last.append(max(s + d for _n, s, d in source))
         for name, _start, dur in mod_events:
             modules.setdefault(module_base(name), []).append(dur / 1e6)
         owner = module_owner(mod_events)
@@ -152,9 +173,12 @@ def reduce_table(table: dict, top: int = 10) -> dict:
                 agg[2] += 1
     if not busy:
         raise ValueError("the trace's device planes hold no operation")
+    span_s = (max(last) - min(first)) / 1e9
     return {
         "busy_s": sum(busy) / len(busy),
-        "span_s": max(spans),
+        "span_s": span_s,
+        "clock_window_s": clock_window_s,
+        "window_s": max(clock_window_s, span_s),
         "device_planes": len(busy),
         "modules": {
             name: {"count": len(ms), "median_ms": statistics.median(ms), "total_s": sum(ms) / 1e3}
@@ -170,14 +194,14 @@ def reduce_table(table: dict, top: int = 10) -> dict:
 
 
 def main(argv: list) -> int:
-    """`python trace_reduce.py <trace_dir> <out.json> [--table out]`:
-    run by the harness in a child process (this one may import jax; it is
-    pinned to the CPU and never touches the chip)."""
+    """`python trace_reduce.py <trace_dir> <out.json> <clock_window_s>
+    [--table out]`: run by the harness in a child process (this one may
+    import jax; it is pinned to the CPU and never touches the chip)."""
     os.environ["JAX_PLATFORMS"] = "cpu"
-    trace_dir, out_path = argv[0], argv[1]
+    trace_dir, out_path, clock_window_s = argv[0], argv[1], float(argv[2])
     path = find_xplane(trace_dir)
     table = load_xplane(path)
-    summary = reduce_table(table)
+    summary = reduce_table(table, clock_window_s)
     if "--table" in argv:
         with open(argv[argv.index("--table") + 1], "w") as f:
             json.dump(table, f)

@@ -292,9 +292,9 @@ def run_reference(cell: dict, seed: int, sample: list, state_dir: str, control: 
     return load_json(out_path)
 
 
-def reduce_trace(trace_dir: str, state_dir: str, keep_table: bool) -> dict:
+def reduce_trace(trace_dir: str, state_dir: str, clock_window_s: float, keep_table: bool) -> dict:
     out_path = os.path.join(state_dir, "trace_summary.json")
-    argv = [os.path.join(BENCH_DIR, "benchlib", "trace_reduce.py"), trace_dir, out_path]
+    argv = [os.path.join(BENCH_DIR, "benchlib", "trace_reduce.py"), trace_dir, out_path, repr(float(clock_window_s))]
     if keep_table:
         argv += ["--table", os.path.join(state_dir, "trace_table.json")]
     run_child(argv, timeout_s=200, env={**os.environ, "JAX_PLATFORMS": "cpu"})
@@ -391,8 +391,7 @@ def decide_correct(cell: dict, args, records: list, bad_streams: int, state_dir:
 def traced_metrics(cell: dict, ctx: dict, records: list, trace_window: dict, state_dir: str, keep_table: bool) -> tuple:
     """Reduce the trace, add it and the traced window's work to `ctx`, and
     let every per-layer metric of the cell read its number from there."""
-    trace = reduce_trace(os.path.join(state_dir, "trace"), state_dir, keep_table)
-    trace["window_s"] = trace_window["window_s"]
+    trace = reduce_trace(os.path.join(state_dir, "trace"), state_dir, trace_window["window_s"], keep_table)
     ctx["trace"], ctx["traced_work"] = trace, traced_work(records, trace_window)
     required = {name: m["unit"] for name, m in cell["per_layer"].items()}
     reported = {name: read_layer_metric(name, ctx) for name in required}
@@ -510,6 +509,8 @@ def measure(args, root: str = REPO_ROOT, state_root: str = "") -> dict:
             "mean_active_slots": work["mean_active_slots"], "mean_live_kv_tokens": work["mean_live_kv_tokens"],
         }
         extra["trace_stop_s"] = result["trace_window"]["stop_s"]
+        # `device.window_s` is the larger of the two (benchlib/trace_reduce.py says why)
+        extra["traced_window"] = {"clock_s": ctx["trace"]["clock_window_s"], "span_s": ctx["trace"]["span_s"]}
     line = emit_mod.build_line(
         correct=correct, attempted=len(attempted), failed=len(failed), metrics=reported, required=required,
         device=out_device, traced=bool(args.trace), compared=compared, breakdown=breakdown, extra=extra,

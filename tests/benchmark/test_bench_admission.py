@@ -8,7 +8,6 @@ The harness finds everything beside its own run.py, so the admitted tree is
 driven as the driver drives a checkout: its own run.py, in a child
 (`_drive.py`), with nothing of this checkout's harness on the path."""
 
-import hashlib
 import json
 import os
 import shutil
@@ -17,7 +16,7 @@ import sys
 
 import pytest
 
-from . import _paths
+from . import _accepted, _paths
 from .test_bench_faults import TINYROOT
 import run as bench_run
 from benchlib import reference, trace_reduce
@@ -35,8 +34,7 @@ def files_of(top: str) -> dict:
     for folder, _dirs, names in os.walk(top):
         for name in names:
             path = os.path.join(folder, name)
-            with open(path, "rb") as f:
-                out[os.path.relpath(path, top)] = hashlib.sha256(f.read()).hexdigest()
+            out[os.path.relpath(path, top)] = _accepted.digest(path)
     return out
 
 
@@ -49,21 +47,23 @@ def in_tree(root: str, *argv, timeout: float = 60) -> dict:
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-@pytest.fixture(scope="module")
-def admitted(tmp_path_factory):
-    """The benchmark's tree as committed, copied, with what a `model_config`
-    PR of another architecture brings laid over it: a configuration, its
-    reference, its counts, a kernel's roofline metric, a traffic file, and
-    entries of `configs`, `workloads` and `per_layer`."""
-    root = str(tmp_path_factory.mktemp("admitted"))
-    bench_dir = os.path.join(root, "benchmark")
-    shutil.copytree(_paths.BENCH_DIR, bench_dir, ignore=shutil.ignore_patterns("__pycache__"))
+def copy_the_benchmark(root: str, with_its_tests: bool = False) -> dict:
+    """The benchmark's tree as committed, copied to `root`: BENCHMARK.json
+    and benchmark/ (and tests/benchmark/, where the record of what was
+    accepted is). Returns BENCHMARK.json as it stood."""
+    for top in ("benchmark", "tests/benchmark")[: 2 if with_its_tests else 1]:
+        shutil.copytree(os.path.join(_paths.REPO_ROOT, top), os.path.join(root, top), ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(_paths.REPO_ROOT, "BENCHMARK.json"), root)
-    before = files_of(root)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    was = json.loads(json.dumps(bench))
+        return json.load(f)
 
+
+def lay_another_architecture_over(root: str, bench: dict) -> dict:
+    """What a `model_config` PR of another architecture brings, laid over
+    the tree at `root`: a configuration, its reference, its counts, a
+    kernel's roofline metric, a traffic file, and entries appended to
+    `configs`, `workloads` and `per_layer`. Returns the entries."""
+    bench_dir = os.path.join(root, "benchmark")
     shutil.copytree(os.path.join(OTHER, "benchmark"), bench_dir, dirs_exist_ok=True)
     shutil.copy(os.path.join(TINYROOT, "benchmark", "traffic", "tiny-open.json"), os.path.join(bench_dir, "traffic"))
     with open(os.path.join(OTHER, "entries.json")) as f:
@@ -73,7 +73,16 @@ def admitted(tmp_path_factory):
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f, indent=2)
     os.symlink(os.path.join(_paths.REPO_ROOT, "modal_tpu"), os.path.join(root, "modal_tpu"))  # the system under test
-    return {"root": root, "bench_dir": bench_dir, "before": before, "was": was, "bench": bench, "entries": entries}
+    return entries
+
+
+@pytest.fixture(scope="module")
+def admitted(tmp_path_factory):
+    root = str(tmp_path_factory.mktemp("admitted"))
+    bench = copy_the_benchmark(root)
+    before, was = files_of(root), json.loads(json.dumps(bench))
+    entries = lay_another_architecture_over(root, bench)
+    return {"root": root, "bench_dir": os.path.join(root, "benchmark"), "before": before, "was": was, "bench": bench, "entries": entries}
 
 
 def test_admitting_another_architecture_changes_no_byte_of_a_file_that_was_there(admitted):

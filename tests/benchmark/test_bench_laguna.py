@@ -1,10 +1,10 @@
 """The Laguna-XS.2 configuration's own files: its counts at hand-reckoned
 sizes and against what the program computes, its names and entries, the
 catalog row it is cut from, the reference as the harness's child, the whole
-run on the CPU at `tiny-laguna`, and that admitting it changed no entry the
-benchmark had. The plain reference against the program (logits, weights, the
-shares of the experts) is in
-tests/test_serving_two_pools_reference.py."""
+run on the CPU at `tiny-laguna`. (That admitting it changed no entry the
+benchmark had is test_bench_accepted.py's, for every configuration at once.)
+The plain reference against the program (logits, weights, the shares of the
+experts) is in tests/test_serving_two_pools_reference.py."""
 
 import json
 import os
@@ -199,24 +199,6 @@ def test_the_tiny_fixture_is_the_same_description_and_maps_the_same_keys():
 
     assert TINY["program_keys"] == REAL["program_keys"] and set(TINY["rope_parameters"]) == set(REAL["rope_parameters"])
     assert get_config(incontainer.service_arguments(TINY, 1)["model"]) == get_config("tiny-laguna")
-
-
-def test_admitting_the_configuration_changed_no_entry_that_was_there():
-    """Every entry the benchmark had at PR 33 is there, in place, as it was
-    (the fixture is PR 28's entries; PR 29's are held by their own test), and
-    what this PR appended comes last in each list."""
-    accepted = load(_paths.FIXTURES, "accepted_pr28.json")["benchmark"]
-    now = load(_paths.REPO_ROOT, "BENCHMARK.json")
-    assert set(accepted) == set(now)
-    for key, value in accepted.items():
-        if isinstance(value, list) and value and isinstance(value[0], dict):
-            assert now[key][: len(value)] == value, key
-        else:
-            assert now[key] == value, key
-    assert [c["name"] for c in now["configs"]][-1] == CONFIG and [w["name"] for w in now["workloads"]][-1] == CELL
-    assert [c["name"] for c in now["configs"]][:3] == ["mistral-7b-v0.3-serve-1chip", "yi-1.5-6b-serve-1chip", "mimo-v2-flash-serve-1chip-ep16"]
-    assert {m["name"] for m in now["per_layer"][-len(NEW_METRICS):]} == NEW_METRICS
-    assert len(now["workloads"]) == 5 and sum(w["chips"] == 4 for w in now["workloads"]) == 0
 
 
 # -- the reference as the harness's child, and the whole run on the CPU ------------------

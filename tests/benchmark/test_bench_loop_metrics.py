@@ -14,7 +14,10 @@ from readers import stats_delta_ratio
 LOOP_METRICS = (
     "loop_host_pct", "loop_emit_pct", "loop_prep_pct", "span_write_pct", "prefill_fill_pct", "queue_wait_mean_ms",
     "loop_emit_ms", "loop_admit_ms", "loop_prefill_launch_ms", "loop_decode_launch_ms", "prefill_chunks_per_iter",
+    "decode_overlap_pct",
 )
+with open(os.path.join(_paths.REPO_ROOT, "BENCHMARK.json")) as f:
+    CELLS = [w["name"] for w in json.load(f)["workloads"]]
 
 
 def args(metric):
@@ -70,6 +73,24 @@ def test_a_loop_metric_s_own_file_reads_the_live_engine_and_is_silent_on_a_progr
     assert stats_delta_ratio.read({"stats_start": parent, "stats_end": parent}, **args(metric)) is None
 
 
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_reads_the_decode_overlap_and_a_parent_s_stats_leave_it_silent(cell):
+    """`decode_overlap_pct` names no cells and moves `serve_tokens_per_s`,
+    so every cell lists it, those that later PRs add too; the program
+    before PR 35 counts `steps` and no `loop.steps_overlapped`: the metric
+    is then left out of the line, never a 0."""
+    import run as bench_run
+
+    loaded = bench_run.load_cell(_paths.REPO_ROOT, cell)
+    entry = loaded["per_layer"]["decode_overlap_pct"]
+    assert "workloads" not in entry and entry["moves"] in loaded["end_to_end"] and entry["layer"] == "engine loop"
+    assert args("decode_overlap_pct") == {"num": "loop.steps_overlapped", "den": "steps", "scale": 100.0}
+    parent = {"stats_start": {"steps": 100, "loop": {"iterations": 90}}, "stats_end": {"steps": 1994, "loop": {"iterations": 1902}}}
+    assert bench_run.read_layer_metric("decode_overlap_pct", parent) is None
+    change = {"stats_start": {"steps": 100, "loop": {"steps_overlapped": 90}}, "stats_end": {"steps": 1994, "loop": {"steps_overlapped": 1864}}}
+    assert bench_run.read_layer_metric("decode_overlap_pct", change) == pytest.approx(100.0 * 1774 / 1894)
+
+
 def test_the_loop_metrics_against_a_hand_count(window):
     read = {m: stats_delta_ratio.read(window, **args(m)) for m in LOOP_METRICS}
     assert read["prefill_fill_pct"] == pytest.approx(100 * 40 / 48)
@@ -86,6 +107,8 @@ def test_the_loop_metrics_against_a_hand_count(window):
         read["loop_prep_pct"] * grew["work_seconds"] / 100
     )
     assert read["span_write_pct"] < read["loop_host_pct"]
+    # of the 5 decode steps the first finds nothing in flight; none can be counted twice
+    assert 0 < read["decode_overlap_pct"] <= 100 * 4 / 5
     # a phase missing at one end of the window silences the metric that sums it, and no other
     window = dict(window, stats_start={"loop": {"work_seconds": 0.0, "phase_seconds": {"emit": 0.0}}})
     assert stats_delta_ratio.read(window, **args("loop_prep_pct")) is None and stats_delta_ratio.read(window, **args("loop_emit_pct")) > 0

@@ -92,6 +92,81 @@ def test_busy_is_averaged_over_the_chips_used():
     assert trace_reduce.reduce_table(table)["busy_s"] == pytest.approx((0.063 + 0.005) / 2)
 
 
+# -- the traced window: busy_s cannot pass window_s, on any trace (PR 36) ----------------
+
+
+def device_plane(n: int, intervals: list) -> dict:
+    ops = [["%fusion.1 = f32[8]{0} fusion(...)", int(a * MS), int((b - a) * MS)] for a, b in intervals]
+    return {"name": f"/device:TPU:{n}", "lines": [{"name": "XLA Ops", "events": ops}]}
+
+
+def back_to_back(start_ms: float, stop_ms: float, step_ms: float = 12.5) -> list:
+    """A device that never idles: one operation ends where the next starts."""
+    edges = [start_ms + i * step_ms for i in range(int((stop_ms - start_ms) / step_ms) + 1)]
+    return list(zip(edges, edges[1:]))
+
+
+# times in ms on the profile's own base; the container's clock read 4.000 s between start_trace returning and stop_trace being called
+TRACES = {
+    # the loop of PR 35: the recording reaches 20 ms before the clock's window and 30 ms past it, and the device is busy all through
+    "never_idle_past_both_edges": ([back_to_back(-20.0, 4030.0)], 4.05, 4.05, 4.05),
+    # what killed a traced run of PR 35 (busy 6.0208 against a clock window of 6.0053), at its own numbers
+    "pr35_cell_2": ([[(0.0, 6020.841771)]], 6.020841771, 6.020841771, 6.020841771),
+    # a device idle at both edges keeps the clock's window: its idle share is not shrunk to the span
+    "idle_at_both_edges": ([[(1000.0, 2000.0)]], 1.0, 1.0, 4.0),
+    "idle_inside_and_at_the_end": ([[(-5.0, 1000.0), (1500.0, 3000.0)]], 2.505, 3.005, 4.0),
+    # two chips: busy is the mean, the span runs from the first operation of either to the last of either
+    "two_planes": ([back_to_back(0.0, 3000.0), back_to_back(1000.0, 4025.0)], (3.0 + 3.025) / 2, 4.025, 4.025),
+    "two_planes_one_nearly_idle": ([[(100.0, 110.0)], back_to_back(-10.0, 4010.0, 20.0)], (0.010 + 4.02) / 2, 4.02, 4.02),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TRACES))
+def test_busy_cannot_pass_the_window_on_any_trace(case):
+    from benchlib import emit as emit_mod
+    from readers import trace_idle_pct
+
+    planes, busy_s, span_s, window_s = TRACES[case]
+    clock = 6.005311369 if case == "pr35_cell_2" else 4.0
+    out = trace_reduce.reduce_table({"planes": [device_plane(n, ops) for n, ops in enumerate(planes)]}, clock)
+    assert out["busy_s"] == pytest.approx(busy_s, rel=1e-9) and out["span_s"] == pytest.approx(span_s, rel=1e-9)
+    assert out["clock_window_s"] == clock and out["window_s"] == pytest.approx(window_s, rel=1e-9)
+    assert 0 < out["busy_s"] <= out["span_s"] <= out["window_s"] and out["window_s"] >= clock
+    assert trace_idle_pct.read({"trace": out}) == pytest.approx(100.0 * (1.0 - busy_s / window_s), abs=1e-9)
+    # the pair goes onto the line as it is: the harness's own refusal (busy_s outside (0, window_s]) stays and finds nothing
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": len(planes), "memory_peak_bytes": 1, "busy_s": out["busy_s"], "window_s": out["window_s"]}
+    line = dict(correct=True, attempted=1, failed=0, metrics={"device_idle_pct": 1.0}, required={"device_idle_pct": "%"}, traced=True, compared={})
+    assert emit_mod.build_line(device=device, **line)["device"]["window_s"] == out["window_s"]
+    if out["busy_s"] > clock:  # and the parent's pair, the clock's window alone, is the one that was refused
+        with pytest.raises(emit_mod.MalformedLine):
+            emit_mod.build_line(device={**device, "window_s": clock}, **line)
+
+
+def test_the_child_takes_the_clock_s_window_on_its_command_line(tmp_path, monkeypatch):
+    monkeypatch.setattr(trace_reduce, "find_xplane", lambda trace_dir: trace_dir)
+    monkeypatch.setattr(trace_reduce, "load_xplane", lambda path: {"planes": [device_plane(0, back_to_back(-20.0, 4030.0))]})
+    out_path, table_path = tmp_path / "summary.json", tmp_path / "table.json"
+    assert trace_reduce.main([str(tmp_path), str(out_path), "4.0", "--table", str(table_path)]) == 0
+    out = json.loads(out_path.read_text())
+    assert (out["clock_window_s"], out["window_s"]) == (4.0, pytest.approx(4.05)) and out["busy_s"] <= out["window_s"]
+    assert json.loads(table_path.read_text())["planes"][0]["name"] == "/device:TPU:0"
+
+
+def test_the_harness_hands_the_clock_s_window_to_the_reduction_and_its_readers_the_pair_it_gives_back(tmp_path, monkeypatch):
+    import run as bench_run
+
+    monkeypatch.setattr(trace_reduce, "find_xplane", lambda trace_dir: trace_dir)
+    monkeypatch.setattr(trace_reduce, "load_xplane", lambda path: {"planes": [device_plane(0, back_to_back(-20.0, 4030.0) + [(4040.0, 4050.0)])]})
+    monkeypatch.setattr(bench_run, "run_child", lambda argv, timeout_s, env=None: trace_reduce.main(argv[1:]))  # the child, in this process
+    cell, ctx = {"per_layer": {"device_idle_pct": {"unit": "%"}}}, {}
+    clock = {"window_s": 4.0, "t_start": 0.0, "t_stop": 4.0, "stop_s": 0.2}
+    required, reported, _breakdown, silent = bench_run.traced_metrics(cell, ctx, [], clock, str(tmp_path), False)
+    trace = ctx["trace"]
+    assert (trace["clock_window_s"], trace["span_s"], trace["window_s"]) == (4.0, pytest.approx(4.07), pytest.approx(4.07))
+    assert trace["busy_s"] == pytest.approx(4.06) and required == {"device_idle_pct": "%"} and not silent
+    assert reported["device_idle_pct"] == pytest.approx(100.0 * 0.01 / 4.07)
+
+
 def test_a_trace_in_which_nothing_ran_on_the_device_is_an_error():
     with pytest.raises(ValueError):
         trace_reduce.reduce_table({"planes": [{"name": "/host:CPU", "lines": []}]})
@@ -124,4 +199,6 @@ def test_the_recorded_trace_reduces_to_the_numbers_worked_out_beside_it():
             covered += e - end
             end = e
     assert out["busy_s"] == pytest.approx(covered / 1e9, rel=1e-9)
-    assert out["busy_s"] <= out["span_s"]
+    # the cut is 0.36 s of a window: with no clock given the window is the cut's own span, and under a clock that read longer, the clock's
+    assert out["busy_s"] <= out["span_s"] == out["window_s"] == pytest.approx((max(e for _s, e in events) - events[0][0]) / 1e9, rel=1e-12)
+    assert trace_reduce.reduce_table(fixture["table"], 0.36)["window_s"] == 0.36
