@@ -4,7 +4,8 @@ lives on this chip.
 The layer is told which experts it holds (`cfg.experts_held`: first, count).
 It routes over ALL `n_routed_experts` as the model's equations say — sigmoid
 scores in float32, the top `experts_per_token` of the scores (plus a stored
-selection bias where the model has one), weights = the chosen scores
+selection bias where the model has one; within the best `topk_group` of
+`n_group` groups where the router has a group limit), weights = the chosen scores
 renormalised (the bias selects, it does not weigh) times `cfg.routed_scale` —
 keeps the (token, expert) pairs that fell on held experts, and returns their
 weighted sum, plus the shared expert's output where the model has one (every
@@ -33,12 +34,27 @@ import jax.numpy as jnp
 from jax import lax
 
 
+def _group_limit(select: jax.Array, n_group: int, topk_group: int) -> jax.Array:
+    """select [T, E] float32 with the experts outside each token's best
+    `topk_group` groups at -inf. The E experts lie in `n_group` groups of
+    consecutive ones; a group scores the sum of its two largest entries."""
+    grouped = select.reshape(select.shape[0], n_group, -1)
+    group_scores = jnp.sum(lax.top_k(grouped, min(2, grouped.shape[-1]))[0], axis=-1)  # [T, n_group]
+    _, best = lax.top_k(group_scores, topk_group)
+    stays = jnp.any(best[..., None] == jnp.arange(n_group, dtype=best.dtype), axis=1)  # [T, n_group]
+    return jnp.where(stays[..., None], grouped, -jnp.inf).reshape(select.shape)
+
+
 def route(cfg, h: jax.Array, layer: dict) -> tuple[jax.Array, jax.Array]:
     """h [T, D] -> (chosen [T, k] expert ids, weights [T, k] float32)."""
     z = jnp.einsum("td,de->te", h, layer["router"], preferred_element_type=jnp.float32)
     scores = jax.nn.sigmoid(z)
     bias = layer.get("router_bias")
-    _, chosen = lax.top_k(scores if bias is None else scores + bias.astype(jnp.float32), cfg.experts_per_token)
+    select = scores if bias is None else scores + bias.astype(jnp.float32)
+    if cfg.n_group > 1:
+        with jax.named_scope("moe_group_limit"):
+            select = _group_limit(select, cfg.n_group, cfg.topk_group)
+    _, chosen = lax.top_k(select, cfg.experts_per_token)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = picked / jnp.sum(picked, axis=-1, keepdims=True)
     return chosen, weights if cfg.routed_scale == 1.0 else weights * cfg.routed_scale

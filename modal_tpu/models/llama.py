@@ -51,7 +51,12 @@ class LayerKind:
     yarn: tuple = ()
     gated: bool = False  # sigmoid(h Wg), one scalar a query head, multiplies that head's attention output before wo
     experts: bool = False
-    attn_name: str = ""  # "", "full" or "swa": suffix of the scope's and the decode kernel's name
+    attn_name: str = ""  # "", "full", "swa" or "mla": suffix of the scope's and the decode kernel's name
+    # latent attention: (query rank, latent rank, nope width, rope width, value width) of a head. The
+    # cache holds ONE row a token, [latent | shared rope key]; `n_kv_heads` is then 1 and `rope_dim` the
+    # rope width. () = keys and values a KV head
+    latent: tuple = ()
+    softmax_scale: float = 0.0  # 0 = 1 / sqrt(head width); a YaRN with `mscale_all_dim` scales it
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,6 +107,22 @@ class LlamaConfig:
     # "sliding_attention": {...}} with rope_theta, partial_rotary_factor, rope_type ("default" or
     # "yarn") and YaRN's numbers; a type it does not name keeps rope_theta / rope_fraction above
     rope_parameters: Any = ()
+    # latent (MLA) attention in every layer, kv_rank > 0: h -> a latent of kv_rank and ONE rope key of
+    # qk_rope_dim a token (all that is cached), the query through a rank of q_rank; a head is
+    # [qk_nope_dim | qk_rope_dim] wide against keys rebuilt from the latent, its values v_head_dim wide
+    q_rank: int = 0
+    kv_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    # one YaRN for every layer, as the DeepSeek family publishes it: {"type": "yarn", "factor",
+    # "original_max_position_embeddings", "beta_fast", "beta_slow", "mscale", "mscale_all_dim"}; with
+    # m(s, a) = 0.1 a ln s + 1, cos and sin are multiplied by m(factor, mscale) / m(factor,
+    # mscale_all_dim) and the softmax's scale by m(factor, mscale_all_dim)^2
+    rope_scaling: Any = ()
+    # the router's group limit: the routed experts lie in n_group groups of consecutive ones, a group
+    # scores the sum of its two best experts, the best topk_group groups stay; 1 = no limit
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self):
         for field in ("attn_pattern", "ffn_pattern", "n_heads_per_layer"):
@@ -115,6 +136,8 @@ class LlamaConfig:
         object.__setattr__(self, "rope_parameters", tuple(
             (name, tuple(sorted(dict(rule).items()))) for name, rule in rules if not isinstance(rule, (int, float))
         ))
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
         if any(self.attn_pattern) and self.window < 1:
             raise ValueError("attn_pattern has window layers but window is 0")
         if any(self.ffn_pattern):
@@ -123,6 +146,14 @@ class LlamaConfig:
                 raise ValueError("ffn_pattern has expert layers: n_routed_experts, experts_per_token and expert_dim must be set")
             if not (0 <= lo and n > 0 and lo + n <= self.n_routed_experts):
                 raise ValueError(f"experts held {lo}..{lo + n} lie outside the {self.n_routed_experts} routed experts")
+            if self.n_routed_experts % self.n_group or not 0 < self.topk_group <= self.n_group:
+                raise ValueError(f"{self.n_routed_experts} routed experts in {self.n_group} groups of which {self.topk_group} stay")
+            if self.experts_per_token > self.topk_group * (self.n_routed_experts // self.n_group):
+                raise ValueError(f"top-{self.experts_per_token} of {self.topk_group} groups of {self.n_routed_experts // self.n_group}")
+        if self.kv_rank and not (self.q_rank > 0 and self.qk_nope_dim > 0 and self.qk_rope_dim > 0 and self.v_head_dim > 0):
+            raise ValueError("latent attention (kv_rank): q_rank, qk_nope_dim, qk_rope_dim and v_head_dim must be set")
+        if self.kv_rank and self.attn_pattern:
+            raise ValueError("latent attention beside window layers: a latent row has no window pool")
         for kind in self.layer_kinds:
             if kind.rope_dim % 2:
                 raise ValueError(f"rotary width {kind.rope_dim} of a head of {self.head_dim} must be even")
@@ -131,6 +162,8 @@ class LlamaConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.kv_rank:
+            return self.qk_nope_dim + self.qk_rope_dim
         return self.qk_head_dim or self.dim // self.n_heads
 
     @property
@@ -157,33 +190,46 @@ class LlamaConfig:
     def layer_kinds(self) -> tuple:
         """One LayerKind a layer: the description the served path runs by."""
         kinds = []
-        rules = {name: dict(rule) for name, rule in self.rope_parameters}
+        rules, scaling = {name: dict(rule) for name, rule in self.rope_parameters}, dict(self.rope_scaling)
+        if scaling and scaling.get("type", scaling.get("rope_type")) != "yarn":
+            raise ValueError(f"rope_scaling {scaling}: the served path scales rotary positions by YaRN or not at all")
+        latent = (self.q_rank, self.kv_rank, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim) if self.kv_rank else ()
         for i in range(self.n_layers):
             windowed = bool(self.attn_pattern and self.attn_pattern[i])
             routed = bool(self.ffn_pattern and self.ffn_pattern[i])
             rule = rules.get("sliding_attention" if windowed else "full_attention", {})
             if rule.get("rope_type", "default") not in ("default", "yarn"):
                 raise ValueError(f"rope_type {rule['rope_type']!r}: the served path turns heads by plain rotary or YaRN")
-            yarn = ()
+            yarn, softmax_scale = (), 0.0
             if rule.get("rope_type") == "yarn":
                 factor = float(rule["factor"])
                 yarn = (
                     factor, int(rule["original_max_position_embeddings"]), float(rule.get("beta_fast", 32)),
-                    float(rule.get("beta_slow", 1)), float(rule.get("attention_factor") or 0.1 * math.log(factor) + 1.0),
+                    float(rule.get("beta_slow", 1)), float(rule.get("attention_factor") or yarn_mscale(factor, 1.0)),
                 )
+            elif scaling:
+                factor, all_dim = float(scaling["factor"]), float(scaling.get("mscale_all_dim", 0))
+                yarn = (
+                    factor, int(scaling["original_max_position_embeddings"]), float(scaling.get("beta_fast", 32)),
+                    float(scaling.get("beta_slow", 1)), yarn_mscale(factor, float(scaling.get("mscale", 1))) / yarn_mscale(factor, all_dim),
+                )
+                softmax_scale = self.head_dim ** -0.5 * yarn_mscale(factor, all_dim) ** 2
             kinds.append(LayerKind(
                 n_heads=self.n_heads_per_layer[i] if self.n_heads_per_layer else self.n_heads,
-                n_kv_heads=(self.window_kv_heads or self.n_kv_heads) if windowed else self.n_kv_heads,
+                # to the cache a latent layer is one KV head under every query head
+                n_kv_heads=1 if latent else (self.window_kv_heads or self.n_kv_heads) if windowed else self.n_kv_heads,
                 window=self.window if windowed else 0,
                 sink=windowed and self.window_sink,
                 rope_theta=float(rule.get("rope_theta") or ((self.window_rope_theta or self.rope_theta) if windowed else self.rope_theta)),
-                rope_dim=self._rope_width(float(rule.get("partial_rotary_factor", self.rope_fraction))),
+                rope_dim=self.qk_rope_dim if latent else self._rope_width(float(rule.get("partial_rotary_factor", self.rope_fraction))),
                 yarn=yarn,
                 gated=self.attn_gate,
                 experts=routed,
                 # the dense models' scope and kernel keep their names; a model
                 # with a layer pattern tells its kinds apart in a trace
-                attn_name=("swa" if windowed else "full") if self.attn_pattern else "",
+                attn_name="mla" if latent else ("swa" if windowed else "full") if self.attn_pattern else "",
+                latent=latent,
+                softmax_scale=softmax_scale,
             ))
         return tuple(kinds)
 
@@ -201,10 +247,11 @@ class LlamaConfig:
 
     @property
     def uniform(self) -> bool:
-        """Every layer alike with a dense FFN: `params["layers"]` is ONE
-        stacked tree and the KV pool one array (the dense presets). Otherwise
+        """Every layer alike with a dense FFN and keys and values a KV head:
+        `params["layers"]` is ONE stacked tree and the KV pool one array (the
+        dense presets). Otherwise
         both are tuples, one entry a group of `layer_groups`."""
-        return not (self.attn_pattern or self.ffn_pattern)
+        return not (self.attn_pattern or self.ffn_pattern or self.kv_rank)
 
     @property
     def has_window(self) -> bool:
@@ -220,7 +267,12 @@ class LlamaConfig:
             hd, vd, (_lo, held) = self.head_dim, self.v_dim, self.experts_held
             total = 2 * self.vocab_size * self.dim + self.dim
             for k in self.layer_kinds:
-                total += self.dim * (k.n_heads * hd + k.n_kv_heads * (hd + vd)) + k.n_heads * vd * self.dim
+                if k.latent:
+                    q_rank, kv_rank, nope, rope, _vd = k.latent
+                    total += self.dim * (q_rank + kv_rank + rope) + q_rank + kv_rank  # the two down-projections, their norms
+                    total += q_rank * k.n_heads * hd + kv_rank * k.n_heads * (nope + vd) + k.n_heads * vd * self.dim
+                else:
+                    total += self.dim * (k.n_heads * hd + k.n_kv_heads * (hd + vd)) + k.n_heads * vd * self.dim
                 total += 2 * self.dim + (k.n_heads if k.sink else 0) + (self.dim * k.n_heads if k.gated else 0)
                 if k.experts:
                     total += (self.dim + self.router_bias) * self.n_routed_experts
@@ -241,6 +293,12 @@ class LlamaConfig:
             + 2 * self.dim  # norms
         )
         return embed * 2 + per_layer * self.n_layers + self.dim
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """m(s, a) = 0.1 a ln s + 1 (1 at s <= 1): the DeepSeek family's rule for
+    what a YaRN of factor s does to cos / sin and to the softmax's scale."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 # Llama-3 architecture hyperparameters (public: Meta Llama 3 release).
@@ -340,6 +398,41 @@ CONFIGS: dict[str, LlamaConfig] = {
         attn_gate=True, ffn_pattern=(0, 1, 1, 1, 1), n_routed_experts=32, experts_per_token=4, expert_dim=32,
         shared_expert_dim=32, routed_scale=2.5, router_bias=False,
     ),
+    # A.X-K1 as published (skt/A.X-K1 config.json): latent attention in all 61
+    # layers (a query of rank 1536, ONE cached row of 512 + 64 a token, 64 heads
+    # of 128 nope + 64 rope against values of 128), a YaRN x32 whose factor
+    # enters the softmax's scale (mscale = mscale_all_dim = 1), a leading dense
+    # layer and then 192 routed experts of 2048 in 8 groups of which the best 4
+    # stay, 8 a token, renormalised sigmoid weights times 2.5, beside a shared
+    # expert of 2048. Whole it is 519 B parameters: a chip serves a depth cut and
+    # its share of the experts and of the vocabulary
+    # (benchmark/configs/a.x-k1-serve-1chip-ep16.json, whose `assumed` says
+    # which readings of the row these are).
+    "a.x-k1": LlamaConfig(
+        name="a.x-k1", vocab_size=163_840, dim=7168, n_layers=61, n_heads=64, n_kv_heads=64, ffn_dim=18432,
+        norm_eps=1e-6, rope_theta=10_000.0, max_seq_len=131_072, q_rank=1536, kv_rank=512, qk_nope_dim=128,
+        qk_rope_dim=64, v_head_dim=128,
+        rope_scaling={
+            "type": "yarn", "factor": 32, "original_max_position_embeddings": 4096, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+        },
+        ffn_pattern=(0,) + (1,) * 60, n_routed_experts=192, experts_per_token=8, expert_dim=2048, shared_expert_dim=2048,
+        routed_scale=2.5, router_bias=False, n_group=8, topk_group=4,
+    ),
+    # the same description at a size the CPU tests hold: 1 dense + 4 expert
+    # layers, a query rank of 24, a latent of 16 + a rope key of 8, 24 experts in
+    # 4 groups of which 2 stay, top-4, 6 held, a YaRN (a ramp over frequencies
+    # 0..3) that scales cos / sin (m(4, 1) / m(4, 0.5)) AND the softmax (m(4, 0.5)^2)
+    "tiny-axk1": LlamaConfig(
+        name="tiny-axk1", vocab_size=512, dim=64, n_layers=5, n_heads=4, n_kv_heads=4, ffn_dim=128, norm_eps=1e-6,
+        rope_theta=100.0, max_seq_len=256, q_rank=24, kv_rank=16, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+        rope_scaling={
+            "type": "yarn", "factor": 4, "original_max_position_embeddings": 64, "beta_fast": 8, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 0.5,
+        },
+        ffn_pattern=(0, 1, 1, 1, 1), n_routed_experts=24, experts_per_token=4, expert_dim=32, n_experts_held=6,
+        shared_expert_dim=32, routed_scale=2.5, router_bias=False, n_group=4, topk_group=2,
+    ),
 }
 
 
@@ -375,7 +468,8 @@ def _init_kind(cfg: LlamaConfig, kind: LayerKind, k: jax.Array) -> dict:
     every share of the experts draws the same expert e. What later kinds
     added draws from four more keys, split from fold_in(key, 1) so that the
     ten stay what they were: the attention gate, the shared expert's gate,
-    up and down."""
+    up and down. A latent layer draws its five projections from four keys split
+    from fold_in(key, 2) and `wo` from the ten's, and has no wq / wk / wv."""
     init = jax.nn.initializers.normal(stddev=0.02)
     ks = jax.random.split(k, 10)
     # drawn only where a kind has what they are for: a model without draws, and boots, as it did
@@ -383,12 +477,29 @@ def _init_kind(cfg: LlamaConfig, kind: LayerKind, k: jax.Array) -> dict:
     hd, vd = cfg.head_dim, cfg.v_dim
     layer = {
         "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
-        "wq": init(ks[0], (cfg.dim, kind.n_heads * hd), cfg.dtype),
-        "wk": init(ks[1], (cfg.dim, kind.n_kv_heads * hd), cfg.dtype),
-        "wv": init(ks[2], (cfg.dim, kind.n_kv_heads * vd), cfg.dtype),
         "wo": init(ks[3], (kind.n_heads * vd, cfg.dim), cfg.dtype),
         "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype),
     }
+    if kind.latent:
+        # four keys split from fold_in(key, 2): the query's down- and up-projection, the latent's
+        # down-projection [latent | rope key] and its up-projection, a head [nope keys | values]
+        # (the published kv_b_proj); the two inner norms' gains are ones
+        q_rank, kv_rank, nope, rope, _vd = kind.latent
+        down_q, up_q, down_kv, up_kv = jax.random.split(jax.random.fold_in(k, 2), 4)
+        layer.update({
+            "wq_down": init(down_q, (cfg.dim, q_rank), cfg.dtype),
+            "q_norm": jnp.ones((q_rank,), cfg.dtype),
+            "wq_up": init(up_q, (q_rank, kind.n_heads * hd), cfg.dtype),
+            "wkv_down": init(down_kv, (cfg.dim, kv_rank + rope), cfg.dtype),
+            "kv_norm": jnp.ones((kv_rank,), cfg.dtype),
+            "wkv_up": init(up_kv, (kv_rank, kind.n_heads * (nope + vd)), cfg.dtype),
+        })
+    else:
+        layer.update({
+            "wq": init(ks[0], (cfg.dim, kind.n_heads * hd), cfg.dtype),
+            "wk": init(ks[1], (cfg.dim, kind.n_kv_heads * hd), cfg.dtype),
+            "wv": init(ks[2], (cfg.dim, kind.n_kv_heads * vd), cfg.dtype),
+        })
     if kind.sink:
         layer["sink"] = init(ks[9], (kind.n_heads,), cfg.dtype)
     if kind.gated:

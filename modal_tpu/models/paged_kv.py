@@ -56,9 +56,11 @@ def k_cache_dim(cfg: LlamaConfig) -> int:
     innermost, and the decode kernel, which needs a page's keys contiguous,
     would be handed a transposed copy of the whole pool at every call
     (PERF.md section 6, PR 29). The zeros add nothing to q.k; scores scale by
-    the model's own width."""
-    hd = cfg.head_dim
-    return hd if hd <= 128 or hd % 128 == 0 else -(-hd // 128) * 128
+    the model's own width. A latent layer's row, [latent | rope key] (512 + 64),
+    keeps the same rule (640): a 576-wide minor dimension is laid out the same
+    way (PERF.md section 6, PR 37)."""
+    width = cfg.kv_rank + cfg.qk_rope_dim if cfg.kv_rank else cfg.head_dim
+    return width if width <= 128 or width % 128 == 0 else -(-width // 128) * 128
 
 
 def window_pages_per_slot(window: int, page_size: int) -> int:
@@ -90,7 +92,10 @@ class PagedKVCache(NamedTuple):
     groups share the page ids of `page_table` (a pool that grows with the
     context), window groups those of `window_table` (a pool bounded by the
     window: a page behind it goes back to its free list while the request
-    lives, and its stale table entry is never addressed).
+    lives, and its stale table entry is never addressed). A group of LATENT
+    layers (`kind.latent`) keeps one array, `[layers, pages, page_size, 1,
+    stored row width]`, a token's `[latent | rope key]` row, and None in
+    v_pages: its values are the first columns of its rows.
 
     A page id names the same page in every layer of a pool: the tables, the
     allocators, `copy_page` and the page shipment index `[:, id]`. The jitted
@@ -135,7 +140,8 @@ class PagedKVCache(NamedTuple):
         for kind, _first, n in cfg.layer_groups:
             pool = (n, window_num_pages if kind.window else num_pages, page_size, kind.n_kv_heads)
             k_pages.append(jnp.zeros(pool + (k_cache_dim(cfg),), cfg.dtype))
-            v_pages.append(jnp.zeros(pool + (cfg.v_dim,), cfg.dtype))
+            # what a pool stores is its layers' kind's: a latent layer has no values of its own
+            v_pages.append(None if kind.latent else jnp.zeros(pool + (cfg.v_dim,), cfg.dtype))
         return PagedKVCache(
             k_pages=tuple(k_pages),
             v_pages=tuple(v_pages),
@@ -159,7 +165,7 @@ class PagedKVCache(NamedTuple):
         return self.page_table.shape[1] * self.page_size
 
     def pool_bytes(self) -> int:
-        """Bytes of every K and V pool."""
+        """Bytes of every K and V pool (and of every latent one)."""
         return sum(int(a.size) * a.dtype.itemsize for a in jax.tree_util.tree_leaves((self.k_pages, self.v_pages)))
 
 
@@ -170,7 +176,7 @@ def pool_bytes_by_kind(cfg: LlamaConfig, cache: PagedKVCache) -> tuple[int, int]
         return cache.pool_bytes(), 0
     sizes = [0, 0]
     for (kind, _first, _n), k, v in zip(cfg.layer_groups, cache.k_pages, cache.v_pages):
-        sizes[bool(kind.window)] += int(k.size) * k.dtype.itemsize + int(v.size) * v.dtype.itemsize
+        sizes[bool(kind.window)] += sum(int(a.size) * a.dtype.itemsize for a in (k, v) if a is not None)
     return sizes[0], sizes[1]
 
 
@@ -218,11 +224,9 @@ def copy_page(cache: PagedKVCache, slot: int, table_index: int, dst_page: jax.Ar
     slots — is never mutated (ISSUE 12 CoW contract)."""
     src = cache.page_table[slot, table_index]
     dst = dst_page.astype(jnp.int32)
-    return cache._replace(
-        k_pages=cache.k_pages.at[:, dst].set(cache.k_pages[:, src]),
-        v_pages=cache.v_pages.at[:, dst].set(cache.v_pages[:, src]),
-        page_table=cache.page_table.at[slot, table_index].set(dst),
-    )
+    # every array `page_table` addresses: one pool, or one a layer group (a latent group has no values)
+    k_pages, v_pages = jax.tree_util.tree_map(lambda pool: pool.at[:, dst].set(pool[:, src]), (cache.k_pages, cache.v_pages))
+    return cache._replace(k_pages=k_pages, v_pages=v_pages, page_table=cache.page_table.at[slot, table_index].set(dst))
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -308,16 +312,17 @@ def import_pages(cache: PagedKVCache, page_ids: list[int], data: dict) -> PagedK
 def _scatter_kv(k_pages, v_pages, k, v, page_ids, offsets):
     """Write per-position K/V rows into their pages.
     k_pages/v_pages: [P, page, n_kv, hd]; k/v: [T, n_kv, hd];
-    page_ids/offsets: [T] (scratch-routed entries carry page 0)."""
+    page_ids/offsets: [T] (scratch-routed entries carry page 0). A latent
+    layer has rows and no values: v_pages and v are None."""
     return (
         k_pages.at[page_ids, offsets].set(k, mode="drop"),
-        v_pages.at[page_ids, offsets].set(v, mode="drop"),
+        None if v_pages is None else v_pages.at[page_ids, offsets].set(v, mode="drop"),
     )
 
 
 def _paged_attention(
     q, k_pages, v_pages, page_table, mask, positions=None, attn_impl="gather",
-    window=0, sink=None, scale=None, kernel_name="paged_decode_attention",
+    window=0, sink=None, scale=None, kernel_name="paged_decode_attention", latent=0,
 ):
     """Attend each slot's page span: what `paged_decode_step` and
     `paged_verify_step` run (a prefill chunk has `_prefill_attention`).
@@ -339,13 +344,22 @@ def _paged_attention(
     stored keys are padded past the model's width; a `window` (the mask the
     caller passes carries it, the kernel walks only the window's pages); a
     `sink` [H], one logit a query head that joins the softmax's denominator
-    and takes no value."""
+    and takes no value; `latent` > 0 where the pool holds latent rows and no
+    values (v_pages None): q is the absorbed query at the rows' stored width,
+    a row's first `latent` columns are its value, and the kernel is the latent
+    one (`paged_decode_attention_mla`)."""
     s, sq, h, hd = q.shape
-    vd = v_pages.shape[-1]
+    vd = latent or v_pages.shape[-1]
     scale = scale or 1.0 / math.sqrt(hd)
     if attn_impl in ("kernel", "kernel_interpret") and sq == 1 and positions is not None:
-        from ..ops.paged_attention import paged_decode_attention
+        from ..ops.paged_attention import paged_decode_attention, paged_decode_attention_mla
 
+        if latent:
+            out = paged_decode_attention_mla(
+                q.reshape(s, h, hd), k_pages.reshape(k_pages.shape[:2] + (hd,)), page_table, positions,
+                latent=latent, scale=scale, name=kernel_name, interpret=(attn_impl == "kernel_interpret"),
+            )
+            return out.reshape(s, sq, h, vd)
         n_kv = k_pages.shape[2]
         n_rep = h // n_kv
         out = paged_decode_attention(
@@ -366,7 +380,7 @@ def _paged_attention(
     k_span = page_table.shape[1] * page
     # [S, pages_per_slot, page, n_kv, hd] -> [S, K, n_kv, hd]
     k_att = k_pages[page_table].reshape(s, k_span, n_kv, hd)
-    v_att = v_pages[page_table].reshape(s, k_span, n_kv, vd)
+    v_att = k_att[..., :latent] if latent else v_pages[page_table].reshape(s, k_span, n_kv, vd)
     n_rep = h // n_kv
     k_att = repeat_kv(k_att, n_rep)
     v_att = repeat_kv(v_att, n_rep)
@@ -395,7 +409,7 @@ def prefill_kv_attended(live: int, pages_per_slot: int, page_size: int) -> int:
     return math.ceil(live / block) * block
 
 
-def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, window=0, sink=None, scale=None):
+def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, window=0, sink=None, scale=None, expand=None):
     """One slot's prefill chunk against the slot's LIVE prefix: a flash
     forward over KV blocks of the page row. q: [Sq, H, hd] at positions q_pos
     [Sq]; k_pages/v_pages: [P, page, n_kv, hd], the chunk's own K/V already
@@ -418,24 +432,39 @@ def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, windo
     from below too; a later row may then see nothing in a block, so its
     running max is kept finite by hand. A `sink` [H] joins each row's sum
     once, after the last block. Values may be narrower than keys, and
-    `scale` is the model's own where q and the stored keys are padded."""
+    `scale` is the model's own where q and the stored keys are padded.
+
+    A pool of latent rows (v_pages None) brings `expand`: (a block's rows
+    [block, 1, stored]) -> (keys [block, H, hd], values [block, H, vd]), the
+    block's keys and values a head rebuilt from its latents, against q as the
+    model has it [Sq, H, hd]. For a chunk of 256 rows that is cheaper on the
+    chip than the decode step's absorbed form, whose accumulator is as wide
+    as the latent (PERF.md section 6, PR 37)."""
     sq, h, hd = q.shape
-    page, n_kv, vd = k_pages.shape[1], k_pages.shape[2], v_pages.shape[-1]
-    n_rep = h // n_kv
+    page, n_kv, stored = k_pages.shape[1:]
     block = block_pages * page
+    if expand is not None:
+        k_s, v_s = jax.eval_shape(expand, jax.ShapeDtypeStruct((block, n_kv, stored), k_pages.dtype))
+        n_kv_q, vd = k_s.shape[1], v_s.shape[-1]
+    else:
+        n_kv_q, vd = n_kv, v_pages.shape[-1]
+    n_rep = h // n_kv_q
     # a row the block does not divide is padded with page 0 (a scratch page,
     # of the pool's first layer where the ids are a later layer's): those
     # positions lie past the span, so past every row that is read
     row = jnp.pad(row, (0, -row.shape[0] % block_pages))
-    qg = q.reshape(sq, n_kv, n_rep, hd)
+    qg = q.reshape(sq, n_kv_q, n_rep, hd)
     scale = scale or 1.0 / math.sqrt(hd)
     offsets = jnp.arange(block, dtype=jnp.int32)
 
     def fold(b, carry):
         m, l, acc = carry
         ids = lax.dynamic_slice_in_dim(row, b * block_pages, block_pages)
-        k_blk = k_pages[ids].reshape(block, n_kv, hd)
-        v_blk = v_pages[ids].reshape(block, n_kv, vd)
+        k_blk = k_pages[ids].reshape(block, n_kv, stored)
+        if expand is not None:
+            k_blk, v_blk = expand(k_blk)
+        else:
+            v_blk = v_pages[ids].reshape(block, n_kv, vd)
         s = jnp.einsum("qgrd,kgd->grqk", qg, k_blk, preferred_element_type=jnp.float32) * scale
         kv_pos = (b * block + offsets)[None, :]
         seen = kv_pos <= q_pos[:, None]  # [Sq, block]
@@ -458,14 +487,14 @@ def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, windo
         return m_new, l, acc
 
     init = (
-        jnp.full((n_kv, n_rep, sq), -jnp.inf, jnp.float32),
-        jnp.zeros((n_kv, n_rep, sq), jnp.float32),
-        jnp.zeros((n_kv, n_rep, sq, vd), jnp.float32),
+        jnp.full((n_kv_q, n_rep, sq), -jnp.inf, jnp.float32),
+        jnp.zeros((n_kv_q, n_rep, sq), jnp.float32),
+        jnp.zeros((n_kv_q, n_rep, sq, vd), jnp.float32),
     )
     first_block = jnp.maximum(q_pos[0] - (window - 1), 0) // block if window else 0
     m, l, acc = lax.fori_loop(first_block, (live + block - 1) // block, fold, init)
     if sink is not None:
-        l = l + jnp.exp(sink.astype(jnp.float32).reshape(n_kv, n_rep)[..., None] - m)
+        l = l + jnp.exp(sink.astype(jnp.float32).reshape(n_kv_q, n_rep)[..., None] - m)
     out = (acc / l[..., None]).astype(q.dtype)  # [n_kv, n_rep, Sq, vd]
     return out.transpose(2, 0, 1, 3).reshape(sq, h, vd)
 
@@ -480,13 +509,81 @@ def _rope(kind, x, positions, inv_freq):
     return jnp.concatenate([apply_rope(x[..., :rd], positions, inv_freq, scale), x[..., rd:]], axis=-1)
 
 
+def _pad_last(x, width):
+    return x if x.shape[-1] == width else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def _heads_qkv(cfg, kind, h, layer, positions, inv_freq, stored):
+    """Keys and values a KV head: (q [S, Sq, H, stored], k [S, Sq, n_kv,
+    stored], v [S, Sq, n_kv, vd]) of the normed input h."""
+    from .quant import qmm
+
+    s, sq, _d = h.shape
+    hd, vd = cfg.head_dim, cfg.v_dim
+    q = qmm(h, layer["wq"]).reshape(s, sq, kind.n_heads, hd)
+    k = qmm(h, layer["wk"]).reshape(s, sq, kind.n_kv_heads, hd)
+    v = qmm(h, layer["wv"]).reshape(s, sq, kind.n_kv_heads, vd)
+    q = _rope(kind, q, positions, inv_freq)
+    k = _rope(kind, k, positions, inv_freq)
+    if cfg.value_scale != 1.0:
+        v = v * jnp.asarray(cfg.value_scale, v.dtype)
+    # k_cache_dim: zeros past the model's width
+    return _pad_last(q, stored), _pad_last(k, stored), v
+
+
+def _latent_qkv(cfg, kind, h, layer, positions, inv_freq, stored):
+    """Latent attention's two down-projections: (q [S, Sq, H, nope + rope],
+    the head as the model has it, its rope part turned; the row that is
+    cached [S, Sq, 1, stored] = [rmsnorm(latent) | the ONE rope key of the
+    token, turned | zeros]; None: the row's first columns are its value)."""
+    from .quant import qmm
+
+    s, sq, _d = h.shape
+    _q_rank, kv_rank, nope, rope, _vd = kind.latent
+    with jax.named_scope("mla_q_proj"):
+        q = qmm(rms_norm(qmm(h, layer["wq_down"]), layer["q_norm"], cfg.norm_eps), layer["wq_up"])
+        q = q.reshape(s, sq, kind.n_heads, nope + rope)
+        q = jnp.concatenate([q[..., :nope], _rope(kind, q[..., nope:], positions, inv_freq)], axis=-1)
+    with jax.named_scope("mla_kv_compress"):
+        down = qmm(h, layer["wkv_down"])[:, :, None, :]  # [S, Sq, 1, kv_rank + rope]
+        row = jnp.concatenate([
+            rms_norm(down[..., :kv_rank], layer["kv_norm"], cfg.norm_eps), _rope(kind, down[..., kv_rank:], positions, inv_freq),
+        ], axis=-1)
+    return q, _pad_last(row, stored), None
+
+
+def _latent_up(kind, layer):
+    """The latent's up-projection a head (the published kv_b_proj
+    [kv_rank, H x (nope + vd)]): (W_UK [kv_rank, H, nope], W_UV [kv_rank, H, vd])."""
+    _q_rank, kv_rank, nope, _rope_dim, vd = kind.latent
+    w_up = layer["wkv_up"].reshape(kv_rank, kind.n_heads, nope + vd)
+    return w_up[..., :nope], w_up[..., nope:]
+
+
+def _absorbed_attention(kind, q, layer, stored, inner):
+    """Latent attention without a key or a value a head: q [S, Sq, H, nope +
+    rope] is taken through W_UK onto the latent (`q' = q_nope W_UK^T`), `inner`
+    (absorbed query [S, Sq, H, stored] -> [S, Sq, H, kv_rank]) attends the
+    cached rows with it, their first kv_rank columns as values, and W_UV takes
+    each head's mix of latents up to its values. Returns [S, Sq, H, vd]."""
+    nope = kind.latent[2]
+    w_uk, w_uv = _latent_up(kind, layer)
+    with jax.named_scope("mla_absorb"):
+        q_latent = jnp.einsum("sqhn,lhn->sqhl", q[..., :nope], w_uk)
+        q_abs = _pad_last(jnp.concatenate([q_latent, q[..., nope:]], axis=-1), stored)
+    out = inner(q_abs)
+    with jax.named_scope("mla_v_up"):
+        return jnp.einsum("sqhl,lhv->sqhv", out, w_uv)
+
+
 def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, table, inv_freq, kp, vp, attend, valid=None):
     """One transformer layer of `kind` over paged KV. x: [S, Sq, D];
     positions: [S, Sq]; kp/vp: [pages, page, n_kv, width], the pool this
-    layer's pages lie in; write_page_ids/offsets: flat [S*Sq] scatter targets
+    layer's pages lie in (a latent layer: kp its rows, vp None);
+    write_page_ids/offsets: flat [S*Sq] scatter targets
     in it; table: the page ids this layer reads, as the caller's `attend`
     takes them; attend: (kind, q [S, Sq, H, hd], k_pages, v_pages, table,
-    sink) -> [S, Sq, H, vd] over the pool as this layer has just written it —
+    layer) -> [S, Sq, H, vd] over the pool as this layer has just written it —
     the caller's own (`_paged_attention` for decode and verify,
     `_prefill_attention` for a prefill chunk). Returns (x, kp, vp, counts):
     uint32 [2], the (token, expert) pairs an expert layer computed for the
@@ -494,35 +591,20 @@ def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, 
     from .quant import qmm
 
     s, sq, d = x.shape
-    hd, vd, n_heads, n_kv = cfg.head_dim, cfg.v_dim, kind.n_heads, kind.n_kv_heads
     # the scopes are names only (HLO metadata, profiler traces): nothing
     # computed changes
     with jax.named_scope(kind.attn_name + "_attention" if kind.attn_name else "paged_attention"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = qmm(h, layer["wq"]).reshape(s, sq, n_heads, hd)
-        k = qmm(h, layer["wk"]).reshape(s, sq, n_kv, hd)
-        v = qmm(h, layer["wv"]).reshape(s, sq, n_kv, vd)
-        q = _rope(kind, q, positions, inv_freq)
-        k = _rope(kind, k, positions, inv_freq)
-        if cfg.value_scale != 1.0:
-            v = v * jnp.asarray(cfg.value_scale, v.dtype)
-        stored = kp.shape[-1]  # k_cache_dim: zeros past the model's width
-        if stored != hd:
-            q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, stored - hd)))
-            k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, stored - hd)))
+        q, k, v = (_latent_qkv if kind.latent else _heads_qkv)(cfg, kind, h, layer, positions, inv_freq, kp.shape[-1])
         with jax.named_scope("kv_write"):
-            kp, vp = _scatter_kv(
-                kp, vp,
-                k.reshape(s * sq, n_kv, stored),
-                v.reshape(s * sq, n_kv, vd),
-                write_page_ids, write_offsets,
-            )
-        attn_out = attend(kind, q, kp, vp, table, layer.get("sink"))
+            flat = lambda a: None if a is None else a.reshape((s * sq,) + a.shape[2:])  # noqa: E731
+            kp, vp = _scatter_kv(kp, vp, flat(k), flat(v), write_page_ids, write_offsets)
+        attn_out = attend(kind, q, kp, vp, table, layer)
         if kind.gated:
             with jax.named_scope("attn_gate"):
                 gate = jax.nn.sigmoid(qmm(h, layer["wg"]).astype(jnp.float32))  # [S, Sq, H]: a scalar a head
                 attn_out = (attn_out.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
-        x = x + qmm(attn_out.reshape(s, sq, n_heads * vd), layer["wo"])
+        x = x + qmm(attn_out.reshape(s, sq, -1), layer["wo"])
     pairs = jnp.zeros((2,), jnp.uint32)
     if kind.experts:
         h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
@@ -574,13 +656,12 @@ def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None):
             )
             return (x_out, pairs_carry + used, kp_all, vp_all), None
 
+        as_one = lambda pool: None if pool is None else pool.reshape((-1,) + pool.shape[2:])  # noqa: E731
         (x, pairs, kp_all, vp_all), _ = lax.scan(
-            body,
-            (x, pairs, kp.reshape((-1,) + kp.shape[2:]), vp.reshape((-1,) + vp.shape[2:])),
-            (layers, jnp.arange(n, dtype=jnp.int32)),
+            body, (x, pairs, as_one(kp), as_one(vp)), (layers, jnp.arange(n, dtype=jnp.int32)),
         )
         k_pages.append(kp_all.reshape(kp.shape))
-        v_pages.append(vp_all.reshape(vp.shape))
+        v_pages.append(None if vp is None else vp_all.reshape(vp.shape))  # a latent group: rows, no values
     if cfg.uniform:
         return x, k_pages[0], v_pages[0], pairs
     return x, tuple(k_pages), tuple(v_pages), pairs
@@ -588,6 +669,22 @@ def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None):
 
 def _kernel_name(kind: LayerKind) -> str:
     return "paged_decode_attention" + ("_" + kind.attn_name if kind.attn_name else "")
+
+
+def _softmax_scale(cfg: LlamaConfig, kind: LayerKind) -> float:
+    """The model's own, whatever width q and the keys are stored or absorbed at."""
+    return kind.softmax_scale or 1.0 / math.sqrt(cfg.head_dim)
+
+
+def _rebuild_kv(kind, layer, rows):
+    """A block of cached latent rows [block, 1, stored] -> its keys [block,
+    H, nope + rope] and values [block, H, vd] a head, as the model's equations
+    have them: k_j = [c W_UK_j | the token's one rope key], v_j = c W_UV_j."""
+    _q_rank, kv_rank, _nope, rope, _vd = kind.latent
+    w_uk, w_uv = _latent_up(kind, layer)
+    c, k_rope = rows[:, 0, :kv_rank], rows[:, :, kv_rank : kv_rank + rope]
+    k = jnp.concatenate([jnp.einsum("kl,lhn->khn", c, w_uk), jnp.broadcast_to(k_rope, (rows.shape[0], kind.n_heads, rope))], axis=-1)
+    return k, jnp.einsum("kl,lhv->khv", c, w_uv)
 
 
 def _advance(cache, k_pages, v_pages, pairs, seq_lens):
@@ -648,15 +745,15 @@ def paged_prefill(
         window_row = cache.window_table[slot]
         window_ids = jnp.where(valid, window_row[jnp.clip(positions // page, 0, row.shape[0] - 1)], 0)
         pools.append((window_ids, write_offsets, window_row))
-    scale = 1.0 / math.sqrt(cfg.head_dim)
 
-    def attend(kind, q, k_pages, v_pages, row, sink):
+    def attend(kind, q, k_pages, v_pages, row, layer):
         # causal within the live prefix: q at position p sees kv_pos <= p (a
         # window layer: and > p - window); rows past `length` are garbage but
         # their outputs are never read
         return _prefill_attention(
             q[0], k_pages, v_pages, row, positions, start_pos + length, block_pages,
-            window=kind.window, sink=sink, scale=scale,
+            window=kind.window, sink=layer.get("sink"), scale=_softmax_scale(cfg, kind),
+            expand=partial(_rebuild_kv, kind, layer) if kind.latent else None,
         )[None]
 
     x = qembed(params["embed"], tokens[None, :])  # [1, S_pad, D]
@@ -703,16 +800,21 @@ def paged_decode_step(
         pools.append((jnp.where(active, window_ids, 0), write_offsets, cache.window_table))
         behind = kv_pos <= positions[:, None, None, None] - cfg.window
         masks.append(jnp.where(behind, -jnp.inf, mask))
-    scale = 1.0 / math.sqrt(cfg.head_dim)
     # the kernel walks the live pages of the slots that decode and nothing for the rest: a slot
     # whose prompt is half prefilled has a length and no query this step
     decoding = jnp.where(active, positions, -1)
 
-    def attend(kind, q, k_pages, v_pages, table, sink):
-        return _paged_attention(
-            q, k_pages, v_pages, table, masks[bool(kind.window)], decoding, attn_impl,
-            window=kind.window, sink=sink, scale=scale, kernel_name=_kernel_name(kind),
-        )
+    def attend(kind, q, k_pages, v_pages, table, layer):
+        def over_span(q, latent=0):
+            return _paged_attention(
+                q, k_pages, v_pages, table, masks[bool(kind.window)], decoding, attn_impl, window=kind.window,
+                sink=layer.get("sink"), scale=_softmax_scale(cfg, kind), kernel_name=_kernel_name(kind), latent=latent,
+            )
+
+        if not kind.latent:
+            return over_span(q)
+        # a decode step never rebuilds a key or a value a head from the cache
+        return _absorbed_attention(kind, q, layer, k_pages.shape[-1], partial(over_span, latent=kind.latent[1]))
 
     x, k_pages, v_pages, pairs = _run_layers(params, cfg, x, positions[:, None], pools, cache, attend, active)
     logits = _logits(params, cfg, x[:, 0, :])  # [slots, V]
@@ -762,7 +864,7 @@ def paged_verify_step(
         kv_pos <= positions[:, None, :, None], 0.0, -jnp.inf
     ).astype(jnp.float32)  # [S, 1, K1, K]
 
-    def attend(_kind, q, k_pages, v_pages, table, _sink):
+    def attend(_kind, q, k_pages, v_pages, table, _layer):
         return _paged_attention(q, k_pages, v_pages, table, mask)
 
     x, k_pages, v_pages, _pairs = _run_layers(

@@ -29,7 +29,9 @@ for every slot that decodes, its live pages in blocks of `block_pages`.
   needed multiply-adds on an MXU the kernel leaves idle anyway (it is bound
   by bytes), and spares every transposition of `[tokens, n_kv, hd]`. Running
   max, sum and accumulator are float32 loop carries (online softmax);
-  probabilities stay float32 into the second matmul.
+  probabilities stay float32 into the second matmul (Mosaic runs a float32
+  product in one bfloat16 pass on the v5e: measured no slower than bfloat16
+  probabilities, PERF.md section 6, PR 37).
 - **One program.** Lengths are data: the same compiled kernel serves every
   batch; the block size comes from the operands' shapes alone (a page's
   bytes). No grid, no bucket, nothing tuned at import.
@@ -39,12 +41,23 @@ values may differ in width, a window layer walks only the pages its window
 can touch, and a layer with a learned sink adds one logit a head to the
 softmax's denominator.
 
+**Latent attention** has a kernel of its own over the same walk
+(`paged_decode_attention_mla`, `_mla_decode_kernel`): the pool holds ONE row
+a token, `[latent | rope key | zeros to the stored width]`, so a page is one
+copy and not a key copy and a value copy; the values are the first `latent`
+columns of the key block already in VMEM; every query head (64) meets every
+row, nothing is masked away but dead positions. At 139,264 operations against
+1,152 bytes a row it is not byte-bound as the kernel above is: 64 rows of
+queries fill half an MXU pass, and the trace's roofline share (35-39% of the
+byte floor on a v5e) is what that costs (PERF.md section 6, PR 37). Both
+kernels are `_walk_live_blocks` with their own page copies and block reads.
+
 The same kernel runs `interpret=True` on CPU CI, pinned against the dense
 float32 reference (tests/test_serving.py, tests/test_serving_two_pools.py,
 tests/test_ops.py), and compiled under Mosaic on a TPU, where it matches the
 gather path (tests/test_ops.py TPU-gated test; `chip_smoke.py`).
 
-Who calls it, and over what: only `paged_decode_step`, through
+Who calls them, and over what: only `paged_decode_step`, through
 `paged_kv._paged_attention`, where its static `attn_impl` says "kernel" or
 "kernel_interpret" (the serving engine asks `paged_kv.resolve_attn_impl()`
 once at construction: the kernel on a TPU, the gather path elsewhere); a
@@ -85,17 +98,78 @@ def _paged_decode_kernel(
     *rest,  # (sink_ref [n_kv * n_rep, 1] where the layer has one,) o_ref, k_buf, v_buf, sems
     page: int,
     n_kv: int,
+    **walk,
+):
+    # o_ref [S, n_kv * n_rep, vd]; k_buf [2, block_pages * page, n_kv, hd] and v_buf alike: the two
+    # blocks in flight; sems [2, 2]: keys / values by buffer
+    sink_ref = rest[0] if len(rest) == 5 else None
+    o_ref, k_buf, v_buf, sems = rest[-4:]
+    rows = k_buf.shape[1] * n_kv  # (token, KV head) rows of a block
+
+    def page_copies(page_id, i, buf):
+        return (
+            pltpu.make_async_copy(k_hbm.at[page_id], k_buf.at[buf, pl.ds(i * page, page)], sems.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[page_id], v_buf.at[buf, pl.ds(i * page, page)], sems.at[1, buf]),
+        )
+
+    def block(buf):
+        # a block's keys as the 2-D matrix they already are in memory (a ref reshape, no relayout)
+        return k_buf.at[buf].reshape(rows, k_buf.shape[-1])[...], v_buf.at[buf].reshape(rows, v_buf.shape[-1])[...].astype(jnp.float32)
+
+    # a block's tail may hold pages never fetched: their probabilities are 0, and 0 x what the
+    # buffer held must be 0 (keys need no such care: a masked score is replaced, not multiplied)
+    v_buf[...] = jnp.zeros_like(v_buf)
+    _walk_live_blocks(page_table_ref, seq_lens_ref, q_ref, sink_ref, o_ref, page_copies, block, page=page, n_kv=n_kv, **walk)
+
+
+def _mla_decode_kernel(
+    page_table_ref,  # [S, pages_per_slot] int32
+    seq_lens_ref,  # [S] int32: the decode token's position; negative where the slot does not decode
+    q_ref,  # [S, heads, width] in VMEM: the absorbed query, [q W_UK^T | q_rope | zeros to the stored width]
+    rows_hbm,  # [P, page, width]: one row a token, [latent | rope key | zeros], where it lies
+    o_ref,  # [S, heads, latent]
+    buf_ref,  # [2, block_pages * page, width]: the two blocks in flight
+    sems,  # [2]
+    *,
+    page: int,
+    **walk,
+):
+    """Latent attention's decode step: ONE copy a page, and the values are the
+    first `latent` columns of the key block already in VMEM. To the walk this
+    is one KV head under every query head, so no score is masked away but a
+    dead position's; the probabilities go to the rows' dtype for the second
+    product, as the gather path's do (float32 ones measured no slower on a
+    v5e, where Mosaic runs the product in one bfloat16 pass either way, and
+    would cost a conversion of the block: PERF.md section 6, PR 37)."""
+    latent = o_ref.shape[-1]
+
+    def page_copies(page_id, i, buf):
+        return (pltpu.make_async_copy(rows_hbm.at[page_id], buf_ref.at[buf, pl.ds(i * page, page)], sems.at[buf]),)
+
+    def block(buf):
+        k = buf_ref[buf]
+        return k, k[:, :latent]
+
+    buf_ref[...] = jnp.zeros_like(buf_ref)  # the values of a block's unfetched tail: 0 x them must be 0
+    _walk_live_blocks(page_table_ref, seq_lens_ref, q_ref, None, o_ref, page_copies, block, page=page, n_kv=1, **walk)
+
+
+def _walk_live_blocks(
+    page_table_ref, seq_lens_ref, q_ref, sink_ref, o_ref,
+    page_copies,  # (page id, its place in the block, buffer) -> the async copies that bring the page in
+    block,  # buffer -> (keys [rows, hd], values [rows, vd]) of the block in it; the second product runs in the values' dtype
+    *,
+    page: int,
+    n_kv: int,
     n_rep: int,
     block_pages: int,
     pages_walked: int,
     window: int,
     scale: float,
 ):
-    # o_ref [S, n_kv * n_rep, vd]; k_buf [2, block_pages * page, n_kv, hd] and v_buf alike: the two
-    # blocks in flight; sems [2, 2]: keys / values by buffer
-    sink_ref = rest[0] if len(rest) == 5 else None
-    o_ref, k_buf, v_buf, sems = rest[-4:]
-    slots, heads, hd = q_ref.shape
+    """What both kernels do: every decoding slot's live pages in blocks of
+    `block_pages`, two blocks in flight, an online softmax a slot."""
+    slots, heads, _hd = q_ref.shape
     vd = o_ref.shape[-1]
     rows = block_pages * page * n_kv  # (token, KV head) rows of a block
 
@@ -114,9 +188,8 @@ def _paged_decode_kernel(
         first, n = span(s)
 
         def one(i, carry):
-            page_id = page_table_ref[s, first + b * block_pages + i]
-            act(pltpu.make_async_copy(k_hbm.at[page_id], k_buf.at[buf, pl.ds(i * page, page)], sems.at[0, buf]))
-            act(pltpu.make_async_copy(v_hbm.at[page_id], v_buf.at[buf, pl.ds(i * page, page)], sems.at[1, buf]))
+            for copy in page_copies(page_table_ref[s, first + b * block_pages + i], i, buf):
+                act(copy)
             return carry
 
         lax.fori_loop(0, jnp.minimum(block_pages, n - b * block_pages), one, 0)
@@ -124,9 +197,6 @@ def _paged_decode_kernel(
     start_block = functools.partial(block_copies, act=lambda copy: copy.start())
     wait_block = functools.partial(block_copies, act=lambda copy: copy.wait())
 
-    # a block's tail may hold pages never fetched: their probabilities are 0, and 0 x what the
-    # buffer held must be 0 (keys need no such care: a masked score is replaced, not multiplied)
-    v_buf[...] = jnp.zeros_like(v_buf)
     first_slot = next_decoding(0)
 
     @pl.when(first_slot < slots)
@@ -155,8 +225,7 @@ def _paged_decode_kernel(
                 start_block(next_s, jnp.where(last, 0, b + 1), 1 - buf)
 
             wait_block(s, b, buf)
-            k = k_buf.at[buf].reshape(rows, hd)[...]
-            v = v_buf.at[buf].reshape(rows, vd)[...]
+            k, v = block(buf)
             s_log = lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
             kv_pos = (first + b * block_pages) * page + col_token
             seen = own_head & (kv_pos <= q_pos)
@@ -167,7 +236,7 @@ def _paged_decode_kernel(
             p_exp = jnp.where(seen, jnp.exp(s_log - m_new), 0.0)
             corr = jnp.exp(m_prev - m_new)
             l_new = l_prev * corr + jnp.sum(p_exp, axis=-1, keepdims=True)
-            acc = acc * corr + jnp.dot(p_exp, v.astype(jnp.float32), preferred_element_type=jnp.float32)
+            acc = acc * corr + jnp.dot(p_exp.astype(v.dtype), v, preferred_element_type=jnp.float32)
             return m_new, l_new, acc, 1 - buf
 
         init = (
@@ -256,3 +325,45 @@ def paged_decode_attention(
         name=name,
     )(page_table, jnp.minimum(seq_lens, pages_per_slot * page - 1), *operands)
     return out.reshape(s, n_kv, n_rep, vd)
+
+
+def paged_decode_attention_mla(
+    q: jax.Array,  # [S, heads, width]: the absorbed query at the rows' stored width
+    rows: jax.Array,  # [P, page, width]: a token's [latent | rope key | zeros] row
+    page_table: jax.Array,  # [S, pages_per_slot] int32
+    seq_lens: jax.Array,  # [S] int32 — each slot's decode position; negative: the slot does not decode
+    *,
+    latent: int,  # the first `latent` columns of a row are also its value
+    scale: float,
+    name: str = "paged_decode_attention_mla",
+    interpret: bool = False,
+) -> jax.Array:
+    """One decode step of latent attention over the paged rows. Returns
+    [S, heads, latent]: each head's probabilities over the latents, which the
+    caller takes up to the head's values. The contract is
+    `paged_decode_attention`'s: a slot whose position is negative walks
+    nothing and gets zeros."""
+    s, heads, width = q.shape
+    page, pages_per_slot = rows.shape[1], page_table.shape[1]
+    block_pages = max(1, min(BLOCK_BYTES // (page * width * rows.dtype.itemsize), pages_per_slot))
+    in_vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(),
+        in_specs=[in_vmem, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=in_vmem,
+        scratch_shapes=[pltpu.VMEM((2, block_pages * page, width), rows.dtype), pltpu.SemaphoreType.DMA((2,))],
+    )
+    # every slot's query and output stay in VMEM for the call (64 slots x 64 heads: 5.2 + 4.2 MB in
+    # bfloat16) beside the two blocks: past the compiler's default allowance, well inside the chip's
+    resident = (q.size + s * heads * latent) * q.dtype.itemsize + 2 * block_pages * page * width * rows.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(
+            _mla_decode_kernel, page=page, n_rep=heads, block_pages=block_pages, pages_walked=pages_per_slot, window=0, scale=scale,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, heads, latent), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(32 << 20, 2 * resident)),
+        interpret=interpret,
+        name=name,
+    )(page_table, jnp.minimum(seq_lens, pages_per_slot * page - 1), q, rows)

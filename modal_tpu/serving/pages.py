@@ -599,7 +599,13 @@ class ModelPages:
         """The `kv_pages_*` keys mean the pool that grows with the context; a
         model without window layers carries no `kv_window_*` key."""
         pool, prefixes = self.allocator, self.prefix_cache
+        # what one more token of context costs over all layers, as the pools store it (a key
+        # padded to a stored width counts padded, a latent row as one row)
+        per_token = self.pool_bytes / (pool.num_pages * self.page_size)
+        if self.window_allocator is not None:
+            per_token += self.window_pool_bytes / (self.window_allocator.num_pages * self.page_size)
         out = {
+            "kv_bytes_per_token": per_token,
             "kv_pages_total": pool.num_pages - 1,
             "kv_pages_allocated": pool.allocated_pages,
             "kv_pages_free": pool.free_pages,

@@ -19,6 +19,10 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
+# What this build's served path can run beyond the dense block, by mechanism: the names a caller
+# may put in `llm_service(requires=...)`. A build that lacks a mechanism lacks its name here.
+MECHANISMS = ("window_kv", "routed_experts", "latent_kv", "router_groups")
+
 
 def llm_service(
     app: Any,
@@ -60,12 +64,24 @@ def llm_service(
     window_num_pages: Optional[int] = None,
     # requests that may wait for a slot; one more is refused (the engine's own bound)
     max_waiting: int = 1024,
+    # mechanisms (names of MECHANISMS) the caller's model needs of the served path: one this build
+    # does not list is refused HERE, in the calling process, before any container is asked for
+    # (a container that met a preset it lacks would crash-loop until the boot timeout). It selects
+    # no path and no container ever sees it
+    requires: Any = (),
     **cls_kwargs: Any,
 ) -> Any:
     """Register a serving class on `app` and return it (an `@app.cls`
     result: instantiate + `.get_web_url()` under a run, or deploy it)."""
     import modal_tpu
     from modal_tpu.tpu_config import parse_tpu_config
+
+    lacking = [name for name in requires if name not in MECHANISMS]
+    if lacking:
+        raise ValueError(
+            f"llm_service(requires={list(requires)!r}): this build's served path has no {', '.join(map(repr, lacking))}; "
+            f"it has {', '.join(MECHANISMS)}"
+        )
 
     # ServingEngine has no mesh: params, KV pool and every step live on the
     # default device, so a multi-chip placement would hold chips it never uses

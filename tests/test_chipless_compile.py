@@ -103,7 +103,12 @@ MIMO_CELL = dict(
 LAGUNA_CELL = dict(
     model=dict(name="laguna-xs.2", n_layers=5, max_seq_len=8192), slots=128, pool_pages=8193, window_pool_pages=4241,
 )
-TWO_POOL_CELLS = {"mimo-v2-flash-serve-1chip-ep16": MIMO_CELL, "laguna-xs.2-serve-1chip": LAGUNA_CELL}
+# the fifth configuration's (benchmark/configs/a.x-k1-serve-1chip-ep16.json): one pool of latent rows, stored 640 wide
+AXK1_CELL = dict(
+    model=dict(name="a.x-k1", n_layers=7, vocab_size=20480, max_seq_len=8192, n_experts_held=12), slots=64, pool_pages=20481,
+    window_pool_pages=None,
+)
+TWO_POOL_CELLS = {"mimo-v2-flash-serve-1chip-ep16": MIMO_CELL, "laguna-xs.2-serve-1chip": LAGUNA_CELL, "a.x-k1-serve-1chip-ep16": AXK1_CELL}
 
 
 def lower_step(program, config, sharding):
@@ -185,7 +190,9 @@ def test_paged_prefill_compiles_for_v5e_with_nothing_of_the_span_s_size(config, 
 POOL_SIZED_MAY_BE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while", "scatter", "fusion"}
 CARRIED_POOL_CASES = [
     (config, program) for config in sorted(CELL_SHAPES) for program in ("paged_decode_step", "paged_prefill")
-] + [("mimo-v2-flash-serve-1chip-ep16", "paged_decode_step"), ("laguna-xs.2-serve-1chip", "paged_decode_step"), ("laguna-xs.2-serve-1chip", "paged_prefill")]
+] + [("mimo-v2-flash-serve-1chip-ep16", "paged_decode_step"), ("laguna-xs.2-serve-1chip", "paged_decode_step"), ("laguna-xs.2-serve-1chip", "paged_prefill")] + [
+    ("a.x-k1-serve-1chip-ep16", "paged_decode_step"), ("a.x-k1-serve-1chip-ep16", "paged_prefill"),
+]
 
 
 @pytest.mark.parametrize("config,program", CARRIED_POOL_CASES)
@@ -199,7 +206,9 @@ def test_the_jitted_steps_compile_for_v5e_with_no_pool_sized_copy(config, progra
     and a second pool of temporaries (PERF.md section 6, PR 31). The third
     configuration has a group of four window layers among single ones, the
     fourth a group of three with 1.6 GB of expert matrices a layer, which
-    XLA's dots read in place from the scanned stack."""
+    XLA's dots read in place from the scanned stack; the fifth one pool of
+    latent rows (no value pool), stored 640 wide so that it goes into its
+    kernel as it lies."""
     import jax
 
     cfg, _params, cache, lowered = lower_step(program, config, one_chip)
@@ -210,7 +219,8 @@ def test_the_jitted_steps_compile_for_v5e_with_no_pool_sized_copy(config, progra
     if program == "paged_decode_step":
         calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
         names = {re.search(r"%([a-z_]+)[.\d]* = ", line).group(1) for line in calls}
-        assert names == ({"paged_decode_attention"} if cfg.uniform else {"paged_decode_attention_full", "paged_decode_attention_swa"}), names
+        assert names == {"paged_decode_attention" + ("_" + k.attn_name if k.attn_name else "") for k in cfg.layer_kinds}, names
+        assert names in ({"paged_decode_attention"}, {"paged_decode_attention_full", "paged_decode_attention_swa"}, {"paged_decode_attention_mla"})
 
     # (a) every instruction, in fusions too: `%name = type[dims]{layout} opcode(`, or a tuple of such types
     pools = jax.tree.leaves((cache.k_pages, cache.v_pages))
@@ -332,3 +342,37 @@ def test_the_decode_kernel_compiles_for_v5e_at_six_query_heads_a_kv_head(one_chi
         shape((128, 8, 6, 128)), shape((8193, 16, 8, 128)), shape((8193, 16, 8, 128)), shape((128, 512), jnp.int32), shape((128,), jnp.int32)
     ).compile().as_text()
     assert re.search(r"%paged_decode_attention_full[.\d]* = bf16\[128,48,128\]", text)
+
+
+@pytest.mark.parametrize("stored", [640, 576], ids=["row-stored-640", "row-576-as-the-model-has-it"])
+def test_the_latent_decode_kernel_compiles_for_v5e_and_only_a_padded_row_goes_in_as_it_lies(stored, one_chip, no_compile_cache):
+    """`paged_decode_attention_mla` at the fifth configuration's shapes (64
+    slots, 64 heads, 20,481 pages of 16 rows): Mosaic takes it as written with
+    5.2 + 4.2 MB of queries and outputs resident, under its name, and the pool
+    goes in with no copy. A row 576 wide (512 + 64, the model's own) is no
+    whole number of 128-lane tiles: the page copies are refused, as for keys of
+    192: why `paged_kv.k_cache_dim` stores a latent row 640 wide."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models.llama import get_config
+    from modal_tpu.models.paged_kv import k_cache_dim
+    from modal_tpu.ops.paged_attention import paged_decode_attention_mla
+
+    assert k_cache_dim(get_config("a.x-k1")) == 640
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [shaped((64, 64, stored)), shaped((20481, 16, stored)), shaped((64, 512), jnp.int32), shaped((64,), jnp.int32)]
+
+    def call(q, rows, table, lens):
+        return paged_decode_attention_mla(q, rows, table, lens, latent=512, scale=0.130861)
+
+    if stored == 576:
+        with pytest.raises(Exception, match=r"aligned to tiling \(128\)"):
+            jax.jit(call).lower(*args).compile()
+        return
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert re.search(r"%paged_decode_attention_mla[.\d]* = bf16\[64,64,512\]", text), "the kernel lost its name or its output width"
+    assert not re.findall(rf"= bf16\[20481,16,{stored}\]\S* copy\(", text)

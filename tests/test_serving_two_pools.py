@@ -1,7 +1,9 @@
 """A model whose layers are not all alike in the serving engine: two kinds of
 KV cache under one page manager (a pool that grows with the context, a pool
 bounded by a window), the decode kernel with a window, a sink and keys wider
-than values, and what the engine refuses, by mechanism. `tiny-mimo` on the
+than values, and what the engine refuses, by mechanism; and a third pool
+form, one array of latent rows a layer group with no value pool
+(`tiny-axk1`), with its own decode kernel. `tiny-mimo` on the
 CPU; the logits against the plain reference are in
 tests/benchmark/test_bench_mimo_v2.py."""
 
@@ -34,8 +36,11 @@ def models(model):
 
     from modal_tpu.models.llama import get_config, init_params
 
-    cfg = get_config("tiny-laguna")
-    return {"tiny-mimo": model, "tiny-laguna": (init_params(cfg, jax.random.PRNGKey(0)), cfg)}
+    both = {"tiny-mimo": model}
+    for preset in ("tiny-laguna", "tiny-axk1"):  # the third: one pool of latent rows, a router with a group limit
+        cfg = get_config(preset)
+        both[preset] = (init_params(cfg, jax.random.PRNGKey(0)), cfg)
+    return both
 
 
 def engine_of(model, **overrides):
@@ -269,8 +274,11 @@ REFUSALS = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(REFUSALS))
-@pytest.mark.parametrize("preset", ["tiny-mimo", "tiny-laguna"])
+# a model of latent layers has no window: what a window refuses it is not refused (its prefix cache is served below)
+REFUSED = [(preset, case) for preset in ("tiny-mimo", "tiny-laguna", "tiny-axk1") for case in sorted(REFUSALS) if preset != "tiny-axk1" or "window" not in case]
+
+
+@pytest.mark.parametrize("preset,case", REFUSED)
 def test_the_engine_refuses_by_mechanism(models, preset, case):
     import jax
     import jax.numpy as jnp
@@ -286,7 +294,7 @@ def test_the_engine_refuses_by_mechanism(models, preset, case):
         params = jax.tree_util.tree_map(lambda a: a.astype(jnp.int8) if a.ndim == 4 else a, params)  # the experts' stacks
     with pytest.raises(ValueError, match=message) as refused:
         ServingEngine(params, cfg, page_size=PAGE, prefill_chunk=CHUNK, **kwargs)
-    assert not {"mimo", "laguna"} & set(str(refused.value).lower().replace("-", " ").split())  # the mechanism, never a model's name
+    assert not {"mimo", "laguna", "axk1", "a.x"} & set(str(refused.value).lower().replace("-", " ").split())  # the mechanism, never a model's name
 
 
 def test_the_trainer_s_switch_layer_is_refused_in_the_paged_path_and_as_a_draft():
@@ -413,9 +421,43 @@ def test_the_decode_kernel_matches_the_gather_path_with_a_window_a_sink_and_two_
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=0)
 
 
+@pytest.mark.parametrize("stored", [24, 128], ids=["row-as-wide-as-the-model", "row-stored-padded"])
+@pytest.mark.parametrize("heads", [4, 16])
+def test_the_latent_decode_kernel_reads_its_values_from_the_key_block_and_matches_the_gather_path(heads, stored):
+    """`paged_decode_attention_mla` (the interpreter runs its body) against
+    the gather path over the same rows: one copy a page, the first 16 columns
+    of a row its value, every head over every row; a slot that does not decode
+    gets zeros, a dead page is never walked (its table entry is out of range)."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models.paged_kv import _paged_attention
+
+    slots, pool, pages_per_slot, latent, width = 5, 40, 6, 16, 24
+    keys = jax.random.split(jax.random.PRNGKey(heads + stored), 3)
+    rows = jax.random.normal(keys[0], (pool, PAGE, 1, stored), jnp.float32).at[..., width:].set(0.0)
+    q = jax.random.normal(keys[1], (slots, 1, heads, stored), jnp.float32).at[..., width:].set(0.0)
+    table = jax.random.permutation(keys[2], pool - 1)[: slots * pages_per_slot].reshape(slots, pages_per_slot).astype(jnp.int32) + 1
+    positions = jnp.asarray([0, 5, 23, -1, 11], jnp.int32)  # a first token, inside a page, the row's last position, idle, a page's last
+    live = jnp.arange(pages_per_slot)[None, :] <= positions[:, None] // PAGE
+    table_kernel = jnp.where(live, table, 10**6)  # the kernel must not touch a page past the live ones
+    kv_pos = jnp.arange(pages_per_slot * PAGE)[None, None, None, :]
+    mask = jnp.where(kv_pos <= positions[:, None, None, None], 0.0, -jnp.inf).astype(jnp.float32)
+    args = dict(scale=0.21, latent=latent)
+    want = _paged_attention(q, rows, None, table, mask, positions, "gather", **args)
+    got = _paged_attention(q, rows, None, table_kernel, mask, positions, "kernel_interpret", kernel_name="paged_decode_attention_mla", **args)
+    assert got.shape == (slots, 1, heads, latent)
+    decoding = np.asarray(positions) >= 0
+    np.testing.assert_allclose(np.asarray(got)[decoding], np.asarray(want)[decoding], atol=2e-5, rtol=0)
+    assert not np.asarray(got)[~decoding].any() and np.abs(np.asarray(want)[decoding]).max() > 0.1
+    # the first token sees one row: its output IS that row's latent
+    first = rows[table[0, 0], 0, 0, :latent]
+    np.testing.assert_allclose(np.asarray(got)[0, 0], np.broadcast_to(np.asarray(first), (heads, latent)), atol=1e-6)
+
+
 # what /v1/stats says of pages, a model: the benchmark's readers and `modal_tpu top` go by these names
 PAGE_KEYS = {
-    "kv_pages_total", "kv_pages_allocated", "kv_pages_free", "kv_pages_high_water", "kv_pool_bytes",
+    "kv_pages_total", "kv_pages_allocated", "kv_pages_free", "kv_pages_high_water", "kv_pool_bytes", "kv_bytes_per_token",
     "kv_pages_cow_copies", "kv_pages_shipped", "kv_ship_drops",
     "prefix_cache_entries", "prefix_cache_pages", "prefix_cache_hits", "prefix_cache_misses",
     "draft_prefix_cache_entries", "draft_prefix_cache_hits",
@@ -423,8 +465,8 @@ PAGE_KEYS = {
 WINDOW_KEYS = {"kv_window_pages_total", "kv_window_pages_high_water", "kv_window_pages_released", "kv_window_pool_bytes"}
 
 
-@pytest.mark.parametrize("preset", ["tiny", "tiny-mimo"])
-def test_stats_of_a_dense_model_carry_no_second_pool(model, preset):
+@pytest.mark.parametrize("preset", ["tiny", "tiny-mimo", "tiny-axk1"])
+def test_stats_of_a_dense_model_carry_no_second_pool(model, models, preset):
     import jax
 
     from modal_tpu.models.llama import get_config, init_params
@@ -435,12 +477,19 @@ def test_stats_of_a_dense_model_carry_no_second_pool(model, preset):
         stats = ServingEngine(init_params(cfg, jax.random.PRNGKey(0)), cfg).stats()
         assert not [k for k in stats if k.startswith("kv_window") or k == "moe"]
         assert stats["kv_pool_bytes"] > 0
+        assert stats["kv_bytes_per_token"] == 2 * 2 * 2 * 32 * 2  # keys and values, 2 layers, 2 KV heads of 32, bfloat16
+    elif preset == "tiny-axk1":  # one pool of latent rows: 5 layers x (16 + 8) x 2 bytes a token, no window pool
+        stats = engine_of(models[preset]).stats()
+        assert not [k for k in stats if k.startswith("kv_window")] and "moe" in stats
+        assert stats["kv_bytes_per_token"] == 5 * 24 * 2 and stats["kv_pool_bytes"] == 120 * PAGE * 5 * 24 * 2
     else:
         stats = engine_of(model).stats()
         assert WINDOW_KEYS | {"moe"} <= set(stats)
         assert stats["moe"] == {"assignments": 0, "local_assignments": 0, "expert_calls": 0, "experts_touched": 0}
     paged = {k for k in stats if k.startswith(("kv_", "prefix_cache_", "draft_prefix_cache_"))}
     assert paged == PAGE_KEYS | (WINDOW_KEYS if preset == "tiny-mimo" else set())
+    if preset == "tiny-mimo":  # both pools: 2 full layers of 4 KV heads, 5 window layers of 8, keys 24 and values 16 wide
+        assert stats["kv_bytes_per_token"] == (2 * 2 + 5 * 4) * (24 + 16) * 2
     assert {"preemptions", "requests_admitted", "loop", "spec_k", "attn_impl"} <= set(stats) and "spec_overlap" not in stats
 
 
@@ -457,3 +506,51 @@ def test_llm_service_takes_the_second_pool_s_size_and_hands_it_to_the_engine():
     with open(inspect.getsourcefile(llm_service)) as f:
         source = f.read()
     assert "window_num_pages=window_num_pages" in source and "max_waiting=max_waiting" in source
+
+
+def test_llm_service_refuses_a_mechanism_it_does_not_list_before_anything_is_registered():
+    """`requires` names what the caller's model needs of the served path: an
+    unlisted name is refused in the calling process (no container boots on a
+    preset it lacks), a listed one changes nothing."""
+    import inspect
+
+    import modal_tpu
+    from modal_tpu.serving import llm_service, service
+
+    assert {"latent_kv", "router_groups", "window_kv", "routed_experts"} <= set(service.MECHANISMS)
+    assert inspect.signature(llm_service).parameters["requires"].default == ()
+    app = modal_tpu.App("requires-refused")
+    with pytest.raises(ValueError, match="has no 'no_such_mechanism'"):
+        llm_service(app, model="tiny", requires=("latent_kv", "no_such_mechanism"))
+    assert not app.registered_classes and not app.registered_functions  # refused before anything was registered
+    plain, asked = modal_tpu.App("requires-a"), modal_tpu.App("requires-b")
+    llm_service(plain, model="tiny")
+    llm_service(asked, model="tiny", requires=["latent_kv", "router_groups"])  # a list, as a configuration file gives it
+    assert sorted(plain.registered_classes) == sorted(asked.registered_classes) == ["LLMService"]
+    assert sorted(plain.registered_functions) == sorted(asked.registered_functions)
+    with open(inspect.getsourcefile(llm_service)) as f:
+        source = f.read()
+    assert "import jax" not in source.split("def llm_service")[0] and "requires=requires" not in source  # no container sees it
+
+
+def test_a_prefix_hit_over_the_latent_pool_copies_a_shared_page_in_every_layer_group(models):
+    """One pool that grows with the context and no window: the prefix cache
+    serves a model of latent layers, and copy-on-write duplicates the page in
+    each group's array (a latent group has rows and no values)."""
+    engine = engine_of(models["tiny-axk1"], prefix_cache=True).start()
+    try:
+        shared = prompts_of(3, [30])[0]
+        a, b = shared + [7, 8, 9], shared + [11, 12]
+        first = engine.submit(a, 6).result(timeout=300)
+        again = engine.submit(a, 6).result(timeout=300)  # the whole prompt is a cached prefix: its last page is shared, then written
+        other = engine.submit(b, 6).result(timeout=300)
+        stats = engine.stats()
+    finally:
+        engine.stop()
+    assert again == first and len(other) == 6
+    assert stats["prefix_cache_hits"] >= 2 and stats["kv_pages_cow_copies"] >= 1
+    cold = engine_of(models["tiny-axk1"], prefix_cache=False).start()
+    try:
+        assert cold.submit(b, 6).result(timeout=300) == other  # a hit changes no token
+    finally:
+        cold.stop()

@@ -6,7 +6,12 @@ layer kinds, 7 layers, 8 of 32 experts held, top-4, keys 24 / values 16 wide,
 window 8, 2 / 4 KV heads, sinks, partial rotary, two bases. `tiny-laguna`: 5
 layers, 6 and 8 query heads a KV head by layer kind, a gate on the attention
 output, YaRN on half a head beside plain rotary on the whole, all 32 experts
-held, top-4 scaled by 2.5, a shared expert. For
+held, top-4 scaled by 2.5, a shared expert. `tiny-axk1`
+(benchlib/reference_axk1.py, the NON-absorbed equations): 1 dense + 4 expert
+layers of latent attention (one cached row of 16 + 8 a token, an absorbed
+decode kernel of its own, a prefill chunk that rebuilds a block's keys and
+values), a YaRN that scales cos / sin and the softmax, 6 of 24 experts held
+under a router with a group limit (4 groups of which 2 stay). For
 each: the reference's weights are the program's bit for bit, chunked prefill
 then decode through both pools equals its full forward pass, the shares of the
 experts add up to the uncut layer, and what the engine serves lies by it where
@@ -20,7 +25,7 @@ import numpy as np
 import pytest
 
 from tests.benchmark import _paths
-from benchlib import reference, reference_laguna, reference_mimo_v2
+from benchlib import reference, reference_axk1, reference_laguna, reference_mimo_v2
 
 
 def load(*path):
@@ -30,6 +35,7 @@ def load(*path):
 
 TINY = load(_paths.FIXTURES, "tiny_mimo.json")
 TINY_LAGUNA = load(_paths.FIXTURES, "lagunaroot", "benchmark", "configs", "tiny-laguna.json")
+TINY_AXK1 = load(_paths.FIXTURES, "axk1root", "benchmark", "configs", "tiny-axk1.json")
 PAGE, CHUNK = 4, 16  # the window of 8 is two pages; a chunk is two windows
 
 
@@ -53,8 +59,16 @@ KINDS = {
     "laguna-sliding-gqa8-experts": ("tiny-laguna", *one_laguna_layer(1, 1, 16)),
     "laguna-full-gqa6-yarn-experts": ("tiny-laguna", *one_laguna_layer(0, 1, 12)),
     "laguna-stack": ("tiny-laguna", {}, {}),
+    # latent attention: over a dense FFN, over the grouped router's experts, the stack, and a row wide
+    # enough to be stored padded (160 + 8 -> 256, as 512 + 64 -> 640 at the published widths)
+    "axk1-latent-dense": ("tiny-axk1", dict(n_layers=1, ffn_pattern=(0,)), dict(num_hidden_layers=1)),
+    "axk1-latent-grouped-experts": ("tiny-axk1", dict(n_layers=1, ffn_pattern=(1,)), dict(num_hidden_layers=1, first_k_dense_replace=0)),
+    "axk1-stack": ("tiny-axk1", {}, {}),
+    "axk1-stack-row-168": ("tiny-axk1", dict(n_layers=2, kv_rank=160), dict(num_hidden_layers=2, kv_lora_rank=160)),
 }
-MODELS = {"tiny-mimo": (reference_mimo_v2, TINY), "tiny-laguna": (reference_laguna, TINY_LAGUNA)}
+MODELS = {
+    "tiny-mimo": (reference_mimo_v2, TINY), "tiny-laguna": (reference_laguna, TINY_LAGUNA), "tiny-axk1": (reference_axk1, TINY_AXK1),
+}
 
 
 def reference_of(kind, seed):
@@ -89,7 +103,7 @@ def layers_of(params, cfg):
 
 
 @pytest.mark.parametrize("seed", [0, 7, 2**31 + 5])
-@pytest.mark.parametrize("stack", ["stack", "laguna-stack"])
+@pytest.mark.parametrize("stack", ["stack", "laguna-stack", "axk1-stack"])
 def test_the_reference_makes_the_program_s_weights_from_the_seed_alone(stack, seed):
     module, fixture = MODELS[KINDS[stack][0]]
     mine = module.init_weights(fixture, seed)
@@ -103,6 +117,12 @@ def test_the_reference_makes_the_program_s_weights_from_the_seed_alone(stack, se
                 assert a[key].dtype == b[key].dtype and a[key].shape == b[key].shape and bool((a[key] == b[key]).all()), key
     if stack == "stack":
         assert {"sink", "router", "router_bias"} <= set(mine["layers"][1]) and "sink" not in mine["layers"][5]
+    elif stack == "axk1-stack":  # five projections and two inner norms in place of wq / wk / wv; no selection bias
+        latent = {"wq_down", "q_norm", "wq_up", "wkv_down", "kv_norm", "wkv_up", "wo"}
+        assert latent <= set(mine["layers"][0]) and not {"wq", "wk", "wv", "router_bias"} & set(mine["layers"][1])
+        assert {"router", "shared_gate", "shared_up", "shared_down"} <= set(mine["layers"][1]) and "router" not in mine["layers"][0]
+        assert mine["layers"][1]["wkv_down"].shape == (64, 16 + 8) and mine["layers"][1]["wkv_up"].shape == (16, 4 * (16 + 16))
+        assert mine["layers"][1]["w_gate"].shape == (6, 64, 32)
     else:  # the gate, the router and the shared expert are drawn, so all three take part in what is compared
         assert {"wg", "router", "shared_gate", "shared_up", "shared_down"} <= set(mine["layers"][1])
         assert "router_bias" not in mine["layers"][1] and "wg" in mine["layers"][0] and "router" not in mine["layers"][0]
@@ -205,6 +225,109 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
     assert pairs == 40 * 4  # every routed pair fell on exactly one share
     assert float(jnp.abs(whole).max()) > 1e-3
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole), atol=1e-6, rtol=1e-5)
+
+
+def test_four_shares_under_the_group_limit_and_the_shared_expert_counted_once_add_up_to_the_uncut_layer():
+    """A.X-K1's expert layer: four chips of 6 of the 24 experts each (a share
+    is one whole group of the router's four; every share routes over all 24
+    with the group limit on and computes the shared expert alike) against the
+    reference's uncut layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models import experts
+    from modal_tpu.models.llama import get_config, init_params
+
+    seed = 5
+    uncut = reference_axk1.Reference({**TINY_AXK1, "n_routed_experts_held": 24}, seed)
+    w = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), uncut.weights["layers"][2])
+    x = jax.random.normal(jax.random.PRNGKey(1), (40, 64), jnp.float32)
+    ones = jnp.ones((40,), bool)
+    with jax.default_matmul_precision("highest"):
+        whole = reference_axk1.experts(uncut.s, x, w, low=False)
+        h = reference_axk1._rms(x, w["mlp_norm"], uncut.s["eps"])
+        shared = reference_axk1.swiglu(h, w["shared_gate"], w["shared_up"], w["shared_down"], low=False)
+        total, pairs, by_share = 0.0, 0, []
+        for first in (0, 6, 12, 18):
+            cfg = get_config("tiny-axk1", experts_held_start=first)
+            assert (cfg.n_group, cfg.topk_group, cfg.experts_held) == (4, 2, (first, 6))
+            share = layers_of(init_params(cfg, jax.random.PRNGKey(seed)), cfg)[2]
+            share = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), share)
+            assert bool((share["w_gate"] == w["w_gate"][first : first + 6]).all()) and bool((share["shared_up"] == w["shared_up"]).all())
+            y, counts = experts.routed_experts(cfg, h, share, ones)
+            total, pairs = total + y, pairs + int(counts[0])
+            by_share.append(int(counts[0]))
+    assert pairs == 40 * 4  # every routed pair fell on exactly one share
+    # the limit is on: a token's four experts lie in two groups, so no share can have them spread evenly
+    chosen, _w = experts.route(get_config("tiny-axk1"), h, jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), share))
+    assert all(len(set(row // 6)) <= 2 for row in np.asarray(chosen))
+    assert float(jnp.abs(whole).max()) > 1e-3 and float(jnp.abs(shared).max()) > 1e-4
+    # the shared expert was computed by every share: count it once
+    np.testing.assert_allclose(np.asarray(total - 3 * shared), np.asarray(whole), atol=1e-6, rtol=1e-5)
+
+
+def route_before_the_group_limit(cfg, h, layer):
+    """`experts.route` as it was before the router had groups, written out."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    z = jnp.einsum("td,de->te", h, layer["router"], preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(z)
+    bias = layer.get("router_bias")
+    _, chosen = lax.top_k(scores if bias is None else scores + bias.astype(jnp.float32), cfg.experts_per_token)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return chosen, weights if cfg.routed_scale == 1.0 else weights * cfg.routed_scale
+
+
+@pytest.mark.parametrize("kind", ["window-experts", "laguna-sliding-gqa8-experts"])
+def test_a_router_with_one_group_routes_bit_for_bit_as_it_did(kind):
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models import experts
+
+    params, cfg = program(kind, 3, float32=False)  # bfloat16, as served; MiMo's has a selection bias, Laguna's a scale
+    layer = layers_of(params, cfg)[0]
+    h = jax.random.normal(jax.random.PRNGKey(4), (64, 64), jnp.bfloat16)
+    assert cfg.n_group == 1
+    got, want = jax.jit(lambda: experts.route(cfg, h, layer))(), jax.jit(lambda: route_before_the_group_limit(cfg, h, layer))()
+    assert bool((got[0] == want[0]).all()) and bool((got[1] == want[1]).all())
+
+
+def test_the_group_limit_is_the_rule_written_out_and_a_masked_group_s_best_expert_is_not_chosen():
+    """numpy, a token at a time: a group's score is the sum of its two largest
+    scores, the best `topk_group` groups stay, the top k of what stays, the
+    weights renormalised and scaled. Among the tokens is one whose single best
+    expert lies in a group that does not stay."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models import experts
+
+    params, cfg = program("axk1-latent-grouped-experts", 8)
+    layer = layers_of(params, cfg)[0]
+    h = jax.random.normal(jax.random.PRNGKey(9), (200, 64), jnp.float32) * 3
+    with jax.default_matmul_precision("highest"):
+        chosen, weights = experts.route(cfg, h, layer)
+        scores = np.asarray(jax.nn.sigmoid(h @ layer["router"]), np.float64)
+    chosen, weights = np.asarray(chosen), np.asarray(weights)
+    lost_its_best = 0
+    for t, s in enumerate(scores):
+        groups = s.reshape(4, 6)
+        group_score = np.sort(groups, axis=-1)[:, -2:].sum(axis=-1)
+        stays = np.argsort(-group_score, kind="stable")[:2]
+        allowed = np.full(24, -np.inf)
+        for g in stays:
+            allowed[g * 6 : (g + 1) * 6] = s[g * 6 : (g + 1) * 6]
+        want = np.argsort(-allowed, kind="stable")[:4]
+        assert sorted(chosen[t]) == sorted(want), t
+        np.testing.assert_allclose(np.sort(weights[t]), np.sort(2.5 * s[want] / s[want].sum()), rtol=1e-5)
+        if np.argmax(s) // 6 not in stays:
+            lost_its_best += 1
+            assert np.argmax(s) not in chosen[t]
+    assert lost_its_best >= 3  # the limit bit: without it every token gets its best expert
 
 
 def test_eight_shares_and_the_shared_expert_counted_once_add_up_to_the_uncut_and_the_all_held_layer():
@@ -337,6 +460,96 @@ def test_yarn_s_frequencies_and_factor_are_the_published_formula():
     assert ref_factor == pytest.approx(factor, rel=1e-12)
 
 
+def test_yarn_s_factor_enters_the_softmax_s_scale_by_the_family_s_rule():
+    """m(s, a) = 0.1 a ln s + 1: cos and sin take m(s, mscale) / m(s,
+    mscale_all_dim), the softmax's scale (qk_nope + qk_rope)^-0.5 x m(s,
+    mscale_all_dim)^2; at the published numbers 1 and 0.130861."""
+    import math
+
+    from modal_tpu.models.llama import get_config, rope_frequencies
+
+    kind = get_config("a.x-k1").layer_kinds[3]
+    m = 0.1 * 1 * math.log(32) + 1
+    assert m == pytest.approx(1.346574, rel=1e-6) and kind.yarn == (32.0, 4096, 32.0, 1.0, 1.0)
+    assert kind.softmax_scale == pytest.approx(192**-0.5 * m * m, rel=1e-12) and kind.softmax_scale == pytest.approx(0.130861, rel=1e-5)
+    assert (kind.rope_dim, kind.rope_theta, kind.n_kv_heads, kind.attn_name, kind.latent) == (64, 10_000.0, 1, "mla", (1536, 512, 128, 64, 128))
+    inv, on_cos_sin, scale = reference_axk1.rotary_rule(reference_axk1.model_shapes(load(_paths.BENCH_DIR, "configs", "a.x-k1-serve-1chip-ep16.json")))
+    np.testing.assert_allclose(np.asarray(rope_frequencies(get_config("a.x-k1"), kind)), inv, rtol=2e-6)
+    plain = 1.0 / 10_000.0 ** (np.arange(0, 64, 2) / 64)
+    np.testing.assert_allclose(inv[:11], plain[:11], rtol=2e-6)  # correction dims 10 and 23
+    np.testing.assert_allclose(inv[23:], plain[23:] / 32, rtol=2e-6)
+    assert (on_cos_sin, scale) == (1.0, pytest.approx(kind.softmax_scale, rel=1e-12))
+    # tiny-axk1 has both effects: mscale 1 over mscale_all_dim 0.5
+    tiny = get_config("tiny-axk1").layer_kinds[0]
+    m_all = 0.1 * 0.5 * math.log(4) + 1
+    assert tiny.yarn[4] == pytest.approx((0.1 * math.log(4) + 1) / m_all, rel=1e-12) and tiny.yarn[4] > 1.05
+    assert tiny.softmax_scale == pytest.approx(24**-0.5 * m_all**2, rel=1e-12)
+    _inv, on_cos_sin, scale = reference_axk1.rotary_rule(reference_axk1.model_shapes(TINY_AXK1))
+    assert (on_cos_sin, scale) == (pytest.approx(tiny.yarn[4], rel=1e-12), pytest.approx(tiny.softmax_scale, rel=1e-12))
+    # a model without the rule keeps 1 / sqrt(head width)
+    assert get_config("laguna-xs.2").layer_kinds[0].softmax_scale == 0.0 and get_config("llama3-8b").layer_kinds[0].latent == ()
+
+
+@pytest.mark.parametrize("stored", [24, 128], ids=["row-as-wide-as-the-model", "row-stored-padded"])
+def test_the_absorbed_scores_and_outputs_equal_the_rebuilt_keys_and_values_on_the_same_latent(stored):
+    """The decode step's form (`q' = q_nope W_UK^T` against the cached rows,
+    their latents as values, the mix taken up through W_UV) against keys and
+    values a head rebuilt from the same rows, written out here."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models import paged_kv as pk
+
+    params, cfg = program("axk1-latent-dense", 2)
+    layer, kind = layers_of(params, cfg)[0], cfg.layer_kinds[0]
+    _q_rank, rank, nope, rope, vd = kind.latent
+    keys = jax.random.split(jax.random.PRNGKey(stored), 2)
+    rows = jax.random.normal(keys[0], (9, PAGE, 1, stored), jnp.float32).at[..., rank + rope :].set(0.0)
+    q = jax.random.normal(keys[1], (2, 1, 4, nope + rope), jnp.float32)
+    table = jnp.asarray([[3, 1, 7], [2, 8, 5]], jnp.int32)
+    positions = jnp.asarray([9, 6], jnp.int32)
+    mask = jnp.where(jnp.arange(3 * PAGE)[None, None, None, :] <= positions[:, None, None, None], 0.0, -jnp.inf).astype(jnp.float32)
+    scale = pk._softmax_scale(cfg, kind)
+    with jax.default_matmul_precision("highest"):
+        got = pk._absorbed_attention(
+            kind, q, layer, stored, lambda q_abs: pk._paged_attention(q_abs, rows, None, table, mask, scale=scale, latent=rank)
+        )
+        w_up = layer["wkv_up"].reshape(rank, 4, nope + vd)
+        for slot in range(2):
+            live = rows[table[slot]].reshape(3 * PAGE, stored)[: int(positions[slot]) + 1]
+            c, k_r = live[:, :rank], live[:, rank : rank + rope]
+            for head in range(4):
+                k = jnp.concatenate([c @ w_up[:, head, :nope], k_r], axis=-1)  # [T, nope + rope]
+                probs = jax.nn.softmax(k @ q[slot, 0, head] * scale)
+                want = probs @ (c @ w_up[:, head, nope:])
+                np.testing.assert_allclose(np.asarray(got[slot, 0, head]), np.asarray(want), atol=2e-6, rtol=1e-5)
+        # and the block a prefill chunk rebuilds is the same keys and values
+        k_blk, v_blk = pk._rebuild_kv(kind, layer, rows[3])
+        np.testing.assert_allclose(np.asarray(k_blk[:, 2, :nope]), np.asarray(rows[3, :, 0, :rank] @ w_up[:, 2, :nope]), atol=2e-6)
+        np.testing.assert_allclose(np.asarray(v_blk[:, 2]), np.asarray(rows[3, :, 0, :rank] @ w_up[:, 2, nope:]), atol=2e-6)
+    assert k_blk.shape == (PAGE, 4, nope + rope) and bool((k_blk[:, 0, nope:] == k_blk[:, 3, nope:]).all())  # ONE rope key under every head
+
+
+def test_the_latent_cache_is_one_array_a_layer_group_and_no_value_pool():
+    import jax
+
+    from modal_tpu.models import paged_kv as pk
+    from modal_tpu.models.llama import get_config
+
+    cfg = get_config("tiny-axk1")
+    cache = pk.PagedKVCache.create(cfg, 3, 50, PAGE, 24)
+    assert [(first, n) for _k, first, n in cfg.layer_groups] == [(0, 1), (1, 4)] and not cfg.uniform and not cfg.has_window
+    assert [a.shape for a in cache.k_pages] == [(1, 50, PAGE, 1, 24), (4, 50, PAGE, 1, 24)]  # a token's row: latent 16 + rope key 8
+    assert cache.v_pages == (None, None) and cache.window_table is None and cache.page_size == PAGE
+    assert cache.pool_bytes() == 50 * PAGE * 5 * 24 * 2 == pk.pool_bytes_by_kind(cfg, cache)[0] and pk.pool_bytes_by_kind(cfg, cache)[1] == 0
+    # at the published widths a row of 512 + 64 is stored 640 wide (the chip's layout: PERF.md section 6, PR 37)
+    real = get_config({"name": "a.x-k1", "n_layers": 7})
+    assert pk.k_cache_dim(real) == 640 and pk.k_cache_dim(cfg) == 24 and pk.k_cache_dim(get_config("tiny-axk1", kv_rank=160)) == 256
+    shapes = jax.eval_shape(lambda: pk.PagedKVCache.create(real, 64, 20481, 16))
+    assert [a.shape for a in shapes.k_pages] == [(1, 20481, 16, 1, 640), (6, 20481, 16, 1, 640)] and shapes.v_pages == (None, None)
+    assert sum(a.size * 2 for a in shapes.k_pages) / (20481 * 16) == 7 * 640 * 2 == 8960
+
+
 def test_a_batch_routed_wholly_onto_one_held_expert_is_computed_in_full():
     import jax
     import jax.numpy as jnp
@@ -392,8 +605,12 @@ def test_what_the_engine_serves_lies_by_the_reference_and_the_fp8_control_does_n
     # (0.018 the smallest); the means lie ten times apart (under 1e-4 against over 7e-4)
     assert out["logit_gap_max"] < 0.012 < out["control_logit_gap_max"]
     assert out["logit_gap_mean"] < 3e-4 < out["control_logit_gap_mean"]
-    assert stats["kv_window_pages_released"] > 0 and stats["kv_window_pages_high_water"] <= stats["kv_window_pages_total"]
     moe = stats["moe"]
+    if preset == "tiny-axk1":  # one pool of latent rows; 6 of 24 held in 4 expert layers
+        assert "kv_window_pages_total" not in stats and stats["kv_bytes_per_token"] == 5 * 24 * 2
+        assert 0 < moe["local_assignments"] < moe["assignments"] and moe["expert_calls"] % (4 * 6) == 0
+        return
+    assert stats["kv_window_pages_released"] > 0 and stats["kv_window_pages_high_water"] <= stats["kv_window_pages_total"]
     if preset == "tiny-mimo":  # 8 of 32 held in 6 expert layers
         assert 0 < moe["local_assignments"] < moe["assignments"] and moe["expert_calls"] % (6 * 8) == 0
     else:  # all 32 held in 4 expert layers: every pair is local, and a call touches no more experts than it has pairs or holds
