@@ -185,22 +185,42 @@ def pool_bytes_by_kind(cfg: LlamaConfig, cache: PagedKVCache) -> tuple[int, int]
 
 @partial(jax.jit, donate_argnums=(0,))
 def assign_pages(cache: PagedKVCache, slot: int, start_index: int, pages: jax.Array) -> PagedKVCache:
-    """Write newly-allocated page ids into slot's table row at
-    [start_index : start_index+len(pages)] (len(pages) is static per call —
-    admission batches one page list at a time)."""
+    """Write an admission's page ids into slot's table row at
+    [start_index : start_index+len(pages)] (len(pages) is static per call:
+    the page manager pads a row to `pages_per_slot`, one executable)."""
     row = lax.dynamic_update_slice(cache.page_table[slot], pages.astype(jnp.int32), (start_index,))
     return cache._replace(page_table=cache.page_table.at[slot].set(row))
 
 
+def pack_entries(num_slots: int, length: int, *tables: list) -> np.ndarray:
+    """`assign_entries`' argument, made on the host: one list of (slot, index,
+    page) a table, `page_table`'s first, as int32 `[tables, 3, length]`. An
+    unused entry names slot `num_slots`, out of range, and is dropped."""
+    packed = np.zeros((len(tables), 3, length), np.int32)
+    packed[:, 0] = num_slots
+    for row, entries in zip(packed, tables):
+        if entries:
+            row[:, : len(entries)] = np.asarray(entries, np.int32).T
+    return packed
+
+
 @partial(jax.jit, donate_argnums=(0,))
-def assign_window_pages(cache: PagedKVCache, slots: jax.Array, indices: jax.Array, pages: jax.Array) -> PagedKVCache:
-    """Write page ids of the window layers' pool into `window_table` at
-    (slots[i], indices[i]): one call for everything a step's bookkeeping
-    allocated. The three arrays have one fixed length a caller (one
-    executable); an unused entry names slot `num_slots`, out of range, and is
-    dropped. Entries behind a window are left as they are: stale, never
-    addressed."""
-    return cache._replace(window_table=cache.window_table.at[slots, indices].set(pages, mode="drop"))
+def assign_entries(cache: PagedKVCache, entries: jax.Array) -> PagedKVCache:
+    """Write page ids into `page_table` at (slot, index), and where the model
+    has window layers into `window_table` too: one call for everything a
+    step's bookkeeping handed out, growth and the window pool's turn-over
+    together. `entries` is `pack_entries`' array, `[tables, 3, length]` with
+    one fixed length a caller (one executable a model: the tree says whether
+    there is a `window_table`). Entries behind a window are left as they
+    are: stale, never addressed."""
+    tables = ("page_table",) if cache.window_table is None else ("page_table", "window_table")
+    if entries.shape[0] != len(tables):
+        raise ValueError(f"entries for {entries.shape[0]} tables, the cache has {len(tables)}")
+    written = {
+        name: getattr(cache, name).at[entries[t, 0], entries[t, 1]].set(entries[t, 2], mode="drop")
+        for t, name in enumerate(tables)
+    }
+    return cache._replace(**written)
 
 
 @partial(jax.jit, donate_argnums=(0,))

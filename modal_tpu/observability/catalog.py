@@ -657,7 +657,8 @@ ENGINE_PHASES: dict[str, tuple[str, str]] = {
     ),
     "decode_prep": (
         "_reserve_decode (who decodes, by count; the page manager's reserve with positions counted at launch: "
-        "grown pages, copy-on-write, the window pool's turn-over), the active array, a joining slot's token set "
+        "grown pages, copy-on-write, the window pool's turn-over; what was handed out goes down in one "
+        "assign_entries call), the active array, a joining slot's token set "
         "into the token vector on the device; in a speculative round also a group's sampling arrays",
         "host",
     ),

@@ -1760,7 +1760,7 @@ class ServingEngine:
             "requests_completed": self.requests_completed,
             "preemptions": self.preemptions,
             # the pools and the prefix cache of the model served: kv_pages_*,
-            # kv_pool_bytes, prefix_cache_*, kv_pages_cow_copies, and
+            # kv_pool_bytes, prefix_cache_*, kv_pages_cow_copies, kv_table_*, and
             # kv_window_* only where there are window layers
             **self.pages.stats(),
             **({"moe": moe} if self.moe_expert_layers else {}),

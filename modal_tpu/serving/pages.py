@@ -287,9 +287,11 @@ class ModelPages:
     `self.cache` goes into a jitted step and the step's returned cache is
     stored back; between steps the methods below update its tables with the
     jitted helpers of `models/paged_kv.py`, each of one fixed shape: one
-    padded-row `assign_pages` an admission, one single-page `assign_pages` a
-    grown page, one `copy_page` a copy-on-write, `assign_window_pages` at one
-    fixed length, one `release_slot` a freed slot.
+    padded-row `assign_pages` an admission, one `copy_page` a copy-on-write,
+    one `assign_entries` for everything else a `reserve` handed out (growth
+    and the window pool's turn-over together, `[tables, 3,
+    _table_write_len]` host integers; none handed out, no call), one
+    `release_slot` a freed slot.
 
     A slot's row in `cache.window_table` is indexed like its row in
     `page_table` (position p lives at index p // page_size), but only the
@@ -339,8 +341,9 @@ class ModelPages:
                     f"tokens and the window of {self.window} before it"
                 )
             self.window_allocator = PageAllocator(window_num_pages, page_size)
-            # one fixed length for every assign_window_pages call (one executable)
-            self._window_assign_len = max(max_slots, math.ceil(prefill_chunk / page_size) + 1)
+        # one fixed length for every assign_entries call (one executable): a decode step's
+        # page a slot, or a prefill chunk's window pages; more go in further calls
+        self._table_write_len = max(max_slots, math.ceil(prefill_chunk / page_size) + 1)
         self.allocator = PageAllocator(num_pages, page_size)
         self.cache = paged_kv.PagedKVCache.create(
             cfg, max_slots, num_pages, page_size, self.pages_per_slot, window_num_pages
@@ -352,6 +355,8 @@ class ModelPages:
         self.window_first = [0] * max_slots
         self.cow_copies = 0
         self.window_pages_released = 0
+        self.table_writes = 0  # assign_entries calls
+        self.table_entries = 0  # (slot, index, page) entries they carried, both tables
 
     # -- what the loop reads --------------------------------------------------
 
@@ -425,7 +430,6 @@ class ModelPages:
                 self.prefix_cache.note_miss()
         # pad the row to pages_per_slot: assign_pages keys an executable on
         # the page-array SHAPE, so padded admissions all share one compile
-        # (growth adds single pages: one more shape, total two)
         row = pages + [0] * (self.pages_per_slot - len(pages))
         self.cache = self._kv.assign_pages(self.cache, idx, 0, self._jnp.asarray(row, self._jnp.int32))
         return hit.covered if hit is not None else 0
@@ -457,7 +461,11 @@ class ModelPages:
           prefix cache or another slot still reads, is never written);
           cached prefixes are evicted for either before this says no;
         - the window layers' pool takes back what lies behind the window of
-          the query at `first` and gives the pages up to `last`."""
+          the query at `first` and gives the pages up to `last`.
+
+        What the pools gave goes to the device's tables in ONE `assign_entries`
+        call, after the copies (more entries than `_table_write_len`: further
+        calls of the same shape; nothing given: no call)."""
         jnp, kv = self._jnp, self._kv
         size, held, pool = self.page_size, self.pages, self.allocator
         grow = [(i, last // size + 1 - len(held[i])) for i, _first, last in wants if last // size >= len(held[i])]
@@ -475,11 +483,12 @@ class ModelPages:
                 window_need.append(max(0, last // size + 1 - self.window_first[i] - len(self.window_pages[i])))
             if not self.window_allocator.can_alloc(sum(window_need)):
                 return False
+        grown = []  # (slot index, row index, page) for `page_table` on the device
         for i, n in grow:
             for page in pool.alloc(n):
+                grown.append((i, len(held[i]), page))
                 held[i].append(page)
-                self.cache = kv.assign_pages(self.cache, i, len(held[i]) - 1, jnp.asarray([page], jnp.int32))
-        for i, t in cow:
+        for i, t in cow:  # reads the table's entry of a page the slot held before: never a grown one
             old = held[i][t]
             if not pool.shared(old):
                 continue  # a copy above left this slot the only holder
@@ -488,28 +497,22 @@ class ModelPages:
             pool.free([old])  # this slot's ref; the other holders keep theirs
             held[i][t] = page
             self.cow_copies += 1
+        tables = [grown]
         if self.window_allocator is not None:
-            self._assign_window(wants, window_need)
+            turned = []  # the same for `window_table`
+            for (i, _first, _last), n in zip(wants, window_need):
+                held_window = self.window_pages[i]
+                for page in self.window_allocator.alloc(n):
+                    turned.append((i, self.window_first[i] + len(held_window), page))
+                    held_window.append(page)
+            tables.append(turned)
+        length = self._table_write_len
+        for at in range(0, max(len(entries) for entries in tables), length):
+            packed = kv.pack_entries(self.max_slots, length, *(entries[at : at + length] for entries in tables))
+            self.cache = kv.assign_entries(self.cache, packed)
+            self.table_writes += 1
+        self.table_entries += sum(len(entries) for entries in tables)
         return True
-
-    def _assign_window(self, wants: list, need: list) -> None:
-        import numpy as np
-
-        jnp, length = self._jnp, self._window_assign_len
-        entries = []
-        for (i, _first, _last), n in zip(wants, need):
-            held = self.window_pages[i]
-            for page in self.window_allocator.alloc(n):
-                entries.append((i, self.window_first[i] + len(held), page))
-                held.append(page)
-        for at in range(0, len(entries), length):
-            part = entries[at : at + length]
-            arr = np.zeros((3, length), np.int32)
-            arr[0] = self.max_slots  # out of range: dropped
-            arr[:, : len(part)] = np.asarray(part, np.int32).T
-            self.cache = self._kv.assign_window_pages(
-                self.cache, jnp.asarray(arr[0]), jnp.asarray(arr[1]), jnp.asarray(arr[2])
-            )
 
     # -- after a write, and at a slot's end -----------------------------------
 
@@ -616,6 +619,9 @@ class ModelPages:
             "prefix_cache_hits": prefixes.hits if prefixes is not None else 0,
             "prefix_cache_misses": prefixes.misses if prefixes is not None else 0,
             "kv_pages_cow_copies": self.cow_copies,
+            # device calls `reserve` made to write table entries, and the entries they carried
+            "kv_table_writes": self.table_writes,
+            "kv_table_entries": self.table_entries,
         }
         if self.window_allocator is not None:
             out.update(

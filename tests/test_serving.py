@@ -270,7 +270,7 @@ def test_the_carried_pool_takes_layer_l_s_rows_in_layer_l_s_pages_and_nowhere_el
 
     from modal_tpu.models.llama import KVCache, get_config, init_params
     from modal_tpu.models.paged_kv import (
-        PagedKVCache, assign_pages, assign_window_pages, paged_decode_step, paged_prefill, paged_verify_step,
+        PagedKVCache, assign_entries, pack_entries, paged_decode_step, paged_prefill, paged_verify_step,
     )
     from modal_tpu.models.sampling import decode_step, prefill
 
@@ -302,9 +302,11 @@ def test_the_carried_pool_takes_layer_l_s_rows_in_layer_l_s_pages_and_nowhere_el
         v_pages=jax.tree.map(lambda a: jax.random.normal(next(noise), a.shape, a.dtype), cache.v_pages),
     )
     rows = {False: [7, 3], True: [9, 4]}  # by "is a window layer": page of positions 0-15, of 16-31
-    cache = assign_pages(cache, slot, 0, jnp.asarray(rows[False], jnp.int32))
-    if not cfg.uniform:
-        cache = assign_window_pages(cache, jnp.asarray([slot, slot]), jnp.asarray([0, 1]), jnp.asarray(rows[True]))
+    def assign(cache, full, window):
+        """[(slot, row index, page)] into the tables the cache has: a dense model's one, a window model's two"""
+        return assign_entries(cache, pack_entries(SLOTS, 4, *([full] if cfg.uniform else [full, window])))
+
+    cache = assign(cache, *([(slot, t, page) for t, page in enumerate(rows[windowed])] for windowed in (False, True)))
 
     def pools(c):
         """[(first layer, is a window group, K [n, P, page, n_kv, hd], V)] as float32 on the host"""
@@ -339,9 +341,7 @@ def test_the_carried_pool_takes_layer_l_s_rows_in_layer_l_s_pages_and_nowhere_el
     got = [np.asarray(logits)]
     if case in BESIDE_A_PROMPT:
         # slot 0 holds ten positions of its own, in pages of its own, and is not active below
-        cache = assign_pages(cache, 0, 0, jnp.asarray([5], jnp.int32))
-        if not cfg.uniform:
-            cache = assign_window_pages(cache, jnp.asarray([0]), jnp.asarray([0]), jnp.asarray([6]))
+        cache = assign(cache, [(0, 0, 5)], [(0, 0, 6)])
         other = jnp.zeros((16,), jnp.int32).at[:10].set(tokens[:10] + 1)
         _l, _t, cache = paged_prefill(params, cfg, other, jnp.int32(10), cache, jnp.int32(0), jnp.int32(0))
         assert int(cache.seq_lens[0]) == 10

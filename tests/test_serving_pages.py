@@ -133,6 +133,84 @@ def test_cached_prefixes_are_evicted_before_a_write_is_refused_and_a_refusal_han
     assert list(np.asarray(pages.cache.page_table)[1, :8]) == pages.pages[1]
 
 
+# what one `reserve` sends to the device's tables: (preset, the manager's geometry, [(slot, prompt tokens)]
+# admitted and prefilled, the step's wants, the `assign_entries` calls it makes, the entries they carry)
+TABLE_WRITE_CASES = {
+    # every slot holds positions 0..7; the five at position 8 cross into a third page
+    "dense-5-of-8-slots-cross": (
+        "tiny", dict(max_slots=8, num_pages=40), [(i, 7) for i in range(8)],
+        [(i, 8, 8) for i in (0, 2, 3, 5, 7)] + [(i, 6, 6) for i in (1, 4, 6)], 1, 5,
+    ),
+    # window 8, pages of 4: at position 12 slot 0's first window page falls behind and goes back (the
+    # turn-over), slot 1 at 8 keeps both of its; each takes a page of either pool, in ONE call
+    "two-pools-growth-and-turn-over": (
+        "tiny-mimo", dict(max_slots=3, num_pages=40, prefix_cache=False), [(0, 11), (1, 7)],
+        [(0, 12, 12), (1, 8, 8)], 1, 4,
+    ),
+    # a speculative round of k = 5 writes positions 7..12: two more pages a slot
+    "speculative-ahead-crosses-two-pages": (
+        "tiny", dict(max_slots=8, num_pages=40), [(i, 7) for i in range(3)], [(i, 7, 12) for i in range(3)], 1, 6,
+    ),
+    # the fixed length is max(3 slots, a chunk's 16 / 4 + 1) = 5: six entries a table go in two calls
+    "more-entries-than-the-fixed-length": (
+        "tiny-mimo", dict(max_slots=3, num_pages=40, prefix_cache=False), [], [(0, 0, 15), (1, 0, 7)], 2, 12,
+    ),
+    "no-slot-crosses": ("tiny", dict(max_slots=8, num_pages=40), [(i, 7) for i in range(8)], [(i, 6, 6) for i in range(8)], 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TABLE_WRITE_CASES))
+def test_a_reserve_writes_what_it_handed_out_in_one_call_and_a_refusal_writes_nothing(case, monkeypatch):
+    from modal_tpu.models import paged_kv
+    from modal_tpu.models.llama import get_config
+    from modal_tpu.serving.pages import ModelPages
+
+    preset, geometry, admitted, wants, calls, entries = TABLE_WRITE_CASES[case]
+    pages = ModelPages(get_config(preset), page_size=PAGE, pages_per_slot=12, prefill_chunk=16, **geometry)
+    made = []
+    assign_entries = paged_kv.assign_entries
+    monkeypatch.setattr(paged_kv, "assign_entries", lambda cache, packed: made.append(packed) or assign_entries(cache, packed))
+
+    def tables():
+        return [np.asarray(t) for t in (pages.cache.page_table, pages.cache.window_table) if t is not None]
+
+    def host():
+        return ([list(p) for p in pages.pages], [list(p) for p in pages.window_pages], list(pages.window_first))
+
+    def check_tables_are_the_host_s():
+        on_device = tables()
+        for i, held in enumerate(pages.pages):
+            assert list(on_device[0][i, : len(held)]) == held
+        for i, held in enumerate(pages.window_pages if pages.window_allocator is not None else []):
+            first = pages.window_first[i]  # live from there on; what lies before is stale
+            assert list(on_device[1][i, first : first + len(held)]) == held
+
+    for i, n in admitted:
+        pages.admit(i, n, pages.lookup(list(range(n))))
+        assert pages.reserve([(i, 0, n - 1)])  # its prefill chunk: window pages where there is a window pool
+    check_tables_are_the_host_s()
+    before, stats = len(made), pages.stats()
+    assert pages.reserve(wants)
+    assert len(made) - before == calls and all(m.dtype == np.int32 and m.shape == made[0].shape for m in made)
+    after = pages.stats()
+    assert after["kv_table_writes"] - stats["kv_table_writes"] == calls
+    assert after["kv_table_entries"] - stats["kv_table_entries"] == entries
+    for i, _first, last in wants:
+        assert len(pages.pages[i]) >= last // PAGE + 1
+        if pages.window_allocator is not None:
+            assert pages.window_first[i] + len(pages.window_pages[i]) == last // PAGE + 1
+    check_tables_are_the_host_s()
+    if case == "two-pools-growth-and-turn-over":
+        assert pages.window_first[:2] == [1, 0] and pages.window_pages_released == 1
+    # more than the pool has: no page of either pool handed out, no call made, the tables as they were
+    i, _first, last = wants[0]
+    held, free, on_device, n_made = host(), pages.free_pages, tables(), len(made)
+    assert not pages.reserve([(i, last + 1, (len(pages.pages[i]) + free + 1) * PAGE)])
+    assert host() == held and pages.free_pages == free and len(made) == n_made
+    assert pages.stats()["kv_table_writes"] == after["kv_table_writes"] and pages.stats()["kv_table_entries"] == after["kv_table_entries"]
+    assert all(np.array_equal(a, b) for a, b in zip(on_device, tables()))
+
+
 def test_a_shipment_lands_in_another_manager_s_pages_and_two_pools_refuse():
     import jax.numpy as jnp
 
@@ -157,3 +235,56 @@ def test_a_shipment_lands_in_another_manager_s_pages_and_two_pools_refuse():
         two.check_ships("prefill_export")
     with pytest.raises(ValueError, match="prefix_cache=True with window layers"):
         ModelPages(get_config("tiny-mimo"), max_slots=2, num_pages=20, page_size=PAGE, prefill_chunk=16, prefix_cache=True)
+
+
+@pytest.fixture(scope="module")
+def engine_window():
+    """`/v1/stats` of a live `tiny` engine around three requests that decode
+    side by side, 40 tokens each over pages of 4: every slot crosses a page
+    boundary every fourth step, the three mostly in the same step."""
+    import jax
+
+    from modal_tpu.models.llama import get_config, init_params
+    from modal_tpu.serving.engine import ServingEngine
+
+    cfg = get_config("tiny")
+    engine = ServingEngine(
+        init_params(cfg, jax.random.PRNGKey(0)), cfg, max_slots=4, num_pages=60, page_size=PAGE, pages_per_slot=16, prefill_chunk=16
+    ).start()
+    try:
+        engine.submit([1, 2, 3], max_new_tokens=3).result(timeout=120)
+        ctx = {"stats_start": engine.stats()}
+        for req in [engine.submit(list(range(60 + i, 70 + i)), max_new_tokens=40) for i in range(3)]:
+            req.result(timeout=120)
+        ctx["stats_end"] = engine.stats()
+    finally:
+        engine.stop()
+    return ctx
+
+
+@pytest.mark.parametrize("metric", ["kv_table_writes_per_step", "kv_table_entries_per_write"])
+def test_the_table_write_metrics_read_a_live_engine_through_the_benchmark_s_reader(engine_window, metric):
+    import json
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        from readers import stats_delta_ratio
+    finally:
+        sys.path.remove(bench)
+    with open(os.path.join(bench, "layer_metrics", metric + ".json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "stats_delta_ratio" and spec["layer"] == "page manager"
+    grew = {k: engine_window["stats_end"][k] - engine_window["stats_start"][k] for k in ("steps", "kv_table_writes", "kv_table_entries")}
+    # 3 slots x 40 tokens cross 3 x 10 page boundaries; a step writes once, whatever crossed in it
+    assert grew["kv_table_entries"] == 30 and 10 <= grew["kv_table_writes"] <= min(30, grew["steps"])
+    value = stats_delta_ratio.read(engine_window, **spec["args"])
+    assert value == pytest.approx(
+        {"kv_table_writes_per_step": grew["kv_table_writes"] / grew["steps"], "kv_table_entries_per_write": 30 / grew["kv_table_writes"]}[metric]
+    )
+    assert 0 < value <= 1 if metric == "kv_table_writes_per_step" else 1 <= value <= 3
+    parent = {"steps": 3, "kv_pages_cow_copies": 0}  # the parent commit's `/v1/stats` lacks the counters: silent, never a 0
+    assert stats_delta_ratio.read({"stats_start": parent, "stats_end": {**parent, "steps": 50}}, **spec["args"]) is None
+    assert stats_delta_ratio.read({"stats_start": engine_window["stats_end"], "stats_end": engine_window["stats_end"]}, **spec["args"]) is None

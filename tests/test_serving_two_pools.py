@@ -458,7 +458,7 @@ def test_the_latent_decode_kernel_reads_its_values_from_the_key_block_and_matche
 # what /v1/stats says of pages, a model: the benchmark's readers and `modal_tpu top` go by these names
 PAGE_KEYS = {
     "kv_pages_total", "kv_pages_allocated", "kv_pages_free", "kv_pages_high_water", "kv_pool_bytes", "kv_bytes_per_token",
-    "kv_pages_cow_copies", "kv_pages_shipped", "kv_ship_drops",
+    "kv_pages_cow_copies", "kv_table_writes", "kv_table_entries", "kv_pages_shipped", "kv_ship_drops",
     "prefix_cache_entries", "prefix_cache_pages", "prefix_cache_hits", "prefix_cache_misses",
     "draft_prefix_cache_entries", "draft_prefix_cache_hits",
 }

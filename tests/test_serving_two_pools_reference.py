@@ -152,10 +152,13 @@ def paged_logits(params, cfg, tokens, n_prompt, impl):
         lo = max(0, first_pos - (cfg.window - 1)) // PAGE
         for index in [i for i in held if i < lo]:
             pool.free([held.pop(index)])
+        given = []
         for index in range(lo, last_pos // PAGE + 1):
             if index not in held:
                 held[index] = pool.alloc(1)[0]
-                cache = pk.assign_window_pages(cache, jnp.asarray([1]), jnp.asarray([index]), jnp.asarray([held[index]]))
+                given.append((1, index, held[index]))
+        if given:  # the growing pool's row is whole from the start: no entry of its own
+            cache = pk.assign_entries(cache, pk.pack_entries(2, CHUNK // PAGE + 1, [], given))
         high = max(high, len(held))
 
     out = []
