@@ -355,6 +355,55 @@ class _LoopPhases:
         }
 
 
+def _set_all(events: list) -> None:
+    for event in events:
+        event.set()
+
+
+class _StreamWakes:
+    """The hand-off from the engine thread to the streams' consumers that
+    wait on an event loop (`GenRequest.wait_new_async`). A wake is a
+    `call_soon_threadsafe`: a byte down the loop's self-pipe, a system call
+    that lets the woken loop's thread take the interpreter. Inside an emit
+    block (`with wakes:` on the engine thread, at most 16 rows of a step or
+    one emit of a chunk's first token or of a speculative group) the waiters
+    are held and set when the block ends, ONE call a loop; outside a block,
+    and from any other thread, each waiter is woken at once. `calls` counts
+    the calls that reached a loop (`/v1/stats` `loop.stream_wakeups`)."""
+
+    def __init__(self) -> None:
+        self.calls = 0
+        self._held: Optional[dict] = None  # loop -> [asyncio.Event], while a block is open
+        self._holder = 0  # the thread that opened it
+
+    def __enter__(self) -> "_StreamWakes":
+        if self._held is None:
+            self._held, self._holder = {}, threading.get_ident()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        held, self._held = self._held, None
+        for loop, events in (held or {}).items():
+            self._call(loop, _set_all, events)
+
+    def wake(self, waiters: list) -> None:
+        """`waiters`: [(loop, asyncio.Event)] of a request, under its condition."""
+        held = self._held
+        if held is not None and self._holder == threading.get_ident():
+            for loop, event in waiters:
+                held.setdefault(loop, []).append(event)
+            return
+        for loop, event in waiters:
+            self._call(loop, event.set)
+
+    def _call(self, loop: Any, callback: Any, *args: Any) -> None:
+        try:
+            loop.call_soon_threadsafe(callback, *args)
+        except RuntimeError:
+            return  # consumer's loop is gone; the buffer still has the tokens
+        self.calls += 1
+
+
 class GenRequest:
     """One generation request: prompt in, token stream out.
 
@@ -374,6 +423,7 @@ class GenRequest:
         top_k: int = 0,
         top_p: float = 1.0,
         seed: int = 0,
+        wakes: Optional[_StreamWakes] = None,  # the engine's; a request alone wakes at once
     ):
         self.id = request_id or f"gr-{replica_id()}-{next(_req_counter)}"
         self.prompt = list(prompt)
@@ -411,6 +461,7 @@ class GenRequest:
         self.queue_from = self.created_at
         self._cond = threading.Condition()
         self._async_waiters: list[tuple[Any, Any]] = []  # (loop, asyncio.Event)
+        self._wakes = wakes if wakes is not None else _StreamWakes()
 
     # -- engine side --------------------------------------------------------
 
@@ -441,12 +492,9 @@ class GenRequest:
 
     def _wake(self) -> None:
         self._cond.notify_all()
-        for loop, event in self._async_waiters:
-            try:
-                loop.call_soon_threadsafe(event.set)
-            except RuntimeError:
-                pass  # consumer's loop is gone; the buffer still has the tokens
-        self._async_waiters.clear()
+        if self._async_waiters:
+            self._wakes.wake(self._async_waiters)
+            self._async_waiters.clear()
 
     # -- consumer side ------------------------------------------------------
 
@@ -670,6 +718,7 @@ class ServingEngine:
         self.drains = 0  # times the loop had to read what was in flight before it could go on
         self.tokens_discarded = 0  # tokens of steps launched for a slot that had ended (EOS seen a step late)
         self.decode_rows = 0  # slots of every decode step launched (speculative rounds too)
+        self._wakes = _StreamWakes()  # every request's hand-off to its async consumers
         # what the loop did, for /v1/stats (ISSUE 26): prompt tokens are the
         # target model's chunks (a prefix-cache hit is not in them, a
         # re-prefill after a preemption is)
@@ -796,7 +845,7 @@ class ServingEngine:
         req = GenRequest(
             prompt, max_new_tokens, request_id=request_id, eos_token_id=eos_token_id,
             trace_context=tracing.current_context(),
-            temperature=temperature, top_k=top_k, top_p=top_p, seed=int(seed),
+            temperature=temperature, top_k=top_k, top_p=top_p, seed=int(seed), wakes=self._wakes,
         )
         req._export = bool(export)
         req._shipment = shipment
@@ -1105,17 +1154,19 @@ class ServingEngine:
     def _emit_first(self, idx: int, slot: _Slot, tok: int) -> None:
         """The slot's prefill-completion emission (shared by local prefill
         completion and shipment import): the token on the host, the TTFT
-        mark, and the end of a request that asked for one token."""
+        mark, and the end of a request that asked for one token: one emit
+        block, whose consumer is woken as it ends."""
         req = slot.request
         self._join(slot, tok)
         slot.last_mark_t = time.time()
         slot.tokens_at_mark = len(req.tokens) + 1  # the token appended below
-        req._append(tok)
-        if len(req.tokens) == 1:
-            self._note_ttft(req)
-        self.tokens_generated += 1
-        self._note_rate(1)
-        self._maybe_finish(idx, slot)
+        with self._wakes:
+            req._append(tok)
+            if len(req.tokens) == 1:
+                self._note_ttft(req)
+            self.tokens_generated += 1
+            self._note_rate(1)
+            self._maybe_finish(idx, slot)
 
     def _prefill_one(self) -> None:
         """Advance the oldest prefilling slot by one chunk. One chunk per
@@ -1535,7 +1586,9 @@ class ServingEngine:
         to its slot only if the `_Slot` the step was launched for still sits
         at its index: a slot that ended by EOS had one more step in flight,
         and that step's token is discarded (its KV write landed in a page the
-        slot had reserved, and `release_slot` runs behind it on the device)."""
+        slot had reserved, and `release_slot` runs behind it on the device).
+        A block of 16 rows is an emit block: its streams' consumers are woken
+        as it ends, one call a consumer loop (`_StreamWakes`)."""
         decoding = step.slots
         self._phase("decode_sync", batch=len(decoding), step=step.step)
         next_host = self._read(step)
@@ -1547,39 +1600,40 @@ class ServingEngine:
                 # has the device run dry while the host emits? Every 16 tokens is 2-3 ms apart
                 # at ~150 us a token, at a sixteenth of the cost of a check a token
                 self._phases.check()
-            for i, s in decoding[lo : lo + 16]:
-                s.ahead -= 1
-                req = s.request
-                if self.slots[i] is not s or req.done:
-                    self.tokens_discarded += 1
-                    continue
-                tok = next_host[i]
-                s.cur_token = tok
-                req._append(tok)
-                emitted += 1
-                if spans_on and req.trace_context is not None:
-                    if req.reached_end() or len(req.tokens) - s.tokens_at_mark >= mark_every:
-                        # periodic decode progress mark: contiguous [last mark →
-                        # now] coverage, so per-token latency attributes to
-                        # `decode` with the step's batch occupancy + KV pool
-                        # state attached (ISSUE 11 timelines)
-                        now = time.time()
-                        tracing.record_span(
-                            "serving.decode",
-                            start=s.last_mark_t or now,
-                            end=now,
-                            parent=req.trace_context,
-                            attrs={
-                                "request_id": req.id,
-                                "tokens": len(req.tokens),
-                                "batch_occupancy": len(decoding),
-                                "kv_pages_free": self.pages.free_pages,
-                                "kv_pages_allocated": self.pages.allocated_pages,
-                            },
-                        )
-                        s.last_mark_t = now
-                        s.tokens_at_mark = len(req.tokens)
-                self._maybe_finish(i, s)
+            with self._wakes:
+                for i, s in decoding[lo : lo + 16]:
+                    s.ahead -= 1
+                    req = s.request
+                    if self.slots[i] is not s or req.done:
+                        self.tokens_discarded += 1
+                        continue
+                    tok = next_host[i]
+                    s.cur_token = tok
+                    req._append(tok)
+                    emitted += 1
+                    if spans_on and req.trace_context is not None:
+                        if req.reached_end() or len(req.tokens) - s.tokens_at_mark >= mark_every:
+                            # periodic decode progress mark: contiguous [last mark →
+                            # now] coverage, so per-token latency attributes to
+                            # `decode` with the step's batch occupancy + KV pool
+                            # state attached (the per-request timelines)
+                            now = time.time()
+                            tracing.record_span(
+                                "serving.decode",
+                                start=s.last_mark_t or now,
+                                end=now,
+                                parent=req.trace_context,
+                                attrs={
+                                    "request_id": req.id,
+                                    "tokens": len(req.tokens),
+                                    "batch_occupancy": len(decoding),
+                                    "kv_pages_free": self.pages.free_pages,
+                                    "kv_pages_allocated": self.pages.allocated_pages,
+                                },
+                            )
+                            s.last_mark_t = now
+                            s.tokens_at_mark = len(req.tokens)
+                    self._maybe_finish(i, s)
         self.tokens_generated += emitted
         self._note_rate(emitted)
 
@@ -1743,54 +1797,56 @@ class ServingEngine:
         total_emitted = 0
         total_accepted = 0
         n_sampled = 0
-        for i, s in group:
-            req = s.request
-            emitted = 0
-            for j in range(k1):
-                tok = int(targets[i, j])
-                req._append(tok)
-                emitted += 1
-                if req.temperature > 0:
-                    n_sampled += 1
-                if req.reached_end() or j == k:
-                    break
-                if int(proposals[i, j]) != tok:
-                    break  # draft diverged: tok IS the target's correction
-                total_accepted += 1
-            new_lens[i] = s.pos + emitted
-            update[i] = True
-            s.pos += emitted
-            s.cur_token = int(targets[i, emitted - 1])
-            total_emitted += emitted
-            if spans_on and req.trace_context is not None:
-                if req.reached_end() or len(req.tokens) - s.tokens_at_mark >= mark_every:
-                    now = time.time()
-                    tracing.record_span(
-                        "serving.decode",
-                        start=s.last_mark_t or now,
-                        end=now,
-                        parent=req.trace_context,
-                        attrs={
-                            "request_id": req.id,
-                            "tokens": len(req.tokens),
-                            "batch_occupancy": batch,
-                            "speculative": True,
-                            "kv_pages_free": self.pages.free_pages,
-                            "kv_pages_allocated": self.pages.allocated_pages,
-                        },
-                    )
-                    s.last_mark_t = now
-                    s.tokens_at_mark = len(req.tokens)
+        # one emit block: the group's consumers are woken as it ends, its finishes included
+        with self._wakes:
+            for i, s in group:
+                req = s.request
+                emitted = 0
+                for j in range(k1):
+                    tok = int(targets[i, j])
+                    req._append(tok)
+                    emitted += 1
+                    if req.temperature > 0:
+                        n_sampled += 1
+                    if req.reached_end() or j == k:
+                        break
+                    if int(proposals[i, j]) != tok:
+                        break  # draft diverged: tok IS the target's correction
+                    total_accepted += 1
+                new_lens[i] = s.pos + emitted
+                update[i] = True
+                s.pos += emitted
+                s.cur_token = int(targets[i, emitted - 1])
+                total_emitted += emitted
+                if spans_on and req.trace_context is not None:
+                    if req.reached_end() or len(req.tokens) - s.tokens_at_mark >= mark_every:
+                        now = time.time()
+                        tracing.record_span(
+                            "serving.decode",
+                            start=s.last_mark_t or now,
+                            end=now,
+                            parent=req.trace_context,
+                            attrs={
+                                "request_id": req.id,
+                                "tokens": len(req.tokens),
+                                "batch_occupancy": batch,
+                                "speculative": True,
+                                "kv_pages_free": self.pages.free_pages,
+                                "kv_pages_allocated": self.pages.allocated_pages,
+                            },
+                        )
+                        s.last_mark_t = now
+                        s.tokens_at_mark = len(req.tokens)
 
-        # roll both pools' lengths to the accepted frontier — the verify
-        # wrote k+1 positions, only pos+emitted of them are real; the draft
-        # over-advanced by its k+1 feeds and rolls back to match. BEFORE any
-        # slot release: a release zeroes the slot's length on the device, and
-        # this roll must not scribble a stale value back onto a freed slot
-        for model in self._models:
-            model.cache = set_seq_lens(model.cache, jnp.asarray(new_lens), jnp.asarray(update))
-        for i, s in group:
-            self._maybe_finish(i, s)
+            # roll both pools' lengths to the accepted frontier — the verify
+            # wrote k+1 positions, only pos+emitted of them are real; the draft
+            # over-advanced by its k+1 feeds and rolls back to match. BEFORE any
+            # slot release: a release zeroes the slot's length on the device, and
+            # this roll must not scribble a stale value back onto a freed slot
+            for model in self._models:
+                model.cache = set_seq_lens(model.cache, jnp.asarray(new_lens), jnp.asarray(update))
+            for i, s in group:
+                self._maybe_finish(i, s)
         return total_emitted, total_accepted, n_sampled
 
     def _maybe_finish(self, idx: int, slot: _Slot) -> None:
@@ -1854,6 +1910,7 @@ class ServingEngine:
                 "drains": self.drains,
                 "tokens_discarded": self.tokens_discarded,
                 "decode_rows": self.decode_rows,
+                "stream_wakeups": self._wakes.calls,
             },
             "requests_admitted": self.requests_admitted,
             "queue_wait_seconds": self.queue_wait_seconds,
