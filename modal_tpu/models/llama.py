@@ -30,7 +30,8 @@ from jax import lax
 
 
 # the names config.json files give a layer's kinds in their per-layer lists
-_PATTERN_NAMES = {"full_attention": 0, "sliding_attention": 1, "dense": 0, "sparse": 1}
+_PATTERN_NAMES = {"full_attention": 0, "sliding_attention": 1, "conv": 2, "dense": 0, "sparse": 1}
+CONV = 2  # attn_pattern's value of a gated short-convolution layer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,6 +58,11 @@ class LayerKind:
     # rope width. () = keys and values a KV head
     latent: tuple = ()
     softmax_scale: float = 0.0  # 0 = 1 / sqrt(head width); a YaRN with `mscale_all_dim` scales it
+    qk_norm: bool = False  # an RMSNorm over each query and key head before rotary
+    # a gated short convolution of this many taps IN PLACE of attention: [B | C | x] = h W_in,
+    # y = (C * conv(B * x)) W_out, depthwise and causal. It reads no page: what it carries from one
+    # step to the next is its last `conv - 1` rows of B * x a slot. 0 = attention
+    conv: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +91,7 @@ class LlamaConfig:
     v_head_dim: int = 0  # value head width; 0 = the query/key width
     rope_fraction: float = 1.0  # rotary covers the first int(head_dim * fraction) dims of a head
     value_scale: float = 1.0  # v is multiplied by this before it is cached
-    attn_pattern: tuple = ()  # a layer: 0 full attention, 1 sliding window; () = all full
+    attn_pattern: tuple = ()  # a layer: 0 full attention, 1 sliding window, 2 short convolution; () = all full
     window: int = 0  # a window layer's query at p sees keys p-window+1 .. p
     window_kv_heads: int = 0  # KV heads of a window layer; 0 = n_kv_heads
     window_rope_theta: float = 0.0  # rotary base of a window layer; 0 = rope_theta
@@ -123,6 +129,8 @@ class LlamaConfig:
     # scores the sum of its two best experts, the best topk_group groups stay; 1 = no limit
     n_group: int = 1
     topk_group: int = 1
+    conv_taps: int = 0  # a convolution layer's taps (config.json's conv_L_cache)
+    qk_norm: bool = False  # every attention layer norms each query and key head before rotary
 
     def __post_init__(self):
         for field in ("attn_pattern", "ffn_pattern", "n_heads_per_layer"):
@@ -138,8 +146,10 @@ class LlamaConfig:
         ))
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
-        if any(self.attn_pattern) and self.window < 1:
+        if 1 in self.attn_pattern and self.window < 1:
             raise ValueError("attn_pattern has window layers but window is 0")
+        if CONV in self.attn_pattern and self.conv_taps < 2:
+            raise ValueError(f"attn_pattern has convolution layers: conv_taps must be 2 or more, it is {self.conv_taps}")
         if any(self.ffn_pattern):
             lo, n = self.experts_held
             if not (0 < self.experts_per_token <= self.n_routed_experts and self.expert_dim > 0):
@@ -195,8 +205,12 @@ class LlamaConfig:
             raise ValueError(f"rope_scaling {scaling}: the served path scales rotary positions by YaRN or not at all")
         latent = (self.q_rank, self.kv_rank, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim) if self.kv_rank else ()
         for i in range(self.n_layers):
-            windowed = bool(self.attn_pattern and self.attn_pattern[i])
+            pattern = self.attn_pattern[i] if self.attn_pattern else 0
+            windowed = pattern == 1
             routed = bool(self.ffn_pattern and self.ffn_pattern[i])
+            if pattern == CONV:
+                kinds.append(LayerKind(n_heads=1, n_kv_heads=1, rope_dim=0, experts=routed, conv=self.conv_taps))
+                continue
             rule = rules.get("sliding_attention" if windowed else "full_attention", {})
             if rule.get("rope_type", "default") not in ("default", "yarn"):
                 raise ValueError(f"rope_type {rule['rope_type']!r}: the served path turns heads by plain rotary or YaRN")
@@ -230,6 +244,7 @@ class LlamaConfig:
                 attn_name="mla" if latent else ("swa" if windowed else "full") if self.attn_pattern else "",
                 latent=latent,
                 softmax_scale=softmax_scale,
+                qk_norm=self.qk_norm,
             ))
         return tuple(kinds)
 
@@ -251,11 +266,15 @@ class LlamaConfig:
         `params["layers"]` is ONE stacked tree and the KV pool one array (the
         dense presets). Otherwise
         both are tuples, one entry a group of `layer_groups`."""
-        return not (self.attn_pattern or self.ffn_pattern or self.kv_rank)
+        return not (self.attn_pattern or self.ffn_pattern or self.kv_rank or self.qk_norm)
 
     @property
     def has_window(self) -> bool:
         return any(k.window for k in self.layer_kinds)
+
+    @property
+    def has_conv(self) -> bool:
+        return any(k.conv for k in self.layer_kinds)
 
     @property
     def has_experts(self) -> bool:
@@ -267,13 +286,16 @@ class LlamaConfig:
             hd, vd, (_lo, held) = self.head_dim, self.v_dim, self.experts_held
             total = 2 * self.vocab_size * self.dim + self.dim
             for k in self.layer_kinds:
-                if k.latent:
+                if k.conv:
+                    total += self.dim * 3 * self.dim + k.conv * self.dim + self.dim * self.dim  # W_in, the taps, W_out
+                elif k.latent:
                     q_rank, kv_rank, nope, rope, _vd = k.latent
                     total += self.dim * (q_rank + kv_rank + rope) + q_rank + kv_rank  # the two down-projections, their norms
                     total += q_rank * k.n_heads * hd + kv_rank * k.n_heads * (nope + vd) + k.n_heads * vd * self.dim
                 else:
                     total += self.dim * (k.n_heads * hd + k.n_kv_heads * (hd + vd)) + k.n_heads * vd * self.dim
                 total += 2 * self.dim + (k.n_heads if k.sink else 0) + (self.dim * k.n_heads if k.gated else 0)
+                total += 2 * hd if k.qk_norm else 0
                 if k.experts:
                     total += (self.dim + self.router_bias) * self.n_routed_experts
                     total += 3 * self.dim * (held * self.expert_dim + self.shared_expert_dim)
@@ -433,6 +455,36 @@ CONFIGS: dict[str, LlamaConfig] = {
         ffn_pattern=(0, 1, 1, 1, 1), n_routed_experts=24, experts_per_token=4, expert_dim=32, n_experts_held=6,
         shared_expert_dim=32, routed_scale=2.5, router_bias=False, n_group=4, topk_group=2,
     ),
+    # LFM2-8B-A1B as published (LiquidAI/LFM2-8B-A1B config.json): 18 gated
+    # short-convolution layers (3 taps) and 6 of GQA (32 query heads, 8 KV
+    # heads of 64, an RMSNorm on each query and key head, rotary base 1e6), two
+    # leading dense layers and then 32 routed experts of 1792, 4 a token,
+    # sigmoid scores with a selection bias, renormalised. `layer_types` is
+    # kept whole so that {"name": "lfm2-8b-a1b", "n_layers": 14} is a cut
+    # (benchmark/configs/lfm2-8b-a1b-serve-1chip.json, whose `assumed` says
+    # which readings of the row these are).
+    "lfm2-8b-a1b": LlamaConfig(
+        name="lfm2-8b-a1b", vocab_size=65_536, dim=2048, n_layers=24, n_heads=32, n_kv_heads=8, ffn_dim=7168,
+        norm_eps=1e-5, rope_theta=1_000_000.0, max_seq_len=128_000,
+        attn_pattern=(
+            "conv", "conv", "full_attention", "conv", "conv", "conv", "full_attention", "conv", "conv", "conv",
+            "full_attention", "conv", "conv", "conv", "full_attention", "conv", "conv", "conv", "full_attention",
+            "conv", "conv", "full_attention", "conv", "conv",
+        ),
+        conv_taps=3, qk_norm=True, ffn_pattern=(0, 0) + (1,) * 22, n_routed_experts=32, experts_per_token=4,
+        expert_dim=1792, router_bias=True, routed_scale=1.0,
+    ),
+    # the same description at a size the CPU tests hold: two dense convolution
+    # layers, then attention and three convolutions over 8 experts, top-2 with
+    # the selection bias, 3 taps, heads 16 wide; 8 KV heads fill a row of 128,
+    # so the pool packs them as the published 8 of 64 are packed into 512
+    "tiny-lfm2": LlamaConfig(
+        name="tiny-lfm2", vocab_size=512, dim=256, n_layers=6, n_heads=16, n_kv_heads=8, ffn_dim=128,
+        norm_eps=1e-5, rope_theta=10_000.0, max_seq_len=256,
+        attn_pattern=("conv", "conv", "full_attention", "conv", "conv", "conv"), conv_taps=3, qk_norm=True,
+        ffn_pattern=(0, 0, 1, 1, 1, 1), n_routed_experts=8, experts_per_token=2, expert_dim=32,
+        router_bias=True, routed_scale=1.0,
+    ),
 }
 
 
@@ -469,17 +521,28 @@ def _init_kind(cfg: LlamaConfig, kind: LayerKind, k: jax.Array) -> dict:
     added draws from four more keys, split from fold_in(key, 1) so that the
     ten stay what they were: the attention gate, the shared expert's gate,
     up and down. A latent layer draws its five projections from four keys split
-    from fold_in(key, 2) and `wo` from the ten's, and has no wq / wk / wv."""
+    from fold_in(key, 2) and `wo` from the ten's, and has no wq / wk / wv. A
+    convolution layer draws its in-projection [D, 3D], its taps [conv, D] and
+    its out-projection [D, D] from three keys split from fold_in(key, 3), and
+    has no attention weights; its operator's norm is `attn_norm`. A head's
+    query and key norms (`qk_norm`) have gains of ones."""
     init = jax.nn.initializers.normal(stddev=0.02)
     ks = jax.random.split(k, 10)
     # drawn only where a kind has what they are for: a model without draws, and boots, as it did
     more = jax.random.split(jax.random.fold_in(k, 1), 4) if kind.gated or cfg.shared_expert_dim else None
     hd, vd = cfg.head_dim, cfg.v_dim
-    layer = {
-        "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
-        "wo": init(ks[3], (kind.n_heads * vd, cfg.dim), cfg.dtype),
-        "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype),
-    }
+    layer = {"attn_norm": jnp.ones((cfg.dim,), cfg.dtype), "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype)}
+    if kind.conv:
+        k_in, k_taps, k_out = jax.random.split(jax.random.fold_in(k, 3), 3)
+        layer.update({
+            "conv_in": init(k_in, (cfg.dim, 3 * cfg.dim), cfg.dtype),  # [B | C | x]
+            "conv_w": init(k_taps, (kind.conv, cfg.dim), cfg.dtype),  # tap j meets the row conv - 1 - j back
+            "conv_out": init(k_out, (cfg.dim, cfg.dim), cfg.dtype),
+        })
+    else:
+        layer["wo"] = init(ks[3], (kind.n_heads * vd, cfg.dim), cfg.dtype)
+    if kind.qk_norm:
+        layer.update({"q_head_norm": jnp.ones((hd,), cfg.dtype), "k_head_norm": jnp.ones((hd,), cfg.dtype)})
     if kind.latent:
         # four keys split from fold_in(key, 2): the query's down- and up-projection, the latent's
         # down-projection [latent | rope key] and its up-projection, a head [nope keys | values]
@@ -494,7 +557,7 @@ def _init_kind(cfg: LlamaConfig, kind: LayerKind, k: jax.Array) -> dict:
             "kv_norm": jnp.ones((kv_rank,), cfg.dtype),
             "wkv_up": init(up_kv, (kv_rank, kind.n_heads * (nope + vd)), cfg.dtype),
         })
-    else:
+    elif not kind.conv:
         layer.update({
             "wq": init(ks[0], (cfg.dim, kind.n_heads * hd), cfg.dtype),
             "wk": init(ks[1], (cfg.dim, kind.n_kv_heads * hd), cfg.dtype),

@@ -63,6 +63,34 @@ def k_cache_dim(cfg: LlamaConfig) -> int:
     return width if width <= 128 or width % 128 == 0 else -(-width // 128) * 128
 
 
+def packs_heads(cfg: LlamaConfig, kind: LayerKind) -> int:
+    """The KV heads a layer's pools store side by side in ONE row a token,
+    `n_kv x head` wide, in place of a row a head; 0 where they store a row a
+    head. Rows are packed where a head is narrower than a 128-lane tile and
+    the heads together fill whole tiles (8 KV heads of 64: a row of 512).
+    XLA's TPU layout pads a 64-wide minor dimension to 128, so `[P, page, 8,
+    64]` would take twice its bytes, and Mosaic refuses the decode kernel's
+    page copies of it ("aligned to tiling (128), but is 64"); the decode step
+    hands the kernel queries that meet their own KV head's columns of the row
+    (`_packed_queries`). Not for the dense presets, whose pool is one array as
+    it always was. The one place the layout is decided: `kv_rows` gives its
+    shapes, and the attention paths take this number as `packed_heads`."""
+    if cfg.uniform or kind.latent or kind.conv or cfg.head_dim >= 128:
+        return 0
+    fills = (kind.n_kv_heads * cfg.head_dim) % 128 == 0 and (kind.n_kv_heads * cfg.v_dim) % 128 == 0
+    return kind.n_kv_heads if fills else 0
+
+
+def kv_rows(cfg: LlamaConfig, kind: LayerKind) -> tuple[tuple, tuple]:
+    """(key row, value row): the shape a layer's pools store a token's keys
+    and values at below `[pages, page]`: `(n_kv, width)`, a row a KV head, or
+    `(n_kv x width,)`, one row a token (`packs_heads`)."""
+    heads = packs_heads(cfg, kind)
+    if heads:
+        return (heads * k_cache_dim(cfg),), (heads * cfg.v_dim,)
+    return (kind.n_kv_heads, k_cache_dim(cfg)), (kind.n_kv_heads, cfg.v_dim)
+
+
 def window_pages_per_slot(window: int, page_size: int) -> int:
     """Pages of a window layer's pool one decoding slot can hold: the window
     (the query's own position included) and the page being written."""
@@ -88,14 +116,20 @@ class PagedKVCache(NamedTuple):
     and v_pages `[n_layers, num_pages, page_size, n_kv, hd]`. Layers of more
     than one kind: k_pages and v_pages are tuples, one array a group of
     `cfg.layer_groups`, `[layers in the group, pages of its kind's pool,
-    page_size, its n_kv, stored key width | value width]`; full-attention
+    page_size, its n_kv, stored key width | value width]` (heads narrower
+    than a lane tile, `packs_heads`: `[layers, pages, page_size, n_kv x
+    width]`, one row a token); full-attention
     groups share the page ids of `page_table` (a pool that grows with the
     context), window groups those of `window_table` (a pool bounded by the
     window: a page behind it goes back to its free list while the request
     lives, and its stale table entry is never addressed). A group of LATENT
     layers (`kind.latent`) keeps one array, `[layers, pages, page_size, 1,
     stored row width]`, a token's `[latent | rope key]` row, and None in
-    v_pages: its values are the first columns of its rows.
+    v_pages: its values are the first columns of its rows. A group of
+    CONVOLUTION layers (`kind.conv`) holds no page: None in both, and in
+    `conv_state` (one entry a group, None for the others) `[layers, slots,
+    conv - 1, D]`, the last rows of B * x each slot's prefix left, which a
+    prefill chunk at position 0 starts from zeros in place of.
 
     A page id names the same page in every layer of a pool: the tables, the
     allocators, `copy_page` and the page shipment index `[:, id]`. The jitted
@@ -116,6 +150,7 @@ class PagedKVCache(NamedTuple):
     # held experts that got at least one of a step's pairs, summed over expert
     # layers and steps the same way
     moe_touched: Optional[jax.Array] = None
+    conv_state: Optional[tuple] = None  # a model with convolution layers: a slot's state beside its pages
 
     @staticmethod
     def create(
@@ -136,18 +171,25 @@ class PagedKVCache(NamedTuple):
             return PagedKVCache(k_pages=jnp.zeros(shape, cfg.dtype), v_pages=jnp.zeros(shape, cfg.dtype), **tables)
         if cfg.has_window and window_num_pages is None:
             raise ValueError("a model with window layers needs window_num_pages, its second pool's size")
-        k_pages, v_pages = [], []
+        k_pages, v_pages, conv_state = [], [], []
         for kind, _first, n in cfg.layer_groups:
-            pool = (n, window_num_pages if kind.window else num_pages, page_size, kind.n_kv_heads)
-            k_pages.append(jnp.zeros(pool + (k_cache_dim(cfg),), cfg.dtype))
+            conv_state.append(jnp.zeros((n, slots, kind.conv - 1, cfg.dim), cfg.dtype) if kind.conv else None)
+            if kind.conv:
+                k_pages.append(None)
+                v_pages.append(None)
+                continue
+            pages = (n, window_num_pages if kind.window else num_pages, page_size)
+            rows = kv_rows(cfg, kind)
+            k_pages.append(jnp.zeros(pages + rows[0], cfg.dtype))
             # what a pool stores is its layers' kind's: a latent layer has no values of its own
-            v_pages.append(None if kind.latent else jnp.zeros(pool + (cfg.v_dim,), cfg.dtype))
+            v_pages.append(None if kind.latent else jnp.zeros(pages + rows[1], cfg.dtype))
         return PagedKVCache(
             k_pages=tuple(k_pages),
             v_pages=tuple(v_pages),
             window_table=jnp.zeros((slots, pages_per_slot), jnp.int32) if cfg.has_window else None,
             moe_pairs=jnp.zeros((), jnp.uint32) if cfg.has_experts else None,
             moe_touched=jnp.zeros((), jnp.uint32) if cfg.has_experts else None,
+            conv_state=tuple(conv_state) if cfg.has_conv else None,
             **tables,
         )
 
@@ -167,6 +209,10 @@ class PagedKVCache(NamedTuple):
     def pool_bytes(self) -> int:
         """Bytes of every K and V pool (and of every latent one)."""
         return sum(int(a.size) * a.dtype.itemsize for a in jax.tree_util.tree_leaves((self.k_pages, self.v_pages)))
+
+    def conv_state_bytes(self) -> int:
+        """Bytes of every convolution layer's state, every slot's."""
+        return sum(int(a.size) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(self.conv_state))
 
 
 def pool_bytes_by_kind(cfg: LlamaConfig, cache: PagedKVCache) -> tuple[int, int]:
@@ -284,7 +330,8 @@ def _one_pool(cache: PagedKVCache, what: str) -> None:
     if isinstance(cache.k_pages, tuple):
         raise ValueError(
             f"{what} ships pages of ONE pool; this model keeps a pool a layer kind (full and window "
-            "attention side by side), and a shipment format over two pools does not exist yet"
+            "attention side by side) or a convolution state a slot beside its pages, and a shipment format "
+            "over two pools or with a slot's state does not exist yet"
         )
 
 
@@ -340,9 +387,28 @@ def _scatter_kv(k_pages, v_pages, k, v, page_ids, offsets):
     )
 
 
+def _own_kv_head(h, n_kv):
+    """[1, H, n_kv, 1]: query head j reads KV head j // (H / n_kv) (repeat_kv's order)."""
+    return ((jnp.arange(h) // (h // n_kv))[:, None] == jnp.arange(n_kv)[None, :])[None, :, :, None]
+
+
+def _packed_queries(q, n_kv):
+    """q [S, H, hd] -> [S, H, n_kv x hd]: each query head in its own KV
+    head's columns of a packed row, zeros in the others, so that q . row is
+    the head's score against its own KV head."""
+    s, h, hd = q.shape
+    return jnp.where(_own_kv_head(h, n_kv), q[:, :, None, :], jnp.zeros((), q.dtype)).reshape(s, h, n_kv * hd)
+
+
+def _own_columns(out, n_kv):
+    """[S, H, n_kv, vd] -> [S, H, vd]: each query head's mix of its own KV
+    head's values (the other columns mixed the other heads' values)."""
+    return jnp.sum(jnp.where(_own_kv_head(out.shape[1], n_kv), out, jnp.zeros((), out.dtype)), axis=2)
+
+
 def _paged_attention(
     q, k_pages, v_pages, page_table, mask, positions=None, attn_impl="gather",
-    window=0, sink=None, scale=None, kernel_name="paged_decode_attention", latent=0,
+    window=0, sink=None, scale=None, kernel_name="paged_decode_attention", latent=0, packed_heads=0,
 ):
     """Attend each slot's page span: what `paged_decode_step` and
     `paged_verify_step` run (a prefill chunk has `_prefill_attention`).
@@ -367,9 +433,11 @@ def _paged_attention(
     and takes no value; `latent` > 0 where the pool holds latent rows and no
     values (v_pages None): q is the absorbed query at the rows' stored width,
     a row's first `latent` columns are its value, and the kernel is the latent
-    one (`paged_decode_attention_mla`)."""
+    one (`paged_decode_attention_mla`). `packed_heads` > 0 where the pool
+    holds one row a token, that many KV heads side by side (`packs_heads`)."""
     s, sq, h, hd = q.shape
-    vd = latent or v_pages.shape[-1]
+    n_kv = packed_heads or k_pages.shape[2]
+    vd = latent or math.prod(v_pages.shape[2:]) // n_kv
     scale = scale or 1.0 / math.sqrt(hd)
     if attn_impl in ("kernel", "kernel_interpret") and sq == 1 and positions is not None:
         from ..ops.paged_attention import paged_decode_attention, paged_decode_attention_mla
@@ -380,7 +448,15 @@ def _paged_attention(
                 latent=latent, scale=scale, name=kernel_name, interpret=(attn_impl == "kernel_interpret"),
             )
             return out.reshape(s, sq, h, vd)
-        n_kv = k_pages.shape[2]
+        if packed_heads:
+            # to the kernel a packed row is one KV head under every query head; a query meets its own
+            # head's columns of it, and the output its own head's columns of the values
+            out = paged_decode_attention(
+                _packed_queries(q.reshape(s, h, hd), n_kv)[:, None], k_pages, v_pages, page_table, positions,
+                window=window, sink=None if sink is None else sink.reshape(1, h), scale=scale, name=kernel_name,
+                interpret=(attn_impl == "kernel_interpret"),
+            )
+            return _own_columns(out.reshape(s, h, n_kv, vd), n_kv).reshape(s, sq, h, vd)
         n_rep = h // n_kv
         out = paged_decode_attention(
             q.reshape(s, n_kv, n_rep, hd),  # repeat_kv order: head = kv*n_rep + rep
@@ -396,7 +472,6 @@ def _paged_attention(
         )
         return out.reshape(s, sq, h, vd)
     page = k_pages.shape[1]
-    n_kv = k_pages.shape[2]
     k_span = page_table.shape[1] * page
     # [S, pages_per_slot, page, n_kv, hd] -> [S, K, n_kv, hd]
     k_att = k_pages[page_table].reshape(s, k_span, n_kv, hd)
@@ -429,7 +504,7 @@ def prefill_kv_attended(live: int, pages_per_slot: int, page_size: int) -> int:
     return math.ceil(live / block) * block
 
 
-def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, window=0, sink=None, scale=None, expand=None):
+def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, window=0, sink=None, scale=None, expand=None, packed_heads=0):
     """One slot's prefill chunk against the slot's LIVE prefix: a flash
     forward over KV blocks of the page row. q: [Sq, H, hd] at positions q_pos
     [Sq]; k_pages/v_pages: [P, page, n_kv, hd], the chunk's own K/V already
@@ -453,6 +528,8 @@ def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, windo
     running max is kept finite by hand. A `sink` [H] joins each row's sum
     once, after the last block. Values may be narrower than keys, and
     `scale` is the model's own where q and the stored keys are padded.
+    `packed_heads` > 0 where the pool holds one row a token, that many KV
+    heads side by side (`packs_heads`).
 
     A pool of latent rows (v_pages None) brings `expand`: (a block's rows
     [block, 1, stored]) -> (keys [block, H, hd], values [block, H, vd]), the
@@ -461,13 +538,15 @@ def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, windo
     chip than the decode step's absorbed form, whose accumulator is as wide
     as the latent (PERF.md section 6, PR 37)."""
     sq, h, hd = q.shape
-    page, n_kv, stored = k_pages.shape[1:]
+    page, stored = k_pages.shape[1], k_pages.shape[-1]
     block = block_pages * page
     if expand is not None:
+        n_kv = k_pages.shape[2]
         k_s, v_s = jax.eval_shape(expand, jax.ShapeDtypeStruct((block, n_kv, stored), k_pages.dtype))
         n_kv_q, vd = k_s.shape[1], v_s.shape[-1]
     else:
-        n_kv_q, vd = n_kv, v_pages.shape[-1]
+        n_kv_q = packed_heads or k_pages.shape[2]
+        vd = math.prod(v_pages.shape[2:]) // n_kv_q
     n_rep = h // n_kv_q
     # a row the block does not divide is padded with page 0 (a scratch page,
     # of the pool's first layer where the ids are a later layer's): those
@@ -480,11 +559,10 @@ def _prefill_attention(q, k_pages, v_pages, row, q_pos, live, block_pages, windo
     def fold(b, carry):
         m, l, acc = carry
         ids = lax.dynamic_slice_in_dim(row, b * block_pages, block_pages)
-        k_blk = k_pages[ids].reshape(block, n_kv, stored)
         if expand is not None:
-            k_blk, v_blk = expand(k_blk)
+            k_blk, v_blk = expand(k_pages[ids].reshape(block, n_kv, stored))
         else:
-            v_blk = v_pages[ids].reshape(block, n_kv, vd)
+            k_blk, v_blk = k_pages[ids].reshape(block, n_kv_q, -1), v_pages[ids].reshape(block, n_kv_q, vd)
         s = jnp.einsum("qgrd,kgd->grqk", qg, k_blk, preferred_element_type=jnp.float32) * scale
         kv_pos = (b * block + offsets)[None, :]
         seen = kv_pos <= q_pos[:, None]  # [Sq, block]
@@ -535,7 +613,8 @@ def _pad_last(x, width):
 
 def _heads_qkv(cfg, kind, h, layer, positions, inv_freq, stored):
     """Keys and values a KV head: (q [S, Sq, H, stored], k [S, Sq, n_kv,
-    stored], v [S, Sq, n_kv, vd]) of the normed input h."""
+    stored], v [S, Sq, n_kv, vd]) of the normed input h; where the kind norms
+    its heads (`qk_norm`), each query and key head is RMS-normed before rotary."""
     from .quant import qmm
 
     s, sq, _d = h.shape
@@ -543,6 +622,9 @@ def _heads_qkv(cfg, kind, h, layer, positions, inv_freq, stored):
     q = qmm(h, layer["wq"]).reshape(s, sq, kind.n_heads, hd)
     k = qmm(h, layer["wk"]).reshape(s, sq, kind.n_kv_heads, hd)
     v = qmm(h, layer["wv"]).reshape(s, sq, kind.n_kv_heads, vd)
+    if kind.qk_norm:
+        q = rms_norm(q, layer["q_head_norm"], cfg.norm_eps)
+        k = rms_norm(k, layer["k_head_norm"], cfg.norm_eps)
     q = _rope(kind, q, positions, inv_freq)
     k = _rope(kind, k, positions, inv_freq)
     if cfg.value_scale != 1.0:
@@ -610,41 +692,88 @@ def _paged_layer(cfg, kind, x, layer, positions, write_page_ids, write_offsets, 
     `valid` [S*Sq] tokens and the held experts they touched; 0 for a dense FFN."""
     from .quant import qmm
 
-    s, sq, d = x.shape
+    s, sq, _d = x.shape
     # the scopes are names only (HLO metadata, profiler traces): nothing
     # computed changes
     with jax.named_scope(kind.attn_name + "_attention" if kind.attn_name else "paged_attention"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q, k, v = (_latent_qkv if kind.latent else _heads_qkv)(cfg, kind, h, layer, positions, inv_freq, kp.shape[-1])
+        q, k, v = (_latent_qkv if kind.latent else _heads_qkv)(cfg, kind, h, layer, positions, inv_freq, k_cache_dim(cfg))
         with jax.named_scope("kv_write"):
-            flat = lambda a: None if a is None else a.reshape((s * sq,) + a.shape[2:])  # noqa: E731
-            kp, vp = _scatter_kv(kp, vp, flat(k), flat(v), write_page_ids, write_offsets)
+            # a token's rows as the pool stores them (kv_rows: a row a head, or the heads side by side)
+            (k_row, v_row), n = kv_rows(cfg, kind), s * sq
+            kp, vp = _scatter_kv(kp, vp, k.reshape((n,) + k_row), None if v is None else v.reshape((n,) + v_row), write_page_ids, write_offsets)
         attn_out = attend(kind, q, kp, vp, table, layer)
         if kind.gated:
             with jax.named_scope("attn_gate"):
                 gate = jax.nn.sigmoid(qmm(h, layer["wg"]).astype(jnp.float32))  # [S, Sq, H]: a scalar a head
                 attn_out = (attn_out.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
         x = x + qmm(attn_out.reshape(s, sq, -1), layer["wo"])
+    x, pairs = _ffn(cfg, kind, x, layer, valid)
+    return x, kp, vp, pairs
+
+
+def _ffn(cfg, kind, x, layer, valid):
+    """A layer's second sub-block, the residual add included: (x, counts) as
+    `_paged_layer` returns them."""
+    from .quant import qmm
+
+    s, sq, d = x.shape
     pairs = jnp.zeros((2,), jnp.uint32)
     if kind.experts:
         h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
         y, pairs = routed_experts(cfg, h.reshape(s * sq, d), layer, valid)
-        x = x + y.reshape(s, sq, d)
-    else:
-        with jax.named_scope("ffn"):
-            h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-            gated = jax.nn.silu(qmm(h, layer["w_gate"]).astype(jnp.float32)).astype(x.dtype) * qmm(h, layer["w_up"])
-            x = x + qmm(gated, layer["w_down"])
-    return x, kp, vp, pairs
+        return x + y.reshape(s, sq, d), pairs
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        gated = jax.nn.silu(qmm(h, layer["w_gate"]).astype(jnp.float32)).astype(x.dtype) * qmm(h, layer["w_up"])
+        return x + qmm(gated, layer["w_down"]), pairs
 
 
-def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None):
+def _conv_layer(cfg, kind, x, layer, state, rows, fresh, valid):
+    """One gated short-convolution layer. x: [S, Sq, D], S sequences of Sq
+    consecutive positions each; state: [S, taps - 1, D], each sequence's last
+    rows of u = B * x before its first position here; rows: [S] int32, the
+    sequence's real positions (the first `rows` of Sq; 0: the sequence has
+    none this step and keeps its state bit for bit); fresh: [S] bool, the
+    sequence starts at position 0 and reads zeros in place of its state.
+    Returns (x, state after the sequence's last real position, counts).
+
+    [B | C | x~] = h W_in; u = B * x~; v_t = sum_j w_j u_{t - taps + 1 + j},
+    with u zero before position 0; y = (C * v) W_out. u is rounded to the
+    model's dtype before the taps, where the state keeps it, so a sequence
+    cut into chunks anywhere meets the same numbers as one in a single chunk;
+    the taps and the gate are float32."""
+    from .quant import qmm
+
+    taps, sq = kind.conv, x.shape[1]
+    with jax.named_scope("short_conv"):
+        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        b, c, xt = jnp.split(qmm(h, layer["conv_in"]), 3, axis=-1)
+        u = (b.astype(jnp.float32) * xt.astype(jnp.float32)).astype(x.dtype)
+        with jax.named_scope("conv_state"):
+            seq = jnp.concatenate([jnp.where(fresh[:, None, None], jnp.zeros_like(state), state), u], axis=1)
+            # the taps - 1 rows that end at the last real position: the old state shifted by `rows`
+            last = rows[:, None] + jnp.arange(taps - 1, dtype=rows.dtype)[None, :]
+            state = jnp.take_along_axis(seq, last[:, :, None], axis=1)
+        w = layer["conv_w"].astype(jnp.float32)
+        v = sum(w[j] * seq[:, j : j + sq].astype(jnp.float32) for j in range(taps))
+        x = x + qmm((c.astype(jnp.float32) * v).astype(x.dtype), layer["conv_out"])
+    x, pairs = _ffn(cfg, kind, x, layer, valid)
+    return x, state, pairs
+
+
+def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None, conv=None):
     """Every layer in turn: a group of like layers at a time (`cfg.layer_groups`;
     the dense presets are one group), one scan over the group's stacked weights.
     `pools`: (write page ids, write offsets, page table) for the pool that
     grows with the context, and a second triple for the window layers' pool
     where the model has them; ids and table count pages within ONE layer.
-    Returns (x, k_pages, v_pages, pairs).
+    `conv`, where the model has convolution layers: (states, rows, fresh), one
+    `[layers, S, taps - 1, D]` state a group of `cfg.layer_groups` (None for
+    the others), the step's S sequences' own, and `_conv_layer`'s rows and
+    fresh; a group's states are carried through its loop as a pool is, and
+    layer l reads and writes its own at index l. Returns (x, k_pages,
+    v_pages, states, pairs).
 
     The group's pool is the scan's CARRY, whole, viewed as
     `[layers * P, page, n_kv, width]` (a bitcast of the stored
@@ -660,8 +789,25 @@ def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None):
     if cfg.uniform:  # one group, kept bare and not as tuples of one
         groups = tuple((g,) for g in groups)
     pairs = jnp.zeros((2,), jnp.uint32)
-    k_pages, v_pages = [], []
-    for (kind, _first, n), layers, kp, vp in zip(cfg.layer_groups, *groups):
+    k_pages, v_pages, states = [], [], []
+    for g, ((kind, _first, n), layers, kp, vp) in enumerate(zip(cfg.layer_groups, *groups)):
+        if kind.conv:
+            conv_states, rows, fresh = conv
+
+            def conv_body(carry, layer_and_index):
+                x_carry, pairs_carry, st_all = carry
+                layer, index = layer_and_index
+                x_out, st, used = _conv_layer(cfg, kind, x_carry, layer, st_all[index], rows, fresh, valid)
+                return (x_out, pairs_carry + used, lax.dynamic_update_index_in_dim(st_all, st, index, 0)), None
+
+            (x, pairs, st_all), _ = lax.scan(
+                conv_body, (x, pairs, conv_states[g]), (layers, jnp.arange(n, dtype=jnp.int32)),
+            )
+            k_pages.append(None)
+            v_pages.append(None)
+            states.append(st_all)
+            continue
+        states.append(None)
         inv_freq = rope_frequencies(cfg, kind)
         write_page_ids, write_offsets, table = pools[bool(kind.window)]
         pool_pages = kp.shape[1]
@@ -683,8 +829,8 @@ def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None):
         k_pages.append(kp_all.reshape(kp.shape))
         v_pages.append(None if vp is None else vp_all.reshape(vp.shape))  # a latent group: rows, no values
     if cfg.uniform:
-        return x, k_pages[0], v_pages[0], pairs
-    return x, tuple(k_pages), tuple(v_pages), pairs
+        return x, k_pages[0], v_pages[0], None, pairs
+    return x, tuple(k_pages), tuple(v_pages), tuple(states) if conv is not None else None, pairs
 
 
 def _kernel_name(kind: LayerKind) -> str:
@@ -707,8 +853,8 @@ def _rebuild_kv(kind, layer, rows):
     return k, jnp.einsum("kl,lhv->khv", c, w_uv)
 
 
-def _advance(cache, k_pages, v_pages, pairs, seq_lens):
-    cache = cache._replace(k_pages=k_pages, v_pages=v_pages, seq_lens=seq_lens)
+def _advance(cache, k_pages, v_pages, conv_state, pairs, seq_lens):
+    cache = cache._replace(k_pages=k_pages, v_pages=v_pages, conv_state=conv_state, seq_lens=seq_lens)
     if cache.moe_pairs is not None:
         cache = cache._replace(moe_pairs=cache.moe_pairs + pairs[0], moe_touched=cache.moe_touched + pairs[1])
     return cache
@@ -748,7 +894,13 @@ def paged_prefill(
     page row (`_prefill_attention`): the chunk's cost follows what the slot
     holds, not `kv_span`. The live length is data, so it is still one
     executable per (cfg, S_pad): callers bucket prompt lengths
-    (PREFILL_BUCKETS) so arbitrary prompts hit a handful of compiles."""
+    (PREFILL_BUCKETS) so arbitrary prompts hit a handful of compiles.
+
+    A convolution layer starts the chunk from the slot's state, or from
+    zeros where `start_pos` is 0 (an admission, a slot reused, a re-prefill
+    after a preemption: no call of the host's own), and writes back the rows
+    that end at the chunk's last REAL position: a padded position never
+    enters the state, and a chunk of one or two tokens shifts the old one."""
     from .quant import qembed
 
     (s_pad,) = tokens.shape
@@ -773,14 +925,22 @@ def paged_prefill(
         return _prefill_attention(
             q[0], k_pages, v_pages, row, positions, start_pos + length, block_pages,
             window=kind.window, sink=layer.get("sink"), scale=_softmax_scale(cfg, kind),
-            expand=partial(_rebuild_kv, kind, layer) if kind.latent else None,
+            expand=partial(_rebuild_kv, kind, layer) if kind.latent else None, packed_heads=packs_heads(cfg, kind),
         )[None]
 
+    conv = None
+    if cache.conv_state is not None:
+        with jax.named_scope("conv_state"):
+            slot_states = tuple(None if st is None else st[:, slot][:, None] for st in cache.conv_state)
+        conv = (slot_states, length[None], (start_pos == 0)[None])
     x = qembed(params["embed"], tokens[None, :])  # [1, S_pad, D]
-    x, k_pages, v_pages, pairs = _run_layers(params, cfg, x, positions[None, :], pools, cache, attend, valid)
+    x, k_pages, v_pages, states, pairs = _run_layers(params, cfg, x, positions[None, :], pools, cache, attend, valid, conv)
+    if states is not None:
+        with jax.named_scope("conv_state"):
+            states = tuple(None if st is None else st.at[:, slot].set(new[:, 0]) for st, new in zip(cache.conv_state, states))
     last = lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)  # [D]
     logits = _logits(params, cfg, last)
-    cache = _advance(cache, k_pages, v_pages, pairs, cache.seq_lens.at[slot].set(start_pos + length))
+    cache = _advance(cache, k_pages, v_pages, states, pairs, cache.seq_lens.at[slot].set(start_pos + length))
     return logits, jnp.argmax(logits).astype(jnp.int32), cache
 
 
@@ -829,6 +989,7 @@ def paged_decode_step(
             return _paged_attention(
                 q, k_pages, v_pages, table, masks[bool(kind.window)], decoding, attn_impl, window=kind.window,
                 sink=layer.get("sink"), scale=_softmax_scale(cfg, kind), kernel_name=_kernel_name(kind), latent=latent,
+                packed_heads=packs_heads(cfg, kind),
             )
 
         if not kind.latent:
@@ -836,9 +997,12 @@ def paged_decode_step(
         # a decode step never rebuilds a key or a value a head from the cache
         return _absorbed_attention(kind, q, layer, k_pages.shape[-1], partial(over_span, latent=kind.latent[1]))
 
-    x, k_pages, v_pages, pairs = _run_layers(params, cfg, x, positions[:, None], pools, cache, attend, active)
+    # a convolution layer moves the state of the slots that decode, and of no other (a slot whose prompt
+    # is half prefilled keeps its state bit for bit)
+    conv = None if cache.conv_state is None else (cache.conv_state, active.astype(jnp.int32), jnp.zeros_like(active))
+    x, k_pages, v_pages, states, pairs = _run_layers(params, cfg, x, positions[:, None], pools, cache, attend, active, conv)
     logits = _logits(params, cfg, x[:, 0, :])  # [slots, V]
-    cache = _advance(cache, k_pages, v_pages, pairs, jnp.where(active, cache.seq_lens + 1, cache.seq_lens))
+    cache = _advance(cache, k_pages, v_pages, states, pairs, jnp.where(active, cache.seq_lens + 1, cache.seq_lens))
     return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
 
@@ -868,7 +1032,8 @@ def paged_verify_step(
     if not cfg.uniform:
         raise ValueError(
             "paged_verify_step runs one pool of one layer kind: a window layer's bounded pool cannot keep "
-            "k+1 speculative positions it may have to roll back, and routed experts are not in the verify path"
+            "k+1 speculative positions it may have to roll back, nor a convolution layer's state, which a "
+            "rejected position would have moved; and routed experts are not in the verify path"
         )
     slots, k1 = tokens.shape
     page = cache.page_size
@@ -887,7 +1052,7 @@ def paged_verify_step(
     def attend(_kind, q, k_pages, v_pages, table, _layer):
         return _paged_attention(q, k_pages, v_pages, table, mask)
 
-    x, k_pages, v_pages, _pairs = _run_layers(
+    x, k_pages, v_pages, _states, _pairs = _run_layers(
         params, cfg, x, positions, [(write_page_ids.reshape(-1), write_offsets.reshape(-1), rows)], cache, attend
     )
     logits = _logits(params, cfg, x)  # [slots, K1, V]

@@ -19,7 +19,11 @@ for every slot that decodes, its live pages in blocks of `block_pages`.
   values (a page is one contiguous run), issued from a `fori_loop` over the
   pages the block really has. Two buffers: block n+1's copies are started
   before block n is waited for, and the block after a slot's last is the next
-  decoding slot's first, so a short slot does not drain the pipe.
+  decoding slot's first, so a short slot does not drain the pipe. A pool of
+  one row a token, a token's KV heads side by side (`[P, page, n_kv x hd]`,
+  where a head is narrower than a lane tile: `paged_kv.packs_heads`), is one
+  KV head to this kernel, met by queries that hold a head in its own KV
+  head's columns and zeros elsewhere.
 - **The arithmetic.** A block's keys are read as the 2-D matrix
   `[tokens * n_kv, hd]` they already are in memory (a ref reshape, no
   relayout), and q.k is ONE matmul of all `n_kv * n_rep` query heads against
@@ -265,8 +269,8 @@ def _first_live_page(q_pos, window: int, page: int):
 
 def paged_decode_attention(
     q: jax.Array,  # [S, n_kv, n_rep, hd]
-    k_pages: jax.Array,  # [P, page, n_kv, hd]
-    v_pages: jax.Array,  # [P, page, n_kv, vd]
+    k_pages: jax.Array,  # [P, page, n_kv, hd], or [P, page, hd] where n_kv is 1
+    v_pages: jax.Array,  # [P, page, n_kv, vd], or [P, page, vd]
     page_table: jax.Array,  # [S, pages_per_slot] int32
     seq_lens: jax.Array,  # [S] int32 — each slot's decode position; negative: the slot does not decode
     *,
@@ -309,8 +313,8 @@ def paged_decode_attention(
         in_specs=in_specs,
         out_specs=in_vmem,
         scratch_shapes=[
-            pltpu.VMEM((2, block_pages * page, n_kv, hd), k_pages.dtype),
-            pltpu.VMEM((2, block_pages * page, n_kv, vd), v_pages.dtype),
+            pltpu.VMEM((2, block_pages * page) + k_pages.shape[2:], k_pages.dtype),
+            pltpu.VMEM((2, block_pages * page) + v_pages.shape[2:], v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )

@@ -66,7 +66,7 @@ from ..observability.catalog import (
     SERVING_TTFT_P95,
 )
 from ..observability.device_telemetry import GC_PAUSES, register_thread
-from .pages import ModelPages
+from .pages import ModelPages, prefix_hit_lacks
 
 _req_counter = itertools.count()
 _replica_id_cache: dict = {}
@@ -201,17 +201,19 @@ def _refuse_unservable(cfg: Any, params: dict, speculative: bool, role: str) -> 
         )
     if getattr(cfg, "uniform", True):
         return
-    kinds = "window layers" if cfg.has_window else "layers of more than one kind"
+    kinds = "window layers" if cfg.has_window else "convolution layers" if cfg.has_conv else "layers of more than one kind"
     if speculative:
         raise ValueError(
             f"speculative decoding (a draft, paged_verify_step) over a model with {kinds} or routed experts: "
             "the verify step writes k+1 positions it may roll back, which a window layer's bounded pool "
-            "cannot keep, and the draft mirror assumes one pool of one layer kind"
+            "cannot keep and a convolution layer's state a slot cannot take back, and the draft mirror "
+            "assumes one pool of one layer kind"
         )
     if role in ("prefill", "decode"):
         raise ValueError(
             f"role={role!r} ships KV pages between replicas, and a shipment addresses ONE pool; this model "
-            "keeps a pool a layer kind and a shipment over two pools does not exist yet"
+            "keeps a pool a layer kind, or a convolution state a slot beside its pages, and a shipment over "
+            "two pools or with a slot's state does not exist yet"
         )
     if cfg.has_experts and any(leaf.dtype == "int8" for leaf in jax.tree_util.tree_leaves(params)):
         raise ValueError(
@@ -604,7 +606,7 @@ class ServingEngine:
         max_waiting: int = 1024,
         draft: Optional[tuple] = None,  # (draft_params, draft_cfg) → speculative decoding
         spec_k: int = 3,  # draft tokens proposed per speculative round
-        prefix_cache: Optional[bool] = None,  # None = env default (on; off with window layers)
+        prefix_cache: Optional[bool] = None,  # None = env default (on; off with window or convolution layers)
         role: Optional[str] = None,  # prefill | decode | both; None = env default
         # pages of the window layers' pool (a model with window attention);
         # None = every slot's window + one chunk's headroom, which no
@@ -633,9 +635,10 @@ class ServingEngine:
         self.max_context = pages_per_slot * page_size
         self.max_waiting = max_waiting
         # shared-prefix KV reuse: content-keyed lookup + CoW pages; off where a
-        # window layer would need its last positions from a hit too
+        # hit cannot hand over every layer's state (a window's last positions,
+        # a convolution's state: serving/pages.prefix_hit_lacks)
         if prefix_cache is None:
-            prefix_cache = _env_on(PREFIX_CACHE_ENV) and not cfg.has_window
+            prefix_cache = _env_on(PREFIX_CACHE_ENV) and not prefix_hit_lacks(cfg)
         geometry = dict(
             max_slots=max_slots, num_pages=num_pages, page_size=page_size,
             pages_per_slot=pages_per_slot, prefill_chunk=prefill_chunk, prefix_cache=bool(prefix_cache),
@@ -647,6 +650,11 @@ class ServingEngine:
         # from what the loop launches; the pairs the held experts computed come
         # back from the device with a step's tokens (cache.moe_pairs)
         self.moe_expert_layers = sum(1 for k in cfg.layer_kinds if k.experts)
+        # convolution layers: a state a slot beside its pages, zeroed inside a prefill chunk at position 0;
+        # counted here are the chunks that started a slot's from zeros and those that carried one
+        self.conv_layers = sum(1 for k in cfg.layer_kinds if k.conv)
+        self.conv_state_resets = 0
+        self.conv_chunks_carried = 0
         self.moe_assignments = 0
         self.moe_local_assignments = 0
         self.moe_expert_calls = 0
@@ -1099,6 +1107,8 @@ class ServingEngine:
                         "draft_prefix_tokens": slot.draft_prefill_done,
                         "remote_prefill": shipment is not None,
                         "requeue": req.preemptions > 0,
+                        # its first chunk starts every convolution layer's state from zeros
+                        **({"conv_state": "zeroed"} if self.conv_layers else {}),
                     },
                 )
             if shipment is not None:
@@ -1237,6 +1247,11 @@ class ServingEngine:
                 slot.prefill_done + len(chunk), self.pages_per_slot, self.page_size
             )
             self.prefill_kv_span += self.max_context
+            if self.conv_layers:
+                if slot.prefill_done:
+                    self.conv_chunks_carried += 1
+                else:
+                    self.conv_state_resets += 1
             if req.trace_context is not None and self.spans_on:
                 tracing.record_span(
                     "serving.prefill_chunk",
@@ -1933,6 +1948,12 @@ class ServingEngine:
             # kv_window_* only where there are window layers
             **self.pages.stats(),
             **({"moe": moe} if self.moe_expert_layers else {}),
+            **({"conv": {
+                "layers": self.conv_layers,
+                "state_bytes_per_slot": self.pages.conv_state_bytes // self.max_slots,
+                "state_resets": self.conv_state_resets,
+                "chunks_carried": self.conv_chunks_carried,
+            }} if self.conv_layers else {}),
             "attn_impl": self.attn_impl,
             "device": self.device,
             "compile": telemetry_summary(),

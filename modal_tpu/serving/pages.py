@@ -279,6 +279,26 @@ class PrefixCache:
 # -- one model's pools ---------------------------------------------------------
 
 
+def prefix_hit_lacks(cfg: Any) -> str:
+    """What a prefix hit cannot hand over, by mechanism, or "" where it hands
+    over every layer's state. A hit is pages of the pool that grows with the
+    context; a layer whose state at the prefix's end lives anywhere else
+    cannot be served from it. The engine's default (no prefix cache) and
+    `ModelPages`' refusal of an explicit one both read this."""
+    if cfg.has_window:
+        return (
+            "window layers (a window layer needs its last `window` positions too, which the pool gave back "
+            "behind the window)"
+        )
+    if cfg.has_conv:
+        return (
+            "convolution layers (a convolution layer needs its state at the prefix's end, which a slot holds "
+            "beside its pages and no page holds)"
+        )
+    return ""
+
+
+
 class ModelPages:
     """One model's KV pages on the host: the device cache, the free lists, the
     prefix cache and every slot's page lists. Only the engine's thread calls
@@ -327,12 +347,11 @@ class ModelPages:
         self.pages_per_slot = pages_per_slot or math.ceil(cfg.max_seq_len / page_size)
         self.window = cfg.window if cfg.has_window else 0
         self.window_allocator: Optional[PageAllocator] = None
+        if prefix_cache and prefix_hit_lacks(cfg):
+            raise ValueError(
+                f"prefix_cache=True with {prefix_hit_lacks(cfg)}: a prefix hit hands over the full-attention pages only"
+            )
         if self.window:
-            if prefix_cache:
-                raise ValueError(
-                    "prefix_cache=True with window layers: a prefix hit hands over the full-attention pages only, and "
-                    "a window layer needs its last `window` positions too, which the pool gave back behind the window"
-                )
             if window_num_pages is None:
                 window_num_pages = paged_kv.default_window_num_pages(cfg, max_slots, page_size, prefill_chunk)
             if window_num_pages - 1 < math.ceil((prefill_chunk + self.window - 1) / page_size) + 1:
@@ -349,6 +368,7 @@ class ModelPages:
             cfg, max_slots, num_pages, page_size, self.pages_per_slot, window_num_pages
         )
         self.pool_bytes, self.window_pool_bytes = paged_kv.pool_bytes_by_kind(cfg, self.cache)
+        self.conv_state_bytes = self.cache.conv_state_bytes()  # every slot's, beside the pools: no page holds it
         self.prefix_cache: Optional[PrefixCache] = PrefixCache(self.allocator, partial_pages) if prefix_cache else None
         self.pages: list[list[int]] = [[] for _ in range(max_slots)]
         self.window_pages: list[list[int]] = [[] for _ in range(max_slots)]

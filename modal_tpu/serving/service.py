@@ -21,7 +21,7 @@ from typing import Any, Optional
 
 # What this build's served path can run beyond the dense block, by mechanism: the names a caller
 # may put in `llm_service(requires=...)`. A build that lacks a mechanism lacks its name here.
-MECHANISMS = ("window_kv", "routed_experts", "latent_kv", "router_groups")
+MECHANISMS = ("window_kv", "routed_experts", "latent_kv", "router_groups", "conv_state", "qk_norm")
 
 
 def llm_service(
@@ -57,7 +57,7 @@ def llm_service(
     draft_config: Optional[str] = None,
     draft_weights: Optional[str] = None,
     spec_k: int = 3,
-    prefix_cache: Optional[bool] = None,  # None = env default (on; off for a model with window layers)
+    prefix_cache: Optional[bool] = None,  # None = env default (on; off for a model with window or convolution layers)
     role: Optional[str] = None,  # prefill|decode|both (None = env/both)
     # a model with window attention keeps a second, bounded KV pool for those
     # layers: its size in pages (None = every slot's window + one chunk)

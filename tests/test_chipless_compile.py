@@ -108,7 +108,13 @@ AXK1_CELL = dict(
     model=dict(name="a.x-k1", n_layers=7, vocab_size=20480, max_seq_len=8192, n_experts_held=12), slots=64, pool_pages=20481,
     window_pool_pages=None,
 )
-TWO_POOL_CELLS = {"mimo-v2-flash-serve-1chip-ep16": MIMO_CELL, "laguna-xs.2-serve-1chip": LAGUNA_CELL, "a.x-k1-serve-1chip-ep16": AXK1_CELL}
+# the sixth configuration's (benchmark/configs/lfm2-8b-a1b-serve-1chip.json): 14 of 24 layers, 11 of them convolutions
+# with a state a slot beside the pool and no page, 3 of attention whose 8 KV heads of 64 are stored one row of 512 a token
+LFM2_CELL = dict(model=dict(name="lfm2-8b-a1b", n_layers=14, max_seq_len=8192), slots=192, pool_pages=24577, window_pool_pages=None)
+TWO_POOL_CELLS = {
+    "mimo-v2-flash-serve-1chip-ep16": MIMO_CELL, "laguna-xs.2-serve-1chip": LAGUNA_CELL, "a.x-k1-serve-1chip-ep16": AXK1_CELL,
+    "lfm2-8b-a1b-serve-1chip": LFM2_CELL,
+}
 
 
 def lower_step(program, config, sharding):
@@ -192,6 +198,7 @@ CARRIED_POOL_CASES = [
     (config, program) for config in sorted(CELL_SHAPES) for program in ("paged_decode_step", "paged_prefill")
 ] + [("mimo-v2-flash-serve-1chip-ep16", "paged_decode_step"), ("laguna-xs.2-serve-1chip", "paged_decode_step"), ("laguna-xs.2-serve-1chip", "paged_prefill")] + [
     ("a.x-k1-serve-1chip-ep16", "paged_decode_step"), ("a.x-k1-serve-1chip-ep16", "paged_prefill"),
+    ("lfm2-8b-a1b-serve-1chip", "paged_decode_step"), ("lfm2-8b-a1b-serve-1chip", "paged_prefill"),
 ]
 
 
@@ -219,8 +226,12 @@ def test_the_jitted_steps_compile_for_v5e_with_no_pool_sized_copy(config, progra
     if program == "paged_decode_step":
         calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
         names = {re.search(r"%([a-z_]+)[.\d]* = ", line).group(1) for line in calls}
-        assert names == {"paged_decode_attention" + ("_" + k.attn_name if k.attn_name else "") for k in cfg.layer_kinds}, names
-        assert names in ({"paged_decode_attention"}, {"paged_decode_attention_full", "paged_decode_attention_swa"}, {"paged_decode_attention_mla"})
+        attention = [k for k in cfg.layer_kinds if not k.conv]  # a convolution layer calls no kernel
+        assert names == {"paged_decode_attention" + ("_" + k.attn_name if k.attn_name else "") for k in attention}, names
+        assert names in (
+            {"paged_decode_attention"}, {"paged_decode_attention_full", "paged_decode_attention_swa"}, {"paged_decode_attention_mla"},
+            {"paged_decode_attention_full"},
+        )
 
     # (a) every instruction, in fusions too: `%name = type[dims]{layout} opcode(`, or a tuple of such types
     pools = jax.tree.leaves((cache.k_pages, cache.v_pages))
@@ -239,7 +250,10 @@ def test_the_jitted_steps_compile_for_v5e_with_no_pool_sized_copy(config, progra
         assert opcode in POOL_SIZED_MAY_BE, f"{name}: a {opcode} as large as a KV pool or one layer of it: {line[:200]}"
         if opcode == "fusion":  # the row scatter and nothing else: named by its scope, in place on its first operand
             assert "kv_write/scatter" in line and '"aliasing_operands":{"lists":[{"indices":["0"' in line, line[:400]
-    assert {"parameter", "while", "scatter"} <= seen, seen  # the pools were found at all
+    # the pools were found at all, carried by a layer loop where a group of attention layers has more than one
+    # (the sixth configuration's stand alone between convolutions) and by a prefill chunk's block loop
+    looped = program == "paged_prefill" or any(n > 1 for kind, _first, n in cfg.layer_groups if not kind.conv)
+    assert {"parameter", "scatter"} | ({"while"} if looped else set()) <= seen, seen
 
     # (b) no second pool among the temporaries, and the outputs are the donated pools
     memory = compiled.memory_analysis()
@@ -320,7 +334,8 @@ def test_the_decode_step_holds_one_small_mosaic_kernel_a_layer_group(config, one
     length, fails here and not as seconds of a boot on the chip."""
     cfg, _params, _cache, lowered = lower_step("paged_decode_step", config, one_chip)
     calls = [line for line in lowered.as_text().splitlines() if "@tpu_custom_call" in line]
-    assert len(calls) == len(cfg.layer_groups), (len(calls), cfg.layer_groups)
+    attention_groups = [kind for kind, _first, _n in cfg.layer_groups if not kind.conv]  # a convolution group calls none
+    assert len(calls) == len(attention_groups), (len(calls), cfg.layer_groups)
     assert all(5_000 < len(line) < MOSAIC_CALL_CHARS_AT_MOST for line in calls), [len(line) for line in calls]
 
 
@@ -376,3 +391,47 @@ def test_the_latent_decode_kernel_compiles_for_v5e_and_only_a_padded_row_goes_in
     text = jax.jit(call).lower(*args).compile().as_text()
     assert re.search(r"%paged_decode_attention_mla[.\d]* = bf16\[64,64,512\]", text), "the kernel lost its name or its output width"
     assert not re.findall(rf"= bf16\[20481,16,{stored}\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("stored", ["row-of-512", "heads-of-64-as-published"])
+def test_the_decode_kernel_at_heads_of_64_compiles_for_v5e_only_on_a_packed_row(stored, one_chip, no_compile_cache):
+    """The sixth configuration's attention (192 slots, 32 query heads over 8 KV
+    heads of 64, 24,577 pages of 16): a pool `[P, 16, 8, 64]` is laid out with
+    its minor dimension padded to 128 and Mosaic refuses the page copies, so
+    `paged_kv.packs_heads` stores a token's 8 heads as one row of 512, which
+    goes into the kernel as it lies, met by queries that hold a head in its own
+    KV head's 64 columns (`paged_kv._paged_attention`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from modal_tpu.models import paged_kv
+    from modal_tpu.models.llama import get_config
+    from modal_tpu.ops.paged_attention import paged_decode_attention
+
+    cfg = get_config(LFM2_CELL["model"])
+    assert all(paged_kv.packs_heads(cfg, k) for k in cfg.layer_kinds if not k.conv)
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    table, lens = shaped((192, 512), jnp.int32), shaped((192,), jnp.int32)
+    if stored == "heads-of-64-as-published":
+        pool = shaped((24577, 16, 8, 64))
+
+        def call(q, k, v, table, lens):
+            return paged_decode_attention(q, k, v, table, lens, scale=64**-0.5, name="paged_decode_attention_full")
+
+        with pytest.raises(Exception, match=r"aligned to tiling \(128\), but is 64"):
+            jax.jit(call).lower(shaped((192, 8, 4, 64)), pool, pool, table, lens).compile()
+        return
+    pool = shaped((24577, 16, 512))
+
+    def call(q, k, v, table, lens):
+        return paged_kv._paged_attention(
+            q, k, v, table, None, lens, "kernel", scale=64**-0.5, kernel_name="paged_decode_attention_full", packed_heads=8,
+        )
+
+    text = jax.jit(call).lower(shaped((192, 1, 32, 64)), pool, pool, table, lens).compile().as_text()
+    assert re.search(r"%paged_decode_attention_full[.\d]* = bf16\[192,32,512\]", text), "the kernel lost its name or its row"
+    assert re.search(r"= bf16\[24577,16,512\]\{2,1,0:T\(8,128\)\(2,1\)\} parameter", text)  # rows of whole tiles: no padding
+    assert not re.findall(r"= bf16\[24577,16,512\]\S* copy\(", text)
