@@ -729,7 +729,20 @@ def _ffn(cfg, kind, x, layer, valid):
         return x + qmm(gated, layer["w_down"]), pairs
 
 
-def _conv_layer(cfg, kind, x, layer, state, rows, fresh, valid):
+def _short_conv(w, u, state, rows, fresh):
+    """The taps of a convolution layer over S sequences: u [S, Sq, D],
+    state / rows / fresh as `_conv_layer` has them. Returns (v [S, Sq, D]
+    float32, the state after each sequence's last real position)."""
+    taps, sq = w.shape[0], u.shape[1]
+    with jax.named_scope("conv_state"):
+        seq = jnp.concatenate([jnp.where(fresh[:, None, None], jnp.zeros_like(state), state), u], axis=1)
+        # the taps - 1 rows that end at the last real position: the old state shifted by `rows`
+        last = rows[:, None] + jnp.arange(taps - 1, dtype=rows.dtype)[None, :]
+        state = jnp.take_along_axis(seq, last[:, :, None], axis=1)
+    return sum(w[j] * seq[:, j : j + sq].astype(jnp.float32) for j in range(taps)), state
+
+
+def _conv_layer(cfg, kind, x, layer, state, rows, fresh, valid, chunk=None):
     """One gated short-convolution layer. x: [S, Sq, D], S sequences of Sq
     consecutive positions each; state: [S, taps - 1, D], each sequence's last
     rows of u = B * x before its first position here; rows: [S] int32, the
@@ -738,6 +751,12 @@ def _conv_layer(cfg, kind, x, layer, state, rows, fresh, valid):
     sequence starts at position 0 and reads zeros in place of its state.
     Returns (x, state after the sequence's last real position, counts).
 
+    `chunk` (a decode step that carries a prefill chunk): (slot, length,
+    fresh) of the chunk, whose C rows follow the S decode rows, x [S + C, 1,
+    D]: the projections take every row at once, the taps run over the S
+    one-row sequences and over the chunk as ONE sequence from the slot's
+    state, which the chunk's own takes the place of.
+
     [B | C | x~] = h W_in; u = B * x~; v_t = sum_j w_j u_{t - taps + 1 + j},
     with u zero before position 0; y = (C * v) W_out. u is rounded to the
     model's dtype before the taps, where the state keeps it, so a sequence
@@ -745,18 +764,21 @@ def _conv_layer(cfg, kind, x, layer, state, rows, fresh, valid):
     the taps and the gate are float32."""
     from .quant import qmm
 
-    taps, sq = kind.conv, x.shape[1]
     with jax.named_scope("short_conv"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         b, c, xt = jnp.split(qmm(h, layer["conv_in"]), 3, axis=-1)
         u = (b.astype(jnp.float32) * xt.astype(jnp.float32)).astype(x.dtype)
-        with jax.named_scope("conv_state"):
-            seq = jnp.concatenate([jnp.where(fresh[:, None, None], jnp.zeros_like(state), state), u], axis=1)
-            # the taps - 1 rows that end at the last real position: the old state shifted by `rows`
-            last = rows[:, None] + jnp.arange(taps - 1, dtype=rows.dtype)[None, :]
-            state = jnp.take_along_axis(seq, last[:, :, None], axis=1)
         w = layer["conv_w"].astype(jnp.float32)
-        v = sum(w[j] * seq[:, j : j + sq].astype(jnp.float32) for j in range(taps))
+        if chunk is None:
+            v, state = _short_conv(w, u, state, rows, fresh)
+        else:
+            slot, length, chunk_fresh = chunk
+            n, d = state.shape[0], u.shape[-1]
+            v, decoded = _short_conv(w, u[:n], state, rows, fresh)
+            v_chunk, carried = _short_conv(w, u[n:].reshape(1, -1, d), state[slot][None], length[None], chunk_fresh[None])
+            v = jnp.concatenate([v, v_chunk.reshape(-1, 1, d)], axis=0)
+            # the chunk's slot decodes no row here: its decode row kept the state it had
+            state = decoded.at[slot].set(carried[0])
         x = x + qmm((c.astype(jnp.float32) * v).astype(x.dtype), layer["conv_out"])
     x, pairs = _ffn(cfg, kind, x, layer, valid)
     return x, state, pairs
@@ -768,12 +790,12 @@ def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None, con
     `pools`: (write page ids, write offsets, page table) for the pool that
     grows with the context, and a second triple for the window layers' pool
     where the model has them; ids and table count pages within ONE layer.
-    `conv`, where the model has convolution layers: (states, rows, fresh), one
-    `[layers, S, taps - 1, D]` state a group of `cfg.layer_groups` (None for
-    the others), the step's S sequences' own, and `_conv_layer`'s rows and
-    fresh; a group's states are carried through its loop as a pool is, and
-    layer l reads and writes its own at index l. Returns (x, k_pages,
-    v_pages, states, pairs).
+    `conv`, where the model has convolution layers: (states, rows, fresh,
+    chunk), one `[layers, S, taps - 1, D]` state a group of
+    `cfg.layer_groups` (None for the others), the step's S sequences' own,
+    and `_conv_layer`'s rows, fresh and chunk; a group's states are carried
+    through its loop as a pool is, and layer l reads and writes its own at
+    index l. Returns (x, k_pages, v_pages, states, pairs).
 
     The group's pool is the scan's CARRY, whole, viewed as
     `[layers * P, page, n_kv, width]` (a bitcast of the stored
@@ -792,12 +814,12 @@ def _run_layers(params, cfg, x, positions, pools, cache, attend, valid=None, con
     k_pages, v_pages, states = [], [], []
     for g, ((kind, _first, n), layers, kp, vp) in enumerate(zip(cfg.layer_groups, *groups)):
         if kind.conv:
-            conv_states, rows, fresh = conv
+            conv_states, rows, fresh, chunk = conv
 
             def conv_body(carry, layer_and_index):
                 x_carry, pairs_carry, st_all = carry
                 layer, index = layer_and_index
-                x_out, st, used = _conv_layer(cfg, kind, x_carry, layer, st_all[index], rows, fresh, valid)
+                x_out, st, used = _conv_layer(cfg, kind, x_carry, layer, st_all[index], rows, fresh, valid, chunk)
                 return (x_out, pairs_carry + used, lax.dynamic_update_index_in_dim(st_all, st, index, 0)), None
 
             (x, pairs, st_all), _ = lax.scan(
@@ -887,7 +909,10 @@ def paged_prefill(
     """Prefill one slot's prompt chunk into its pages while existing slots'
     pages stay untouched. Padded positions (>= length) scatter to the scratch
     page and are never attended. Returns (last_logits [V], next_token [],
-    cache); chunked callers ignore logits until the final chunk.
+    cache); chunked callers ignore logits until the final chunk. The serving
+    engine runs a chunk inside `paged_decode_step` (`chunk=`); this program is
+    the chunk alone, for the paths that keep it apart: a speculative engine's
+    two models and a replica that only prefills.
 
     Each layer writes the chunk's K/V into the pool and then attends the
     slot's live prefix, `start_pos + length` positions, in KV blocks of its
@@ -932,7 +957,7 @@ def paged_prefill(
     if cache.conv_state is not None:
         with jax.named_scope("conv_state"):
             slot_states = tuple(None if st is None else st[:, slot][:, None] for st in cache.conv_state)
-        conv = (slot_states, length[None], (start_pos == 0)[None])
+        conv = (slot_states, length[None], (start_pos == 0)[None], None)
     x = qembed(params["embed"], tokens[None, :])  # [1, S_pad, D]
     x, k_pages, v_pages, states, pairs = _run_layers(params, cfg, x, positions[None, :], pools, cache, attend, valid, conv)
     if states is not None:
@@ -952,6 +977,7 @@ def paged_decode_step(
     cache: PagedKVCache,
     active: jax.Array,  # [slots] bool
     attn_impl: str = "gather",
+    chunk: Optional[tuple] = None,  # (tokens [C], length [], slot [], start_pos []): a prefill chunk riding along
 ):
     """One continuous-batching decode step over EVERY slot (fixed shape:
     inactive slots compute on garbage routed to the scratch page). Returns
@@ -960,7 +986,21 @@ def paged_decode_step(
 
     attn_impl selects the attention inner: "gather" (dense span gather, runs
     anywhere) or "kernel"/"kernel_interpret" (Pallas HBM→VMEM page streaming,
-    ops/paged_attention.py) — static, so each choice is its own executable."""
+    ops/paged_attention.py) — static, so each choice is its own executable.
+
+    `chunk`: one slot's prefill chunk, as `paged_prefill` takes it (the
+    tokens padded to a bucket, the real count, the slot, the tokens already
+    in it), carried in the same program: its C rows follow the S decode rows
+    through every layer, so each weight is read once for both and applied to
+    the S + C rows in one product. Inside a layer the rows part at attention:
+    the decode rows attend over their spans as without a chunk, the chunk's
+    over its slot's live prefix (`_prefill_attention`, as `paged_prefill`),
+    each after writing its own keys and values; a convolution layer runs the
+    chunk's taps from its slot's state. The slot of the chunk is not `active`.
+    One executable a bucket C, whichever slots decode (none: every `active`
+    false). Returns (logits, next_tokens, cache, last_logits [V], last_token
+    []): the decode rows' as without a chunk, and the chunk's last real
+    row's, the slot's next token where the chunk ends its prompt."""
     from .quant import qembed
 
     slots = cache.num_slots
@@ -970,14 +1010,30 @@ def paged_decode_step(
     page_idx = jnp.clip(positions // page, 0, rows.shape[1] - 1)
     write_page_ids = jnp.where(active, jnp.take_along_axis(rows, page_idx[:, None], axis=1)[:, 0], 0)
     write_offsets = jnp.where(active, positions % page, 0)
+    window_ids = None
+    if cache.window_table is not None:
+        window_ids = jnp.where(active, jnp.take_along_axis(cache.window_table, page_idx[:, None], axis=1)[:, 0], 0)
+    ids, valid, at = tokens, active, positions
+    if chunk is not None:
+        chunk_tokens, length, slot, start_pos = chunk
+        idx = jnp.arange(chunk_tokens.shape[0], dtype=jnp.int32)
+        in_chunk = idx < length
+        chunk_at = start_pos + idx
+        chunk_page = jnp.clip(chunk_at // page, 0, rows.shape[1] - 1)
+        chunk_offsets = jnp.where(in_chunk, chunk_at % page, 0)
+        write_page_ids = jnp.concatenate([write_page_ids, jnp.where(in_chunk, rows[slot][chunk_page], 0)])
+        write_offsets = jnp.concatenate([write_offsets, chunk_offsets])
+        if window_ids is not None:
+            window_ids = jnp.concatenate([window_ids, jnp.where(in_chunk, cache.window_table[slot][chunk_page], 0)])
+        ids, valid, at = (jnp.concatenate(pair) for pair in ((tokens, chunk_tokens), (active, in_chunk), (positions, chunk_at)))
+        block_pages = prefill_kv_block_pages(rows.shape[1], page)
 
-    x = qembed(params["embed"], tokens[:, None])  # [slots, 1, D]
+    x = qembed(params["embed"], ids[:, None])  # [slots (+ C), 1, D]
     kv_pos = jnp.arange(cache.kv_span, dtype=jnp.int32)[None, None, None, :]
     mask = jnp.where(kv_pos <= positions[:, None, None, None], 0.0, -jnp.inf).astype(jnp.float32)
     pools, masks = [(write_page_ids, write_offsets, rows)], [mask]
-    if cache.window_table is not None:
-        window_ids = jnp.take_along_axis(cache.window_table, page_idx[:, None], axis=1)[:, 0]
-        pools.append((jnp.where(active, window_ids, 0), write_offsets, cache.window_table))
+    if window_ids is not None:
+        pools.append((window_ids, write_offsets, cache.window_table))
         behind = kv_pos <= positions[:, None, None, None] - cfg.window
         masks.append(jnp.where(behind, -jnp.inf, mask))
     # the kernel walks the live pages of the slots that decode and nothing for the rest: a slot
@@ -992,18 +1048,39 @@ def paged_decode_step(
                 packed_heads=packs_heads(cfg, kind),
             )
 
+        q_decode = q if chunk is None else q[:slots]
         if not kind.latent:
-            return over_span(q)
-        # a decode step never rebuilds a key or a value a head from the cache
-        return _absorbed_attention(kind, q, layer, k_pages.shape[-1], partial(over_span, latent=kind.latent[1]))
+            out = over_span(q_decode)
+        else:
+            # a decode step never rebuilds a key or a value a head from the cache
+            out = _absorbed_attention(kind, q_decode, layer, k_pages.shape[-1], partial(over_span, latent=kind.latent[1]))
+        if chunk is None:
+            return out
+        # the chunk's rows: causal over the slot's live prefix (a window layer: and the window)
+        prefilled = _prefill_attention(
+            q[slots:, 0], k_pages, v_pages, table[slot], chunk_at, start_pos + length, block_pages,
+            window=kind.window, sink=layer.get("sink"), scale=_softmax_scale(cfg, kind),
+            expand=partial(_rebuild_kv, kind, layer) if kind.latent else None, packed_heads=packs_heads(cfg, kind),
+        )
+        return jnp.concatenate([out, prefilled[:, None].astype(out.dtype)], axis=0)
 
     # a convolution layer moves the state of the slots that decode, and of no other (a slot whose prompt
-    # is half prefilled keeps its state bit for bit)
-    conv = None if cache.conv_state is None else (cache.conv_state, active.astype(jnp.int32), jnp.zeros_like(active))
-    x, k_pages, v_pages, states, pairs = _run_layers(params, cfg, x, positions[:, None], pools, cache, attend, active, conv)
-    logits = _logits(params, cfg, x[:, 0, :])  # [slots, V]
-    cache = _advance(cache, k_pages, v_pages, states, pairs, jnp.where(active, cache.seq_lens + 1, cache.seq_lens))
-    return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+    # is half prefilled keeps its state bit for bit) but the chunk's, from zeros where it starts a prompt
+    conv = None
+    if cache.conv_state is not None:
+        conv = (cache.conv_state, active.astype(jnp.int32), jnp.zeros_like(active), None if chunk is None else (slot, length, start_pos == 0))
+    x, k_pages, v_pages, states, pairs = _run_layers(params, cfg, x, at[:, None], pools, cache, attend, valid, conv)
+    seq_lens = jnp.where(active, cache.seq_lens + 1, cache.seq_lens)
+    if chunk is None:
+        logits = _logits(params, cfg, x[:, 0, :])  # [slots, V]
+        cache = _advance(cache, k_pages, v_pages, states, pairs, seq_lens)
+        return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+    # the decode rows and the chunk's last real row through the head in one product
+    last = lax.dynamic_index_in_dim(x[slots:, 0], length - 1, axis=0, keepdims=True)  # [1, D]
+    logits = _logits(params, cfg, jnp.concatenate([x[:slots, 0], last]))  # [slots + 1, V]
+    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    cache = _advance(cache, k_pages, v_pages, states, pairs, seq_lens.at[slot].set(start_pos + length))
+    return logits[:slots], next_tokens[:slots], cache, logits[slots], next_tokens[slots]
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))

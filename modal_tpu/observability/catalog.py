@@ -641,13 +641,15 @@ ENGINE_PHASES: dict[str, tuple[str, str]] = {
         "host",
     ),
     "prefill_prep": (
-        "_prefill_one up to the launch: the page manager's reserve (copy-on-write, the window pool's pages), "
+        "_ready_chunk making a chunk ready: the page manager's reserve (copy-on-write, the window pool's pages), "
         "the padded array, the scalars "
         "(request_id, chunk_tokens, offset, bucket)",
         "host",
     ),
     "prefill_dispatch": (
-        "the paged_prefill(...) call until it returns (request_id)",
+        "the launch of a chunk where no slot decodes: paged_decode_step(..., chunk=...) with every row "
+        "inactive until it returns; in a speculative engine or a prefill replica the paged_prefill(...) call "
+        "(request_id)",
         "host",
     ),
     "prefill_sync": (
@@ -663,8 +665,9 @@ ENGINE_PHASES: dict[str, tuple[str, str]] = {
         "host",
     ),
     "decode_dispatch": (
-        "paged_decode_step(...) for step n+1 (and sample_step, an expert model's counters packed behind the "
-        "tokens) until it returns; in a speculative round a group's draft chain, verify and target sampling (batch)",
+        "paged_decode_step(...) for step n+1, with the iteration's prefill chunk where it has one (and "
+        "sample_step, an expert model's counters packed behind the tokens) until it returns; in a speculative "
+        "round a group's draft chain, verify and target sampling (batch)",
         "host",
     ),
     "decode_sync": (

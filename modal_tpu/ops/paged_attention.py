@@ -72,6 +72,11 @@ shifted to the layer's place in it (`paged_kv._run_layers`): to this module
 that is a pool and a table like any other, so nothing here knows of layers
 and no layer's pages are sliced out for a call. This module only provides
 the op.
+
+Each entry point is a `jax.jit` of its own, so a process traces and lowers
+a kernel once a shape and every step program that calls it takes it from
+there: the decode step and the step of each prefill chunk bucket have the
+same decode rows (PERF.md, set-up with the chunk in the step).
 """
 
 from __future__ import annotations
@@ -267,6 +272,7 @@ def _first_live_page(q_pos, window: int, page: int):
     return jnp.maximum(q_pos - (window - 1), 0) // page
 
 
+@functools.partial(jax.jit, static_argnames=("window", "scale", "name", "interpret"))
 def paged_decode_attention(
     q: jax.Array,  # [S, n_kv, n_rep, hd]
     k_pages: jax.Array,  # [P, page, n_kv, hd], or [P, page, hd] where n_kv is 1
@@ -331,6 +337,7 @@ def paged_decode_attention(
     return out.reshape(s, n_kv, n_rep, vd)
 
 
+@functools.partial(jax.jit, static_argnames=("latent", "scale", "name", "interpret"))
 def paged_decode_attention_mla(
     q: jax.Array,  # [S, heads, width]: the absorbed query at the rows' stored width
     rows: jax.Array,  # [P, page, width]: a token's [latent | rope key | zeros] row

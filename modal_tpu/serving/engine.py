@@ -10,7 +10,7 @@ lets requests join and leave it per step:
   mid-decode, without restarting in-flight sequences (`paged_decode_step` is
   one fixed-shape executable; admission is data, not shape).
 - **prefill/decode separation**: prompts prefill in `prefill_chunk`-token
-  slices, one slice per loop iteration, interleaved with decode steps — a
+  slices, one slice per loop iteration, carried inside its decode step — a
   4k-token prompt cannot stall everyone else's token cadence for its whole
   prefill, it pays its own TTFT instead.
 - **paged KV**: all slots share one page pool (models/paged_kv.py). HBM is
@@ -25,9 +25,10 @@ The loop runs on its own thread (jax releases the GIL during device
 compute); `submit()` is thread-safe and returns immediately — TTFT is the
 engine's admission+prefill latency, not queue drain.
 
-The plain decode path keeps ONE step in flight: an iteration admits, launches
-a prefill chunk, launches decode step n+1 on step n's tokens AS THE DEVICE
-HOLDS THEM, and only then reads step n and emits it, so the host's work runs
+The plain decode path keeps ONE step in flight: an iteration admits, makes a
+prefill chunk ready, launches decode step n+1 on step n's tokens AS THE DEVICE
+HOLDS THEM with the chunk inside it (one program, the weights read once for
+both), and only then reads step n and emits it, so the host's work runs
 while the chip does. What is launched and unread is `_Unread`; whoever needs a
 value at once (preemption, an export, a stop) calls `_drain` first.
 """
@@ -568,7 +569,7 @@ class _Slot:
     cur_token: Any = 0
     ahead: int = 0  # tokens launched for this slot and not read yet
     state: str = "prefill"  # "prefill" | "decode"
-    admitted_step: int = 0
+    admitted: int = 0  # admissions before this one: the slots' age, oldest first, no two alike
     # decode progress marks (ISSUE 11 timelines): the last serving.decode
     # span's end time and the token count it covered up to
     last_mark_t: float = 0.0
@@ -587,6 +588,19 @@ class _Unread:
     tokens: Any = None  # a step's [slots] next tokens on the device: the next step's feed
     step: int = 0  # a step's number: its launch and its read carry it in their annotations
     started: float = 0.0  # a chunk's t0, for its serving.prefill span
+
+
+@dataclass
+class _Chunk:
+    """A slot's next chunk of its prompt, ready for the device: what its
+    launch takes and what the host notes once it is launched."""
+
+    idx: int
+    slot: _Slot
+    length: int  # real tokens
+    bucket: int  # the padded length: one executable a bucket
+    args: tuple  # (tokens [bucket], length, slot, start_pos) on the device, as the step programs take them
+    started: float  # for its serving.prefill_chunk span
 
 
 class ServingEngine:
@@ -704,6 +718,9 @@ class ServingEngine:
         self._models = [self.pages] + ([self.draft_pages] if self.draft_pages is not None else [])
         # ISSUE 18 fleet mode: the advertised role
         self.role = role
+        # a prefill chunk rides in the iteration's decode step (one program reads the weights for both),
+        # but where the draft mirrors the target chunk by chunk, or where no slot ever decodes
+        self.chunks_ride = not self.spec_k and role != "prefill"
         SERVING_ROLE.set(float(ROLE_GAUGE_VALUES[self.role]))
         self.kv_pages_shipped = 0
         self.kv_ship_drops = 0
@@ -738,6 +755,9 @@ class ServingEngine:
         self.prefill_bucket_tokens = 0
         self.prefill_kv_attended = 0  # positions the chunks' attention loops visited
         self.prefill_kv_span = 0  # max_context a chunk: what a span-wide attention would visit
+        self.prefill_chunks_fused = 0  # chunks that ran inside a decode step
+        # every step program dispatched, by name and bucket: each is an executable readied once
+        self._step_programs: set[str] = set()
         # the per-request timeline knobs, read once: an engine keeps the
         # setting it was built under
         self.spans_on = _spans_enabled()
@@ -1019,6 +1039,9 @@ class ServingEngine:
         self.moe_experts_touched += (touched - self._moe_touched_seen) & 0xFFFFFFFF
         self._moe_pairs_seen, self._moe_touched_seen = pairs, touched
 
+    def _note_program(self, name: str, bucket: Optional[int] = None) -> None:
+        self._step_programs.add(name if bucket is None else f"{name}/{bucket}")
+
     def _note_moe_call(self, tokens: int) -> None:
         self.moe_assignments += tokens * self.cfg.experts_per_token * self.moe_expert_layers
         self.moe_expert_calls += self.moe_expert_layers * self.cfg.experts_held[1]
@@ -1078,7 +1101,7 @@ class ServingEngine:
                     return  # pool dry; decode-side preemption or completions will free
                 self.waiting.popleft()
                 SERVING_QUEUE_DEPTH.set(float(len(self.waiting)))
-                slot = _Slot(request=req, prefill_tokens=prefill_tokens, admitted_step=self.step_count)
+                slot = _Slot(request=req, prefill_tokens=prefill_tokens, admitted=self.requests_admitted)
                 self.slots[free_idx] = slot
             # the commit: pages handed out, the rows written, and the hit or miss
             # counted once — not per dry-pool retry above (a remote-prefill
@@ -1183,6 +1206,11 @@ class ServingEngine:
         loop iteration: decode steps interleave, so in-flight token cadence
         survives long-prompt arrivals.
 
+        Where the engine serves one model the chunk rides in the iteration's
+        decode step instead (`_decode_step`): one program reads the weights
+        for both. A speculative engine and a replica that only prefills
+        launch it here on its own (`paged_prefill`).
+
         Target and draft pools progress INDEPENDENTLY (ISSUE 18): each has
         its own prefix cache, so their covered offsets differ — the target
         may start mid-page (partial-page extension + CoW) while the draft
@@ -1193,81 +1221,29 @@ class ServingEngine:
         import jax.numpy as jnp
         import numpy as np
 
-        from ..models.paged_kv import paged_prefill, prefill_bucket, prefill_kv_attended
+        from ..models.paged_kv import paged_prefill, prefill_bucket
 
-        with self._lock:
-            candidates = [
-                (i, s) for i, s in enumerate(self.slots) if s is not None and s.state == "prefill"
-            ]
-        if not candidates:
+        if self.chunks_ride:
             return
-        idx, slot = min(candidates, key=lambda t: t[1].admitted_step)
+        prefilling = self._prefilling()
+        if prefilling is None:
+            return
+        idx, slot = prefilling
         req = slot.request
         total = len(slot.prefill_tokens)
-        target_done_now = False
-        logits = None
-        next_tok = None
-        t0 = time.time()
+        chunk = None
         if slot.prefill_done < total:
-            chunk = slot.prefill_tokens[slot.prefill_done : slot.prefill_done + self.prefill_chunk]
-            bucket = prefill_bucket(len(chunk), self.max_context)
-            self._phase(
-                "prefill_prep", request_id=req.id, chunk_tokens=len(chunk),
-                offset=slot.prefill_done, bucket=bucket,
-            )
-            wants = [(idx, slot.prefill_done, slot.prefill_done + len(chunk) - 1)]
-            if not self._reserve(wants) and not (self._drain() and self._reserve(wants)):
-                # a copy-on-write (or the window pool, which holds the chunk
-                # and the window before it) starved for a page, and reading
-                # what was in flight ended no slot that held one: free capacity
-                # the hard way and retry next iteration. The needy slot itself
-                # is a valid victim — if it alone holds the pool, preempting it
-                # (requeue, pages freed) is the only move that ever unsticks
-                # the loop
-                self._preempt_youngest()
+            chunk = self._ready_chunk(idx, slot)
+            if chunk is None:
                 return
-            padded = np.zeros((bucket,), np.int32)
-            padded[: len(chunk)] = chunk
-            tokens_j, length_j = jnp.asarray(padded), jnp.int32(len(chunk))
-            slot_j, start_j = jnp.int32(idx), jnp.int32(slot.prefill_done)
             self._phase("prefill_dispatch", request_id=req.id)
-            logits, next_tok, self.pages.cache = paged_prefill(
-                self.params, self.cfg, tokens_j, length_j, self.pages.cache, slot_j, start_j
-            )
+            self._note_program("paged_prefill", chunk.bucket)
+            logits, next_tok, self.pages.cache = paged_prefill(self.params, self.cfg, *chunk.args[:2], self.pages.cache, *chunk.args[2:])
             self._phases.launched(next_tok)
             self._phase("emit", request_id=req.id)
-            # a window pool's chunk gives back all but the window at its end
-            self.pages.trim(idx, slot.prefill_done + len(chunk))
             if self.moe_expert_layers:
-                self._note_moe_call(len(chunk))
-            self.prompt_tokens_prefilled += len(chunk)
-            self.prefill_chunks += 1
-            self.prefill_bucket_tokens += bucket
-            self.prefill_kv_attended += prefill_kv_attended(
-                slot.prefill_done + len(chunk), self.pages_per_slot, self.page_size
-            )
-            self.prefill_kv_span += self.max_context
-            if self.conv_layers:
-                if slot.prefill_done:
-                    self.conv_chunks_carried += 1
-                else:
-                    self.conv_state_resets += 1
-            if req.trace_context is not None and self.spans_on:
-                tracing.record_span(
-                    "serving.prefill_chunk",
-                    start=t0,
-                    end=time.time(),
-                    parent=req.trace_context,
-                    attrs={
-                        "request_id": req.id,
-                        "chunk_tokens": len(chunk),
-                        "offset": slot.prefill_done,
-                        "bucket": bucket,
-                    },
-                )
-            slot.prefill_done += len(chunk)
-            slot.pos = slot.prefill_done
-            target_done_now = slot.prefill_done >= total
+                self._note_moe_call(chunk.length)
+            self._chunk_ran(chunk)
         if self.spec_k and slot.draft_prefill_done < total:
             # the draft mirror advances its own chunk from its own covered
             # offset; draft KV content is chunk-split-independent, so the
@@ -1285,6 +1261,7 @@ class ServingEngine:
             tokens_j, length_j = jnp.asarray(dpadded), jnp.int32(len(dchunk))
             slot_j, start_j = jnp.int32(idx), jnp.int32(slot.draft_prefill_done)
             self._phase("prefill_dispatch", request_id=req.id)
+            self._note_program("draft.paged_prefill", dbucket)
             _dl, dnext, self.draft_pages.cache = paged_prefill(
                 self.draft_params, self.draft_cfg, tokens_j, length_j, self.draft_pages.cache, slot_j, start_j
             )
@@ -1295,43 +1272,122 @@ class ServingEngine:
                 self.draft_pages.publish(idx, req.prompt)
                 if slot.first_emitted and slot.state == "prefill":
                     slot.state = "decode"  # target finished earlier (import)
-        if target_done_now:
-            # prefill complete: the model's continuation after the whole
-            # prefix is a NEW token — for a fresh request the first one
-            # (TTFT); for a preempted-and-readmitted one the next one
-            # (already-emitted tokens re-entered via prefill_tokens and are
-            # never re-appended — the continuation after them is new)
-            self.pages.publish(idx, req.prompt)  # the prompt's KV is resident: followers may share it
-            if req.temperature > 0:
-                # first/continuation token sampled with the request's own
-                # (seed, token-index) key — companion-independent by
-                # construction (models/sampling.sample_step)
-                from ..models.sampling import sample_step
-
-                next_tok = sample_step(
-                    logits[None, :],
-                    jnp.asarray([req.seed], jnp.int32),
-                    jnp.asarray([len(req.tokens)], jnp.int32),
-                    jnp.asarray([req.temperature], jnp.float32),
-                    jnp.asarray([req.top_k], jnp.int32),
-                    jnp.asarray([req.top_p], jnp.float32),
-                )[0]
-                self.sampled_tokens += 1
-                SERVING_SAMPLED_TOKENS.inc()
-            # the token stays on the device: the slot joins the next decode
-            # step on it, and the host reads it behind that step's launch
-            # (the chunk sits before the step on the device, so the read
-            # returns at the chunk's end)
+        if chunk is not None and slot.prefill_done >= total:
             self._collect_first()
-            self._join(slot, next_tok)
-            slot.ahead += 1
-            self._first = _Unread([(idx, slot)], self._with_counts(next_tok), started=t0)
+            self._chunk_done(chunk, logits, next_tok)
             if self.spec_k or req._export:
                 # a value is needed at once: the speculative round feeds on
                 # host tokens, an export ships the token with live pages
                 if self._flying is not None:
                     self._drain()
                 self._collect_first()
+
+    def _prefilling(self) -> Optional[tuple[int, _Slot]]:
+        """The oldest slot still prefilling, and its index."""
+        with self._lock:
+            candidates = [(i, s) for i, s in enumerate(self.slots) if s is not None and s.state == "prefill"]
+        return min(candidates, key=lambda t: t[1].admitted) if candidates else None
+
+    def _ready_chunk(self, idx: int, slot: _Slot) -> Optional[_Chunk]:
+        """The slot's next chunk of its prompt, its pages reserved and its
+        tokens padded to their bucket; None where the pools could not hold
+        it and a slot was preempted instead."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from ..models.paged_kv import prefill_bucket
+
+        t0 = time.time()
+        req = slot.request
+        tokens = slot.prefill_tokens[slot.prefill_done : slot.prefill_done + self.prefill_chunk]
+        bucket = prefill_bucket(len(tokens), self.max_context)
+        self._phase(
+            "prefill_prep", request_id=req.id, chunk_tokens=len(tokens),
+            offset=slot.prefill_done, bucket=bucket,
+        )
+        wants = [(idx, slot.prefill_done, slot.prefill_done + len(tokens) - 1)]
+        if not self._reserve(wants) and not (self._drain() and self._reserve(wants)):
+            # a copy-on-write (or the window pool, which holds the chunk
+            # and the window before it) starved for a page, and reading
+            # what was in flight ended no slot that held one: free capacity
+            # the hard way and retry next iteration. The needy slot itself
+            # is a valid victim — if it alone holds the pool, preempting it
+            # (requeue, pages freed) is the only move that ever unsticks
+            # the loop
+            self._preempt_youngest()
+            return None
+        padded = np.zeros((bucket,), np.int32)
+        padded[: len(tokens)] = tokens
+        args = (jnp.asarray(padded), jnp.int32(len(tokens)), jnp.int32(idx), jnp.int32(slot.prefill_done))
+        return _Chunk(idx, slot, len(tokens), bucket, args, t0)
+
+    def _chunk_ran(self, chunk: _Chunk) -> None:
+        """The host's part of a chunk once it is launched: the window pool's
+        turn-over, the counters, its span, the slot's progress."""
+        from ..models.paged_kv import prefill_kv_attended
+
+        idx, slot = chunk.idx, chunk.slot
+        req = slot.request
+        end = slot.prefill_done + chunk.length
+        # a window pool's chunk gives back all but the window at its end
+        self.pages.trim(idx, end)
+        self.prompt_tokens_prefilled += chunk.length
+        self.prefill_chunks += 1
+        self.prefill_bucket_tokens += chunk.bucket
+        self.prefill_kv_attended += prefill_kv_attended(end, self.pages_per_slot, self.page_size)
+        self.prefill_kv_span += self.max_context
+        if self.conv_layers:
+            if slot.prefill_done:
+                self.conv_chunks_carried += 1
+            else:
+                self.conv_state_resets += 1
+        if req.trace_context is not None and self.spans_on:
+            tracing.record_span(
+                "serving.prefill_chunk",
+                start=chunk.started,
+                end=time.time(),
+                parent=req.trace_context,
+                attrs={
+                    "request_id": req.id,
+                    "chunk_tokens": chunk.length,
+                    "offset": slot.prefill_done,
+                    "bucket": chunk.bucket,
+                },
+            )
+        slot.prefill_done = slot.pos = end
+
+    def _chunk_done(self, chunk: _Chunk, logits: Any, next_tok: Any) -> None:
+        """The chunk ended its slot's prompt: the model's continuation after
+        the whole prefix is a NEW token — for a fresh request the first one
+        (TTFT); for a preempted-and-readmitted one the next one
+        (already-emitted tokens re-entered via prefill_tokens and are never
+        re-appended — the continuation after them is new). The token stays
+        on the device: the slot joins the next decode step on it, and the
+        host reads it behind that step's launch."""
+        idx, slot = chunk.idx, chunk.slot
+        req = slot.request
+        self.pages.publish(idx, req.prompt)  # the prompt's KV is resident: followers may share it
+        if req.temperature > 0:
+            # first/continuation token sampled with the request's own
+            # (seed, token-index) key — companion-independent by
+            # construction (models/sampling.sample_step)
+            import jax.numpy as jnp
+
+            from ..models.sampling import sample_step
+
+            next_tok = sample_step(
+                logits[None, :],
+                jnp.asarray([req.seed], jnp.int32),
+                jnp.asarray([len(req.tokens)], jnp.int32),
+                jnp.asarray([req.temperature], jnp.float32),
+                jnp.asarray([req.top_k], jnp.int32),
+                jnp.asarray([req.top_p], jnp.float32),
+            )[0]
+            self.sampled_tokens += 1
+            SERVING_SAMPLED_TOKENS.inc()
+        self._join(slot, next_tok)
+        slot.ahead += 1
+        self._first = _Unread([(idx, slot)], self._with_counts(next_tok), started=chunk.started)
 
     def _with_counts(self, tokens: Any) -> Any:
         """What a sync fetches for `tokens`: of a model with routed experts
@@ -1472,7 +1528,7 @@ class ServingEngine:
             victims = [(i, s) for i, s in enumerate(self.slots) if s is not None]
             if not victims:
                 return False
-            idx, slot = max(victims, key=lambda t: t[1].admitted_step)
+            idx, slot = max(victims, key=lambda t: t[1].admitted)
             self.slots[idx] = None
             self.waiting.appendleft(slot.request)
             SERVING_QUEUE_DEPTH.set(float(len(self.waiting)))
@@ -1533,25 +1589,37 @@ class ServingEngine:
         """Launch decode step n+1, then read and emit step n. No token's
         value crosses to the host between the two launches: the slots that
         were in step n feed on its `next_tokens` as the device holds them, a
-        slot that joins has its token set into that vector on the device."""
+        slot that joins has its token set into that vector on the device.
+
+        The oldest prefilling slot's next chunk rides in the same program
+        (`paged_decode_step(chunk=...)`), and runs in it alone where no slot
+        decodes. A chunk that ends its prompt leaves its slot's first token
+        on the device: the slot joins the next step on it, and the host reads
+        it behind that step's launch, as it reads the step's own tokens."""
         import jax.numpy as jnp
         import numpy as np
 
         from ..models.paged_kv import paged_decode_step, set_token
 
+        chunk = None
+        if self.chunks_ride and (prefilling := self._prefilling()) is not None:
+            idx, slot = prefilling
+            if slot.prefill_done < len(slot.prefill_tokens):
+                chunk = self._ready_chunk(idx, slot)
         self._phase("decode_prep")
         if self.spec_k:
             return self._spec_round()
         decoding = self._reserve_decode()
+        if chunk is not None and self.slots[chunk.idx] is not chunk.slot:
+            chunk = None  # preempted to find the step its pages: the chunk's went with them
         unread, self._flying = self._flying, None  # step n (a drain in the reserve has read it)
-        if decoding:
+        if decoding or chunk is not None:
             rode = dict(unread.slots) if unread is not None else {}
             joins = [(i, s.cur_token) for i, s in decoding if rode.get(i) is not s]
             active = np.zeros((self.max_slots,), bool)
             active[[i for i, _s in decoding]] = True
             if unread is not None:
                 tokens_j = unread.tokens
-                self.steps_overlapped += 1
             else:
                 tokens = np.zeros((self.max_slots,), np.int32)
                 for i, tok in joins:
@@ -1562,11 +1630,23 @@ class ServingEngine:
             for i, tok in joins:
                 tokens_j = set_token(tokens_j, np.int32(i), np.int32(tok) if isinstance(tok, int) else tok)
             active_j = jnp.asarray(active)
-            self.step_count += 1
-            self._phase("decode_dispatch", batch=len(decoding), step=self.step_count)
-            logits, next_tokens, self.pages.cache = paged_decode_step(
-                self.params, self.cfg, tokens_j, self.pages.cache, active_j, self.attn_impl
-            )
+            if decoding:
+                self.step_count += 1
+                if unread is not None:
+                    self.steps_overlapped += 1
+                self._phase("decode_dispatch", batch=len(decoding), step=self.step_count)
+            else:  # the chunk alone: its launch
+                self._phase("prefill_dispatch", request_id=chunk.slot.request.id)
+            if chunk is None:
+                self._note_program("paged_decode_step")
+                logits, next_tokens, self.pages.cache = paged_decode_step(
+                    self.params, self.cfg, tokens_j, self.pages.cache, active_j, self.attn_impl
+                )
+            else:
+                self._note_program("paged_decode_step", chunk.bucket)
+                logits, next_tokens, self.pages.cache, chunk_logits, chunk_token = paged_decode_step(
+                    self.params, self.cfg, tokens_j, self.pages.cache, active_j, self.attn_impl, chunk=chunk.args
+                )
             if any(s.request.temperature > 0 for _i, s in decoding):
                 # one extra fixed-shape dispatch ONLY when a sampling request is
                 # in the batch — a pure-greedy batch keeps the PR 9 single-
@@ -1584,17 +1664,26 @@ class ServingEngine:
                 self.sampled_tokens += n_sampled
                 SERVING_SAMPLED_TOKENS.inc(n_sampled)
             self._phases.launched(next_tokens)
-            self.decode_rows += len(decoding)
-            self._flying = _Unread(decoding, self._with_counts(next_tokens), tokens=next_tokens, step=self.step_count)
-            SERVING_BATCH_OCCUPANCY.observe(float(len(decoding)))
             if self.moe_expert_layers:
-                self._note_moe_call(len(decoding))
-            for _i, s in decoding:
-                s.pos += 1  # the fed token is written at its position: counted at launch
-                s.ahead += 1
+                self._note_moe_call(len(decoding) + (chunk.length if chunk is not None else 0))
+            if decoding:
+                self.decode_rows += len(decoding)
+                self._flying = _Unread(decoding, self._with_counts(next_tokens), tokens=next_tokens, step=self.step_count)
+                SERVING_BATCH_OCCUPANCY.observe(float(len(decoding)))
+                for _i, s in decoding:
+                    s.pos += 1  # the fed token is written at its position: counted at launch
+                    s.ahead += 1
+            if chunk is not None:
+                self._phase("emit", request_id=chunk.slot.request.id)
+                self._chunk_ran(chunk)
+                self.prefill_chunks_fused += 1
         if unread is not None:
             self._collect_step(unread)
         self._collect_first()
+        if chunk is not None and chunk.slot.prefill_done >= len(chunk.slot.prefill_tokens):
+            self._chunk_done(chunk, chunk_logits, chunk_token)
+            if chunk.slot.request._export:
+                self._collect_first()  # an export ships the token with live pages: read at once
 
     def _collect_step(self, step: _Unread) -> None:
         """Read a launched decode step's tokens and emit them. A token goes
@@ -1707,7 +1796,7 @@ class ServingEngine:
             self.sampled_tokens += n_sampled
             SERVING_SAMPLED_TOKENS.inc(n_sampled)
         if self.spans_on:
-            rep = min(decoding, key=lambda t: t[1].admitted_step)[1].request
+            rep = min(decoding, key=lambda t: t[1].admitted)[1].request
             if rep.trace_context is not None:
                 tracing.record_span(
                     "serving.spec_verify",
@@ -1748,6 +1837,8 @@ class ServingEngine:
         seeds_j, temps_j = jnp.asarray(seeds), jnp.asarray(temps)
         top_ks_j, top_ps_j = jnp.asarray(top_ks), jnp.asarray(top_ps)
         self._phase("decode_dispatch", batch=len(group))
+        self._note_program("draft.paged_decode_step")
+        self._note_program("paged_verify_step")
 
         # 1) draft chain: propose k tokens with the SAME (seed, index) keys
         # the target will sample with — a good draft then agrees often even
@@ -1934,6 +2025,10 @@ class ServingEngine:
             "prefill_bucket_tokens": self.prefill_bucket_tokens,
             "prefill_kv_attended": self.prefill_kv_attended,
             "prefill_kv_span": self.prefill_kv_span,
+            "prefill_chunks_fused": self.prefill_chunks_fused,
+            # the step programs (name/bucket) dispatched so far: what a warm-up readied
+            "step_programs": sorted(self._step_programs),
+            "step_programs_ready": len(self._step_programs),
             "tracing": {"span_write_seconds": tracing.span_write_seconds()},
             "max_slots": self.max_slots,
             "active_slots": active,

@@ -159,9 +159,10 @@ def test_the_counters_against_a_hand_count_with_one_forced_preemption(tiny_model
     params, cfg = tiny_model
     engine = _engine(params, cfg, prefix_cache=False)
     older = engine.submit(list(range(1, 21)), max_new_tokens=8)  # 20 tokens: bucket 32
-    _iterate(engine)  # admitted, prefilled, one decode step launched
+    _iterate(engine)  # admitted, its chunk launched alone: its first token in flight
     younger = engine.submit(list(range(30, 40)), max_new_tokens=8)  # 10 tokens: bucket 16
-    _iterate(engine)  # the younger: admitted, prefilled, and both decode: that step is in flight
+    _iterate(engine)  # the younger admitted, its chunk launched inside the older's first step
+    _iterate(engine)  # both decode: that step is in flight
     assert len(older.tokens) == 2 and len(younger.tokens) == 1 and engine.stats()["loop"]["drains"] == 0
     assert engine._preempt_youngest() and younger.preemptions == 1 and older.preemptions == 0
     # the step in flight was read first: its token is part of what the younger prefills again
@@ -197,8 +198,8 @@ def test_a_steady_iteration_launches_step_n_plus_one_before_it_reads_step_n(tiny
         del phases[:]
         _iterate(engine)
         names = [name for name, _attrs in phases]
-        if before is not None and engine._flying is not None and "prefill_dispatch" not in names:
-            # a steady iteration: nothing to admit or prefill, one step in flight on entry
+        if before is not None and engine._flying is not None and not {"prefill_prep", "prefill_sync"} & set(names):
+            # a steady iteration: nothing to admit, prefill or read of a chunk, one step in flight on entry
             assert names == ["decode_prep", "decode_dispatch", "decode_sync", "emit"]
             # the sync reads the step BEFORE the one just launched
             assert dict(phases)["decode_sync"]["batch"] == len(before.slots)
@@ -247,9 +248,16 @@ def test_a_chunk_s_first_token_is_read_behind_the_launch_of_the_step_it_joins(ti
     _iterate(engine)
     del phases[:]
     joiner = engine.submit(list(range(30, 41)), max_new_tokens=9)
-    _iterate(engine)  # the joiner's whole prompt is one chunk; both decode in the step launched here
+    _iterate(engine)  # the joiner's whole prompt is one chunk, launched inside the older's step
     assert phases == [
-        "admit", "prefill_prep", "prefill_dispatch", "emit",  # the chunk is launched; nothing is read
+        "admit", "prefill_prep",  # the chunk is made ready; nothing is launched
+        "decode_prep", "decode_dispatch", "emit",  # the older's step carries the chunk; the chunk's counters
+        "decode_sync", "emit",  # the older's step before it; the joiner's first token is not read
+    ]
+    assert len(joiner.tokens) == 0 and [s.request for _i, s in engine._flying.slots] == [older]
+    del phases[:]
+    _iterate(engine)  # both decode in the step launched here
+    assert phases == [
         "decode_prep", "decode_dispatch",  # the step both are in, the joiner on the device's own first token
         "decode_sync", "emit",  # the older's step before it
         "prefill_sync", "emit",  # only now the joiner's first token

@@ -628,11 +628,11 @@ def test_a_request_that_ends_by_count_is_in_no_further_step(tiny_model):
     while not (short.done and long.done):
         _iterate(engine)
         in_steps.append(sorted(s.request.id for _i, s in engine._flying.slots) if engine._flying is not None else [])
-    # iteration 1 prefills `short` (its first token and step 1), iteration 2 `long`: short's third
-    # token is step 2's, so it is in steps 1 and 2 and in no other, though its last token is unread
-    # when step 3 is put together
-    assert [short.id in ids for ids in in_steps] == [True, True] + [False] * (len(in_steps) - 2)
-    assert [long.id in ids for ids in in_steps] == [False] + [True] * 5 + [False]
+    # iteration 1 launches `short`'s chunk alone (its first token), iteration 2 `long`'s inside step 1
+    # (short's second): short's third token is step 2's, so it is in steps 1 and 2 and in no other,
+    # though its last token is unread when step 3 is put together
+    assert [short.id in ids for ids in in_steps] == [False, True, True] + [False] * (len(in_steps) - 3)
+    assert [long.id in ids for ids in in_steps] == [False, False] + [True] * 5 + [False]
     assert len(short.tokens) == 3 and len(long.tokens) == 6
     stats = engine.stats()
     assert stats["loop"]["tokens_discarded"] == 0 and stats["steps"] == 6 and stats["loop"]["drains"] == 0

@@ -70,16 +70,15 @@ def test_a_decoding_slot_s_window_row_never_exceeds_the_window_and_one_page(mode
     engine = engine_of(model)
     pages = engine.pages
     seen = {"decode": 0, "prefill": 0}
-    reserve_decode = engine._reserve_decode
+    decode_step = engine._decode_step
 
     def watched():
-        decoding = reserve_decode()
+        decode_step()  # the launch: the decoding slots' next page taken, a chunk's window trimmed
         for i, s in enumerate(engine.slots):
             if s is not None:
                 seen[s.state] = max(seen[s.state], len(pages.window_pages[i]))
-        return decoding
 
-    engine._reserve_decode = watched
+    engine._decode_step = watched
     engine.start()
     try:
         handles = [engine.submit(p, 40) for p in prompts_of(1, (70, 5, 30, 100, 17))]
@@ -138,16 +137,26 @@ def iterate(engine, drained):
 
 
 @pytest.mark.parametrize("preset", ["tiny-mimo", "tiny-laguna"])
-def test_positions_counted_at_launch_turn_the_window_pool_over_as_a_loop_that_reads_every_step(models, preset):
+def test_positions_counted_at_launch_turn_the_window_pool_over_as_a_loop_that_reads_every_step(models, preset, monkeypatch):
     """ISSUE 35: with a step in flight the window pool's `trim` / `reserve`
     see a position the host counted at launch, not one it read back. The
     streams are the drained loop's, every step's window row is the window and
     the page being written, and the expert counters that ride with a step's
     tokens add up the same. (That these streams lie by the plain reference is
     tests/test_serving_two_pools_reference.py, through the same loop.)"""
+    from modal_tpu.models import paged_kv
+
     prompts = prompts_of(7, (70, 5, 30, 100, 17))
-    served, stats = {}, {}
+    served, stats, launches = {}, {}, {}
+    step = paged_kv.paged_decode_step
+
+    def counted(*args, **kwargs):
+        launches[drained].append(bool(np.asarray(args[4]).any()))  # whether a slot decodes in it
+        return step(*args, **kwargs)
+
+    monkeypatch.setattr(paged_kv, "paged_decode_step", counted)
     for drained in (True, False):
+        launches[drained] = []
         engine = engine_of(models[preset])
         pages = engine.pages
         handles = [engine.submit(p, 36) for p in prompts]  # 36 tokens cross a window of 8 four times
@@ -167,7 +176,18 @@ def test_positions_counted_at_launch_turn_the_window_pool_over_as_a_loop_that_re
         assert pages.window_allocator.free_pages == pages.window_allocator.num_pages - 1 and pages.free_pages == pages.total_pages
     assert served[False] == served[True] and all(len(t) == 36 for t in served[False])
     piped, synced = stats[False], stats[True]
-    assert piped["moe"] == synced["moe"] and piped["kv_window_pages_released"] == synced["kv_window_pages_released"] > 0
+    # the (token, expert) pairs add up the same
+    same = ("assignments", "local_assignments")
+    assert {k: piped["moe"][k] for k in same} == {k: synced["moe"][k] for k in same}
+    # a launch is one call an expert layer and held expert, and a launch is a step or a chunk where no slot
+    # decodes: a loop that reads every step frees an ended slot and admits an iteration sooner, so how many
+    # chunks ran alone (and the experts a call touched) is the loop's own
+    calls = engine.moe_expert_layers * engine.cfg.experts_held[1]
+    for drained, counts in ((False, piped), (True, synced)):
+        alone = launches[drained].count(False)
+        assert counts["moe"]["expert_calls"] == calls * len(launches[drained]) == calls * (counts["steps"] + alone)
+        assert 0 < alone <= counts["prefill_chunks"] == counts["prefill_chunks_fused"]
+    assert piped["kv_window_pages_released"] == synced["kv_window_pages_released"] > 0
     assert piped["loop"]["steps_overlapped"] > 0.9 * piped["steps"] and synced["loop"]["steps_overlapped"] == 0
     # nothing forced a read of what was in flight: the only drains are the periodic ones
     assert piped["loop"]["tokens_discarded"] == piped["preemptions"] == 0 and piped["loop"]["drains"] == piped["steps"] // 16
@@ -188,7 +208,7 @@ def test_admission_waits_while_either_pool_lacks_room(model, pool):
     engine._admit()
     assert engine.slots[0] is not None and not engine.waiting
     assert len(pages.pages[0]) == 6 and pages.window_pages[0] == []  # its chunk's pages come when its chunk runs
-    engine._prefill_one()
+    engine._decode_step()  # the chunk made ready and launched
     assert len(pages.window_pages[0]) == WINDOW // PAGE  # 16 of 20 tokens in: what the query at 16 still sees
     pages.release(0)
 
@@ -230,7 +250,25 @@ def test_reserve_is_all_or_none_over_both_pools_and_every_slot(model, dry):
     assert [list(table[i, :4]) for i in (0, 1)] == pages.window_pages
 
 
-def test_preemption_frees_both_pools_and_the_streams_do_not_change(model):
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_preemption_frees_both_pools_and_the_streams_do_not_change(model, dtype):
+    """A preempted request re-prefills its prompt and the tokens it kept
+    through both pools and loses no token: up to where it was preempted its
+    stream is the one it had unpreempted, and from there the one a fresh
+    request of those tokens gets. In float32 that is the unpreempted stream
+    throughout. In bfloat16 the re-prefill attends, in chunks, positions
+    that decode steps wrote a row at a time, and the two round apart: a
+    greedy token can flip after a re-prefill, here as on an engine that
+    launches the chunk on its own (`paged_prefill`) and preempts the same
+    request at the same token."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    if dtype == "float32":
+        params, cfg = model
+        model = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params), dataclasses.replace(cfg, dtype=jnp.float32)
     prompts = prompts_of(3, (60, 44, 70, 25))
     roomy = engine_of(model).start()
     try:
@@ -238,14 +276,39 @@ def test_preemption_frees_both_pools_and_the_streams_do_not_change(model):
     finally:
         roomy.stop()
     # a full pool of 44 pages = 176 positions cannot hold three of these to their ends
-    tight = engine_of(model, num_pages=45).start()
+    tight = engine_of(model, num_pages=45)
+    kept: dict = {}  # request id -> the tokens it kept at each preemption
+    preempt = tight._preempt_youngest
+
+    def noted():
+        if not preempt():
+            return False
+        victim = tight.waiting[0]  # requeued at the front
+        kept.setdefault(victim.id, []).append(len(victim.tokens))
+        return True
+
+    tight._preempt_youngest = noted
+    tight.start()
     try:
-        got = [h.result(timeout=300) for h in [tight.submit(p, 50) for p in prompts]]
+        handles = [tight.submit(p, 50) for p in prompts]
+        got = [h.result(timeout=300) for h in handles]
         stats = tight.stats()
     finally:
         tight.stop()
-    assert stats["preemptions"] > 0
-    assert got == want  # a preempted request re-prefills through both pools and loses no token
+    assert stats["preemptions"] == sum(map(len, kept.values())) > 0
+    fresh = engine_of(model).start()
+    try:
+        for prompt, handle, stream, unpreempted in zip(prompts, handles, got, want):
+            marks = kept.get(handle.id, [])
+            first = marks[0] if marks else len(stream)
+            assert stream[:first] == unpreempted[:first]
+            for at, until in zip(marks, marks[1:] + [len(stream)]):
+                again = fresh.submit(prompt + stream[:at], 50 - at).result(timeout=300)
+                assert stream[at:until] == again[: until - at]
+    finally:
+        fresh.stop()
+    if dtype == "float32":
+        assert got == want
     assert tight.pages.free_pages == 44
     assert tight.pages.window_allocator.free_pages == tight.pages.window_allocator.num_pages - 1
 
